@@ -2,8 +2,9 @@
 """CI cache-effectiveness check: the compile-once contract, enforced.
 
 Runs a small fit + predict workload TWICE, each in a fresh subprocess,
-against one temporary ``MXNET_COMPILE_CACHE_DIR``.  The first run is
-cold (it populates the persistent XLA compile cache); the second run
+against one ``JAX_COMPILATION_CACHE_DIR`` — a fixed directory under the
+checkout, emptied first (the path is part of JAX's cache key).  The first
+run is cold (it populates the persistent XLA compile cache); the second run
 must perform ZERO XLA compilations — every executable (train step,
 fused update, eval forward, predictor buckets) must load from the
 cache.  Any persistent-cache miss in the second run means an
@@ -23,7 +24,6 @@ import os
 import shutil
 import subprocess
 import sys
-import tempfile
 
 _WORKLOAD = r"""
 import json, os, sys
@@ -57,7 +57,8 @@ print("CCCHECK " + json.dumps(compile_cache.stats()), flush=True)
 
 def _run_once(cache_dir, repo_root):
     env = dict(os.environ,
-               MXNET_COMPILE_CACHE_DIR=cache_dir,
+               JAX_COMPILATION_CACHE_DIR=cache_dir,
+               JAX_ENABLE_COMPILATION_CACHE="true",
                CCCHECK_REPO=repo_root,
                JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"))
     proc = subprocess.run([sys.executable, "-c", _WORKLOAD], env=env,
@@ -75,7 +76,9 @@ def _run_once(cache_dir, repo_root):
 
 def main():
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cache_dir = tempfile.mkdtemp(prefix="cccheck_")
+    cache_dir = os.path.join(repo_root, ".jax_cache_probe", "ci")
+    shutil.rmtree(cache_dir, ignore_errors=True)
+    os.makedirs(cache_dir)
     try:
         cold = _run_once(cache_dir, repo_root)
         if cold is None:

@@ -5,8 +5,10 @@ Reference: ``example/image-classification/common/fit.py`` — lr-factor
 scheduling (:6-23), checkpoint resume (:24-35), per-rank checkpoint
 prefixes, ``--kv-store device`` default, ``--test-io`` IO-throughput mode,
 ``--benchmark`` synthetic-data mode.  TPU notes: ``--kv-store device``
-maps to an in-XLA allreduce over the chip mesh; ``--dtype bfloat16``
-is the fp16-analog low-precision mode.
+maps to an in-XLA allreduce over the chip mesh.  Training is float32:
+``Module`` has no dtype argument, so ``--dtype bfloat16`` is refused
+with the path that is missing (``bench.py`` casts the bound arrays in
+place after ``init_params``; nothing public does).
 """
 
 import argparse
@@ -87,13 +89,21 @@ def add_fit_args(parser):
     train.add_argument("--test-io", type=int, default=0,
                        help="1 = measure input-pipeline throughput only")
     train.add_argument("--dtype", type=str, default="float32",
-                       choices=("float32", "bfloat16"))
+                       choices=("float32", "bfloat16"),
+                       help="float32 only: bfloat16 is refused, Module "
+                            "cannot bind or initialise bf16 parameters")
     train.add_argument("--monitor", dest="monitor", type=int, default=0)
     return train
 
 
 def fit(args, network, data_loader, **kwargs):
     """reference fit.py fit() — the full train flow."""
+    if args.dtype != "float32":
+        raise NotImplementedError(
+            "--dtype %s: Module.bind/init_params create float32 "
+            "parameters whatever the iterator's dtype, and there is no "
+            "public cast (bench.py rewrites Executor.arg_dict in place)"
+            % args.dtype)
     kv = mx.kvstore.create(args.kv_store)
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)-15s Node[" + str(kv.rank)
@@ -129,9 +139,14 @@ def fit(args, network, data_loader, **kwargs):
 
     checkpoint = _save_model(args, kv.rank)
     ctx = mx.tpu() if mx.num_tpus() > 0 else mx.cpu()
+    logging.info("training on %s (%s)", ctx, ctx.jax_device().device_kind)
     model = mx.mod.Module(symbol=network, context=ctx)
 
-    eval_metrics = ["accuracy"]
+    # callers (chip_smoke.py) may add metrics and per-batch callbacks
+    eval_metrics = ["accuracy"] + list(kwargs.pop("extra_metrics", ()))
+    batch_end_callbacks = [
+        mx.callback.Speedometer(args.batch_size, args.disp_batches)] \
+        + list(kwargs.pop("extra_batch_end_callbacks", ()))
     if args.top_k > 0:
         eval_metrics.append(mx.metric.create("top_k_accuracy",
                                              top_k=args.top_k))
@@ -151,8 +166,7 @@ def fit(args, network, data_loader, **kwargs):
               initializer=initializer,
               arg_params=arg_params,
               aux_params=aux_params,
-              batch_end_callback=mx.callback.Speedometer(args.batch_size,
-                                                         args.disp_batches),
+              batch_end_callback=batch_end_callbacks,
               epoch_end_callback=checkpoint,
               allow_missing=True,
               monitor=monitor,

@@ -6,6 +6,7 @@ Reference: ``example/image-classification/score.py`` (loads
 """
 
 import argparse
+import logging
 import os
 import sys
 
@@ -21,6 +22,7 @@ def score(model_prefix, epoch, val_iter, metrics, batch_size):
     sym, arg_params, aux_params = mx.model.load_checkpoint(model_prefix,
                                                            epoch)
     ctx = mx.tpu() if mx.num_tpus() > 0 else mx.cpu()
+    logging.info("scoring on %s (%s)", ctx, ctx.jax_device().device_kind)
     mod = mx.mod.Module(symbol=sym, context=ctx)
     mod.bind(for_training=False, data_shapes=val_iter.provide_data,
              label_shapes=val_iter.provide_label)

@@ -15,8 +15,9 @@ from . import telemetry
 from . import perfdebug
 from . import faults
 from . import compile_cache
-# MXNET_COMPILE_CACHE_DIR arms the persistent XLA compile cache before
-# any executor build can compile (no-op when unset; never raises)
+# arm the persistent XLA compile cache (JAX_COMPILATION_CACHE_DIR, else
+# the fixed in-checkout default) before any executor build can compile;
+# never raises, and initialises no backend
 compile_cache._init_from_env()
 from . import retry
 from . import elastic

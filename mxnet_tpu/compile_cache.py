@@ -8,11 +8,15 @@ records.  This module treats compiled executables as durable, reusable
 artifacts — the whole-program-compilation idiom of AOT-XLA (Julia→TPU)
 and TVM's compiled-kernel artifact reuse — in two tiers:
 
-**Tier 1 — the persistent compilation cache.**  ``MXNET_COMPILE_CACHE_DIR``
-(or :func:`enable`) points JAX's persistent compilation cache at a
-directory: every XLA compile first consults the on-disk cache and only
-compiles on a miss, writing the serialized executable back for the next
-process.  This module owns the operational half the raw JAX knob lacks:
+**Tier 1 — the persistent compilation cache.**  On by default: every
+XLA compile first consults JAX's on-disk cache and only compiles on a
+miss, writing the serialized executable back for the next process.  The
+directory is ``JAX_COMPILATION_CACHE_DIR`` when that is set — this
+module adopts it and never points JAX anywhere else — and otherwise the
+one fixed path :data:`DEFAULT_DIR` inside the checkout (the path is part
+of JAX's cache key, so a directory that moves never hits).
+``JAX_ENABLE_COMPILATION_CACHE=false`` keeps the cache off.  This module
+owns the operational half the raw JAX knob lacks:
 
 * size/GC bounds — ``MXNET_COMPILE_CACHE_MAX_BYTES`` caps the directory,
   :func:`gc` evicts least-recently-used entries (the ``-atime`` sidecar
@@ -75,7 +79,8 @@ from . import telemetry as _telemetry
 from .base import MXNetError, atomic_write
 
 __all__ = [
-    "enabled", "recording", "enable", "disable", "cache_dir", "stats",
+    "DEFAULT_DIR", "enabled", "recording", "enable", "disable",
+    "cache_dir", "stats",
     "cache_entries", "cache_size_bytes", "gc", "verify", "note_build",
     "instrument", "records", "recording_scope", "reset_records",
     "manifest_path", "save_manifest", "save_manifest_if_changed",
@@ -88,6 +93,12 @@ _log = logging.getLogger("mxnet_tpu.compile_cache")
 #: warm-up manifest schema version (bumped on incompatible changes;
 #: :func:`load_manifest` rejects unknown versions)
 MANIFEST_VERSION = 1
+
+#: the cache directory when ``JAX_COMPILATION_CACHE_DIR`` is unset: one
+#: fixed path inside the checkout (listed in ``.gitignore``)
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
 #: suffixes of one persistent-cache entry: JAX writes the compressed
 #: serialized executable to ``<key>-cache`` and touches ``<key>-atime``
@@ -155,9 +166,12 @@ def _env_float(name, default):
 def enable(directory=None, max_bytes=None):
     """Activate the two-tier compile cache.
 
-    ``directory`` defaults to ``MXNET_COMPILE_CACHE_DIR``; ``max_bytes``
-    to ``MXNET_COMPILE_CACHE_MAX_BYTES`` (0 = unbounded).  Configures
-    JAX's persistent compilation cache (min-compile-time floor from
+    The directory is ``JAX_COMPILATION_CACHE_DIR`` when that variable is
+    set (JAX has already adopted it; a different ``directory`` argument
+    is an error, never a silent override), else ``directory``, else
+    :data:`DEFAULT_DIR`.  ``max_bytes`` defaults to
+    ``MXNET_COMPILE_CACHE_MAX_BYTES`` (0 = unbounded).  Configures JAX's
+    persistent compilation cache (min-compile-time floor from
     ``MXNET_COMPILE_CACHE_MIN_COMPILE_SECS``, default 0 so every
     program is cached; corrupt-entry reads NON-fatal), installs the
     hit/miss telemetry listeners, sweeps zero-length entries (full
@@ -165,20 +179,26 @@ def enable(directory=None, max_bytes=None):
     enforces the size bound.  Idempotent; safe to call after compiles
     already happened (JAX's cached "cache unused" verdict is reset)."""
     global _dir, _max_bytes
-    directory = directory or os.environ.get("MXNET_COMPILE_CACHE_DIR", "")
-    if not directory:
-        raise MXNetError(
-            "compile_cache.enable needs a directory (argument or "
-            "MXNET_COMPILE_CACHE_DIR)")
-    directory = os.path.abspath(directory)
-    os.makedirs(directory, exist_ok=True)
-    if max_bytes is None:
-        max_bytes = _env_int("MXNET_COMPILE_CACHE_MAX_BYTES", 0)
     import jax
     from jax._src import compilation_cache as _jcc
 
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if placed:
+        if directory and os.path.abspath(directory) \
+                != os.path.abspath(placed):
+            raise MXNetError(
+                "compile_cache.enable(%r): JAX_COMPILATION_CACHE_DIR=%r "
+                "already places the cache; unset it to choose another "
+                "directory" % (directory, placed))
+        # JAX read the variable itself: adopt its spelling untouched
+        directory = placed
+    else:
+        directory = os.path.abspath(directory or DEFAULT_DIR)
+        jax.config.update("jax_compilation_cache_dir", directory)
+    os.makedirs(directory, exist_ok=True)
+    if max_bytes is None:
+        max_bytes = _env_int("MXNET_COMPILE_CACHE_MAX_BYTES", 0)
     jax.config.update("jax_enable_compilation_cache", True)
-    jax.config.update("jax_compilation_cache_dir", directory)
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs",
         float(os.environ.get("MXNET_COMPILE_CACHE_MIN_COMPILE_SECS", "0")
@@ -214,7 +234,9 @@ def enable(directory=None, max_bytes=None):
 
 
 def disable():
-    """Deactivate tier 1 + tier 2 recording (entries on disk are kept)."""
+    """Deactivate tier 1 + tier 2 recording (entries on disk are kept).
+    JAX's directory setting is left alone — only its enable flag flips,
+    so a ``JAX_COMPILATION_CACHE_DIR`` placement survives a re-enable."""
     global _dir
     import jax
     from jax._src import compilation_cache as _jcc
@@ -222,20 +244,22 @@ def disable():
     with _lock:
         _dir = None
     jax.config.update("jax_enable_compilation_cache", False)
-    jax.config.update("jax_compilation_cache_dir", None)
     _jcc.reset_cache()
 
 
 def _init_from_env():
-    """Package-import hook: arm from ``MXNET_COMPILE_CACHE_DIR`` when
-    set; never raises (a bad cache dir must not break import)."""
-    if _dir is not None or not os.environ.get("MXNET_COMPILE_CACHE_DIR"):
+    """Package-import hook: arm the cache (``JAX_COMPILATION_CACHE_DIR``
+    or :data:`DEFAULT_DIR`) unless ``JAX_ENABLE_COMPILATION_CACHE`` turned
+    it off; never raises (a bad cache dir must not break import)."""
+    import jax
+
+    if _dir is not None or not jax.config.jax_enable_compilation_cache:
         return
     try:
         enable()
     except Exception as e:  # noqa: broad-except — import-time guard
-        _log.warning("compile_cache: could not enable from "
-                     "MXNET_COMPILE_CACHE_DIR: %s", e)
+        _log.warning("compile_cache: could not enable the persistent "
+                     "cache: %s", e)
 
 
 # -- telemetry listeners ----------------------------------------------------
@@ -324,11 +348,12 @@ def _install_read_fault_shim():
 
     _orig_get = _jcc.get_executable_and_time
 
-    def _guarded(cache_key, compile_options, backend):
+    def _guarded(cache_key, compile_options, backend, executable_devices):
         if _dir is not None and _faults.should_fire("compile_cache.read"):
             _truncate_entry(cache_key)
         try:
-            return _orig_get(cache_key, compile_options, backend)
+            return _orig_get(cache_key, compile_options, backend,
+                             executable_devices)
         except Exception:
             if _dir is not None:
                 _drop_entry(cache_key,

@@ -12,6 +12,7 @@ copy/compute overlap the reference got from pinned-memory copy workers).
 
 from __future__ import annotations
 
+import contextlib
 import gzip
 import logging
 import os
@@ -1082,6 +1083,32 @@ def _mp_decode_worker(ctor_kwargs, shm_names, data_shape, label_shape,
         s.close()
 
 
+_child_env_lock = threading.Lock()
+
+
+@contextlib.contextmanager
+def _host_only_child_env():
+    """Environment for starting a decode worker: ``JAX_PLATFORMS=cpu``.
+
+    A chip belongs to one process, and the parent that feeds it holds
+    it.  The worker imports this package (and so JAX) and hands batches
+    back as NDArrays, which would initialise whatever backend JAX
+    defaults to — a second claim on the parent's chip that fails or
+    hangs.  A ``spawn`` child inherits ``os.environ`` as it stands at
+    ``start()``, so the variable is set across that call and restored;
+    the parent's own JAX read it at import and is unaffected."""
+    with _child_env_lock:
+        prev = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        try:
+            yield
+        finally:
+            if prev is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = prev
+
+
 class MultiProcessIter(DataIter):
     """Host-sharded multi-process decode (round-4/5 IO-scaling design).
 
@@ -1139,7 +1166,8 @@ class MultiProcessIter(DataIter):
                             args=(kw, [s.name for s in shms], full_data,
                                   label_shape, cmd_q, free_q, out_q),
                             daemon=True)
-            p.start()
+            with _host_only_child_env():
+                p.start()
             self._workers.append(p)
             self._cmd_qs.append(cmd_q)
             self._free_qs.append(free_q)

@@ -24,7 +24,7 @@ parameters the update itself is sharded over the batch axis —
   HBM drops ~world-size (``optimizer_state_hbm`` pins it);
 * the updated rows **all-gather** back into the replicated parameter.
 
-The sharded update runs under :func:`~jax.experimental.shard_map` with
+The sharded update runs under :func:`jax.shard_map` with
 the collectives spelled explicitly (``all_gather`` / ``psum`` over the
 named batch axis), so graftlint's ``collective-consistency`` pass can
 prove the axis vocabulary and CI's seeded-mutation test can verify a
@@ -166,7 +166,6 @@ def zero_sgd_update(mesh, momentum, rescale_grad, clip_gradient,
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from .executor import sgd_step_math
@@ -200,11 +199,11 @@ def zero_sgd_update(mesh, momentum, rescale_grad, clip_gradient,
         in_specs = (P(axis_name), P(axis_name), P(), P())
         out_specs = (P(), P()) if guard else (P(),)
 
-    # check_rep=False: the replicated outputs are established by the
-    # explicit all_gather/psum above, which this jax version's static
-    # replication checker cannot see through
-    sm = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    # check_vma=False: the replicated outputs are established by the
+    # explicit all_gather/psum above, which the static replication
+    # checker cannot see through
+    sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
 
     def apply(p, g, m, lr, wd):
         res = sm(p, g, m, lr, wd) if has_mom else sm(p, g, lr, wd)
@@ -302,7 +301,6 @@ def build_replica_audit(mesh, axis_name=DATA_AXIS):
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(arrays):
@@ -313,10 +311,10 @@ def build_replica_audit(mesh, axis_name=DATA_AXIS):
         first = jnp.argmax(bad).astype(jnp.int32)      # 0 when clean
         return jnp.stack([count, first])
 
-    # check_rep=False: the gathered comparison establishes the
+    # check_vma=False: the gathered comparison establishes the
     # replicated output itself — same rationale as zero_sgd_update
-    sm = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                   check_rep=False)
+    sm = jax.shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
+                       check_vma=False)
     return jax.jit(lambda arrays: sm(arrays))
 
 

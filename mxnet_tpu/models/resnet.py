@@ -2,8 +2,10 @@
 resnet.py`` — He et al. v2 pre-activation units, the BASELINE.md headline
 model).
 
-TPU notes: default dtype can be bf16 via ``dtype='bfloat16'`` (the fp16
-variant ``resnet-fp16`` of the reference); BN stats stay f32 inside the op.
+TPU notes: the symbol carries no dtype — ``get_symbol`` has no ``dtype``
+argument and ``Module`` binds float32.  A bf16 step exists only where the
+caller casts the bound arrays itself (``bench.py``); BN stats then stay
+f32 inside the op.
 """
 
 from .. import symbol as sym

@@ -792,7 +792,7 @@ def _invoke(op, args, kwargs):
         # trace-time device hint: lowering decisions (Pallas vs XLA)
         # follow the op's device, not the process default backend — set
         # BEFORE the cache lookup (the jit cache keys on the device)
-        tok = _reg.trace_device.set(octx.device_type)
+        tok = _reg.trace_device.set(octx.platform)
         try:
             fn = _reg.jitted_apply(op.name, _reg.attrs_key(attrs), True)
             if inputs:

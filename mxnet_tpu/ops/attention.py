@@ -16,7 +16,8 @@ Design:
   (m, l, acc) across K steps — the canonical TPU flash-attention schedule
   (MXU for the two dots, VPU for the online-softmax rescale).
 * ``flash_attention`` — ``jax.custom_vjp``: forward picks the Pallas kernel
-  on TPU (tile-aligned shapes) else the scan; backward recomputes blockwise
+  on a TPU trace (``_kernel_refusal`` says when not, and the choice is
+  counted under ``ops.kernel_path``) else the scan; backward recomputes blockwise
   from the saved (o, lse) residuals — the standard FA2 backward, written as
   plain JAX matmuls per K block so XLA schedules them on the MXU.
 
@@ -199,10 +200,7 @@ def _flash_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
             # lse rides as (bh, lq, 1) so the block's minor-two dims are
             # (block_q, 1) — sublane divisible by 8, lane equal to the
             # array dim.  A (1, block_q) block puts 1 in the sublane
-            # slot and fails Mosaic's tile rule — which silently meant
-            # this kernel NEVER lowered on real TPU until round 5 (the
-            # d%128 gate routed the only hardware test through the scan
-            # path)
+            # slot and fails Mosaic's tile rule
             pl.BlockSpec((1, block_q, 1), lambda b_, q_, k_: (b_, q_, 0)),
         ],
         out_shape=[
@@ -222,14 +220,29 @@ def _flash_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
     return out.reshape(b, h, lq, d), lse.reshape(b, h, lq)
 
 
-def _use_pallas(q, k, block_q, block_k):
-    if jax.default_backend() != "tpu":
-        return False
+def _kernel_refusal(q, k, block_q, block_k):
+    """Why the Pallas kernel cannot take this call (``ops.kernel_path``
+    reason), or None when it can.
+
+    The rule is what the v5e compiler accepts, asked of it shape by shape
+    (``tests/test_tpu_aot_compile.py`` holds the kernel to it): the grid
+    needs each block to divide its sequence, and Mosaic needs a block's
+    second-minor extent divisible by 8 unless the block spans the whole
+    dimension.  The head dim is always a whole dimension of its block,
+    so every head width lowers — 64 included; an earlier ``d % 128``
+    gate kept such heads off the kernel for no reason the compiler
+    gives."""
+    from .registry import on_tpu
+
+    if not on_tpu():
+        return "not_tpu"
     lq, lk = q.shape[2], k.shape[2]
-    d = q.shape[3]
-    return (lq % min(block_q, lq) == 0 and lk % min(block_k, lk) == 0
-            and min(lq, block_q) % 8 == 0 and min(lk, block_k) % 128 == 0
-            and d % 128 == 0)
+    bq, bk = min(block_q, lq), min(block_k, lk)
+    if lq % bq or lk % bk:
+        return "tile"
+    if (bq != lq and bq % 8) or (bk != lk and bk % 8):
+        return "tile"
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -243,9 +256,14 @@ def _flash(q, k, v, causal, scale, block_q, block_k):
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
-    if _use_pallas(q, k, block_q, block_k):
+    from .registry import count_kernel_path
+
+    reason = _kernel_refusal(q, k, block_q, block_k)
+    if reason is None:
+        count_kernel_path("FlashAttention", "pallas", "ok")
         out, lse = _flash_pallas(q, k, v, causal, scale, block_q, block_k)
     else:
+        count_kernel_path("FlashAttention", "xla", reason)
         out, lse = _flash_scan(q, k, v, causal, scale, block_k)
     return out, (q, k, v, out, lse)
 

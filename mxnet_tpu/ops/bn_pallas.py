@@ -75,13 +75,16 @@ def _lane_group(c):
     return 128 if c % 128 == 0 else None
 
 
-def _admissible(n, c, s, itemsize):
+def _refusal(n, c, s, itemsize):
+    """Why a shape cannot take the kernel (``"tile"``: C does not divide
+    into 128-lane groups; ``"vmem"``: one block exceeds the budget), or
+    None when it can."""
     lg = _lane_group(c)
     if lg is None:
-        return None
+        return "tile"
     if s * n * lg * itemsize > _BLOCK_BUDGET:
-        return None
-    return lg
+        return "vmem"
+    return None
 
 
 def _bn_fwd_kernel(x_ref, gamma_ref, beta_ref, y_ref, mean_ref, var_ref, *,
@@ -148,32 +151,34 @@ def _pallas_mode():
     return os.environ.get("MXNET_BN_PALLAS", "0")
 
 
-def _on_tpu():
-    """Device of the computation being traced: the executor/imperative
-    dispatch sets ``registry.trace_device``; outside any such trace fall
-    back to the process default backend."""
-    from .registry import trace_device
-
-    dev = trace_device.get()
-    if dev is not None:
-        return dev == "tpu"
-    return jax.default_backend() == "tpu"
-
-
 def eligible(x):
-    """Whether the Pallas path applies for this input (trace-time)."""
+    """Whether the Pallas path applies for this input (trace-time).
+
+    ``MXNET_BN_PALLAS=1``/``auto`` asks for the compiled kernel, which
+    exists on a TPU trace only; ``interpret`` asks for the Pallas
+    interpreter (the CPU tests of the kernel math) and nothing else ever
+    selects it.  A request that cannot be met takes the XLA path and is
+    counted with its reason (``ops.kernel_path``)."""
+    from .registry import count_kernel_path, on_tpu
+
     mode = _pallas_mode()
     if mode not in ("1", "auto", "interpret"):
         return False
-    if mode != "interpret" and not _on_tpu():
-        return False
     if x.ndim < 2:
+        reason = "tile"
+    else:
+        s = 1
+        for d in x.shape[2:]:
+            s *= d
+        reason = _refusal(x.shape[0], x.shape[1], s, x.dtype.itemsize)
+    if reason is None and mode != "interpret" and not on_tpu():
+        reason = "not_tpu"
+    if reason is not None:
+        count_kernel_path("BatchNorm", "xla", reason)
         return False
-    n, c = x.shape[0], x.shape[1]
-    s = 1
-    for d in x.shape[2:]:
-        s *= d
-    return _admissible(n, c, s, x.dtype.itemsize) is not None
+    count_kernel_path("BatchNorm",
+                      "interpret" if mode == "interpret" else "pallas", "ok")
+    return True
 
 
 def _bn_fwd_call(xt, gamma2, beta2, eps, fix_gamma, relu, interpret):
@@ -181,7 +186,7 @@ def _bn_fwd_call(xt, gamma2, beta2, eps, fix_gamma, relu, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     s, n, c = xt.shape
-    lg = _admissible(n, c, s, xt.dtype.itemsize)
+    lg = _lane_group(c)   # the caller checked eligible()
     kernel = functools.partial(_bn_fwd_kernel, eps=eps,
                                fix_gamma=fix_gamma, relu=relu)
     y, mean, var = pl.pallas_call(
@@ -216,7 +221,7 @@ def _bn_bwd_call(xt, gt, mean2, var2, gamma2, beta2, eps, fix_gamma, relu,
     from jax.experimental.pallas import tpu as pltpu
 
     s, n, c = xt.shape
-    lg = _admissible(n, c, s, xt.dtype.itemsize)
+    lg = _lane_group(c)   # the caller checked eligible()
     kernel = functools.partial(_bn_bwd_kernel, eps=eps,
                                fix_gamma=fix_gamma, relu=relu)
     dx, dgamma, dbeta = pl.pallas_call(
@@ -294,7 +299,7 @@ def bn_train(x, gamma, beta, eps, fix_gamma, relu=False):
     for d in x.shape[2:]:
         s *= d
     xt = x.transpose(spatial_axes + (0, 1)).reshape(s, n, c)
-    interpret = _pallas_mode() == "interpret" or not _on_tpu()
+    interpret = _pallas_mode() == "interpret"
     f = _bn_fused_fn(float(eps), bool(fix_gamma), bool(relu), interpret)
     y, mean, var = f(xt, gamma.reshape(1, c), beta.reshape(1, c))
     y = y.reshape(x.shape[2:] + (n, c)).transpose(

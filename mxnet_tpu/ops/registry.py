@@ -270,6 +270,28 @@ trace_device = _contextvars.ContextVar("mxnet_tpu_trace_device",
                                        default=None)
 
 
+def on_tpu():
+    """Whether the computation being traced targets a TPU: the
+    executor/imperative dispatch sets :data:`trace_device`; outside any
+    such trace the process default backend decides."""
+    dev = trace_device.get()
+    if dev is not None:
+        return dev == "tpu"
+    return jax.default_backend() == "tpu"
+
+
+def count_kernel_path(op, path, reason):
+    """Trace-time record of which lowering a Pallas-capable op took:
+    ``ops.kernel_path{op, path, reason}`` with ``path`` one of
+    ``pallas`` / ``interpret`` (reason ``ok``) or ``xla`` — an op whose
+    kernel was asked for and refused says why (``not_tpu``, ``tile``,
+    ``vmem``, ``dtype``), so a run can print the path each op really
+    took instead of trusting the request."""
+    from .. import telemetry
+
+    telemetry.inc("ops.kernel_path", op=op, path=path, reason=reason)
+
+
 def jitted_apply(op_name, attrs_tuple, is_train):
     # keyed on the trace device too: the traced jaxpr bakes in
     # device-dependent lowering decisions (Pallas vs XLA), so a CPU call

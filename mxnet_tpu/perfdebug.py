@@ -76,9 +76,9 @@ __all__ = [
 _log = logging.getLogger("mxnet_tpu.perfdebug")
 
 #: bf16 dense peak TFLOP/s by PJRT ``device_kind`` (published chip
-#: specs) — the denominator of every MFU figure.  ``MXNET_PEAK_TFLOPS``
-#: (or the bench harness' ``BENCH_PEAK_TFLOPS``) overrides for kinds
-#: not listed.
+#: specs) — the denominator of every MFU figure.  A kind that is not
+#: listed is an error on measurement paths (:func:`device_peak_tflops`),
+#: never a default.
 PEAK_TFLOPS_BY_KIND = {
     "TPU v2": 46.0,
     "TPU v3": 123.0,
@@ -165,13 +165,6 @@ def _shape_sig(args, kwargs):
     return hashlib.sha256("|".join(parts).encode()).hexdigest()[:8]
 
 
-def _first(cost):
-    # older jax returns a one-dict-per-device list from cost_analysis
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else None
-    return cost
-
-
 _MEM_FIELDS = (
     ("argument_bytes", "argument_size_in_bytes"),
     ("output_bytes", "output_size_in_bytes"),
@@ -181,41 +174,30 @@ _MEM_FIELDS = (
 )
 
 
-def _analyze_lowered(lowered):
+def _analyze_compiled(lowered):
     """(fingerprint, flops, bytes_accessed, hbm_breakdown) of one
-    lowered program; compiles it for the cost/memory numbers (falling
-    back to pre-compile cost analysis where the backend supports it)."""
+    lowered program, compiled for the cost/memory numbers.  Raises what
+    the compiler raises."""
     fp = fingerprint_text(lowered.as_text())
-    cost = None
-    mem = {}
-    try:
-        compiled = lowered.compile()
-    except Exception:
-        compiled = None
-    if compiled is not None:
-        try:
-            cost = _first(compiled.cost_analysis())
-        except Exception:
-            cost = None
-        try:
-            m = compiled.memory_analysis()
-            mem = {name: int(getattr(m, attr))
-                   for name, attr in _MEM_FIELDS if hasattr(m, attr)}
-        except Exception:
-            mem = {}
-    if cost is None:
-        try:
-            cost = _first(lowered.cost_analysis())
-        except Exception:
-            cost = None
-    flops = None
-    bytes_accessed = None
-    if cost:
-        if cost.get("flops"):
-            flops = float(cost["flops"])
-        if cost.get("bytes accessed"):
-            bytes_accessed = float(cost["bytes accessed"])
+    compiled = lowered.compile()
+    cost = compiled.cost_analysis() or {}
+    m = compiled.memory_analysis()
+    mem = {name: int(getattr(m, attr)) for name, attr in _MEM_FIELDS}
+    flops = float(cost["flops"]) if cost.get("flops") else None
+    bytes_accessed = float(cost["bytes accessed"]) \
+        if cost.get("bytes accessed") else None
     return fp, flops, bytes_accessed, mem
+
+
+def _analyze_lowered(lowered):
+    """:func:`_analyze_compiled` for the live capture hook, which must
+    never raise into the step: a program that will not compile or cost
+    out keeps its fingerprint and loses the numbers."""
+    try:
+        return _analyze_compiled(lowered)
+    except Exception as e:  # noqa: broad-except — attribution only
+        _log.debug("perfdebug: cost/memory analysis failed: %s", e)
+        return fingerprint_text(lowered.as_text()), None, None, {}
 
 
 def _hbm_total(mem):
@@ -340,17 +322,15 @@ def analyze_signature(sig):
     abstract_args)`` — the shape ``Module._last_bulk_sig`` stores.  Used
     by the bench harnesses to stamp ``hlo_fingerprint`` /
     ``cost_gflops`` / ``hbm_peak_bytes`` onto their JSON rows; one
-    lower+compile covers fingerprint AND cost.  Returns a dict or
-    None."""
-    if sig is None:
-        return None
+    lower+compile covers fingerprint AND cost.  A measurement rests on
+    these numbers, so a program that will not lower, compile or yield a
+    flop count raises instead of returning a partial dict."""
     fn, args = sig
-    try:
-        lowered = fn.lower(*args)
-        fp, flops, bytes_accessed, mem = _analyze_lowered(lowered)
-    except Exception as e:
-        _log.debug("perfdebug: analyze_signature failed: %s", e)
-        return None
+    fp, flops, bytes_accessed, mem = _analyze_compiled(fn.lower(*args))
+    if not flops:
+        raise RuntimeError(
+            "XLA cost analysis reports no flops for the compiled program "
+            "(fingerprint %s)" % fp)
     return {"fingerprint": fp, "flops": flops,
             "bytes_accessed": bytes_accessed, "hbm": mem,
             "hbm_peak_bytes": _device_peak_bytes() or _hbm_total(mem)}
@@ -481,27 +461,17 @@ def diff_fingerprints(path):
 
 
 # -- live MFU ---------------------------------------------------------------
-def device_peak_tflops(device=None):
-    """Rated bf16 dense peak of ``device`` (default: first local
-    device): ``MXNET_PEAK_TFLOPS`` / ``BENCH_PEAK_TFLOPS`` override,
-    else the :data:`PEAK_TFLOPS_BY_KIND` table; None when unknown."""
-    env = os.environ.get("MXNET_PEAK_TFLOPS") \
-        or os.environ.get("BENCH_PEAK_TFLOPS")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    if device is None:
-        try:
-            import jax
-
-            devices = jax.local_devices()
-            device = devices[0] if devices else None
-        except Exception:
-            device = None
-    kind = getattr(device, "device_kind", "") or ""
-    return PEAK_TFLOPS_BY_KIND.get(kind)
+def device_peak_tflops(device):
+    """Rated bf16 dense peak of ``device`` from :data:`PEAK_TFLOPS_BY_KIND`.
+    An unknown ``device_kind`` raises: a measurement must not divide by a
+    peak nobody published for the device it ran on."""
+    kind = device.device_kind
+    if kind not in PEAK_TFLOPS_BY_KIND:
+        raise KeyError(
+            "no published bf16 peak for device_kind %r: add it to "
+            "perfdebug.PEAK_TFLOPS_BY_KIND with its source (known: %s)"
+            % (kind, ", ".join(sorted(PEAK_TFLOPS_BY_KIND))))
+    return PEAK_TFLOPS_BY_KIND[kind]
 
 
 def step_flops():
@@ -522,7 +492,11 @@ def note_throughput(samples_per_sec, batch_size):
     fl = step_flops()
     if not fl or not batch_size or not samples_per_sec:
         return None
-    peak = device_peak_tflops()
+    import jax
+
+    # the live gauge stays silent for a kind without a published peak
+    # (the CPU, a new chip); measurement paths raise instead
+    peak = PEAK_TFLOPS_BY_KIND.get(jax.local_devices()[0].device_kind)
     if not peak:
         return None
     tflops = samples_per_sec * (fl / float(batch_size)) / 1e12

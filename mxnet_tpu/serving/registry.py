@@ -30,6 +30,7 @@ from .. import compile_cache as _compile_cache
 from .. import predict as _predict
 from .. import telemetry as _telemetry
 from ..base import MXNetError, atomic_write, atomic_write_bytes
+from ..context import current_context
 from .batcher import DynamicBatcher
 
 __all__ = ["UnknownModel", "ServedModel", "ModelRegistry", "save_model",
@@ -177,8 +178,8 @@ class ServedModel:
         """Compile every declared bucket now, at load time, so no live
         request ever eats a first-call XLA trace.
 
-        With the compile-once subsystem active
-        (``MXNET_COMPILE_CACHE_DIR``), the warm-up's compiles are
+        With the compile-once subsystem active (the default,
+        :mod:`mxnet_tpu.compile_cache`), the warm-up's compiles are
         persistent-cache loads on any repeat load of the same
         symbol+shapes — ``serving.warmup.cold_compiles`` reports how
         many executables actually paid a backend compile (0 on a warm
@@ -294,7 +295,10 @@ class ModelRegistry:
 
     def __init__(self, ctx=None, batch_timeout_us=2000,
                  max_queue_depth=128):
-        self._ctx = ctx
+        # the chip when there is one, like every other default context:
+        # left to ``Predictor``'s own default, a registry built without
+        # ``ctx`` served from the host CPU beside an idle chip
+        self._ctx = ctx if ctx is not None else current_context()
         self._serve_opts = {"batch_timeout_us": batch_timeout_us,
                             "max_queue_depth": max_queue_depth}
         self._models = {}
@@ -341,8 +345,8 @@ class ModelRegistry:
             prev.close()
         _telemetry.inc("serving.model.loads", model=name)
         _telemetry.event("serving.model.load", model=name, version=version)
-        logging.info("serving: model %r v%d loaded (buckets %s)",
-                     name, model.version, list(model.buckets))
+        logging.info("serving: model %r v%d loaded on %s (buckets %s)",
+                     name, model.version, self._ctx, list(model.buckets))
         return model
 
     reload = load

@@ -6,6 +6,8 @@ for serving reloads and fit resume)."""
 
 import json
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -291,3 +293,71 @@ def test_disabled_is_inert(tmp_path):
     assert compile_cache.stats()["hits"] == 0
     fn = object()
     assert compile_cache.instrument(fn, "x", "y") is fn
+
+
+# -- where the cache lives ----------------------------------------------------
+
+_DIR_PROBE = r"""
+import json, sys
+import jax
+import mxnet_tpu as mx
+from mxnet_tpu import compile_cache as cc
+from mxnet_tpu.base import MXNetError
+
+out = {"enabled": cc.enabled(), "dir": cc.cache_dir(),
+       "jax_dir": jax.config.jax_compilation_cache_dir}
+if sys.argv[2] == "compile":
+    jax.jit(lambda x: x * 2 + 1)(jax.numpy.arange(7.0)).block_until_ready()
+    out["entries"] = cc.cache_entries()
+    try:
+        cc.enable(sys.argv[1])
+        out["other_dir"] = "accepted"
+    except MXNetError:
+        out["other_dir"] = "refused"
+    out["jax_dir_after"] = jax.config.jax_compilation_cache_dir
+print("PROBE " + json.dumps(out))
+"""
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _dir_probe(tmp_path, compile_one=False, **env):
+    """Import the package in a fresh process (the session's own cache
+    setting stays untouched) and report where its cache landed."""
+    full = {k: v for k, v in os.environ.items()
+            if k not in ("JAX_COMPILATION_CACHE_DIR",
+                         "JAX_ENABLE_COMPILATION_CACHE")}
+    full.update(env, PYTHONPATH=_ROOT)
+    proc = subprocess.run(
+        [sys.executable, "-c", _DIR_PROBE, str(tmp_path / "other"),
+         "compile" if compile_one else "import"],
+        env=full, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    (line,) = [ln for ln in proc.stdout.splitlines()
+               if ln.startswith("PROBE ")]
+    return json.loads(line[len("PROBE "):])
+
+
+def test_jax_variable_places_the_cache_and_is_never_overwritten(tmp_path):
+    placed = str(tmp_path / "placed")
+    out = _dir_probe(tmp_path, compile_one=True,
+                     JAX_COMPILATION_CACHE_DIR=placed)
+    assert out["enabled"] and out["dir"] == placed
+    assert out["jax_dir"] == out["jax_dir_after"] == placed
+    assert out["entries"] > 0 and os.listdir(placed)   # entries there...
+    assert not (tmp_path / "other").exists()           # ...and nowhere else
+    assert out["other_dir"] == "refused"
+
+
+def test_cache_is_on_by_default_at_one_fixed_path_in_the_checkout(tmp_path):
+    """Nothing set: on, at ``<checkout>/.jax_cache`` — a path with no
+    temporary, pid or clock part, the same in every process."""
+    out = _dir_probe(tmp_path)
+    assert out["enabled"]
+    assert out["dir"] == out["jax_dir"] == os.path.join(_ROOT, ".jax_cache")
+    assert compile_cache.DEFAULT_DIR == out["dir"]
+
+
+def test_jax_switch_keeps_the_default_cache_off(tmp_path):
+    out = _dir_probe(tmp_path, JAX_ENABLE_COMPILATION_CACHE="false")
+    assert not out["enabled"] and out["dir"] is None

@@ -1,6 +1,7 @@
 """Data iterators (reference ``tests/python/unittest/test_io.py``)."""
 
 import gzip
+import os
 import struct
 
 import numpy as np
@@ -336,3 +337,33 @@ def test_multiprocess_decode_rejects_bad_combos(tmp_path):
     with _pytest.raises(ValueError):
         io.ImageRecordIter(path_imgrec="x.rec", data_shape=(3, 8, 8),
                            batch_size=2, decode_procs=2, brightness=0.2)
+
+
+def _report_jax_platforms(q):
+    import os as _os
+
+    import jax
+
+    q.put((_os.environ.get("JAX_PLATFORMS"), jax.default_backend()))
+
+
+def test_decode_workers_start_host_only(monkeypatch):
+    """A chip belongs to one process: decode workers are spawned with
+    ``JAX_PLATFORMS=cpu`` in their environment whatever the parent's
+    says, and the parent's is restored."""
+    import multiprocessing as mp
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    ctx = mp.get_context("spawn")
+    q = ctx.Queue()
+    p = ctx.Process(target=_report_jax_platforms, args=(q,), daemon=True)
+    with io._host_only_child_env():
+        p.start()
+    assert os.environ["JAX_PLATFORMS"] == "tpu,cpu"
+    assert q.get(timeout=120) == ("cpu", "cpu")
+    p.join(60)
+    assert not p.is_alive()
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with io._host_only_child_env():
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert "JAX_PLATFORMS" not in os.environ

@@ -217,7 +217,10 @@ def test_disabled_capture_is_inert():
 # -- live MFU ---------------------------------------------------------------
 
 def test_mfu_gauge_from_speedometer(monkeypatch):
-    monkeypatch.setenv("MXNET_PEAK_TFLOPS", "100")
+    import jax
+
+    monkeypatch.setitem(perfdebug.PEAK_TFLOPS_BY_KIND,
+                        jax.local_devices()[0].device_kind, 100.0)
     _fit(_mlp())
     flops = perfdebug.step_flops()
     assert flops and flops > 0
@@ -237,12 +240,15 @@ def test_mfu_gauge_from_speedometer(monkeypatch):
     assert telemetry.gauge_value("perf.mfu_pct") is not None
 
 
-def test_mfu_none_without_peak(monkeypatch):
-    monkeypatch.delenv("MXNET_PEAK_TFLOPS", raising=False)
-    monkeypatch.delenv("BENCH_PEAK_TFLOPS", raising=False)
+def test_mfu_none_without_peak():
+    import jax
+
     _fit(_mlp())
-    # CPU device_kind is not in the peak table -> MFU unknown, no gauge
+    # CPU device_kind is not in the peak table -> the live gauge stays
+    # silent, while the measurement-path lookup refuses to guess
     assert perfdebug.note_throughput(1e6, 8) is None
+    with pytest.raises(KeyError, match="no published bf16 peak"):
+        perfdebug.device_peak_tflops(jax.local_devices()[0])
 
 
 # -- flight recorder --------------------------------------------------------
@@ -484,19 +490,6 @@ def test_gate_threshold_flag(tmp_path):
          "regression_vs_best_pct": 12.0}])
     assert _run_gate(path, "--threshold", "15").returncode == 0
     assert _run_gate(path, "--threshold", "10").returncode == 1
-
-
-def test_gate_matches_repo_bench_file():
-    """The checked-in BENCH_extra.json must agree with the gate: it
-    exits non-zero iff the file carries unwaived >5% regressions (the
-    three known inference regressions today)."""
-    path = os.path.join(ROOT, "BENCH_extra.json")
-    rows = json.load(open(path)).get("rows", [])
-    expected_fail = any(
-        (r.get("regression_vs_best_pct") or 0) > 5 and not r.get("waiver")
-        for r in rows)
-    r = _run_gate(path)
-    assert (r.returncode != 0) == expected_fail, r.stdout
 
 
 def test_gate_missing_file_is_noop(tmp_path):

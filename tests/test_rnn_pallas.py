@@ -45,7 +45,7 @@ def _run_rnn(flag, seq=7, batch=4, nin=6, nh=8):
 
 def test_fused_lstm_kernel_matches_scan_path():
     outs_ref, grads_ref = _run_rnn("0")
-    outs_k, grads_k = _run_rnn("1")
+    outs_k, grads_k = _run_rnn("interpret")
     assert len(outs_k) == len(outs_ref) == 3  # y, h, c (state_outputs)
     for a, b in zip(outs_k, outs_ref):
         assert_almost_equal(a, b, rtol=1e-5, atol=1e-5)

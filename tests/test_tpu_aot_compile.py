@@ -1,0 +1,188 @@
+"""The main path's Pallas kernels, compiled at real widths for a TPU v5e
+that is described and not attached (``jax.experimental.topologies``).
+
+Interpret-mode tests run the kernel math; only the chip's compiler says
+whether a kernel lowers — tile rules, scoped VMEM, layouts.  Nothing runs
+here: a compile that passes is not a chip run.  Every case is skipped when
+the topology cannot be described (no TPU compiler installed).
+
+Each case compiles in a child process (``python tests/test_tpu_aot_compile.py
+<case>``), not in the pytest worker: loading the TPU compiler installs its
+own process-wide signal handlers (SIGTERM among them), which must not leak
+into the workers that run the suite's signal tests.  The children inherit
+the suite's environment — CPU platform, persistent compile cache off (an
+entry written for a described device cannot be read back without the
+chip).
+"""
+
+import contextlib
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from mxnet_tpu.ops import attention, bn_pallas, registry  # noqa: E402
+from mxnet_tpu.ops import rnn_pallas  # noqa: E402
+
+#: exit code of a child that could not describe the chip
+_SKIP = 77
+
+#: case name -> zero-argument callable, run in the child
+CASES = {}
+
+
+@contextlib.contextmanager
+def _tpu_trace():
+    """What the executor does around a trace bound for the chip: the
+    dispatch rules ask ``registry.on_tpu()``, and here JAX sees a CPU."""
+    token = registry.trace_device.set("tpu")
+    try:
+        yield
+    finally:
+        registry.trace_device.reset(token)
+
+
+def _compile(fn, *shapes):
+    """Compile ``fn`` for the described chip from ``(shape, dtype)``
+    pairs, and check that the kernel is in the compiled text."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: broad-except — any failure to describe
+        # the chip (no libtpu, unknown topology name) skips the case
+        print("cannot describe a v5e topology: %s" % e)
+        sys.exit(_SKIP)
+    sharding = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    assert "tpu_custom_call" in \
+        jax.jit(fn).lower(*args).compile().as_text()
+
+
+# -- flash attention -----------------------------------------------------------
+# the decode phase's prefill shapes (chip_smoke.py: 12 heads of 64, buckets
+# 32/128/512) and the long-sequence shape at both head widths
+def _flash_case(b, h, l, d):
+    def run():
+        probe = jax.ShapeDtypeStruct((b, h, l, d), jnp.bfloat16)
+        # the dispatch rule must admit exactly what the compiler admits
+        with _tpu_trace():
+            assert attention._kernel_refusal(probe, probe, 256, 512) is None
+        shape = ((b, h, l, d), jnp.bfloat16)
+        _compile(lambda q, k, v: attention._flash_pallas(
+            q, k, v, True, float(d) ** -0.5), shape, shape, shape)
+    return run
+
+
+for _shape in [(1, 12, 32, 64), (1, 12, 128, 64), (1, 12, 512, 64),
+               (2, 8, 2048, 128), (2, 8, 2048, 64)]:
+    CASES["flash_attention-%dx%dx%dx%d" % _shape] = _flash_case(*_shape)
+
+
+def _flash_refusal():
+    """A strict sub-block whose extent is not a multiple of 8 is the one
+    thing Mosaic refuses at these shapes; ``_kernel_refusal`` names it
+    ``tile`` instead of letting the lowering fail."""
+    probe = jax.ShapeDtypeStruct((1, 12, 200, 64), jnp.bfloat16)
+    with _tpu_trace():
+        assert attention._kernel_refusal(probe, probe, 100, 512) == "tile"
+        assert attention._kernel_refusal(probe, probe, 256, 512) is None
+    shape = ((1, 12, 200, 64), jnp.bfloat16)
+    try:
+        _compile(lambda q, k, v: attention._flash_pallas(
+            q, k, v, True, 0.125, block_q=100), shape, shape, shape)
+    except Exception as e:  # noqa: broad-except — the compiler's refusal
+        assert "divisible by 8" in str(e), e
+    else:
+        raise AssertionError("the compiler took a 100-row sub-block")
+
+
+CASES["flash_attention-refuses-what-the-compiler-refuses"] = _flash_refusal
+
+
+# -- fused BatchNorm -----------------------------------------------------------
+#: ResNet-50 BatchNorm inputs at batch 128: (channels, spatial side)
+_RESNET50_BN = [(64, 112), (64, 56), (256, 56), (128, 56), (128, 28),
+                (512, 28), (256, 28), (256, 14), (1024, 14), (512, 14),
+                (512, 7), (2048, 7)]
+
+
+def _bn_stages(dtype):
+    """Every ResNet-50 b128 stage ``bn_pallas`` admits under its block
+    budget, as the kernel's ``(S, N, C)`` view."""
+    n = 128
+    return [(side * side, n, c) for c, side in _RESNET50_BN
+            if bn_pallas._refusal(n, c, side * side,
+                                  jnp.dtype(dtype).itemsize) is None]
+
+
+def _bn_case(snc):
+    def run():
+        x = (snc, jnp.bfloat16)
+        vec = ((1, snc[2]), jnp.float32)
+        _compile(lambda xt, g, b: bn_pallas._bn_fwd_call(
+            xt, g, b, 2e-5, False, True, False), x, vec, vec)
+        _compile(lambda xt, gt, m, v, g, b: bn_pallas._bn_bwd_call(
+            xt, gt, m, v, g, b, 2e-5, False, True, False),
+            x, x, vec, vec, vec, vec)
+    return run
+
+
+for _snc in _bn_stages(jnp.bfloat16):
+    CASES["batchnorm-fwd-bwd-%dx%dx%d" % _snc] = _bn_case(_snc)
+
+
+# -- fused LSTM ----------------------------------------------------------------
+def _lstm_case():
+    """PTB 2x200, batch 32, sequence 35 (bench_extra.lstm_score)."""
+    t, n, h = 35, 32, 200
+    assert rnn_pallas.fits(t, n, h, jnp.float32)
+    f32 = jnp.float32
+    xp, w4, bh = ((t, 4, n, h), f32), ((4, h, h), f32), ((4, h), f32)
+    state, seq = ((n, h), f32), ((t, n, h), f32)
+    _compile(lambda *a: rnn_pallas._run_fwd(*a, False),
+             xp, w4, bh, state, state)
+    _compile(lambda *a: rnn_pallas._run_bwd(*a, False),
+             xp, seq, seq, w4, state, state, seq, state, state)
+
+
+CASES["lstm-fwd-bwd-ptb-35x32x200"] = _lstm_case
+
+
+# -- the tests -----------------------------------------------------------------
+def test_bn_budget_admits_the_late_stages():
+    assert _bn_stages(jnp.bfloat16) == [
+        (196, 128, 256), (196, 128, 1024), (196, 128, 512),
+        (49, 128, 512), (49, 128, 2048)]
+    assert _bn_stages(jnp.float32) == [(49, 128, 512), (49, 128, 2048)]
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_a_described_v5e(case):
+    env = dict(os.environ, TPU_LOG_DIR="disabled",
+               # nothing attaches a chip, so several children may load the
+               # TPU compiler at once; libtpu's one-process lockfile would
+               # otherwise let one in and make the others skip
+               ALLOW_MULTIPLE_LIBTPU_LOAD="1")
+    proc = subprocess.run([sys.executable, os.path.abspath(__file__), case],
+                          env=env, capture_output=True, text=True,
+                          timeout=600)
+    if proc.returncode == _SKIP:
+        pytest.skip(proc.stdout.strip()[-300:])
+    assert proc.returncode == 0, proc.stderr[-3000:]
+
+
+if __name__ == "__main__":
+    assert not jax.config.jax_enable_compilation_cache, \
+        "run with JAX_ENABLE_COMPILATION_CACHE=false (tests/conftest.py)"
+    CASES[sys.argv[1]]()

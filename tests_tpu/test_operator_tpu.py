@@ -158,10 +158,10 @@ def test_run_bulk_parity_on_tpu():
 
 
 def test_flash_attention_pallas_on_chip():
-    """FlashAttention op end-to-end on hardware at a small shape (d=32
-    routes to the blockwise-scan path by the _use_pallas gate; the
-    Pallas kernel itself is exercised at eligible shapes by
-    test_flash_attention_pallas_kernel_routes_on_chip below)."""
+    """FlashAttention op end-to-end on hardware at a small shape, forward
+    through the Pallas kernel (a head of 32 lowers: the gate is what the
+    compiler accepts, ops/attention._kernel_refusal) and backward through
+    the blockwise scan, against the CPU and a dense reference."""
     rs = np.random.RandomState(0)
     b, h, l, d = 1, 2, 128, 32
     q = rs.normal(0, 1, (b, h, l, d)).astype(np.float32)
@@ -283,22 +283,20 @@ def test_pallas_bn_on_chip_matches_xla():
                                    err_msg=k)
 
 
+@pytest.mark.parametrize("d", [128, 64])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_flash_attention_pallas_kernel_routes_on_chip(dtype):
-    """At kernel-eligible shapes (d % 128 == 0, aligned seq) the REAL
-    Pallas kernel must (a) be selected, (b) lower and run on hardware,
-    and (c) match the dense reference — in f32 AND bf16 (training
-    dtype).  The older on-chip test uses d=32, which the _use_pallas
-    gate routes to the scan path — that masked a Mosaic tile-rule
-    violation in the lse out-spec that made the kernel fail to lower on
-    TPU at every eligible shape until round 5."""
+def test_flash_attention_pallas_kernel_routes_on_chip(dtype, d):
+    """At kernel-eligible shapes the REAL Pallas kernel must (a) be
+    selected, (b) lower and run on hardware, and (c) match the dense
+    reference — in f32 AND bf16 (training dtype), at a head of 128 and
+    at the head of 64 the LM serves with."""
     import jax.numpy as jnp
 
     from mxnet_tpu.ops import attention as att
 
-    b, h, l, d = 2, 4, 512, 128
-    assert att._use_pallas(np.zeros((b, h, l, d)), np.zeros((b, h, l, d)),
-                           256, 512)
+    b, h, l = 2, 4, 512
+    assert att._kernel_refusal(np.zeros((b, h, l, d)),
+                               np.zeros((b, h, l, d)), 256, 512) is None
     rs = np.random.RandomState(3)
     jdt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
     q = jnp.asarray(rs.normal(0, 1, (b, h, l, d)).astype(np.float32),
