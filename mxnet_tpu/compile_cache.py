@@ -70,6 +70,7 @@ import logging
 import os
 import threading
 import time
+from collections import deque
 
 import numpy as np
 
@@ -113,6 +114,7 @@ _records = []          # tier-2 build records, in build order
 _record_seq = 0        # monotonic build stamp (recording_scope cursor)
 _saved_manifests = {}  # path -> content hash (save_manifest_if_changed)
 _listening = False     # jax.monitoring listeners installed
+_tls = threading.local()  # .hit: the thread's last cache verdict
 _orig_get = None       # unwrapped compilation_cache.get_executable_and_time
 
 # process-local persistent-cache counters: kept even when telemetry is
@@ -120,6 +122,11 @@ _orig_get = None       # unwrapped compilation_cache.get_executable_and_time
 _hits = 0
 _misses = 0
 _saved_seconds = 0.0
+_program_seconds = 0.0
+#: ``(monotonic_s at the end, seconds, hit)`` of the newest programs that
+#: went through ``compile_or_get_cached``, so that a reader can cut at a
+#: moment of its own (the benchmark: the opening of its window)
+_programs = deque(maxlen=4096)
 _evictions = 0
 _corrupt_dropped = 0
 
@@ -253,6 +260,7 @@ def _init_from_env():
     it off; never raises (a bad cache dir must not break import)."""
     import jax
 
+    _install_listeners()
     if _dir is not None or not jax.config.jax_enable_compilation_cache:
         return
     try:
@@ -267,6 +275,9 @@ _EVENT_HITS = "/jax/compilation_cache/cache_hits"
 _EVENT_MISSES = "/jax/compilation_cache/cache_misses"
 _EVENT_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
 _EVENT_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: brackets ``compile_or_get_cached`` in JAX 0.9.0, hit or miss: one
+#: event a program.  A hit's retrieval time is a part of it
+_EVENT_PROGRAM = "/jax/core/compile/backend_compile_duration"
 
 
 def _on_event(event, **_kw):
@@ -274,6 +285,7 @@ def _on_event(event, **_kw):
     if _dir is None:
         return
     if event == _EVENT_HITS:
+        _tls.hit = True
         with _lock:
             _hits += 1
         _telemetry.inc("xla.compile.persistent_cache_hits")
@@ -284,7 +296,15 @@ def _on_event(event, **_kw):
 
 
 def _on_duration(event, duration, **_kw):
-    global _saved_seconds
+    global _saved_seconds, _program_seconds
+    if event == _EVENT_PROGRAM:
+        # counted with the cache off too: a compile is a compile
+        hit = getattr(_tls, "hit", False)
+        _tls.hit = False
+        with _lock:
+            _program_seconds += float(duration)
+            _programs.append((time.monotonic(), float(duration), hit))
+        return
     if _dir is None:
         return
     if event == _EVENT_SAVED:
@@ -529,10 +549,21 @@ def stats():
             "hits": _hits,
             "misses": _misses,
             "compile_time_saved_seconds": round(_saved_seconds, 3),
+            "program_seconds": round(_program_seconds, 6),
             "evictions": _evictions,
             "corrupt_dropped": _corrupt_dropped,
             "recorded_builds": len(_records),
         }
+
+
+def programs():
+    """``(monotonic_s, seconds, hit)`` of the newest programs XLA compiled
+    or loaded from the persistent cache in this process (at most 4096),
+    oldest first: the seconds are those inside JAX's
+    ``compile_or_get_cached``, the moment is its end on
+    ``time.monotonic()``."""
+    with _lock:
+        return list(_programs)
 
 
 # -- tier 2: build recording ------------------------------------------------

@@ -1027,23 +1027,26 @@ class BaseModule:
                                  resume_metric_state))
                     while True:
                         try:
-                            self._fit_epochs(
-                                fit_data, eval_data, eval_metric,
-                                validation_metric, epoch_end_callback,
-                                batch_end_callback, eval_end_callback,
-                                eval_batch_end_callback, monitor,
-                                begin_epoch, num_epoch, checkpoint_prefix,
-                                checkpoint_period, nan_policy,
-                                nan_check_period, use_bulk, bulk_k,
-                                _trip_nan_policy, owns_iter, run=run,
-                                resume_nbatch=resume_nbatch,
-                                resume_metric_state=resume_metric_state,
-                                anomaly_policy=anomaly_policy,
-                                anomaly_detector=anomaly_detector,
-                                anomaly_consec=anomaly_consec,
-                                trip_anomaly=_trip_anomaly,
-                                audit_every=audit_every_n_batches,
-                                audit_policy=audit_policy)
+                            # a batch that raises in the middle of its
+                            # span leaves the frame to end it
+                            with _tracing.frame():
+                                self._fit_epochs(
+                                    fit_data, eval_data, eval_metric,
+                                    validation_metric, epoch_end_callback,
+                                    batch_end_callback, eval_end_callback,
+                                    eval_batch_end_callback, monitor,
+                                    begin_epoch, num_epoch,
+                                    checkpoint_prefix, checkpoint_period,
+                                    nan_policy, nan_check_period, use_bulk,
+                                    bulk_k, _trip_nan_policy, owns_iter,
+                                    run=run, resume_nbatch=resume_nbatch,
+                                    resume_metric_state=resume_metric_state,
+                                    anomaly_policy=anomaly_policy,
+                                    anomaly_detector=anomaly_detector,
+                                    anomaly_consec=anomaly_consec,
+                                    trip_anomaly=_trip_anomaly,
+                                    audit_every=audit_every_n_batches,
+                                    audit_policy=audit_policy)
                             break
                         except _ELASTIC_RESYNC as e:
                             if elastic_run is None:
@@ -1148,7 +1151,7 @@ class BaseModule:
                 def _flush(chunk, nbatch):
                     # one span per fused chunk — the bulk-mode analogue
                     # of the per-batch span below
-                    bsp = _tracing.start_span("fit.batch", stack=False,
+                    bsp = _tracing.start_span("fit.batch", loop=True,
                                               epoch=epoch, k=len(chunk))
                     with _telemetry.phase("bulk_step"):
                         # device metrics consume the stacked outputs
@@ -1215,10 +1218,12 @@ class BaseModule:
                     if data_batch is _FIT_END:
                         break
                     nbatch += 1
-                    # per-batch trace span (data wait excluded — it sits
-                    # before the batch starts); disabled-mode cost is two
-                    # no-op calls, inside the fit overhead pin
-                    bsp = _tracing.start_span("fit.batch", stack=False,
+                    # per-batch trace span, the root the batch's phases
+                    # nest under (data wait excluded — it sits before the
+                    # batch starts); disabled-mode cost is two no-op
+                    # calls, inside the fit overhead pin.  A batch that
+                    # raises leaves it to fit's tracing.frame()
+                    bsp = _tracing.start_span("fit.batch", loop=True,
                                               epoch=epoch, nbatch=nbatch)
                     if _faults.should_fire("fit.preempt"):
                         # deterministic preemption: a REAL SIGTERM to
@@ -1299,8 +1304,6 @@ class BaseModule:
                     if check_nan:
                         window_all_staged = True  # flag consumed: new window
                     _telemetry.inc("fit.batches")
-                    bsp.end("retry" if (nan_detected or anomaly_detected)
-                            else "ok")
                     if audit_every is not None and \
                             (nbatch + 1) % audit_every == 0:
                         audit = getattr(self, "_run_integrity_audit",
@@ -1311,33 +1314,39 @@ class BaseModule:
                                       epoch, nbatch)
                     if monitor is not None:
                         monitor.toc_print()
-                    if batch_end_callback is not None:
-                        batch_end_param = BatchEndParam(
-                            epoch=epoch, nbatch=nbatch,
-                            eval_metric=eval_metric, locals=locals(),
-                            nan_detected=nan_detected,
-                            nan_action=nan_action,
-                            anomaly_detected=anomaly_detected,
-                            anomaly_action=anomaly_action)
-                        for callback in _as_list(batch_end_callback):
-                            callback(batch_end_param)
-                    if run is not None:
-                        # cadence snapshot + pending-preemption drain;
-                        # the guard drain mirrors the epoch-boundary one
-                        # so a poisoned window never checkpoints silently
-                        run.after_batch(
-                            self, epoch, nbatch, fit_data, eval_metric,
-                            drain_guard=lambda e=epoch, b=nbatch,
-                            g=window_all_staged: self._drain_nan_window(
-                                nan_policy, nan_check_period, e, b, g,
-                                _trip_nan_policy),
-                            # a NaN- or anomaly-tripped batch's update
-                            # never landed (skipped or rolled back): it
-                            # must not enter the elastic data ledger as
-                            # trained
-                            data_batch=None
-                            if (nan_detected or anomaly_detected)
-                            else data_batch)
+                    with _telemetry.phase("callbacks"):
+                        if batch_end_callback is not None:
+                            batch_end_param = BatchEndParam(
+                                epoch=epoch, nbatch=nbatch,
+                                eval_metric=eval_metric, locals=locals(),
+                                nan_detected=nan_detected,
+                                nan_action=nan_action,
+                                anomaly_detected=anomaly_detected,
+                                anomaly_action=anomaly_action)
+                            for callback in _as_list(batch_end_callback):
+                                callback(batch_end_param)
+                        if run is not None:
+                            # cadence snapshot + pending-preemption
+                            # drain; the guard drain mirrors the
+                            # epoch-boundary one so a poisoned window
+                            # never checkpoints silently
+                            run.after_batch(
+                                self, epoch, nbatch, fit_data,
+                                eval_metric,
+                                drain_guard=lambda e=epoch, b=nbatch,
+                                g=window_all_staged:
+                                self._drain_nan_window(
+                                    nan_policy, nan_check_period, e, b,
+                                    g, _trip_nan_policy),
+                                # a NaN- or anomaly-tripped batch's
+                                # update never landed (skipped or rolled
+                                # back): it must not enter the elastic
+                                # data ledger as trained
+                                data_batch=None
+                                if (nan_detected or anomaly_detected)
+                                else data_batch)
+                    bsp.end("retry" if (nan_detected or anomaly_detected)
+                            else "ok")
                 # epoch-boundary drain: with nan_check_period > 1 the
                 # last window may not have been read yet — a NaN epoch
                 # must not survive into checkpoint/eval unflagged

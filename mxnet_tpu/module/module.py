@@ -28,6 +28,7 @@ from .. import optimizer as opt
 from .. import perfdebug as _perfdebug
 from .. import random as _random
 from .. import telemetry as _telemetry
+from .. import tracing as _tracing
 from ..base import MXNetError
 from ..context import Context, cpu
 from ..executor import Executor
@@ -1348,7 +1349,9 @@ class Module(BaseModule):
                 build_replica_audit(self._mesh, self._batch_axis_name()),
                 self._exec._symbol_name(), "replica_audit"))
             self._audit_fn_cache = cached
-        res = np.asarray(cached[1](arrays))  # host-sync: ok — the audit's one tiny result read
+        res = cached[1](arrays)
+        with _tracing.host_read("audit"):
+            res = np.asarray(res)  # host-sync: ok — the audit's one tiny result read
         count, first = int(res[0]), int(res[1])
         _telemetry.inc("reliability.audits")
         if count == 0:

@@ -29,6 +29,7 @@ import numpy as np
 
 from . import profiler as _profiler
 from . import random as _random
+from . import tracing as _tracing
 from .base import MXNetError
 from .context import Context, current_context
 from .ops import registry as _reg
@@ -117,7 +118,8 @@ class NDArray:
     def asnumpy(self):
         """Blocking copy to host (reference ``ndarray.py`` asnumpy; the sync
         point, like WaitToRead + CopyDeviceToCPU)."""
-        return np.asarray(self._jx)
+        with _tracing.host_read("asnumpy"):
+            return np.asarray(self._jx)
 
     def asscalar(self):
         if self.size != 1:
@@ -125,7 +127,8 @@ class NDArray:
         return self.asnumpy().reshape(())[()]
 
     def wait_to_read(self):
-        self._jx.block_until_ready()
+        with _tracing.host_read("wait_to_read"):
+            self._jx.block_until_ready()
 
     wait_to_write = wait_to_read
 
@@ -381,12 +384,14 @@ class _HostNDArray(NDArray):
         v = NDArray._jx.__get__(self)
         if isinstance(v, np.ndarray):
             return v.copy()
-        return np.asarray(v)
+        with _tracing.host_read("asnumpy"):
+            return np.asarray(v)
 
     def wait_to_read(self):
         v = NDArray._jx.__get__(self)
         if not isinstance(v, np.ndarray):
-            v.block_until_ready()
+            with _tracing.host_read("wait_to_read"):
+                v.block_until_ready()
 
     wait_to_write = wait_to_read
 

@@ -113,7 +113,8 @@ class GenerateSession:
     __slots__ = ("prompt", "max_new_tokens", "temperature", "deadline",
                  "on_token", "on_event", "tokens", "future", "seed",
                  "tenant", "migrations", "migrate_t0", "t_submit",
-                 "t_first", "t_done", "slot", "admit_step", "done_step",
+                 "t_admit", "t_first", "t_done", "slot", "admit_step",
+                 "done_step",
                  "trace", "_finished", "_lock", "_on_done")
 
     def __init__(self, prompt, max_new_tokens, temperature, deadline_ms,
@@ -138,6 +139,9 @@ class GenerateSession:
         #: when the re-prefill lands (true failure-to-resumed latency)
         self.migrate_t0 = None
         self.t_submit = time.monotonic()
+        #: when the engine's loop handed the session its slot (the
+        #: latest admission: a migrated session queues again)
+        self.t_admit = None
         self.t_first = None
         self.t_done = None
         self.slot = None
@@ -216,6 +220,12 @@ class GenerateSession:
         token)."""
         return None if self.t_first is None \
             else self.t_first - self.t_submit
+
+    def queue_wait(self):
+        """Seconds from submit to the slot (None while queued): the part
+        of a first-token wait that is queueing, the rest is prefill."""
+        return None if self.t_admit is None \
+            else self.t_admit - self.t_submit
 
 
 class DecodeEngine:
@@ -903,8 +913,13 @@ class DecodeEngine:
             # _finish runs the pool's on_done hook, which takes the POOL
             # lock, and pool.describe() takes pool-then-engine — holding
             # the engine lock here would order the locks both ways
+            isp = _tracing.start_span("serving.decode.iter", loop=True,
+                                      replica=self.replica)
+            qsp = _tracing.start_span("serving.decode.queue")
             with self._cond:
                 if not self._running:
+                    qsp.drop()
+                    isp.drop()
                     return
                 # liveness heartbeat: stamped every loop iteration (the
                 # idle wait below is 20ms, so an IDLE engine still beats)
@@ -930,6 +945,7 @@ class DecodeEngine:
                         shed.append((sess, "deadline"))
                     elif free:
                         sess.slot = free.pop(0)
+                        sess.t_admit = now
                         self._slot_sessions[sess.slot] = sess
                         admits.append(sess)
                     else:
@@ -938,11 +954,16 @@ class DecodeEngine:
                 have_active = any(x is not None
                                   for x in self._slot_sessions)
                 if not admits and not shed and not have_active:
+                    # an iteration without work leaves no record
+                    qsp.drop()
+                    isp.drop()
                     if self._draining:
                         self._running = False
                         return
                     self._cond.wait(0.02)
                     continue
+                queued = len(keep)
+            qsp.end("ok", queued=queued)
             for sess, reason in shed:
                 # every exit path resolves the future and fires on_done
                 # — a dropped session would leak the pool's outstanding
@@ -964,12 +985,26 @@ class DecodeEngine:
                     # again would double-fire the pool's on_done hook
                     break
             with self._cond:
-                have_active = any(x is not None
-                                  for x in self._slot_sessions)
-            if have_active:
+                active = sum(x is not None for x in self._slot_sessions)
+            if active:
                 state = self._step(state)
+            isp.end("ok", admits=len(admits), active=active)
 
     def _admit(self, sess, state):
+        """:meth:`_prefill_session` inside its ``serving.admit`` span,
+        which covers ALL of an admission: padding, the dispatch, the
+        first-token read, the emit and the bookkeeping.  Runs on the
+        ENGINE thread, so the span parents explicitly off the session
+        root (the thread-local stack belongs to the loop iteration) and
+        is stacked, for its children and its place in a device profile."""
+        with _tracing.start_span(
+                "serving.admit", parent=sess.trace, replica=self.replica,
+                resumed=len(sess.tokens) > 0,
+                queue_wait_ms=round(1e3 * (sess.queue_wait() or 0.0),
+                                    3)) as asp:
+            return self._prefill_session(sess, state, asp)
+
+    def _prefill_session(self, sess, state, asp):
         """Prefill ``sess`` into its (already reserved) slot: one
         bucket-shaped dispatch + one tiny admission-time host read for
         the first token (TTFT); the hot loop's own budget is untouched.
@@ -1001,6 +1036,7 @@ class DecodeEngine:
                                reason="kv_blocks")
                 sess.trace.end("shed", reason="kv_blocks",
                                where="admit")
+                asp.end("shed", reason="kv_blocks")
                 self._retire(sess, error=e)
                 self._occupancy_gauge()
                 return state, False
@@ -1017,27 +1053,26 @@ class DecodeEngine:
             bucket = next(b for b in self.prefill_buckets if n <= b)
             tokens = np.zeros((bucket,), np.int32)
             tokens[:n] = full
-        # runs on the ENGINE thread: parent explicitly off the session
-        # root (the thread-local stack belongs to whoever submitted)
-        asp = _tracing.start_span("serving.admit", parent=sess.trace,
-                                  stack=False, replica=self.replica,
-                                  resumed=resumed, bucket=bucket)
+        asp.annotate(bucket=bucket)
         try:
-            if plan is not None:
-                state, out = self._prefill_fns[bucket](
-                    self._params, state, tokens, np.int32(plan.start),
-                    np.int32(n), np.int32(sess.slot),
-                    np.ascontiguousarray(self._kv.tables[sess.slot]),
-                    limit, np.float32(sess.temperature),
-                    np.uint32(sess.seed), np.bool_(True),
-                    np.int32(plan.cow_src), np.int32(plan.cow_dst))
-            else:
-                state, out = self._prefill_fns[bucket](
-                    self._params, state, tokens, np.int32(n),
-                    np.int32(sess.slot), limit,
-                    np.float32(sess.temperature), np.uint32(sess.seed),
-                    np.bool_(True))
-            out = np.asarray(out)  # lint: ok[host-sync] admission-time first-token read (TTFT), not the per-step hot loop
+            with _tracing.start_span("serving.prefill.dispatch"):
+                if plan is not None:
+                    state, out = self._prefill_fns[bucket](
+                        self._params, state, tokens,
+                        np.int32(plan.start), np.int32(n),
+                        np.int32(sess.slot),
+                        np.ascontiguousarray(self._kv.tables[sess.slot]),
+                        limit, np.float32(sess.temperature),
+                        np.uint32(sess.seed), np.bool_(True),
+                        np.int32(plan.cow_src), np.int32(plan.cow_dst))
+                else:
+                    state, out = self._prefill_fns[bucket](
+                        self._params, state, tokens, np.int32(n),
+                        np.int32(sess.slot), limit,
+                        np.float32(sess.temperature),
+                        np.uint32(sess.seed), np.bool_(True))
+            with _tracing.host_read("prefill.first_token"):
+                out = np.asarray(out)  # lint: ok[host-sync] admission-time first-token read (TTFT), not the per-step hot loop
         except Exception as e:
             # a poisoned prefill poisons the whole donated state: fail
             # every session this engine holds and restart from zeros
@@ -1049,8 +1084,8 @@ class DecodeEngine:
             # index the (now device-resident) prompt prefix for future
             # admissions — insertion AFTER a successful dispatch only
             self._kv.offer(sess.slot, sess.prompt)
-        asp.end("ok", reprefilled=n if resumed else 0,
-                prefix_reused=plan.reused_tokens if plan else 0)
+        asp.annotate(reprefilled=n if resumed else 0,
+                     prefix_reused=plan.reused_tokens if plan else 0)
         now = time.monotonic()
         tok = int(out[0])
         sess.tokens.append(tok)
@@ -1088,6 +1123,11 @@ class DecodeEngine:
         return state, False
 
     def _step(self, state):
+        """:meth:`_step_slots` inside its ``serving.decode.step`` span."""
+        with _tracing.start_span("serving.decode.step") as ssp:
+            return self._step_slots(state, ssp)
+
+    def _step_slots(self, state, ssp):
         """ONE fixed-shape decode dispatch for all slots + the single
         packed host read; host bookkeeping fans tokens out to sessions."""
         keep = np.ones((self.slots,), bool)
@@ -1139,19 +1179,24 @@ class DecodeEngine:
                     "fault 'serving.replica.kill': replica %s of model "
                     "%r hard-killed mid-generation"
                     % (self.replica, self.name))
-            if self._kv is not None:
-                # the tables ride along as a tiny int32 H2D argument —
-                # fixed shape, no recompile, not a device read
-                state, packed = self._step_fn(
-                    self._params, state, keep,
-                    np.ascontiguousarray(self._kv.tables))
-            else:
-                state, packed = self._step_fn(self._params, state, keep)
-            packed = np.asarray(packed)  # lint: ok[host-sync] THE one sanctioned host read per decode step (packed token/done/active buffer)
+            with _tracing.start_span("serving.decode.dispatch"):
+                if self._kv is not None:
+                    # the tables ride along as a tiny int32 H2D argument
+                    # — fixed shape, no recompile, not a device read
+                    state, packed = self._step_fn(
+                        self._params, state, keep,
+                        np.ascontiguousarray(self._kv.tables))
+                else:
+                    state, packed = self._step_fn(self._params, state,
+                                                  keep)
+            with _tracing.host_read("decode.packed"):
+                packed = np.asarray(packed)  # lint: ok[host-sync] THE one sanctioned host read per decode step (packed token/done/active buffer)
         except Exception as e:
+            ssp.end("error", error=type(e).__name__)
             return self._fail_all(e, state)
         dt = time.perf_counter() - t0
         emitted = 0
+        fsp = _tracing.start_span("serving.decode.fanout")
         for i, sess in enumerate(sessions):
             if sess is None:
                 continue
@@ -1177,6 +1222,8 @@ class DecodeEngine:
                 self._slot_len[i] += 1
             if packed[1, i]:
                 self._retire(sess)
+        fsp.end("ok", emitted=emitted)
+        ssp.annotate(live=emitted)
         with self._cond:
             self.steps += 1
             self.tokens_out += emitted

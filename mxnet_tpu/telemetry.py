@@ -44,13 +44,14 @@ import time
 from collections import deque
 
 from . import profiler as _profiler
+from . import tracing as _tracing
 
 __all__ = ["enabled", "enable", "disable", "inc", "declare", "set_gauge",
            "observe", "event", "phase", "snapshot", "dump", "dump_events",
            "prometheus_text", "write_prometheus", "reset", "sample_memory",
            "phase_totals", "counter_total", "gauge_value", "hist_quantile",
            "hist_state", "quantile_from_counts", "events_recent",
-           "add_phase_hook", "remove_phase_hook", "set_phase_hook",
+           "add_phase_hook", "remove_phase_hook",
            "aggregate", "start_exporter", "stop_exporter",
            "exporter_running"]
 
@@ -207,15 +208,10 @@ def events_recent(n=100):
 #: exist today — the flight recorder's per-batch timing feed
 #: (:mod:`mxnet_tpu.perfdebug`) and the training watchdog's progress
 #: feed (:mod:`mxnet_tpu.sentinel`) — which is exactly why this is a
-#: LIST: the old single ``set_phase_hook`` slot meant whoever installed
-#: second silently evicted the other.  Stored as a tuple so the hot
-#: path iterates a stable snapshot (one truthiness check when empty);
-#: registration swaps the whole tuple under ``_lock``.
+#: LIST.  Stored as a tuple so the hot path iterates a stable snapshot
+#: (one truthiness check when empty); registration swaps the whole
+#: tuple under ``_lock``.
 _phase_hooks = ()
-#: the hook installed through the deprecated ``set_phase_hook`` alias
-#: (so a second ``set_phase_hook`` call keeps its replace semantics
-#: without evicting ``add_phase_hook`` registrations)
-_set_alias_hook = None
 
 
 def add_phase_hook(hook):
@@ -236,28 +232,17 @@ def remove_phase_hook(hook):
         _phase_hooks = tuple(h for h in _phase_hooks if h is not hook)
 
 
-def set_phase_hook(hook):
-    """Deprecated single-slot spelling: replaces only the hook a
-    previous ``set_phase_hook`` installed (or clears it with ``None``)
-    — registrations made through :func:`add_phase_hook` are never
-    evicted.  New code should use ``add_phase_hook`` /
-    ``remove_phase_hook``."""
-    global _phase_hooks, _set_alias_hook
-    with _lock:
-        hooks = tuple(h for h in _phase_hooks if h is not _set_alias_hook)
-        _set_alias_hook = hook
-        if hook is not None:
-            hooks = hooks + (hook,)
-        _phase_hooks = hooks
-
-
 class phase:
-    """Time one training-loop phase: a histogram observation in
-    ``<family>.phase_seconds{phase=<name>}`` and — when the profiler is
-    running — a chrome-trace span via ``profiler.record``.
+    """One training-loop phase: the span ``<family>.<name>`` (child of
+    the thread's current span; :mod:`mxnet_tpu.tracing` times it, on its
+    one clock, and puts it into a device profile as
+    ``mx.<family>.<name>``), a histogram observation in
+    ``<family>.phase_seconds{phase=<name>}``, the phase hooks and — when
+    ``mx.profiler`` is running — a ``<family>:<name>`` slice of its
+    chrome-trace dump.
 
-    Disabled-cheap like ``profiler.span``: the enabled check happens once
-    in ``__init__`` and a disabled phase does no clock reads.  Note JAX
+    Disabled-cheap like ``profiler.span``: the enabled checks happen
+    once and a disabled phase does no clock reads.  Note JAX
     dispatch is asynchronous, so device compute time is attributed to the
     first phase that blocks on results (see docs/observability.md) — in
     the sync-free fit loop that is the explicit ``sync`` phase (device
@@ -265,23 +250,26 @@ class phase:
     ``metric`` and friends time only their dispatch work.
     """
 
-    __slots__ = ("_name", "_family", "_t0", "_on", "_prof")
+    __slots__ = ("_name", "_family", "_span", "_on", "_prof")
 
     def __init__(self, name, family="fit"):
         self._prof = _profiler.running()
         self._on = _enabled or self._prof
-        if self._on:
-            self._name = name
-            self._family = family
+        self._name = name
+        self._family = family
 
     def __enter__(self):
-        if self._on:
-            self._t0 = time.perf_counter()
+        self._span = _tracing.start_span(
+            "%s.%s" % (self._family, self._name), loop=True,
+            timed=True) if self._on or _tracing.enabled() \
+            else _tracing.NULL_SPAN
         return self
 
-    def __exit__(self, *exc):
+    def __exit__(self, exc_type, exc, tb):
+        sp = self._span
+        sp.end("ok" if exc_type is None else "error")
         if self._on:
-            dt = time.perf_counter() - self._t0
+            dt = sp.dur_s
             if _enabled:
                 observe(self._family + ".phase_seconds", dt,
                         phase=self._name)

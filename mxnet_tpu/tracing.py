@@ -18,48 +18,68 @@ Span model (a deliberately small slice of the OpenTelemetry shape):
 * a **trace** is one request/step's causal tree, identified by a
   16-hex ``trace_id``;
 * a **span** is one timed operation inside it — 8-hex ``span_id``,
-  ``parent_id`` link, wall-clock ``t0``/``t1``, measured ``dur_s``,
-  free-form ``attrs``, and a typed ``status``: ``ok`` / ``shed`` /
-  ``migrated`` / ``retry`` / ``error`` (``in_flight`` for live spans
-  in a :func:`tree` read);
+  ``parent_id`` link, ``t0_ns``/``t1_ns`` on ``time.monotonic_ns()``
+  (ONE clock: the one ``GenerateSession.t_submit`` and the benchmark's
+  window use), the wall-clock ``t0``/``t1`` and ``dur_s`` derived from
+  them, the opening thread's ``tid``, free-form ``attrs``, and a typed
+  ``status``: ``ok`` / ``shed`` / ``migrated`` / ``retry`` / ``error``
+  (``in_flight`` for live spans in a :func:`tree` read);
 * parenting is implicit on one thread (a thread-local span stack) and
   explicit across threads/processes (``parent=`` a :class:`Span`, or
   ``trace_id=``/``parent_id=`` from a wire context).
 
-Finished spans land in a bounded ring (``MXNET_TRACE_RING``, default
-4096) that the flight recorder dumps as ndjson
+Two sinks.  Finished spans land in a bounded ring (``MXNET_TRACE_RING``,
+default 4096) that the flight recorder dumps as ndjson
 (``spans-<pid>-<seq>-<reason>.ndjson``) and ``GET /trace/<id>`` on the
-serving frontend assembles — live spans included — via :func:`tree`.
-When the chrome-trace profiler is running, every ended span is also a
-``profiler.record`` event on the same timeline as phase/dispatch
-spans.
+serving frontend assembles — live spans included — via :func:`tree`;
+spans whose root is a loop iteration (``loop=True``: a ``fit`` batch, a
+decode-engine iteration, a lone phase) go to a second ring of the same
+bound, so a busy loop never shortens how long a request's tree stays
+readable.  And a span opened with ``stack=True`` — opened and closed on
+one thread — is also a ``jax.profiler.TraceAnnotation`` named
+``mx.<name>`` while a device profile is being taken, so it lies in the
+``.xplane.pb`` beside the device's operations, on the profiler's own
+clock (event times there are relative to the session's start: no Python
+clock can be matched to them afterwards).
 
-Cost model (the PR 2 discipline): tracing is OFF by default and
-:func:`start_span` checks one module bool first, returning the shared
-falsy :data:`NULL_SPAN` — a disabled entry point pays one call and one
-branch, no clock read, no allocation.  Enable with ``MXNET_TRACE=1``
-(or :func:`enable`); tests/test_tracing.py pins the disabled per-batch
-overhead.
+Cost model (the PR 2 discipline): tracing is OFF by default and turns
+itself ON while somebody profiles the device.  :func:`start_span` checks
+one module bool and ``TraceAnnotation.is_enabled()`` (a static call)
+first, returning the shared falsy :data:`NULL_SPAN` — a disabled entry
+point pays one call and two branches, no clock read, no allocation.
+Enable with ``MXNET_TRACE=1`` (or :func:`enable`), or start a
+``jax.profiler`` trace; tests/test_tracing.py and
+tests/test_tracing_profile.py pin the disabled overhead.
 
 See docs/observability.md "Distributed tracing & fleet aggregation".
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
+import random
 import threading
 import time
 from collections import deque
 
-from . import profiler as _profiler
+from jax.profiler import TraceAnnotation as _Annotation
 
-__all__ = ["enabled", "enable", "disable", "start_span", "current",
-           "ctx", "tree", "spans_recent", "reset", "Span", "NULL_SPAN",
-           "STATUSES"]
+__all__ = ["enabled", "enable", "disable", "start_span", "host_read",
+           "frame", "current", "ctx", "tree", "spans_recent", "reset",
+           "Span", "NULL_SPAN", "STATUSES"]
 
 #: the typed span statuses (``in_flight`` is synthesized for live
 #: spans in :func:`tree` reads, never stored)
 STATUSES = ("ok", "shed", "migrated", "retry", "error")
+
+#: wall-clock nanoseconds at monotonic zero, taken once: every wall time
+#: a record shows is its monotonic time plus this
+_WALL_NS = time.time_ns() - time.monotonic_ns()
+
+#: True while a ``jax.profiler`` session is live in this process (a
+#: static call): what switches the spans on by themselves
+_profile_live = _Annotation.is_enabled
 
 
 def _ring_size():
@@ -70,7 +90,8 @@ def _ring_size():
 
 
 _lock = threading.Lock()
-_ring = deque(maxlen=_ring_size())   # finished span dicts, oldest first
+_ring = deque(maxlen=_ring_size())   # finished spans, oldest first
+_loop_ring = deque(maxlen=_ring_size())  # ... of traces a loop rooted
 _live = {}                           # span_id -> Span (in flight)
 _tls = threading.local()
 
@@ -78,9 +99,9 @@ _enabled = os.environ.get("MXNET_TRACE", "0") not in ("0", "", "false")
 
 
 def enabled():
-    """True when spans record (``MXNET_TRACE=1`` or :func:`enable`);
-    the one check every entry point makes."""
-    return _enabled
+    """True when spans record (``MXNET_TRACE=1``, :func:`enable`, or a
+    device profile being taken); the check every entry point makes."""
+    return _enabled or _profile_live()
 
 
 def enable():
@@ -93,15 +114,26 @@ def disable():
     _enabled = False
 
 
+#: ids come from a generator seeded by the OS once (and again in a forked
+#: child): ``os.urandom`` per span is a system call per span
+_rand = random.Random()
+os.register_at_fork(after_in_child=_rand.seed)
+
+
 def _new_id(nbytes):
-    return os.urandom(nbytes).hex()
+    return "%0*x" % (2 * nbytes, _rand.getrandbits(8 * nbytes))
 
 
 def _stack():
     st = getattr(_tls, "stack", None)
     if st is None:
         st = _tls.stack = []
+        _tls.tid = threading.get_native_id()
     return st
+
+
+def _wall(ns):
+    return round((ns + _WALL_NS) * 1e-9, 6)
 
 
 class _NullSpan:
@@ -123,6 +155,9 @@ class _NullSpan:
     def end(self, status="ok", **attrs):
         pass
 
+    def drop(self):
+        pass
+
     def ctx(self):
         return None
 
@@ -142,23 +177,35 @@ class Span:
     once via :meth:`end` (idempotent — a second call is ignored, so a
     failover path and a late resolve cannot double-record)."""
 
-    __slots__ = ("trace_id", "span_id", "parent_id", "name", "t0",
-                 "status", "attrs", "_pc0", "_stacked", "_ended")
+    __slots__ = ("trace_id", "span_id", "parent_id", "name", "t0_ns",
+                 "t1_ns", "tid", "status", "attrs", "_stacked", "_loop",
+                 "_recorded", "_ann", "_ended")
 
-    def __init__(self, name, trace_id, parent_id):
+    def __init__(self, name, trace_id, parent_id, loop=False,
+                 recorded=True):
         self.name = name
         self.trace_id = trace_id
-        self.span_id = _new_id(4)
+        self.span_id = _new_id(4) if recorded else None
         self.parent_id = parent_id
-        self.t0 = time.time()
+        self.t1_ns = None
         self.status = None
         self.attrs = {}
-        self._pc0 = time.perf_counter()
         self._stacked = False
+        self._loop = loop
+        self._recorded = recorded
+        self._ann = None
         self._ended = False
+        self.tid = None
+        self.t0_ns = time.monotonic_ns()
 
     def __bool__(self):
         return True
+
+    @property
+    def dur_s(self):
+        """Seconds from open to :meth:`end` (None while live)."""
+        return None if self.t1_ns is None \
+            else (self.t1_ns - self.t0_ns) * 1e-9
 
     def annotate(self, **attrs):
         """Attach attributes to a live span (last write per key wins)."""
@@ -170,35 +217,47 @@ class Span:
         remote side can parent its span here."""
         return {"trace_id": self.trace_id, "span_id": self.span_id}
 
-    def _snapshot(self, live=False):
+    def _record(self, live=False):
+        """The span as the dict every reader gets (built on the read, not
+        on the hot path that ends the span)."""
+        t0, t1 = self.t0_ns, self.t1_ns
         return {"trace_id": self.trace_id, "span_id": self.span_id,
                 "parent_id": self.parent_id, "name": self.name,
-                "t0": round(self.t0, 6),
+                "t0": _wall(t0), "t1": None if t1 is None else _wall(t1),
+                "dur_s": None if t1 is None
+                else round((t1 - t0) * 1e-9, 6),
+                "t0_ns": t0, "t1_ns": t1, "tid": self.tid,
                 "status": "in_flight" if live else self.status,
                 "attrs": dict(self.attrs)}
 
     def end(self, status="ok", **attrs):
         """Finish the span with a typed ``status``; moves it from the
-        live set into the bounded finished ring (and onto the
-        chrome-trace timeline when the profiler runs)."""
-        prof = _profiler.running()
-        end_us = _profiler.now_us() if prof else 0.0
-        dur = time.perf_counter() - self._pc0
+        live set into its bounded finished ring and closes its
+        annotation in the device profile."""
+        self._finish(status, attrs)
+
+    def drop(self):
+        """Forget a span that turned out to cover no work (an idle loop
+        iteration): it leaves the live set and the thread's stack and
+        nothing is recorded."""
+        self._finish(None, None)
+
+    def _finish(self, status, attrs):
+        t1 = time.monotonic_ns()
+        if not self._recorded:      # a bare timer: nobody else holds it
+            self.t1_ns = t1
+            return
         with _lock:
             if self._ended:
                 return
             self._ended = True
+            self.t1_ns = t1
             self.status = status
             if attrs:
                 self.attrs.update(attrs)
             _live.pop(self.span_id, None)
-            rec = {"trace_id": self.trace_id, "span_id": self.span_id,
-                   "parent_id": self.parent_id, "name": self.name,
-                   "t0": round(self.t0, 6),
-                   "t1": round(self.t0 + dur, 6),
-                   "dur_s": round(dur, 6), "status": status,
-                   "attrs": dict(self.attrs)}
-            _ring.append(rec)
+            if status is not None:
+                (_loop_ring if self._loop else _ring).append(self)
         if self._stacked:
             st = getattr(_tls, "stack", None)
             # only pop when ending on the opening thread with this
@@ -206,9 +265,13 @@ class Span:
             # not corrupt another thread's stack
             if st and st[-1] is self:
                 st.pop()
-        if prof:
-            _profiler.record("trace:%s" % self.name, "trace",
-                             end_us - dur * 1e6, end_us)
+            ann = self._ann
+            # a TraceMe belongs to the thread that opened it
+            if ann is not None \
+                    and getattr(_tls, "tid", None) == self.tid:
+                if self.attrs:
+                    ann.set_metadata(**self.attrs)
+                ann.__exit__(None, None, None)
 
     def __enter__(self):
         return self
@@ -222,7 +285,7 @@ class Span:
 
 
 def start_span(name, parent=None, trace_id=None, parent_id=None,
-               stack=True, **attrs):
+               stack=True, loop=False, timed=False, **attrs):
     """Open a span.
 
     Parent resolution, in order: an explicit ``parent`` :class:`Span`
@@ -231,23 +294,32 @@ def start_span(name, parent=None, trace_id=None, parent_id=None,
     (``trace_id``/``parent_id`` from a KVStore message), else the
     calling thread's current span; with none of those this span ROOTS
     a fresh trace.  ``stack=False`` opts out of thread-local parenting
-    for spans that outlive their opening call (a session's root span
-    must not become the implicit parent of unrelated work on the
-    submitting thread).  Returns :data:`NULL_SPAN` when disabled."""
-    if not _enabled:
-        return NULL_SPAN
+    for spans that outlive their opening call or end on another thread
+    (a session's root span must not become the implicit parent of
+    unrelated work on the submitting thread); such a span emits no
+    annotation into a device profile, a stacked one does.
+    ``loop=True`` says that this span, where it roots a trace, is one
+    iteration of a loop: it and its descendants go to the loop ring (a
+    span with a parent follows its parent).  Returns :data:`NULL_SPAN`
+    when nothing records — unless ``timed``, which then returns a bare
+    unrecorded span, for a caller that needs the length either way
+    (``telemetry.phase``'s histogram)."""
+    live = _profile_live()
+    if not _enabled and not live:
+        return Span(name, None, None, recorded=False) if timed \
+            else NULL_SPAN
+    cur = _stack()
     if parent is not None and parent:
-        tid, pid = parent.trace_id, parent.span_id
+        tid, pid, loop = parent.trace_id, parent.span_id, parent._loop
     elif trace_id is not None:
-        tid, pid = trace_id, parent_id
+        tid, pid, loop = trace_id, parent_id, False
+    elif cur:
+        top = cur[-1]
+        tid, pid, loop = top.trace_id, top.span_id, top._loop
     else:
-        cur = _stack()
-        top = cur[-1] if cur else None
-        if top is not None:
-            tid, pid = top.trace_id, top.span_id
-        else:
-            tid, pid = _new_id(8), None
-    sp = Span(name, tid, pid)
+        tid, pid = _new_id(8), None
+    sp = Span(name, tid, pid, loop=loop)
+    sp.tid = _tls.tid
     if attrs:
         sp.attrs.update(attrs)
     with _lock:
@@ -258,14 +330,41 @@ def start_span(name, parent=None, trace_id=None, parent_id=None,
             oldest = next(iter(_live.values()))
             _live.pop(oldest.span_id, None)
             oldest._ended = True
-            _ring.append(oldest._snapshot(live=False) | {
-                "t1": None, "dur_s": None, "status": "error",
-                "attrs": dict(oldest.attrs, dropped="live-ring-full")})
+            oldest.status = "error"
+            oldest.attrs["dropped"] = "live-ring-full"
+            (_loop_ring if oldest._loop else _ring).append(oldest)
         _live[sp.span_id] = sp
     if stack:
         sp._stacked = True
-        _stack().append(sp)
+        cur.append(sp)
+        if live:
+            sp._ann = _Annotation("mx." + name)
+            sp._ann.__enter__()
     return sp
+
+
+def host_read(site):
+    """The span of ONE blocking device-to-host read (``host_read``,
+    attribute ``site``), wherever the program makes one outside a
+    ``sync`` phase: ``with tracing.host_read("asnumpy"): ...``.  A
+    reader counts the outermost of these and of the ``fit.sync`` spans
+    on a thread."""
+    return start_span("host_read", loop=True, site=site)
+
+
+@contextlib.contextmanager
+def frame(status="error"):
+    """Ends, on the way out, every stacked span that the calling thread
+    opened inside the block and left open: a loop body that raised in
+    the middle of its iteration's span must not leave that span as the
+    parent of whatever the thread does next."""
+    st = _stack()
+    depth = len(st)
+    try:
+        yield
+    finally:
+        while len(st) > depth:
+            st.pop().end(status)
 
 
 def current():
@@ -283,10 +382,11 @@ def ctx():
 
 
 def _trace_spans(trace_id):
-    """Every recorded span of one trace: finished (from the ring) plus
+    """Every recorded span of one trace: finished (from the rings) plus
     live (synthesized ``in_flight``), lock held by caller."""
-    out = [dict(r) for r in _ring if r["trace_id"] == trace_id]
-    out.extend(sp._snapshot(live=True) for sp in _live.values()
+    out = [sp._record() for ring in (_ring, _loop_ring) for sp in ring
+           if sp.trace_id == trace_id]
+    out.extend(sp._record(live=True) for sp in _live.values()
                if sp.trace_id == trace_id)
     return out
 
@@ -306,7 +406,7 @@ def tree(trace_id):
         spans = _trace_spans(trace_id)
     if not spans:
         return None
-    spans.sort(key=lambda s: s["t0"])
+    spans.sort(key=lambda s: s["t0_ns"])
     ids = {s["span_id"] for s in spans}
     children = {}
     roots, orphans = [], []
@@ -333,18 +433,22 @@ def tree(trace_id):
 
 
 def spans_recent(n=1000):
-    """The newest ``n`` FINISHED spans (copies, oldest first) — what
-    the flight recorder dumps as its ndjson span ring."""
+    """The newest ``n`` FINISHED spans of both rings (copies, in the
+    order they ended) — what the flight recorder dumps as its ndjson
+    span ring and the benchmark's readers take their records from."""
     with _lock:
-        return [dict(r) for r in list(_ring)[-int(n):]]
+        done = [sp for ring in (_ring, _loop_ring) for sp in ring]
+    done.sort(key=lambda sp: sp.t1_ns or sp.t0_ns)
+    return [sp._record() for sp in done[-int(n):]]
 
 
 def reset():
-    """Clear the finished ring and the live set (tests; enablement and
+    """Clear the finished rings and the live set (tests; enablement and
     other threads' stacks are unchanged)."""
-    global _ring
+    global _ring, _loop_ring
     with _lock:
         _ring = deque(maxlen=_ring_size())
+        _loop_ring = deque(maxlen=_ring_size())
         _live.clear()
     st = getattr(_tls, "stack", None)
     if st:

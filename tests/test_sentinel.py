@@ -286,34 +286,6 @@ def test_phase_hook_list_feeds_all_consumers():
         telemetry.remove_phase_hook(hb)
 
 
-def test_set_phase_hook_alias_does_not_evict_registrations():
-    """The deprecating alias replaces only its OWN hook: the flight
-    recorder (registered at perfdebug import) and any add_phase_hook
-    consumer keep observing."""
-    seen = []
-    added = telemetry.add_phase_hook(
-        lambda fam, ph, s: seen.append("added"))
-    alias_seen = []
-    try:
-        telemetry.set_phase_hook(
-            lambda fam, ph, s: alias_seen.append("alias1"))
-        telemetry.set_phase_hook(
-            lambda fam, ph, s: alias_seen.append("alias2"))
-        with telemetry.phase("probe2"):
-            pass
-        assert "added" in seen
-        assert alias_seen == ["alias2"]  # replace, not stack
-        telemetry.set_phase_hook(None)
-        seen.clear()
-        alias_seen.clear()
-        with telemetry.phase("probe3"):
-            pass
-        assert "added" in seen and not alias_seen
-    finally:
-        telemetry.remove_phase_hook(added)
-        telemetry.set_phase_hook(None)
-
-
 def test_watchdog_and_flight_recorder_share_the_phase_feed():
     """Regression for the single-slot eviction bug: with the flight
     recorder armed AND a watchdog started, one timed phase lands in
