@@ -10,8 +10,8 @@ batching & replica pool"):
   logits plus the per-layer K/V rows to seed a slot of the engine's
   device-resident cache;
 * :func:`decode_step_math` — ONE token for ALL ``S`` cache slots at
-  once: scatter the incoming token's K/V into each slot's cache row,
-  attend over ``positions <= length`` and produce ``(S, vocab)``
+  once: write the incoming token's K/V into each slot's cache row
+  (:func:`write_rows`), attend over ``positions <= length`` and produce ``(S, vocab)``
   logits.  Fixed shapes in, fixed shapes out — the function compiles
   once per ``(S, max_len)`` and never again
   (:mod:`mxnet_tpu.serving.decode` wraps it with sampling and slot
@@ -37,8 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["LMConfig", "init_params", "forward_logits", "prefill_kv",
-           "decode_step_math", "prefill_kv_paged", "decode_step_paged",
-           "params_to_blob", "params_from_blob"]
+           "write_rows", "decode_step_math", "prefill_kv_paged",
+           "decode_step_paged", "params_to_blob", "params_from_blob"]
 
 #: model hyperparameters; ``max_len`` bounds the KV cache (and therefore
 #: prompt + generated length), ``eos_id`` is the token that retires a
@@ -160,6 +160,33 @@ def prefill_kv(cfg, params, tokens, length):
     return last, tuple(ks), tuple(vs)
 
 
+@jax.jit
+def write_rows(cache, rows, pos):
+    """``cache (S, max_len, heads, head_dim)`` with ``rows[i]`` (of
+    ``(S, heads, head_dim)``) written at ``[i, pos[i]]``; ``pos (S,)``
+    lies within ``0..max_len-1``.  Equal, as an array, to
+    ``cache.at[arange(S), pos].set(rows)``.
+
+    One ``dynamic_update_slice`` a slot, and not that scatter: an
+    update-slice is done in place in whatever layout the compiler keeps
+    the cache in, while a scatter wants the cache row-major.  On the TPU
+    the dense cache lives position-minor (what both attention
+    contractions read), and the scatter cost two transposes of the whole
+    array a layer and a step, most of the step's time (PERF.md, PR 25).
+    Nothing here asks which layout or which backend it is: where the
+    cache is kept row-major the update-slices are in place just the same.
+
+    Jitted on its own so that a step traces the ``S`` update-slices once
+    and not once a layer and array: an engine traces its step twice at
+    every start, and that time is the server's set-up.
+    """
+    pieces = jnp.split(rows[:, None], cache.shape[0])
+    for i, piece in enumerate(pieces):
+        cache = jax.lax.dynamic_update_slice(
+            cache, piece, (i, pos[i], 0, 0), allow_negative_indices=False)
+    return cache
+
+
 def decode_step_math(cfg, params, cache_k, cache_v, last_tok, lengths):
     """One decode token for all ``S`` slots.
 
@@ -167,17 +194,20 @@ def decode_step_math(cfg, params, cache_k, cache_v, last_tok, lengths):
     head_dim)``; ``last_tok (S,) int32`` is each slot's most recent
     token (prompt tail after prefill, previous sample afterwards);
     ``lengths (S,) int32`` is each slot's cache fill — the position the
-    incoming token's K/V is scattered to, and the inclusive attention
+    incoming token's K/V is written to, and the inclusive attention
     horizon.  Returns ``(logits (S, vocab), new_cache_k, new_cache_v)``.
 
     Inactive slots ride along (fixed shape => no recompile): their
-    scatter lands on a row the mask makes unreachable until a real
-    write replaces it, and their logits are discarded host-side.
+    row lands where the mask makes it unreachable until a real write
+    replaces it, and their logits are discarded host-side.
+
+    The rows go in through :func:`write_rows`, which assumes nothing
+    about the layout the cache lives in on the device and is not a
+    scatter, so the compiled step holds no copy of a cache-sized array.
     """
     (s, m) = cache_k[0].shape[:2]
     hd = cfg.embed // cfg.heads
     scale = 1.0 / np.sqrt(hd)
-    rows = jnp.arange(s)
     kpos = jnp.arange(m)
     pos = jnp.clip(lengths, 0, cfg.max_len - 1)
     x = params["embed"][last_tok] + params["pos"][pos]
@@ -188,8 +218,8 @@ def decode_step_math(cfg, params, cache_k, cache_v, last_tok, lengths):
         qkv = jnp.einsum("se,ef->sf", h, pl["qkv_w"])
         q, k, v = (a.reshape(s, cfg.heads, hd)
                    for a in jnp.split(qkv, 3, axis=-1))
-        ck = cache_k[l].at[rows, pos].set(k)
-        cv = cache_v[l].at[rows, pos].set(v)
+        ck = write_rows(cache_k[l], k, pos)
+        cv = write_rows(cache_v[l], v, pos)
         scores = jnp.einsum("shd,smhd->shm", q, ck) * scale
         mask = kpos[None, None, :] <= pos[:, None, None]
         att = jax.nn.softmax(

@@ -83,6 +83,97 @@ def test_greedy_decode_matches_full_forward():
         eng.close()
 
 
+def _scatter_rows(cache, rows, pos):
+    """The dense step's cache write until PR 25, and the reference for
+    :func:`tlm.write_rows` since."""
+    import jax.numpy as jnp
+
+    return cache.at[jnp.arange(cache.shape[0]), pos].set(rows)
+
+
+@pytest.mark.parametrize("lengths", [
+    pytest.param([0, 3, 5, 9], id="a-slot-at-position-0"),
+    pytest.param([MAX_LEN - 1, 3, 5, 9], id="a-slot-at-max_len-1"),
+    # slot 1 was never admitted; slot 2 retired at the cache's end, where
+    # its count is one past the last row and the write is clamped
+    pytest.param([4, 0, MAX_LEN, 9], id="inactive-slots-ride-along"),
+    pytest.param([6, 11, 6, 11], id="two-slots-at-one-position"),
+])
+def test_dense_step_writes_the_rows_the_scatter_wrote(lengths, monkeypatch):
+    """``write_rows`` equals ``cache.at[rows, pos].set(k)`` as an array,
+    and the step built on it returns the logits and every layer's K and V
+    that the step built on the scatter returns, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    s, hd = len(lengths), EMBED // HEADS
+    rs = np.random.RandomState(11)
+
+    def caches():
+        return tuple(
+            jnp.asarray(rs.normal(size=(s, MAX_LEN, HEADS, hd)),
+                        jnp.float32) for _ in range(LAYERS))
+
+    cache_k, cache_v = caches(), caches()
+    rows = jnp.asarray(rs.normal(size=(s, HEADS, hd)), jnp.float32)
+    pos = jnp.clip(jnp.asarray(lengths, jnp.int32), 0, MAX_LEN - 1)
+    np.testing.assert_array_equal(
+        tlm.write_rows(cache_k[0], rows, pos),
+        _scatter_rows(cache_k[0], rows, pos))
+
+    last = jnp.asarray(rs.randint(0, VOCAB, size=s), jnp.int32)
+
+    def step():
+        # a jit of its own each time: the trace looks write_rows up anew
+        return jax.jit(lambda *a: tlm.decode_step_math(CFG_NO_EOS, *a))(
+            PARAMS, cache_k, cache_v, last,
+            jnp.asarray(lengths, jnp.int32))
+
+    got = step()
+    monkeypatch.setattr(tlm, "write_rows", _scatter_rows)
+    want = step()
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_step_lowers_for_the_shapes_the_benchmark_lowers_it_with():
+    """``benchmark/families/decode_engine.py`` ``scratch_bytes`` lowers
+    ``engine._step_fn`` again after every window, on the chip only, from
+    shapes alone: per-layer K and V of ``(slots, max_len, heads,
+    head_dim)`` float32 and the six small arrays.  The same call here, so
+    that a state of another shape fails on the CPU first."""
+    import jax
+    import jax.numpy as jnp
+
+    eng = _engine(autostart=False)
+    try:
+        cfg, s = eng.cfg, eng.slots
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        kv = sds((s, cfg.max_len, cfg.heads, cfg.embed // cfg.heads),
+                 jnp.float32)
+        state = (tuple(kv for _ in range(cfg.layers)),
+                 tuple(kv for _ in range(cfg.layers)),
+                 sds((s,), jnp.int32), sds((s,), jnp.int32),
+                 sds((s,), jnp.int32), sds((s,), jnp.bool_),
+                 sds((s,), jnp.float32), sds((s,), jnp.uint32))
+        params = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype), eng._params)
+        lowered = eng._step_fn.lower(params, state, sds((s,), jnp.bool_))
+        assert lowered.compile().memory_analysis() \
+            .temp_size_in_bytes >= 0
+        new_state, packed = lowered.out_info
+        assert jax.tree_util.tree_map(
+            lambda a: (a.shape, a.dtype), new_state) == \
+            jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), state)
+        assert packed.shape == (3, s)
+    finally:
+        eng.close()
+
+
 def test_eos_retires_early_and_is_included():
     """With a reachable EOS the sequence stops at it (EOS is the last
     token) instead of running to max_new_tokens; either way the decode

@@ -49,9 +49,10 @@ def _tpu_trace():
         registry.trace_device.reset(token)
 
 
-def _compile(fn, *shapes):
-    """Compile ``fn`` for the described chip from ``(shape, dtype)``
-    pairs, and check that the kernel is in the compiled text."""
+def _one_chip():
+    """The sharding that pins a shape onto one chip of the described
+    ``v5e:2x2``; the child exits with ``_SKIP`` where it cannot be
+    described."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -62,7 +63,13 @@ def _compile(fn, *shapes):
         # the chip (no libtpu, unknown topology name) skips the case
         print("cannot describe a v5e topology: %s" % e)
         sys.exit(_SKIP)
-    sharding = SingleDeviceSharding(topo.devices[0])
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    """Compile ``fn`` for the described chip from ``(shape, dtype)``
+    pairs, and check that the kernel is in the compiled text."""
+    sharding = _one_chip()
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
             for s, d in shapes]
     assert "tpu_custom_call" in \
@@ -157,6 +164,86 @@ def _lstm_case():
 
 
 CASES["lstm-fwd-bwd-ptb-35x32x200"] = _lstm_case
+
+
+# -- the dense decode tier's two cache writes ------------------------------------
+#: benchmark/configs/gpt2-large.json's widths (vocabulary, embed, heads,
+#: layers, ffn, positions) with two of its 36 layers, its slots, and its
+#: largest prefill bucket
+_GPT2_LARGE, _SLOTS, _BUCKET = (50304, 1280, 20, 2, 5120, 1024), 12, 768
+
+
+def _dense_engine_case(program):
+    """The engine's own ``jit_step`` / ``jit_prefill``, state donated, at the
+    ``gpt2-large`` cell's widths: the program may hold no temporaries the
+    size of a cache array (beyond what it needs whatever the cache's
+    layout) and no copy of one.  A write of the new rows that is not done in
+    the layout the cache lives in on the chip shows up as both (the scatter
+    the step had until PR 25: two transposes an array)."""
+    def run():
+        import re
+
+        from mxnet_tpu.models import transformer_lm as tlm
+        from mxnet_tpu.serving import DecodeEngine
+
+        class Unwarmed(DecodeEngine):
+            """Builds the programs and its state, and runs nothing."""
+
+            def _warm(self, state):
+                return state
+
+        cfg = tlm.LMConfig(*_GPT2_LARGE, eos_id=_GPT2_LARGE[0])
+        # the tree init_params builds, without drawing 170 M numbers
+        v, e, f, n = cfg.vocab, cfg.embed, cfg.ffn, cfg.layers
+        shapes = {"embed": (v, e), "pos": (cfg.max_len, e), "head": (e, v),
+                  "ln_f": (e,),
+                  "blocks": {"ln1": (n, e), "qkv_w": (n, e, 3 * e),
+                             "out_w": (n, e, e), "ln2": (n, e),
+                             "up_w": (n, e, f), "down_w": (n, f, e)}}
+        engine = Unwarmed(
+            cfg, jax.tree_util.tree_map(
+                lambda shape: jnp.zeros(shape, jnp.float32), shapes,
+                is_leaf=lambda a: isinstance(a, tuple)),
+            slots=_SLOTS, prefill_buckets=(_BUCKET,), autostart=False)
+        one_chip = _one_chip()
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        params, state = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype),
+            (engine._params, engine._boot_state))
+        if program == "step":
+            lowered = engine._step_fn.lower(params, state,
+                                            sds((_SLOTS,), jnp.bool_))
+        else:
+            lowered = engine._prefill_fns[_BUCKET].lower(
+                params, state, sds((_BUCKET,), jnp.int32),
+                sds((), jnp.int32), sds((), jnp.int32), sds((), jnp.int32),
+                sds((), jnp.float32), sds((), jnp.uint32),
+                sds((), jnp.bool_))
+        compiled = lowered.compile()
+        kv = state[0][0]
+        cache_bytes = kv.size * kv.dtype.itemsize
+        # the prefill's own largest temporary is not the cache's: the
+        # logits of every prompt position, of which it keeps one row
+        own = 4 * _BUCKET * cfg.vocab if program == "prefill" else 0
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp - own < cache_bytes, \
+            "%d bytes of temporaries (%d of them the program's own), " \
+            "one cache array is %d" % (temp, own, cache_bytes)
+        copies = re.findall(
+            r"= f32\[%d,%d,%d,%d\]\{[^}]*\} copy\(.*" % kv.shape,
+            compiled.as_text())
+        assert not copies, "%d copies of a cache array, the first: %s" \
+            % (len(copies), copies[0][:200])
+    return run
+
+
+CASES["decode-dense-step-gpt2-large-12-slots-no-cache-copy"] = \
+    _dense_engine_case("step")
+CASES["decode-dense-prefill-768-gpt2-large-no-cache-copy"] = \
+    _dense_engine_case("prefill")
 
 
 # -- the tests -----------------------------------------------------------------
