@@ -1,0 +1,473 @@
+"""K-EXAONE-style decoder for the decode tier: window and full attention
+in one cache, and one chip's share of sigmoid-routed experts.
+
+The block is pre-norm: ``x += Attn(RMS(x))``, ``x += MLP(RMS(x))``, eps
+1e-5.  Attention has ``heads`` query heads over ``kv_heads`` K/V heads of
+``head_dim`` (query head ``i`` reads K/V head ``i // (heads // kv_heads)``),
+no biases, RMS-normed ``q`` and ``k`` per head.  A *window* layer
+(``layer_types[l] == "sliding_attention"``) rotates ``q`` and ``k`` (RoPE,
+half-split pairs) and a query at position ``p`` reads ``p-window+1..p``; a
+*full* layer has no positions at all and reads everything before it.  The
+MLP of a *dense* layer is one SwiGLU; a *sparse* layer routes:
+``s = sigmoid(h Wr)``, ``chosen = top_k(s + bias)``, ``w = s / (sum over
+chosen of s + 1e-20) * routed_scale``, ``y = sum over chosen of w_e E_e(h)
++ E_shared(h)``.
+
+**This chip's share.**  The layer is told ``(first_expert, experts_held,
+num_experts)``.  The router, the choice and the weights are over all
+``num_experts``; the sum runs over the chosen experts that are held here.
+What the absent experts would add is left out (they live on other chips,
+whose exchange is not this module's), the shared expert is computed whole.
+Nothing is dropped at any imbalance: a row may pick ``top_k`` held experts
+and gets every one of them.  :func:`sparse_mlp` over each share, the shared
+expert counted once, adds up to the uncut layer
+(``tests/test_exaone_moe.py``).
+
+**The routed product** is :func:`routed_experts`: every held expert's
+SwiGLU over all rows, combined with weights that are zero where a row did
+not choose the expert.  Fixed shapes, no sort, no capacity.  At a decode
+step (some hundred rows) the held experts' weights are what the product
+reads and their bytes bind it; PERF.md section 6 (PR 27) has what was
+measured against it.
+
+**Precision.**  Weights and cache bfloat16 (whatever dtype ``params`` come
+in is used as it is: the tests run float32).  Every matrix product takes
+operands in the weights' dtype and accumulates in float32.  Kept in
+float32: the residual stream and its additions, the norms, the rotation,
+the router's product (``highest`` precision: its 128 scores decide a
+discrete choice), sigmoid, top-k and weights, the scores' softmax, the
+logits.  Rounded to the weights' dtype: the normed activations entering a
+product, ``q``/``k``/``v`` (so what the cache holds is what the prefill
+attended over), the attention weights entering the weighted sum, and the
+SwiGLU's inner activations.
+
+**The cache** (:meth:`ExaoneMoE.cache_spec`): a full layer holds
+``(slots, kv_heads, max_len, head_dim)``; a window layer a ring
+``(slots, kv_heads, window, head_dim)`` written at ``pos % window``, K
+stored already rotated, masked by the absolute position each ring row
+holds.  A prompt longer than the window leaves its last ``window``
+positions in the ring.  K/V heads come before positions because that is
+the order both attention products read: compiled for the chip with
+positions first, every step copied every cache array into this order and
+back (PERF.md section 6, PR 27).
+
+:func:`forward_logits` is the in-repo plain reference: float32, ``highest``
+precision, no cache, one sequence, written out on its own.  Prefill and
+decode step share :func:`_block`, which takes its cache access as an
+argument.
+"""
+
+from __future__ import annotations
+
+import math
+from collections import namedtuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .transformer_lm import CacheLayer
+
+__all__ = ["ExaoneConfig", "ExaoneMoE", "init_params",
+           "forward_logits", "sparse_mlp", "route", "routed_experts",
+           "write_ring", "write_full"]
+
+#: ``layers`` is how many are held; ``layer_types`` / ``mlp_types`` name
+#: each ("sliding_attention" | "full_attention", "dense" | "sparse").
+#: ``first_expert`` / ``experts_held`` are this chip's share of
+#: ``num_experts``; ``vocab`` is the slice of the vocabulary held here.
+ExaoneConfig = namedtuple("ExaoneConfig", [
+    "vocab", "embed", "heads", "kv_heads", "head_dim", "layers",
+    "layer_types", "mlp_types", "dense_ffn", "expert_ffn", "num_experts",
+    "top_k", "first_expert", "experts_held", "window", "rope_theta",
+    "routed_scale", "max_len", "eos_id"])
+
+EPS = 1e-5
+_NEG = jnp.float32(-1e30)
+
+
+def init_params(cfg, seed=0, dtype=jnp.bfloat16):
+    """Seeded parameters (host arrays; the engine commits them to its
+    device).  ``layers`` is a list, since dense and sparse layers differ;
+    the router's matrix and selection bias stay float32."""
+    rs = np.random.RandomState(seed)
+    e, hd = cfg.embed, cfg.head_dim
+    resid = 0.02 / math.sqrt(2.0 * cfg.layers)
+
+    def nrm(*shape, s=0.02, dt=dtype):
+        return jnp.asarray(rs.normal(0, s, shape).astype(np.float32), dt)
+
+    def swiglu(width, *lead):
+        return {"gate": nrm(*lead, e, width), "up": nrm(*lead, e, width),
+                "down": nrm(*lead, width, e, s=resid)}
+
+    layers = []
+    for l in range(cfg.layers):
+        p = {"ln1": jnp.ones((e,), jnp.float32),
+             "ln2": jnp.ones((e,), jnp.float32),
+             "q_norm": jnp.ones((hd,), jnp.float32),
+             "k_norm": jnp.ones((hd,), jnp.float32),
+             "wq": nrm(e, cfg.heads * hd), "wk": nrm(e, cfg.kv_heads * hd),
+             "wv": nrm(e, cfg.kv_heads * hd),
+             "wo": nrm(cfg.heads * hd, e, s=resid)}
+        if cfg.mlp_types[l] == "sparse":
+            p["moe"] = dict(
+                swiglu(cfg.expert_ffn, cfg.experts_held),
+                router=nrm(e, cfg.num_experts, dt=jnp.float32),
+                bias=nrm(cfg.num_experts, s=0.01, dt=jnp.float32),
+                shared=swiglu(cfg.expert_ffn))
+        else:
+            p["mlp"] = swiglu(cfg.dense_ffn)
+        layers.append(p)
+    return {"embed": nrm(cfg.vocab, e), "head": nrm(e, cfg.vocab),
+            "ln_f": jnp.ones((e,), jnp.float32), "layers": layers}
+
+
+# -- pieces both the program and the reference are written from ----------------
+def _rms(x, g):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + EPS) * g
+
+
+def _mm(a, w):
+    """``a @ w``: operands in the weights' dtype, float32 accumulation."""
+    return jnp.dot(a.astype(w.dtype), w,
+                   preferred_element_type=jnp.float32)
+
+
+def _swiglu(h, w):
+    a = jax.nn.silu(_mm(h, w["gate"])) * _mm(h, w["up"])
+    return _mm(a, w["down"])
+
+
+def _rope(cfg, x, pos):
+    """Rotate ``x (T, n, head_dim)`` at positions ``pos (T,)``: pairs are
+    ``(x[i], x[i + head_dim/2])``, frequency ``theta ** (-2i/head_dim)``."""
+    half = cfg.head_dim // 2
+    inv = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[:, None] * inv[None]
+    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def route(cfg, h, moe):
+    """``h (T, embed)`` float32 -> ``(chosen (T, top_k) int32 over all
+    num_experts, weights (T, top_k) float32)``."""
+    with jax.default_matmul_precision("highest"):
+        s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32), moe["router"]))
+    _, chosen = jax.lax.top_k(s + moe["bias"], cfg.top_k)
+    picked = jnp.take_along_axis(s, chosen, axis=-1)
+    w = picked / (picked.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scale
+    return chosen.astype(jnp.int32), w
+
+
+def _combine(cfg, chosen, w):
+    """``(T, experts_held)`` float32: each row's weight for each held
+    expert, 0 where the row did not choose it."""
+    local = chosen - cfg.first_expert
+    hit = jax.nn.one_hot(local, cfg.experts_held, dtype=jnp.float32)
+    return (hit * w[..., None]).sum(1)
+
+
+def routed_experts(h, comb, moe):
+    """The held experts' part: ``sum_x comb[t, x] * E_x(h[t])``.  Every
+    held expert over every row, the combine weight applied before the down
+    projection so that experts and inner width contract in one product."""
+    dt = moe["gate"].dtype
+    hb = h.astype(dt)
+    g = jnp.einsum("te,xef->txf", hb, moe["gate"],
+                   preferred_element_type=jnp.float32)
+    u = jnp.einsum("te,xef->txf", hb, moe["up"],
+                   preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(g) * u * comb[:, :, None]).astype(dt)
+    return jnp.einsum("txf,xfe->te", a, moe["down"],
+                      preferred_element_type=jnp.float32)
+
+
+def sparse_mlp(cfg, h, moe, shared=True):
+    """The sparse MLP of this share: ``(y (T, embed), chosen (T, top_k))``.
+    ``shared=False`` leaves the shared expert out (a share other than the
+    one that counts it, when shares are added up)."""
+    with jax.named_scope("moe.route"):
+        chosen, w = route(cfg, h, moe)
+        comb = _combine(cfg, chosen, w)
+    with jax.named_scope("moe.experts"):
+        y = routed_experts(h, comb, moe)
+    if shared:
+        with jax.named_scope("moe.shared"):
+            y = y + _swiglu(h, moe["shared"])
+    return y, chosen
+
+
+# -- the plain reference -------------------------------------------------------
+def forward_logits(cfg, params, tokens, with_choices=False):
+    """``tokens (T,) int32 -> (T, vocab)`` float32 logits of one sequence:
+    the equations of the module docstring in float32 at ``highest``
+    precision, no cache, each held expert in a plain loop.
+    ``with_choices`` also returns the router's choices, one ``(T, top_k)``
+    array a sparse layer."""
+    (t,) = tokens.shape
+    f32 = jnp.float32
+    params = jax.tree_util.tree_map(lambda a: a.astype(f32), params)
+    group = cfg.heads // cfg.kv_heads
+    pos = jnp.arange(t)
+    causal = pos[None, :] <= pos[:, None]
+    choices = []
+    with jax.default_matmul_precision("highest"):
+        x = params["embed"][tokens]
+        for l, p in enumerate(params["layers"]):
+            h = _rms(x, p["ln1"])
+            q = (h @ p["wq"]).reshape(t, cfg.heads, cfg.head_dim)
+            k = (h @ p["wk"]).reshape(t, cfg.kv_heads, cfg.head_dim)
+            v = (h @ p["wv"]).reshape(t, cfg.kv_heads, cfg.head_dim)
+            q, k = _rms(q, p["q_norm"]), _rms(k, p["k_norm"])
+            mask = causal
+            if cfg.layer_types[l] == "sliding_attention":
+                q, k = _rope(cfg, q, pos), _rope(cfg, k, pos)
+                mask = mask & (pos[None, :] > pos[:, None] - cfg.window)
+            k, v = (jnp.repeat(a, group, axis=1) for a in (k, v))
+            scores = jnp.einsum("qhd,khd->hqk", q, k) \
+                / math.sqrt(cfg.head_dim)
+            att = jax.nn.softmax(jnp.where(mask[None], scores, _NEG), -1)
+            ctx = jnp.einsum("hqk,khd->qhd", att, v)
+            x = x + ctx.reshape(t, -1) @ p["wo"]
+            h = _rms(x, p["ln2"])
+            if "mlp" in p:
+                x = x + _swiglu(h, p["mlp"])
+                continue
+            moe = p["moe"]
+            chosen, w = route(cfg, h, moe)
+            choices.append(chosen)
+            y = _swiglu(h, moe["shared"])
+            for e in range(cfg.experts_held):
+                mine = (chosen == cfg.first_expert + e)
+                w_e = jnp.where(mine, w, 0.0).sum(-1, keepdims=True)
+                y = y + w_e * _swiglu(
+                    h, {n: moe[n][e] for n in ("gate", "up", "down")})
+            x = x + y
+        logits = _rms(x, params["ln_f"]) @ params["head"]
+    return (logits, choices) if with_choices else logits
+
+
+# -- cache writes --------------------------------------------------------------
+def write_ring(cache, rows, at):
+    """``cache (S, n, W, d)`` with ``rows[i] (n, d)`` at ``[i, :, at[i]]``:
+    a select over the whole ring, in place under donation.  A ring is small
+    (W rows a slot), so one pass over it costs less than S separate writes
+    (PERF.md section 6, PR 27)."""
+    hit = jnp.arange(cache.shape[2])[None, :] == at[:, None]
+    return jnp.where(hit[:, None, :, None],
+                     rows[:, :, None].astype(cache.dtype), cache)
+
+
+@jax.jit
+def write_full(cache, rows, at):
+    """``cache (S, n, max_len, d)`` with ``rows[i] (n, d)`` at
+    ``[i, :, at[i]]``: one update-slice a slot, in place in whatever layout
+    the cache lives in (:func:`transformer_lm.write_rows` has why, and why
+    this is a ``jit`` of its own); a pass over all ``max_len`` rows would
+    move the whole cache."""
+    pieces = jnp.split(rows[:, :, None].astype(cache.dtype), cache.shape[0])
+    for i, piece in enumerate(pieces):
+        cache = jax.lax.dynamic_update_slice(
+            cache, piece, (i, 0, at[i], 0), allow_negative_indices=False)
+    return cache
+
+
+# -- the block, shared by prefill and decode step ------------------------------
+def _block(cfg, l, p, x, pos, attend, counts=None):
+    """One layer over rows ``x (T, embed)`` float32 at absolute positions
+    ``pos (T,)``.  ``attend(l, q, k, v)`` is the caller's cache access: it
+    is handed ``q (T, heads, d)``, ``k``/``v (T, kv_heads, d)`` (normed,
+    rotated, in the weights' dtype) and returns the context ``(T, heads,
+    d)`` float32.  ``counts(l, chosen)`` is told a sparse layer's choices."""
+    window = cfg.layer_types[l] == "sliding_attention"
+    t = x.shape[0]
+    dt = p["wq"].dtype
+    with jax.named_scope("attn.window" if window else "attn.full"):
+        h = _rms(x, p["ln1"])
+        q = _mm(h, p["wq"]).reshape(t, cfg.heads, cfg.head_dim)
+        k = _mm(h, p["wk"]).reshape(t, cfg.kv_heads, cfg.head_dim)
+        v = _mm(h, p["wv"]).reshape(t, cfg.kv_heads, cfg.head_dim)
+        q, k = _rms(q, p["q_norm"]), _rms(k, p["k_norm"])
+        if window:
+            q, k = _rope(cfg, q, pos), _rope(cfg, k, pos)
+        ctx = attend(l, q.astype(dt), k.astype(dt), v.astype(dt))
+        x = x + _mm(ctx.reshape(t, -1), p["wo"])
+    h = _rms(x, p["ln2"])
+    if "mlp" in p:
+        with jax.named_scope("mlp.dense"):
+            return x + _swiglu(h, p["mlp"])
+    y, chosen = sparse_mlp(cfg, h, p["moe"])
+    if counts is not None:
+        counts(l, chosen)
+    return x + y
+
+
+def _grouped(cfg, q):
+    """``(T, heads, d) -> (T, kv_heads, group, d)``."""
+    return q.reshape(q.shape[0], cfg.kv_heads, cfg.heads // cfg.kv_heads,
+                     cfg.head_dim)
+
+
+def _softmax_ctx(scores, mask, values, spec_ctx):
+    att = jax.nn.softmax(jnp.where(mask, scores, _NEG), axis=-1)
+    return jnp.einsum(spec_ctx, att.astype(values.dtype), values,
+                      preferred_element_type=jnp.float32)
+
+
+class ExaoneMoE:
+    """The model object the decode engine is given (its model protocol,
+    :mod:`mxnet_tpu.serving.decode`): cache specification, prefill, decode
+    step, and the routing counters as extra device state."""
+
+    def __init__(self, cfg, cache_dtype=jnp.bfloat16):
+        if cfg.heads % cfg.kv_heads:
+            raise ValueError("heads=%d not a multiple of kv_heads=%d"
+                             % (cfg.heads, cfg.kv_heads))
+        if len(cfg.layer_types) < cfg.layers \
+                or len(cfg.mlp_types) < cfg.layers:
+            raise ValueError("layer_types/mlp_types name fewer than "
+                             "layers=%d layers" % cfg.layers)
+        if not 0 <= cfg.first_expert <= cfg.first_expert \
+                + cfg.experts_held <= cfg.num_experts:
+            raise ValueError("experts %d..%d are not within 0..%d"
+                             % (cfg.first_expert, cfg.first_expert
+                                + cfg.experts_held, cfg.num_experts))
+        self.cfg = cfg
+        #: what the cache holds K and V in (the tests' float32 runs pass
+        #: float32; K and V are rounded to it before they are attended)
+        self.cache_dtype = cache_dtype
+        self.sparse = [l for l in range(cfg.layers)
+                       if cfg.mlp_types[l] == "sparse"]
+
+    # -- the protocol ------------------------------------------------------
+    def cache_spec(self):
+        cfg, dtype = self.cfg, self.cache_dtype
+        return tuple(
+            CacheLayer("ring", cfg.window, cfg.kv_heads, cfg.head_dim,
+                       dtype, True)
+            if cfg.layer_types[l] == "sliding_attention" else
+            CacheLayer("full", cfg.max_len, cfg.kv_heads, cfg.head_dim,
+                       dtype, True) for l in range(cfg.layers))
+
+    def extra_state(self):
+        """The routing counters (uint32, wrapping): picks routed to each
+        held expert of each sparse layer, picks made in all, rows stepped.
+        Counted in decode steps, over active rows."""
+        return {"moe_picks": jnp.zeros((len(self.sparse),
+                                        self.cfg.experts_held), jnp.uint32),
+                "moe_picks_total": jnp.zeros((), jnp.uint32),
+                "rows": jnp.zeros((), jnp.uint32),
+                "steps": jnp.zeros((), jnp.uint32)}
+
+    def counters(self, extra):
+        """The extra state read back (whole numbers), with the gauges the
+        engine publishes under ``gauges``: picks a held expert sees a step,
+        the held experts' share of all picks, and the busiest held
+        expert's picks over the mean's."""
+        picks = np.asarray(extra["moe_picks"], np.int64)
+        total, steps = int(extra["moe_picks_total"]), int(extra["steps"])
+        out = {"moe_picks": picks.tolist(), "moe_picks_total": total,
+               "rows": int(extra["rows"]), "steps": steps}
+        if steps and picks.sum():
+            out["gauges"] = {
+                "serving.moe.tokens_per_expert":
+                    float(picks.sum()) / (picks.size * steps),
+                "serving.moe.local_share": float(picks.sum()) / total,
+                "serving.moe.imbalance": float(picks.max() / picks.mean())}
+        return out
+
+    def prefill(self, params, tokens, length):
+        """One bucket-padded prompt ``tokens (P,)`` of ``length`` real
+        tokens -> ``(last_logits (vocab,), ks, vs)``: what to write into a
+        slot of each layer's cache from row 0, K/V heads first (a full
+        layer's positions ``0..P-1``; a ring whole, holding the last
+        ``window`` positions below ``length`` where they belong)."""
+        cfg = self.cfg
+        (p_len,) = tokens.shape
+        pos = jnp.arange(p_len)
+        causal = pos[None, :] <= pos[:, None]
+        near = pos[None, :] > pos[:, None] - cfg.window
+        # ring row j holds the last position below ``length`` that is j
+        # modulo the window; rows no position has reached yet hold what the
+        # decode step's mask never reads
+        ring_src = jnp.clip(
+            (length - 1) - ((length - 1 - jnp.arange(cfg.window))
+                            % cfg.window), 0, p_len - 1)
+        ks, vs = [], []
+
+        def attend(l, q, k, v):
+            window = cfg.layer_types[l] == "sliding_attention"
+            scores = jnp.einsum("qkgd,mkd->kgqm", _grouped(cfg, q), k,
+                                preferred_element_type=jnp.float32) \
+                / math.sqrt(cfg.head_dim)
+            ctx = _softmax_ctx(scores, (causal & near) if window
+                               else causal, v, "kgqm,mkd->qkgd")
+            for rows, held in ((k, ks), (v, vs)):
+                held.append(jnp.swapaxes(
+                    rows[ring_src] if window else rows, 0, 1).astype(
+                        self.cache_dtype))
+            return ctx
+
+        x = params["embed"][tokens].astype(jnp.float32)
+        for l, p in enumerate(params["layers"]):
+            x = _block(cfg, l, p, x, pos, attend)
+        last = jnp.take(x, jnp.clip(length - 1, 0, p_len - 1), axis=0)
+        with jax.named_scope("head"):
+            logits = _mm(_rms(last, params["ln_f"]), params["head"])
+        return logits, tuple(ks), tuple(vs)
+
+    def decode_step(self, params, cache_k, cache_v, last_tok, lengths,
+                    active, extra):
+        """One token for all ``S`` slots: the incoming token's K/V goes to
+        position ``lengths`` of each slot's cache (row ``lengths % window``
+        of a ring) and is attended over with everything the slot holds.
+        Returns ``(logits (S, vocab), cache_k, cache_v, extra)``."""
+        cfg = self.cfg
+        pos = jnp.clip(lengths, 0, cfg.max_len - 1)
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+        new_k, new_v = list(cache_k), list(cache_v)
+        ring = jnp.arange(cfg.window)
+        # the absolute position ring row j holds once ``pos`` is written
+        ring_holds = pos[:, None] - ((pos[:, None] - ring[None]) % cfg.window)
+        masks = {True: (ring_holds >= 0)[:, None, None, :],
+                 False: (jnp.arange(cfg.max_len)[None, :]
+                         <= pos[:, None])[:, None, None, :]}
+        live = active.astype(jnp.uint32)
+        picks = []
+
+        def attend(l, q, k, v):
+            window = cfg.layer_types[l] == "sliding_attention"
+            if window:
+                at = pos % cfg.window
+                ck = write_ring(cache_k[l], k, at)
+                cv = write_ring(cache_v[l], v, at)
+            else:
+                ck = write_full(cache_k[l], k, pos)
+                cv = write_full(cache_v[l], v, pos)
+            new_k[l], new_v[l] = ck, cv
+            scores = jnp.einsum("skgd,skmd->skgm", _grouped(cfg, q), ck,
+                                preferred_element_type=jnp.float32) * scale
+            return _softmax_ctx(scores, masks[window], cv,
+                                "skgm,skmd->skgd")
+
+        def counts(l, chosen):
+            local = chosen - cfg.first_expert
+            hit = jax.nn.one_hot(local, cfg.experts_held, dtype=jnp.uint32)
+            picks.append((hit * live[:, None, None]).sum((0, 1)))
+
+        x = params["embed"][last_tok].astype(jnp.float32)
+        for l, p in enumerate(params["layers"]):
+            x = _block(cfg, l, p, x, pos, attend, counts)
+        with jax.named_scope("head"):
+            logits = _mm(_rms(x, params["ln_f"]), params["head"])
+        rows = live.sum()
+        extra = {"moe_picks": extra["moe_picks"] + jnp.stack(picks).reshape(
+                     extra["moe_picks"].shape),
+                 "moe_picks_total": extra["moe_picks_total"]
+                 + rows * np.uint32(cfg.top_k * len(self.sparse)),
+                 "rows": extra["rows"] + rows,
+                 "steps": extra["steps"] + (rows > 0).astype(jnp.uint32)}
+        return logits, tuple(new_k), tuple(new_v), extra

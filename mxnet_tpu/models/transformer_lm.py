@@ -36,7 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["LMConfig", "init_params", "forward_logits", "prefill_kv",
+__all__ = ["LMConfig", "CacheLayer", "slot_shape", "DecodeModel",
+           "init_params", "forward_logits", "prefill_kv",
            "write_rows", "decode_step_math", "prefill_kv_paged",
            "decode_step_paged", "params_to_blob", "params_from_blob"]
 
@@ -45,6 +46,24 @@ __all__ = ["LMConfig", "init_params", "forward_logits", "prefill_kv",
 #: sequence early
 LMConfig = namedtuple("LMConfig", ["vocab", "embed", "heads", "layers",
                                    "ffn", "max_len", "eos_id"])
+
+
+#: one layer of a model's cache, as the decode engine builds it (K and V
+#: each, ``(slots,) + slot_shape(layer)`` of ``dtype``): ``kind`` "full"
+#: (``rows`` = max_len, row ``p`` holds position ``p``) or "ring" (``rows``
+#: = a window, row ``p % rows`` holds position ``p``); ``heads_major`` says
+#: a slot is ``(kv_heads, rows, head_dim)`` and not ``(rows, kv_heads,
+#: head_dim)``: the order in which the model's own attention reads it
+CacheLayer = namedtuple("CacheLayer", ["kind", "rows", "kv_heads",
+                                       "head_dim", "dtype", "heads_major"],
+                        defaults=(False,))
+
+
+def slot_shape(layer):
+    """The shape of one slot of a :class:`CacheLayer`."""
+    if layer.heads_major:
+        return (layer.kv_heads, layer.rows, layer.head_dim)
+    return (layer.rows, layer.kv_heads, layer.head_dim)
 
 
 def init_params(cfg, seed=0, dtype=jnp.float32):
@@ -366,6 +385,45 @@ def decode_step_paged(cfg, params, pool_k, pool_v, tables, last_tok,
     x = _rmsnorm(x, params["ln_f"])
     logits = jnp.einsum("se,ev->sv", x, params["head"]).astype(jnp.float32)
     return logits, tuple(new_k), tuple(new_v)
+
+
+class DecodeModel:
+    """This LM as the decode engine's model protocol
+    (:mod:`mxnet_tpu.serving.decode`): a float32 cache of ``layers`` full
+    layers, the functions above behind the protocol's names, no extra
+    device state, and the paged twins."""
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+
+    def cache_spec(self):
+        cfg = self.cfg
+        return tuple(CacheLayer("full", cfg.max_len, cfg.heads,
+                                cfg.embed // cfg.heads, jnp.float32)
+                     for _ in range(cfg.layers))
+
+    def extra_state(self):
+        return None
+
+    def prefill(self, params, tokens, length):
+        return prefill_kv(self.cfg, params, tokens, length)
+
+    def decode_step(self, params, cache_k, cache_v, last_tok, lengths,
+                    active, extra):
+        del active
+        logits, cache_k, cache_v = decode_step_math(
+            self.cfg, params, cache_k, cache_v, last_tok, lengths)
+        return logits, cache_k, cache_v, extra
+
+    def prefill_paged(self, params, pool_k, pool_v, table, tokens, start,
+                      length):
+        return prefill_kv_paged(self.cfg, params, pool_k, pool_v, table,
+                                tokens, start, length)
+
+    def decode_step_paged(self, params, pool_k, pool_v, tables, last_tok,
+                          lengths):
+        return decode_step_paged(self.cfg, params, pool_k, pool_v, tables,
+                                 last_tok, lengths)
 
 
 def params_to_blob(cfg, params):

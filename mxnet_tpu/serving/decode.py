@@ -42,6 +42,33 @@ out through per-session callbacks.  Inactive slots ride along at fixed
 shape; their scatter rows are unreachable under the attention mask
 until a real write replaces them.
 
+**The model protocol.**  The engine learns nothing of an architecture; it
+is given a *model object* and asks it for four things:
+
+* ``cfg`` with ``vocab``, ``max_len`` and ``eos_id``, and
+  ``cache_spec()``: one :class:`~mxnet_tpu.models.transformer_lm.CacheLayer`
+  ``(kind, rows, kv_heads, head_dim, dtype, heads_major)`` a layer, from
+  which :meth:`DecodeEngine._fresh_state` builds K and V as ``(slots, rows,
+  kv_heads, head_dim)`` (``heads_major``: ``(slots, kv_heads, rows,
+  head_dim)``) — ``kind`` "full" (``rows`` = ``max_len``) or "ring"
+  (``rows`` = a window); layers may differ;
+* ``prefill(params, tokens, length) -> (last_logits, ks, vs)``: the rows
+  to write into ONE slot of each layer's cache, from row 0;
+* ``decode_step(params, cache_k, cache_v, last_tok, lengths, active,
+  extra) -> (logits, cache_k, cache_v, extra)``: one token for all slots;
+* ``extra_state()``: optional extra device state the step carries beside
+  the cache (routing counters), or None.  It is an argument of its own,
+  never donated, so :meth:`DecodeEngine.model_counters` may read the
+  latest from any thread; ``model.counters(extra)`` names what it holds.
+
+A bare :class:`~mxnet_tpu.models.transformer_lm.LMConfig` stands for
+:class:`~mxnet_tpu.models.transformer_lm.DecodeModel`, the first
+implementer; :class:`~mxnet_tpu.models.exaone_moe.ExaoneMoE` is the second.
+The model object is the only choice of a model path.  The paged layout
+asks for the two further methods ``prefill_paged``/``decode_step_paged``
+and a cache of full float32 layers alike; a model without them is refused
+with :class:`UnsupportedKVLayout`.
+
 The engine is single-device; multi-replica throughput is
 :class:`~mxnet_tpu.serving.pool.ReplicaPool`'s job.  The hot loop is
 covered by the graftlint host-sync pass (``ci/graftlint``): the packed
@@ -71,7 +98,7 @@ from .batcher import (LATENCY_BUCKETS, DeadlineExceeded, Future,
 from .kvblocks import KVBlockPool, KVBlocksExhausted
 
 __all__ = ["GenerateSession", "DecodeEngine", "ReplicaKilled",
-           "TTFT_BUCKETS"]
+           "UnsupportedKVLayout", "TTFT_BUCKETS"]
 
 _log = logging.getLogger("mxnet_tpu.serving")
 
@@ -92,6 +119,12 @@ class ReplicaKilled(MXNetError):
     mid-generation: the engine is permanently closed (a crashed replica
     process, not a transient step fault) and its sessions must migrate
     — the pool treats this as an instant circuit-open."""
+
+
+class UnsupportedKVLayout(MXNetError):
+    """The model's cache specification cannot be held in the KV layout
+    asked for: the paged block pool holds full float32 layers of one
+    shape, through a model's ``prefill_paged``/``decode_step_paged``."""
 
 
 class GenerateSession:
@@ -229,12 +262,13 @@ class GenerateSession:
 
 
 class DecodeEngine:
-    """Slot-based continuous batching over one
-    :mod:`~mxnet_tpu.models.transformer_lm` replica.
+    """Slot-based continuous batching over one replica of a model.
 
     Parameters
     ----------
-    cfg : transformer_lm.LMConfig
+    cfg : model object, or transformer_lm.LMConfig
+        What implements the model protocol (module docstring); a bare
+        ``LMConfig`` stands for ``transformer_lm.DecodeModel(cfg)``.
     params : pytree
         Host or device params; committed to ``device``.
     slots : int
@@ -275,7 +309,10 @@ class DecodeEngine:
                  kv_prefix_cache=None):
         import jax
 
-        self.cfg = cfg
+        #: the model protocol's implementer; ``cfg`` is its config
+        self.model = cfg if hasattr(cfg, "cache_spec") \
+            else _tlm.DecodeModel(cfg)
+        cfg = self.cfg = self.model.cfg
         self.name = name
         self.replica = str(replica)
         self.slots = int(slots) if slots is not None \
@@ -322,6 +359,19 @@ class DecodeEngine:
                 "kv_layout/MXNET_KV_LAYOUT must be 'dense' or 'paged', "
                 "got %r" % layout)
         self.kv_layout = layout
+        self._spec = tuple(self.model.cache_spec())
+        if layout == "paged" and not (
+                hasattr(self.model, "decode_step_paged")
+                and all(c.kind == "full" for c in self._spec)):
+            raise UnsupportedKVLayout(
+                "model %r (%s) has no paged decode path: the block pool "
+                "holds full layers of one shape; serve it with "
+                "kv_layout='dense'" % (name, type(self.model).__name__))
+        #: the model's extra device state (None when it has none): never
+        #: donated, replaced by every step, read by model_counters()
+        self._extra = None
+        self._extra_seen = None
+        self._extra_total = None
         #: paged storage control plane (None under the dense layout)
         self._kv = KVBlockPool(
             cfg, self.slots, block_size=kv_block_size,
@@ -359,7 +409,7 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        cfg = self.cfg
+        cfg, model = self.cfg, self.model
         s, m = self.slots, cfg.max_len
         eos = np.int32(cfg.eos_id)
 
@@ -426,9 +476,8 @@ class DecodeEngine:
 
             def step(params, state, keep, tables):
                 pool_k, pool_v = state[0], state[1]
-                logits, pool_k, pool_v = _tlm.decode_step_paged(
-                    cfg, params, pool_k, pool_v, tables, state[2],
-                    state[3])
+                logits, pool_k, pool_v = model.decode_step_paged(
+                    params, pool_k, pool_v, tables, state[2], state[3])
                 rest, packed = finish_step(state[2:], logits, keep)
                 return (pool_k, pool_v) + rest, packed
 
@@ -445,9 +494,8 @@ class DecodeEngine:
                                for pk in pool_k)
                 pool_v = tuple(pv.at[cow_dst].set(pv[cow_src])
                                for pv in pool_v)
-                last_logits, pool_k, pool_v = _tlm.prefill_kv_paged(
-                    cfg, params, pool_k, pool_v, table, tokens, start,
-                    length)
+                last_logits, pool_k, pool_v = model.prefill_paged(
+                    params, pool_k, pool_v, table, tokens, start, length)
                 rest, out = arm_slot(state[2:], slot, last_logits,
                                      length, limit, temp, seed, activate)
                 return (pool_k, pool_v) + rest, out
@@ -462,18 +510,35 @@ class DecodeEngine:
                                      nb, bs))
                 for b in self.prefill_buckets}
         else:
-            def step(params, state, keep):
+            extra0 = model.extra_state()
+            self._extra = None if extra0 is None \
+                else jax.device_put(extra0, self._device)
+            with self._cond:
+                if self._extra_seen is not None:
+                    # a rebuild counts on from zero; what was read stays
+                    self._extra_seen = jax.tree_util.tree_map(
+                        np.zeros_like, self._extra_seen)
+
+            def advance(params, state, keep, extra):
                 cache_k, cache_v = state[0], state[1]
-                logits, cache_k, cache_v = _tlm.decode_step_math(
-                    cfg, params, cache_k, cache_v, state[2], state[3])
+                logits, cache_k, cache_v, extra = model.decode_step(
+                    params, cache_k, cache_v, state[2], state[3], state[5],
+                    extra)
                 rest, packed = finish_step(state[2:], logits, keep)
-                return (cache_k, cache_v) + rest, packed
+                return (cache_k, cache_v) + rest, packed, extra
+
+            if extra0 is None:
+                def step(params, state, keep):
+                    return advance(params, state, keep, None)[:2]
+            else:
+                def step(params, state, keep, extra):
+                    return advance(params, state, keep, extra)
 
             def prefill(params, state, tokens, length, slot, limit,
                         temp, seed, activate):
                 cache_k, cache_v = state[0], state[1]
-                last_logits, ks, vs = _tlm.prefill_kv(cfg, params,
-                                                      tokens, length)
+                last_logits, ks, vs = model.prefill(params, tokens,
+                                                    length)
                 cache_k = tuple(
                     jax.lax.dynamic_update_slice(ck, k[None],
                                                  (slot, 0, 0, 0))
@@ -541,20 +606,21 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
 
-        cfg = self.cfg
         s = self.slots
-        hd = cfg.embed // cfg.heads
         if self._kv is not None:
             self._kv.reset()
-            kv_shape = (self._kv.num_blocks, self._kv.block_size,
-                        cfg.heads, hd)
+            lead = (self._kv.num_blocks, self._kv.block_size)
         else:
-            kv_shape = (s, cfg.max_len, cfg.heads, hd)
+            lead = None
         self._slot_len = [0] * s
-        state = (tuple(jnp.zeros(kv_shape, jnp.float32)
-                       for _ in range(cfg.layers)),
-                 tuple(jnp.zeros(kv_shape, jnp.float32)
-                       for _ in range(cfg.layers)),
+
+        def zeros():
+            return tuple(jnp.zeros(
+                lead + (c.kv_heads, c.head_dim) if lead
+                else (s,) + _tlm.slot_shape(c), c.dtype)
+                for c in self._spec)
+
+        state = (zeros(), zeros(),
                  jnp.zeros((s,), jnp.int32),        # last_tok
                  jnp.zeros((s,), jnp.int32),        # lengths
                  jnp.zeros((s,), jnp.int32),        # limits
@@ -587,9 +653,19 @@ class DecodeEngine:
                 self._params, state, np.zeros((b,), np.int32),
                 np.int32(1), np.int32(0), np.int32(0), np.float32(0.0),
                 np.uint32(0), np.bool_(False))
-        state, _packed = self._step_fn(self._params, state,
-                                       np.ones((self.slots,), bool))
+        state, _packed = self._dispatch_step(
+            state, np.ones((self.slots,), bool))
         return state
+
+    def _dispatch_step(self, state, keep):
+        """The dense step's one dispatch: a model's extra device state
+        rides beside the donated state and comes back new."""
+        if self._extra is None:
+            return self._step_fn(self._params, state, keep)
+        state, packed, extra = self._step_fn(
+            self._params, state, keep, self._extra)
+        self._extra = extra  # lint: ok[lock-discipline] explicit hand-off: one writer (the thread that runs the step), an atomic swap of an immutable device array that is never donated; model_counters() reads whichever is latest
+        return state, packed
 
     def set_health_hooks(self, on_error=None, on_ok=None,
                          on_migrate=None):
@@ -789,12 +865,13 @@ class DecodeEngine:
         if self._kv is not None:
             kv = self._kv.describe()
         else:
-            hd = self.cfg.embed // self.cfg.heads
             kv = {"layout": "dense",
-                  "hbm_bytes": (2 * self.cfg.layers * self.slots
-                                * self.cfg.max_len * self.cfg.heads
-                                * hd * 4)}
+                  "hbm_bytes": sum(
+                      2 * self.slots * c.rows * c.kv_heads * c.head_dim
+                      * np.dtype(c.dtype).itemsize for c in self._spec)}
+        model = self.model_counters()
         return {"name": self.name, "kind": "generate",
+                **({"model_counters": model} if model else {}),
                 "version": getattr(self, "version", None),
                 "replica": self.replica, "device": str(self._device),
                 "slots": self.slots, "active": active, "queued": queued,
@@ -803,6 +880,38 @@ class DecodeEngine:
                 "reprefilled_tokens": reprefilled,
                 "prefill_buckets": list(self.prefill_buckets),
                 "max_len": self.cfg.max_len, "kv": kv}
+
+    def model_counters(self):
+        """What the model's extra device state has counted since the
+        engine was built, as whole numbers by the model's own names ({}
+        for a model without such state).  One small device read, made by
+        the caller's thread and never by the loop: the state is not
+        donated, so the latest one a step returned stays readable.  The
+        device counts in wrapping uint32; the differences between reads
+        are summed here, so a count is exact while reads are less than
+        2**32 picks apart."""
+        extra = self._extra
+        if extra is None:
+            return {}
+        import jax
+
+        with _tracing.host_read("decode.model_counters"):
+            now = jax.tree_util.tree_map(
+                lambda a: np.asarray(a).astype(np.int64), extra)  # lint: ok[host-sync] counters read on the caller's thread (describe/telemetry), never in the loop
+        with self._cond:
+            if self._extra_seen is None:
+                self._extra_total = now
+            else:
+                self._extra_total = jax.tree_util.tree_map(
+                    lambda t, a, b: t + (a - b) % (1 << 32),
+                    self._extra_total, now, self._extra_seen)
+            self._extra_seen = now
+            total = self._extra_total
+        out = self.model.counters(total)
+        labels = {"model": self.name, "replica": self.replica}
+        for name, value in out.get("gauges", {}).items():
+            _telemetry.set_gauge(name, value, **labels)
+        return out
 
     # -- worker ------------------------------------------------------------
     def start(self):
@@ -1187,8 +1296,7 @@ class DecodeEngine:
                         self._params, state, keep,
                         np.ascontiguousarray(self._kv.tables))
                 else:
-                    state, packed = self._step_fn(self._params, state,
-                                                  keep)
+                    state, packed = self._dispatch_step(state, keep)
             with _tracing.host_read("decode.packed"):
                 packed = np.asarray(packed)  # lint: ok[host-sync] THE one sanctioned host read per decode step (packed token/done/active buffer)
         except Exception as e:

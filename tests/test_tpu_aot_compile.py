@@ -246,6 +246,79 @@ CASES["decode-dense-prefill-768-gpt2-large-no-cache-copy"] = \
     _dense_engine_case("prefill")
 
 
+def _gpt2_step_as_the_benchmark_lowers_it():
+    """``benchmark/families/decode_engine.py`` ``scratch_bytes`` lowers
+    ``engine._step_fn`` after every window for shapes it writes out by
+    hand: ``(params, (K tuple, V tuple, six small arrays), keep)``, float32
+    ``(slots, max_len, heads, head_dim)`` a layer.  The engine's model
+    protocol must leave that signature as it is."""
+    from mxnet_tpu.models import transformer_lm as tlm
+    from mxnet_tpu.serving import DecodeEngine
+
+    class Unwarmed(DecodeEngine):
+        def _fresh_state(self):
+            return None
+
+        def _warm(self, state):
+            return state
+
+    cfg = tlm.LMConfig(*_GPT2_LARGE, eos_id=_GPT2_LARGE[0])
+    engine = Unwarmed(cfg, {}, slots=_SLOTS, prefill_buckets=(_BUCKET,),
+                      autostart=False)
+    one_chip = _one_chip()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    s = _SLOTS
+    kv = sds((s, cfg.max_len, cfg.heads, cfg.embed // cfg.heads),
+             jnp.float32)
+    state = (tuple(kv for _ in range(cfg.layers)),
+             tuple(kv for _ in range(cfg.layers)),
+             sds((s,), jnp.int32), sds((s,), jnp.int32),
+             sds((s,), jnp.int32), sds((s,), jnp.bool_),
+             sds((s,), jnp.float32), sds((s,), jnp.uint32))
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: tlm.init_params(cfg)))
+    compiled = engine._step_fn.lower(params, state,
+                                     sds((s,), jnp.bool_)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < kv.size * kv.dtype.itemsize
+
+
+CASES["decode-dense-step-gpt2-large-as-the-benchmark-lowers-it"] = \
+    _gpt2_step_as_the_benchmark_lowers_it
+
+
+def _exaone_step_case():
+    """The engine's ``jit_step`` over ``models/exaone_moe.py`` at
+    ``benchmark/configs/k-exaone-236b-a23b.json``'s widths and slots (256 x
+    4096, bfloat16, 5 layers, 16 of 128 experts): it fits the chip, its
+    temporaries stay under one full-layer cache array, and it holds no copy
+    of a cache-sized array (with positions before K/V heads in the cache
+    it held ten, one an array: PERF.md, PR 27)."""
+    from benchmark import harness
+    from benchmark.tools import aot_compile_moe as tool
+
+    config = harness.load_json(os.path.join(
+        ROOT, "benchmark", "configs", "k-exaone-236b-a23b.json"))
+    engine, params, state, keep, extra, _sds = tool.engine_programs(
+        config, _one_chip())
+    compiled = engine._step_fn.lower(params, state, keep, extra).compile()
+    full = max(a.size * a.dtype.itemsize for a in state[0])
+    assert full == 256 * 8 * 4096 * 128 * 2
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < full, ma.temp_size_in_bytes
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.0e9
+    copies = tool.cache_copies(compiled.as_text(), state)
+    assert not copies, "%d copies of a cache array, the first: %s" \
+        % (len(copies), copies[0][:200])
+
+
+CASES["decode-step-k-exaone-256-slots-no-cache-copy"] = _exaone_step_case
+
+
 # -- the tests -----------------------------------------------------------------
 def test_bn_budget_admits_the_late_stages():
     assert _bn_stages(jnp.bfloat16) == [
