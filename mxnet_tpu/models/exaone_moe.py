@@ -49,7 +49,10 @@ holds.  A prompt longer than the window leaves its last ``window``
 positions in the ring.  K/V heads come before positions because that is
 the order both attention products read: compiled for the chip with
 positions first, every step copied every cache array into this order and
-back (PERF.md section 6, PR 27).
+back (PERF.md section 6, PR 27).  The decode step reads a full layer
+through :func:`ops.attention.decode_attention`: on the TPU only the blocks
+of rows at or below each slot's length (PERF.md section 6, PR 28); a ring
+is read whole, every row of it live once a session is past the window.
 
 :func:`forward_logits` is the in-repo plain reference: float32, ``highest``
 precision, no cache, one sequence, written out on its own.  Prefill and
@@ -66,6 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.attention import decode_attention, decode_attention_plan
 from .transformer_lm import CacheLayer
 
 __all__ = ["ExaoneConfig", "ExaoneMoE", "init_params",
@@ -353,10 +357,15 @@ class ExaoneMoE:
                        dtype, True) for l in range(cfg.layers))
 
     def extra_state(self):
-        """The routing counters (uint32, wrapping): picks routed to each
-        held expert of each sparse layer, picks made in all, rows stepped.
-        Counted in decode steps, over active rows."""
-        return {"moe_picks": jnp.zeros((len(self.sparse),
+        """The device counters (uint32, wrapping), counted in decode
+        steps over active rows: picks routed to each held expert of each
+        sparse layer, picks made in all, rows stepped; and, over the full
+        layers, the blocks of cache rows the attention read and the blocks
+        the rows' slots hold (:func:`ops.attention.decode_attention`: where
+        it reads every row, a slot is one block)."""
+        return {"attn_blocks_read": jnp.zeros((), jnp.uint32),
+                "attn_blocks_held": jnp.zeros((), jnp.uint32),
+                "moe_picks": jnp.zeros((len(self.sparse),
                                         self.cfg.experts_held), jnp.uint32),
                 "moe_picks_total": jnp.zeros((), jnp.uint32),
                 "rows": jnp.zeros((), jnp.uint32),
@@ -365,18 +374,27 @@ class ExaoneMoE:
     def counters(self, extra):
         """The extra state read back (whole numbers), with the gauges the
         engine publishes under ``gauges``: picks a held expert sees a step,
-        the held experts' share of all picks, and the busiest held
-        expert's picks over the mean's."""
+        the held experts' share of all picks, the busiest held expert's
+        picks over the mean's, and the share of the full layers' cache rows
+        (``slots x max_len`` a step, over active slots) that the attention
+        read."""
         picks = np.asarray(extra["moe_picks"], np.int64)
         total, steps = int(extra["moe_picks_total"]), int(extra["steps"])
+        read, held = (int(extra["attn_blocks_" + k]) for k in ("read", "held"))
         out = {"moe_picks": picks.tolist(), "moe_picks_total": total,
-               "rows": int(extra["rows"]), "steps": steps}
+               "rows": int(extra["rows"]), "steps": steps,
+               "attn_blocks_read": read, "attn_blocks_held": held}
+        gauges = {}
         if steps and picks.sum():
-            out["gauges"] = {
+            gauges = {
                 "serving.moe.tokens_per_expert":
                     float(picks.sum()) / (picks.size * steps),
                 "serving.moe.local_share": float(picks.sum()) / total,
                 "serving.moe.imbalance": float(picks.max() / picks.mean())}
+        if held:
+            gauges["serving.attn.rows_read_share"] = read / held
+        if gauges:
+            out["gauges"] = gauges
         return out
 
     def prefill(self, params, tokens, length):
@@ -432,26 +450,27 @@ class ExaoneMoE:
         ring = jnp.arange(cfg.window)
         # the absolute position ring row j holds once ``pos`` is written
         ring_holds = pos[:, None] - ((pos[:, None] - ring[None]) % cfg.window)
-        masks = {True: (ring_holds >= 0)[:, None, None, :],
-                 False: (jnp.arange(cfg.max_len)[None, :]
-                         <= pos[:, None])[:, None, None, :]}
+        ring_mask = (ring_holds >= 0)[:, None, None, :]
         live = active.astype(jnp.uint32)
-        picks = []
+        picks, blocks = [], []
 
         def attend(l, q, k, v):
-            window = cfg.layer_types[l] == "sliding_attention"
-            if window:
+            q = _grouped(cfg, q)
+            if cfg.layer_types[l] == "sliding_attention":
                 at = pos % cfg.window
                 ck = write_ring(cache_k[l], k, at)
                 cv = write_ring(cache_v[l], v, at)
-            else:
-                ck = write_full(cache_k[l], k, pos)
-                cv = write_full(cache_v[l], v, pos)
+                new_k[l], new_v[l] = ck, cv
+                scores = jnp.einsum("skgd,skmd->skgm", q, ck,
+                                    preferred_element_type=jnp.float32) \
+                    * scale
+                return _softmax_ctx(scores, ring_mask, cv, "skgm,skmd->skgd")
+            # a full layer reads the rows each slot holds, in blocks
+            ck = write_full(cache_k[l], k, pos)
+            cv = write_full(cache_v[l], v, pos)
             new_k[l], new_v[l] = ck, cv
-            scores = jnp.einsum("skgd,skmd->skgm", _grouped(cfg, q), ck,
-                                preferred_element_type=jnp.float32) * scale
-            return _softmax_ctx(scores, masks[window], cv,
-                                "skgm,skmd->skgd")
+            blocks.append(decode_attention_plan(q, ck)[0])
+            return decode_attention(q, ck, cv, pos, scale)
 
         def counts(l, chosen):
             local = chosen - cfg.first_expert
@@ -464,7 +483,14 @@ class ExaoneMoE:
         with jax.named_scope("head"):
             logits = _mm(_rms(x, params["ln_f"]), params["head"])
         rows = live.sum()
-        extra = {"moe_picks": extra["moe_picks"] + jnp.stack(picks).reshape(
+        # a full layer read ``pos // b + 1`` of a slot's ``max_len // b``
+        # blocks, ``b`` the rows of a block on the path its trace took
+        read = sum((live * (pos // b + 1).astype(jnp.uint32)).sum()
+                   for b in blocks)
+        held = rows * np.uint32(sum(cfg.max_len // b for b in blocks))
+        extra = {"attn_blocks_read": extra["attn_blocks_read"] + read,
+                 "attn_blocks_held": extra["attn_blocks_held"] + held,
+                 "moe_picks": extra["moe_picks"] + jnp.stack(picks).reshape(
                      extra["moe_picks"].shape),
                  "moe_picks_total": extra["moe_picks_total"]
                  + rows * np.uint32(cfg.top_k * len(self.sparse)),

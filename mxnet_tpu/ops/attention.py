@@ -21,6 +21,12 @@ Design:
   from the saved (o, lse) residuals — the standard FA2 backward, written as
   plain JAX matmuls per K block so XLA schedules them on the MXU.
 
+* ``decode_attention`` — one query a slot over a dense K/V cache whose
+  slots hold different numbers of rows (the decode step's full-attention
+  layer): on a TPU trace a Pallas kernel that fetches only the blocks of
+  rows below each slot's length (``_decode_pallas``), else the two einsums
+  and the softmax over every row (``_decode_xla``); not differentiated.
+
 Shapes follow (batch, heads, seq, head_dim) throughout.
 """
 
@@ -374,6 +380,186 @@ def flash_attention(q, k, v, causal=False, softmax_scale=None,
         softmax_scale = float(1.0 / np.sqrt(q.shape[-1]))
     return _flash(q, k, v, bool(causal), float(softmax_scale),
                   int(block_q), int(block_k))
+
+
+# ---------------------------------------------------------------------------
+# decode attention: one query a slot, over the rows the slot holds
+# ---------------------------------------------------------------------------
+
+#: bytes of K (and as many of V) one grid step of the decode kernel moves:
+#: a grid step costs about 0.35 us whatever it moves, so it should move a
+#: megabyte; K and V double-buffered are then 4 MiB of the 16 MiB a kernel
+#: may use on a v5e
+_DECODE_BLOCK_BYTES = 1 << 20
+
+
+def _decode_xla(q, cache_k, cache_v, lengths, scale):
+    """Every row of the cache, masked: the scores over all ``rows``, their
+    softmax in float32, the weights rounded to the cache's dtype, the
+    weighted sum accumulated in float32."""
+    scores = jnp.einsum("skgd,skmd->skgm", q, cache_k,
+                        preferred_element_type=jnp.float32) * scale
+    mask = jnp.arange(cache_k.shape[2])[None, :] <= lengths[:, None]
+    att = jax.nn.softmax(
+        jnp.where(mask[:, None, None, :], scores, NEG_INF), axis=-1)
+    return jnp.einsum("skgm,skmd->skgd", att.astype(cache_v.dtype), cache_v,
+                      preferred_element_type=jnp.float32)
+
+
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                   acc_scr, *, scale, block):
+    """Grid ``(slots, blocks of rows)``, blocks innermost: the running
+    maximum, sum and accumulator of one slot's ``(kv_heads, group)``
+    queries stay in VMEM across its blocks.  A step wholly above the
+    slot's length does nothing (what the caller's index map gives it is
+    the next slot's first block, fetched ahead of its turn)."""
+    from jax.experimental import pallas as pl
+
+    j = pl.program_id(1)
+    length = len_ref[pl.program_id(0)]
+    last = length // block
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def accumulate(edge):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]     # (kv, g | block, d)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale   # (kv, g, block)
+        if edge:
+            # the block the length falls in: rows above it hold whatever
+            # an earlier session left, and take no part
+            at = j * block + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, block), 2)
+            s = jnp.where(at <= length, s, NEG_INF)
+            rows = j * block + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block, 1), 1)
+            v = jnp.where(rows <= length, v, jnp.zeros_like(v))
+        m_prev = m_scr[...]
+        m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
+        m_scr[...] = m_cur
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)           # (kv, g, d)
+
+    @pl.when(j < last)
+    def _whole():
+        accumulate(False)
+
+    @pl.when(j == last)
+    def _edge():
+        accumulate(True)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[0] = acc_scr[...] / l_scr[...]
+
+
+def _decode_pallas(q, cache_k, cache_v, lengths, scale, block,
+                   interpret=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, kv, g, d = q.shape
+    rows = cache_k.shape[2]
+
+    def rows_of(i, j, lens):
+        # the steps above a slot's last block ask for the next slot's first
+        # block, all of them the same one: it is fetched once, behind the
+        # last block's arithmetic, and is in VMEM when its slot's turn
+        # comes; nothing else is fetched in a skipped step (the last slot
+        # has no next: its skipped steps fetch its own first block, once)
+        ahead = j > lens[i] // block
+        return (jnp.minimum(i + ahead, s - 1), 0, jnp.where(ahead, 0, j), 0)
+
+    def whole(i, j, lens):
+        return (i, 0, 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(s, rows // block),
+            in_specs=[pl.BlockSpec((1, kv, g, d), whole),
+                      pl.BlockSpec((1, kv, block, d), rows_of),
+                      pl.BlockSpec((1, kv, block, d), rows_of)],
+            out_specs=pl.BlockSpec((1, kv, g, d), whole),
+            scratch_shapes=[pltpu.VMEM((kv, g, 1), jnp.float32),    # m
+                            pltpu.VMEM((kv, g, 1), jnp.float32),    # l
+                            pltpu.VMEM((kv, g, d), jnp.float32)]),  # acc
+        out_shape=jax.ShapeDtypeStruct((s, kv, g, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="decode_attention",
+        interpret=interpret,
+    )(lengths, q, cache_k, cache_v)
+
+
+def decode_attention_plan(q, cache_k):
+    """``(block, reason)``: the rows of a block the Pallas kernel reads at
+    these shapes with ``reason`` None, or ``(rows, reason)`` with why the
+    call takes the plain path, which reads all ``rows`` of every slot as
+    one block (``ops.kernel_path`` reasons).
+
+    The block follows what can be seen here: all K/V heads of as many rows
+    as make :data:`_DECODE_BLOCK_BYTES`, a power of two that divides
+    ``rows``, 128 at the least (a block's scores are ``(group, block)``
+    float32 tiles, whole lanes).  The rules are the v5e compiler's, asked
+    shape by shape (``tests/test_tpu_aot_compile.py``)."""
+    from .registry import on_tpu
+
+    kv, rows, d = cache_k.shape[1:]
+    if not on_tpu():
+        return rows, "not_tpu"
+    if cache_k.dtype not in (jnp.bfloat16, jnp.float32) \
+            or q.dtype != cache_k.dtype:
+        return rows, "dtype"
+    block = 128
+    while block * 2 * kv * d * cache_k.dtype.itemsize <= _DECODE_BLOCK_BYTES \
+            and rows % (block * 2) == 0:
+        block *= 2
+    if rows % block:
+        return rows, "tile"
+    if block * kv * d * cache_k.dtype.itemsize > 2 * _DECODE_BLOCK_BYTES:
+        return rows, "vmem"
+    return block, None
+
+
+def decode_attention(q, cache_k, cache_v, lengths, scale):
+    """Attention of one query a slot over a dense cache whose slots hold
+    different numbers of rows.
+
+    ``q (S, kv_heads, group, d)``: the queries grouped by the K/V head they
+    read; ``cache_k``/``cache_v (S, kv_heads, rows, d)``; ``lengths (S,)``
+    int32 within ``0 .. rows - 1``, the inclusive horizon: slot ``i``
+    attends rows ``0 .. lengths[i]``, so every slot reads one row at the
+    least.  Returns the context ``(S, kv_heads, group, d)`` float32.
+    Scores accumulate in float32 from operands in the cache's dtype, the
+    weights are rounded to the cache's dtype before the product with V,
+    which accumulates in float32.
+
+    On a TPU trace the Pallas kernel reads, of each slot, only the blocks
+    of rows at or below its length, and keeps the softmax in VMEM across
+    them: neither rows nobody holds nor the ``(S, kv_heads, group, rows)``
+    scores touch HBM.  Elsewhere, and at shapes the kernel refuses
+    (:func:`decode_attention_plan`), every row is read and masked.  The
+    choice is counted under ``ops.kernel_path``."""
+    from .registry import count_kernel_path
+
+    block, reason = decode_attention_plan(q, cache_k)
+    if reason is None:
+        count_kernel_path("decode_attention", "pallas", "ok")
+        return _decode_pallas(q, cache_k, cache_v, lengths, float(scale),
+                              block)
+    count_kernel_path("decode_attention", "xla", reason)
+    return _decode_xla(q, cache_k, cache_v, lengths, scale)
 
 
 # ---------------------------------------------------------------------------

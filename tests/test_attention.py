@@ -1,4 +1,4 @@
-"""Flash attention + ring attention tests.
+"""Flash attention, decode attention + ring attention tests.
 
 Numerics oracle is the quadratic reference attention; the blockwise scan,
 the Pallas kernel (interpret mode on CPU), and the ring-parallel version
@@ -12,8 +12,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from mxnet_tpu import telemetry
 from mxnet_tpu.ops.attention import (
-    _attn_reference, _flash_pallas, _flash_scan, flash_attention)
+    _attn_reference, _decode_pallas, _decode_xla, _flash_pallas,
+    _flash_scan, decode_attention, decode_attention_plan, flash_attention)
 
 
 def _rand_qkv(b=2, h=3, lq=64, lk=64, d=16, dtype=np.float32, seed=0):
@@ -199,3 +201,99 @@ def test_ulysses_head_count_guard():
     with pytest.raises(ValueError, match="n_heads"):
         ulysses_self_attention(jnp.asarray(q), jnp.asarray(k),
                                jnp.asarray(v), mesh, seq_axis="data")
+
+
+# -- decode attention ----------------------------------------------------------
+_ROWS, _BLOCK = 64, 16
+
+
+def _decode_case(lengths, group, d, dtype, seed=0):
+    """``(q, clean K, clean V, K and V with NaN in every row above each
+    slot's length, lengths)``: 2 K/V heads, ``_ROWS`` rows a slot."""
+    rs = np.random.RandomState(seed)
+    s = len(lengths)
+    q, k, v = (jnp.asarray(rs.normal(0, 1, shape), dtype) for shape in
+               [(s, 2, group, d)] + [(s, 2, _ROWS, d)] * 2)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    dead = (jnp.arange(_ROWS)[None, :] > lengths[:, None])[:, None, :, None]
+    return (q, k, v, jnp.where(dead, jnp.nan, k), jnp.where(dead, jnp.nan, v),
+            lengths)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("group,d", [(1, 64), (8, 64), (1, 128), (8, 128)])
+@pytest.mark.parametrize("lengths", [
+    [0, 1, _BLOCK - 2, _BLOCK - 1, _BLOCK, _ROWS - 1],  # a block's edge
+    [0, 0, 0], [_ROWS - 1] * 3, [37] * 4,               # all equal
+    [5, 50, 21, 33, 62, 16, 47],                        # all different
+], ids=["edges", "empty", "full", "equal", "different"])
+def test_decode_kernel_interpret_reads_no_row_above_a_length(
+        lengths, group, d, dtype, tol):
+    """The kernel (interpreter) against the two einsums and the softmax
+    over every row.  The cache the kernel is given holds NaN in every row
+    above each slot's length: a block it should skip, or a row of the
+    length's own block that it should mask, would show in the result."""
+    q, k, v, k_nan, v_nan, lengths = _decode_case(lengths, group, d, dtype)
+    want = _decode_xla(q, k, v, lengths, 0.25)
+    got = _decode_pallas(q, k_nan, v_nan, lengths, 0.25, _BLOCK,
+                         interpret=True)
+    assert got.dtype == jnp.float32 and got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
+def test_decode_xla_is_the_softmax_over_the_rows_a_slot_holds():
+    q, k, v, _, _, lengths = _decode_case([0, 9, 63], 4, 16, jnp.float32)
+    got = np.asarray(_decode_xla(q, k, v, lengths, 0.25))
+    for i, n in enumerate(np.asarray(lengths) + 1):
+        s = np.einsum("kgd,kmd->kgm", q[i], k[i, :, :n]) * 0.25
+        p = np.exp(s - s.max(-1, keepdims=True))
+        want = np.einsum("kgm,kmd->kgd", p / p.sum(-1, keepdims=True),
+                         v[i, :, :n])
+        np.testing.assert_allclose(got[i], want, rtol=2e-5, atol=2e-5)
+
+
+def test_decode_attention_off_the_tpu_reads_every_row_and_says_so():
+    q, k, v, _, _, lengths = _decode_case([3, 40], 8, 16, jnp.float32)
+    assert decode_attention_plan(q, k) == (_ROWS, "not_tpu")
+
+    def counted():
+        return telemetry.snapshot()["counters"].get(
+            "ops.kernel_path", {}).get(
+                "op=decode_attention,path=xla,reason=not_tpu", 0)
+
+    was = telemetry.enabled()
+    telemetry.enable()
+    try:
+        before = counted()
+        got = decode_attention(q, k, v, lengths, 0.25)
+        assert counted() == before + 1
+    finally:
+        if not was:
+            telemetry.disable()
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(_decode_xla(q, k, v, lengths, 0.25)))
+
+
+@pytest.mark.parametrize("shape,dtype,want", [
+    # the K-EXAONE cell: all 8 K/V heads of 512 rows are 1 MiB
+    ((8, 8, 128, 4096), jnp.bfloat16, (512, None)),
+    ((1, 8, 128, 32768), jnp.bfloat16, (4096, None)),
+    ((20, 1, 64, 1024), jnp.float32, (128, None)),
+    ((4, 4, 128, 384), jnp.bfloat16, (128, None)),
+    ((4, 4, 128, 200), jnp.bfloat16, (200, "tile")),
+    ((32, 4, 256, 1024), jnp.float32, (1024, "vmem")),
+    ((8, 8, 128, 4096), jnp.float16, (4096, "dtype")),
+])
+def test_decode_attention_plan_on_a_tpu_trace(shape, dtype, want):
+    from mxnet_tpu.ops import registry
+
+    kv, g, d, rows = shape
+    q = jax.ShapeDtypeStruct((2, kv, g, d), dtype)
+    cache = jax.ShapeDtypeStruct((2, kv, rows, d), dtype)
+    token = registry.trace_device.set("tpu")
+    try:
+        assert decode_attention_plan(q, cache) == want
+    finally:
+        registry.trace_device.reset(token)

@@ -11,9 +11,26 @@ import pytest
 from mxnet_tpu import serving
 from mxnet_tpu.models import exaone_moe as xm
 from mxnet_tpu.models import transformer_lm as tlm
+from mxnet_tpu.ops import attention
 from mxnet_tpu.serving.decode import UnsupportedKVLayout
 
 WINDOW = 8
+#: rows of a block when a test runs the decode-attention kernel (three
+#: blocks of the tests' ``max_len``)
+BLOCK = 16
+
+
+@pytest.fixture
+def decode_kernel(monkeypatch):
+    """The full layer's attention through the Pallas kernel, run by the
+    interpreter in blocks of ``BLOCK`` rows: the choice of path is patched
+    where the model reads it (off the TPU it reads every row)."""
+    monkeypatch.setattr(xm, "decode_attention_plan",
+                        lambda q, cache_k: (BLOCK, None))
+    monkeypatch.setattr(
+        xm, "decode_attention",
+        lambda q, ck, cv, lengths, scale: attention._decode_pallas(
+            q, ck, cv, lengths, scale, BLOCK, interpret=True))
 
 
 def _cfg(first_expert=0, experts_held=16, max_len=48):
@@ -39,13 +56,38 @@ def _share(params, first, held):
     return dict(params, layers=[cut(p) for p in params["layers"]])
 
 
-@pytest.mark.parametrize("prompt,bucket,new", [
+_SESSIONS = [
     (3, 4, 12),       # shorter than the window, decoding across its wrap
     (8, 16, 6),       # the window exactly
     (13, 16, 14),     # longer than the window: the ring holds the last 8
     (20, 32, 20),     # two wraps in the prompt, two more while decoding
-])
+]
+
+
+@pytest.mark.parametrize("prompt,bucket,new", _SESSIONS)
 def test_prefill_then_decode_equal_the_reference_logits(prompt, bucket, new):
+    """Off the TPU the full layer reads every row: a slot is one block."""
+    counted = _prefill_then_decode(prompt, bucket, new)
+    assert counted["attn_blocks_read"] == counted["attn_blocks_held"] == new
+    assert counted["gauges"]["serving.attn.rows_read_share"] == 1.0
+
+
+@pytest.mark.parametrize("prompt,bucket,new", _SESSIONS)
+def test_prefill_then_decode_through_the_decode_kernel(
+        prompt, bucket, new, decode_kernel):
+    """The same sessions with the full layer's attention in the Pallas
+    kernel (interpreter), beside two idle slots of length 0 that ride
+    along; the counters say which blocks of the slot's 3 it read."""
+    counted = _prefill_then_decode(prompt, bucket, new)
+    read = sum(p // BLOCK + 1 for p in range(prompt, prompt + new))
+    assert counted["attn_blocks_read"] == read
+    assert counted["attn_blocks_held"] == 3 * new
+    assert counted["attn_blocks_read"] <= counted["attn_blocks_held"]
+    assert counted["gauges"]["serving.attn.rows_read_share"] \
+        == pytest.approx(read / (3.0 * new))
+
+
+def _prefill_then_decode(prompt, bucket, new):
     cfg = _cfg(first_expert=4, experts_held=8)
     params = xm.init_params(cfg, seed=prompt, dtype=jnp.float32)
     model = xm.ExaoneMoE(cfg, jnp.float32)
@@ -78,6 +120,7 @@ def test_prefill_then_decode_equal_the_reference_logits(prompt, bucket, new):
     counted = model.counters(jax.device_get(extra))
     assert counted["rows"] == counted["steps"] == new
     assert counted["moe_picks_total"] == new * cfg.top_k * 4
+    return counted
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -127,10 +170,19 @@ def test_every_row_on_the_same_held_experts_is_dropped_nowhere(rows):
 
 @pytest.mark.parametrize("seed", [1, 2])
 def test_through_the_pool_tokens_and_routing_counters(seed):
+    _through_the_pool(seed, BLOCK * 3)
+
+
+def test_through_the_pool_with_the_decode_kernel(decode_kernel):
+    _through_the_pool(3, BLOCK)
+
+
+def _through_the_pool(seed, block):
     """``lm_pool`` -> ``ReplicaPool`` -> ``DecodeEngine`` by the model
     protocol, more sessions than slots (continuous admission): greedy
-    tokens equal the reference's argmax and the device's routing counters
-    equal a count made from the reference's choices."""
+    tokens equal the reference's argmax, the device's routing counters
+    equal a count made from the reference's choices, and the full layer
+    read the blocks of ``block`` rows that the sessions' lengths imply."""
     cfg = _cfg(first_expert=8, experts_held=4)
     params = xm.init_params(cfg, seed=seed, dtype=jnp.float32)
     pool = serving.lm_pool(xm.ExaoneMoE(cfg, jnp.float32), params,
@@ -149,7 +201,7 @@ def test_through_the_pool_tokens_and_routing_counters(seed):
         assert engine.describe()["model_counters"]["rows"] \
             == counted["rows"]
         want_picks = np.zeros((4, 4), np.int64)
-        rows = 0
+        rows = blocks_read = 0
         for (prompt, new), out in zip(asked, served):
             seq = jnp.asarray(np.concatenate([prompt, out]))
             logits, choices = xm.forward_logits(cfg, params, seq,
@@ -159,6 +211,7 @@ def test_through_the_pool_tokens_and_routing_counters(seed):
                 == list(out)
             # decode steps fed positions n .. n + new - 2
             rows += new - 1
+            blocks_read += sum(p // block + 1 for p in range(n, n + new - 1))
             for l, chosen in enumerate(choices):
                 local = np.asarray(chosen)[n:n + new - 1] - cfg.first_expert
                 for x in range(4):
@@ -167,6 +220,10 @@ def test_through_the_pool_tokens_and_routing_counters(seed):
         assert counted["moe_picks_total"] == rows * cfg.top_k * 4
         np.testing.assert_array_equal(counted["moe_picks"], want_picks)
         assert 0 < counted["gauges"]["serving.moe.local_share"] < 1
+        assert counted["attn_blocks_read"] == blocks_read
+        assert counted["attn_blocks_held"] == rows * (cfg.max_len // block)
+        assert counted["gauges"]["serving.attn.rows_read_share"] \
+            == pytest.approx(blocks_read / (rows * (cfg.max_len / block)))
     finally:
         pool.close(drain=False)
 

@@ -319,6 +319,102 @@ def _exaone_step_case():
 CASES["decode-step-k-exaone-256-slots-no-cache-copy"] = _exaone_step_case
 
 
+# -- decode attention ----------------------------------------------------------
+def _decode_attention_case():
+    """``ops.attention.decode_attention`` alone at the K-EXAONE cell's
+    full layer (256 slots, 8 K/V heads of 128 with 8 queries each, 4096
+    rows, bfloat16): the plan admits it in blocks of 512 rows (1 MiB of K
+    a grid step), and what the plan admits the compiler takes."""
+    q = jax.ShapeDtypeStruct((256, 8, 8, 128), jnp.bfloat16)
+    cache = jax.ShapeDtypeStruct((256, 8, 4096, 128), jnp.bfloat16)
+    with _tpu_trace():
+        assert attention.decode_attention_plan(q, cache) == (512, None)
+        _compile(lambda q, k, v, n: attention.decode_attention(
+            q, k, v, n, 128 ** -0.5),
+            (q.shape, q.dtype), (cache.shape, cache.dtype),
+            (cache.shape, cache.dtype), ((256,), jnp.int32))
+
+
+CASES["decode_attention-256x8x8x128-over-4096-rows"] = _decode_attention_case
+
+
+def _decode_attention_vmem():
+    """Where the plan says ``vmem`` the compiler does: K and V of one
+    block, double-buffered, are most of the 16 MiB a kernel may use once a
+    block passes 2 MiB."""
+    shape, cache = (4, 32, 4, 256), (4, 32, 1024, 256)
+    with _tpu_trace():
+        assert attention.decode_attention_plan(
+            jax.ShapeDtypeStruct(shape, jnp.float32),
+            jax.ShapeDtypeStruct(cache, jnp.float32)) == (1024, "vmem")
+    try:
+        _compile(lambda q, k, v, n: attention._decode_pallas(
+            q, k, v, n, 0.0625, 128),
+            (shape, jnp.float32), (cache, jnp.float32),
+            (cache, jnp.float32), ((4,), jnp.int32))
+    except Exception as e:  # noqa: broad-except — the compiler's refusal
+        assert "vmem" in str(e), e
+    else:
+        raise AssertionError("the compiler took 4 MiB blocks of K and V")
+
+
+CASES["decode_attention-refuses-what-the-compiler-refuses"] = \
+    _decode_attention_vmem
+
+
+def _exaone_step_with_the_kernel():
+    """The K-EXAONE ``jit_step`` as it is traced for the chip, the full
+    layer's attention in the kernel: one ``tpu_custom_call``, no score
+    array over all ``max_len`` rows, no copy of a cache-sized array (a
+    ``pallas_call`` wants its operands in the default layout, which is the
+    one the cache lives in), temporaries a fifteenth of what the masked
+    read over every row held.  The parameters' shapes are the benchmark's
+    own weights', which are drawn by the device and so, as shapes, by
+    nobody (``init_params`` draws 3.7 G numbers on the host first)."""
+    from benchmark import harness
+    from benchmark.families import exaone_moe_engine as family
+    from benchmark.reference import exaone_moe_engine as ref
+    from benchmark.tools import aot_compile_moe as tool
+    from mxnet_tpu.models import exaone_moe as xm
+    from mxnet_tpu.serving import DecodeEngine
+
+    class Unbuilt(DecodeEngine):
+        def _fresh_state(self):
+            return None
+
+        def _warm(self, state):
+            return state
+
+    config = harness.load_json(os.path.join(
+        ROOT, "benchmark", "configs", "k-exaone-236b-a23b.json"))
+    model = xm.ExaoneMoE(family.model_config(xm, ref.sizes(config)),
+                         jnp.dtype(config["precision"]["kv_cache"]))
+    engine = Unbuilt(model, {}, slots=config["engine"]["slots"],
+                     prefill_buckets=config["engine"]["prefill_buckets"],
+                     autostart=False)
+    one_chip = _one_chip()
+    params, state, keep, extra = family.step_shapes(
+        engine, jax.eval_shape(
+            lambda: ref.init_weights(config, 0, jax.devices()[0])),
+        lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip))
+    with _tpu_trace():
+        compiled = engine._step_fn.lower(params, state, keep,
+                                         extra).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "f32[256,8,8,4096]" not in text
+    assert state[0][3].shape == (256, 8, 4096, 128)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+    copies = tool.cache_copies(text, state)
+    assert not copies, "%d copies of a cache array, the first: %s" \
+        % (len(copies), copies[0][:200])
+
+
+CASES["decode-step-k-exaone-256-slots-with-the-decode-kernel"] = \
+    _exaone_step_with_the_kernel
+
+
 # -- the tests -----------------------------------------------------------------
 def test_bn_budget_admits_the_late_stages():
     assert _bn_stages(jnp.bfloat16) == [
