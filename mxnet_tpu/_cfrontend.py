@@ -65,8 +65,10 @@ def nd_copy_from(a, addr, size):
     # host buffer is in the array's dtype unless bf16 (no numpy dtype on
     # the C side): bf16 arrays take f32 host data
     host_dt = np.float32 if str(a.dtype) == "bfloat16" else a.dtype
-    v = _host_view(addr, size, host_dt).reshape(a.shape)
-    a[:] = v
+    # a copy the array owns: on the CPU backend ``jnp.asarray`` may alias a
+    # numpy buffer instead of copying it, and this one is the caller's, who
+    # may free or reuse it as soon as the call returns
+    a[:] = _host_view(addr, size, host_dt).reshape(a.shape).copy()
 
 
 def nd_copy_to(a, addr, size):

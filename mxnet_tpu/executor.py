@@ -132,7 +132,7 @@ def _mirror_mode():
     FLOPs — the deep end of the reference's mirror trade)."""
     import os
 
-    # lint: ok[tracer-purity] read at trace time BY DESIGN — the executor keys its fn cache on trace_env_fingerprint(), so a changed value retraces
+    # lint: ok[tracer-purity] read at trace time BY DESIGN — the executor keys its fn cache (_get_fn, _seg_fn) on this function's value, so a changed value retraces
     v = os.environ.get("MXNET_BACKWARD_DO_MIRROR", "")
     if v in ("", "0"):
         return 0
@@ -175,10 +175,15 @@ def _graph_forward(symbol, arg_vals, aux_vals, is_train, rng):
 
 
 def _bn_relu_peephole(symbol, nodes):
-    """BatchNorm nodes whose SOLE consumer is a relu ``Activation`` fuse
-    into one kernel application (stats+normalize+relu in a single HBM
-    pass via ops/bn_pallas.py) — the executor-level analog of cuDNN's
-    fused BN-activation.  Returns ({id(bn)}, {id(act): bn_node})."""
+    """BatchNorm nodes whose SOLE consumer is a relu ``Activation`` are
+    applied in the Activation's slot, the relu written ``jnp.maximum(out,
+    0)`` inside the BatchNorm.  Kept for what it measures, not for a
+    kernel (the fused one it fed is gone): ResNet-50's ``train`` program
+    at batch 256 holds 10.284 GB of temporaries with it and 11.106 GB
+    with ``jax.nn.relu`` as an op of its own, one stem-sized activation
+    more (compiled for v5e; 12.01 against 12.83 GB at the peak on the
+    chip, equal speed: PERF.md, PR 29).  Returns ({id(bn)}, {id(act):
+    bn_node})."""
     count = {}
     for node in nodes:
         if node.is_variable:
@@ -529,9 +534,9 @@ class Executor:
         return kind_name
 
     def _get_fn(self, kind):
-        # keyed on the trace-time env fingerprint: MXNET_BN_*/mirror/
-        # barrier toggles must retrace, not silently reuse a stale jit
-        cache_key = (kind, _ops_registry.trace_env_fingerprint())
+        # keyed on the one knob a trace depends on: a mirror toggle must
+        # retrace, not silently reuse a stale jit
+        cache_key = (kind, _mirror_mode())
         if cache_key in self._fns:
             # IN-PROCESS jit function reuse — split from the on-disk
             # xla.compile.persistent_cache_hits (compile_cache.py)
@@ -904,8 +909,7 @@ class Executor:
         self._seg_dev_of = dev_of
 
     def _seg_fn(self, si, is_train):
-        key = ("seg", si, is_train,
-               _ops_registry.trace_env_fingerprint())
+        key = ("seg", si, is_train, _mirror_mode())
         if key in self._fns:
             _telemetry.inc("xla.compile.fn_cache_hits")
             return self._fns[key]

@@ -1,33 +1,19 @@
 """Decode-capable transformer LM — the served autoregressive workload.
 
-``parallel/lm.py`` is the *training* flagship (dp x tp x pp x sp x ep in
-one SPMD step); this module is its serving-side counterpart: a compact
-decoder-only transformer whose forward math is split exactly along the
-line a continuous-batching server needs (docs/serving.md "Continuous
-batching & replica pool"):
+``parallel/lm.py`` is the *training* flagship (one SPMD step over dp x tp
+x pp x sp x ep); this is its serving-side counterpart, one device a replica
+(:class:`~mxnet_tpu.serving.pool.ReplicaPool` spreads engines over devices).
 
-* :func:`prefill_kv` — run the full prompt once, return the last-token
-  logits plus the per-layer K/V rows to seed a slot of the engine's
-  device-resident cache;
-* :func:`decode_step_math` — ONE token for ALL ``S`` cache slots at
-  once: write the incoming token's K/V into each slot's cache row
-  (:func:`write_rows`), attend over ``positions <= length`` and produce ``(S, vocab)``
-  logits.  Fixed shapes in, fixed shapes out — the function compiles
-  once per ``(S, max_len)`` and never again
-  (:mod:`mxnet_tpu.serving.decode` wraps it with sampling and slot
-  state into the single jitted step);
-* :func:`forward_logits` — plain batched teacher-forcing forward, the
-  ground truth the decode path is pinned bit-compatible against
-  (``tests/test_decode.py``: greedy decode == argmax of the full
-  forward).
-
-The math is deliberately single-device per replica — multi-replica
-throughput comes from :class:`~mxnet_tpu.serving.pool.ReplicaPool`
-spreading engines over ``jax.devices()``, not from sharding one model.
+The layer is written once, in :func:`_block`, which takes its cache access
+as an argument.  The five entry points are what a continuous-batching
+server needs (docs/serving.md) and differ in that access alone: teacher
+forcing (:func:`forward_logits`, which ``tests/test_decode.py`` pins greedy
+decode against), a prompt into a slot and one token for every slot
+(:func:`prefill_kv`, :func:`decode_step_math`), and the same two through a
+block pool (:func:`prefill_kv_paged`, :func:`decode_step_paged`).
 """
 
-from __future__ import annotations
-
+import functools
 import io
 import json
 from collections import namedtuple
@@ -41,9 +27,8 @@ __all__ = ["LMConfig", "CacheLayer", "slot_shape", "DecodeModel",
            "write_rows", "decode_step_math", "prefill_kv_paged",
            "decode_step_paged", "params_to_blob", "params_from_blob"]
 
-#: model hyperparameters; ``max_len`` bounds the KV cache (and therefore
-#: prompt + generated length), ``eos_id`` is the token that retires a
-#: sequence early
+#: model hyperparameters; ``max_len`` bounds the KV cache (so prompt +
+#: generated length), ``eos_id`` is the token that retires a sequence early
 LMConfig = namedtuple("LMConfig", ["vocab", "embed", "heads", "layers",
                                    "ffn", "max_len", "eos_id"])
 
@@ -67,9 +52,8 @@ def slot_shape(layer):
 
 
 def init_params(cfg, seed=0, dtype=jnp.float32):
-    """Parameter pytree (host -> the caller ``device_put``s it where the
-    replica lives).  Per-layer weights are stacked on axis 0 so the
-    pytree stays flat and a layer loop indexes rows."""
+    """Parameter pytree on the host (the caller ``device_put``s it where
+    the replica lives); per-layer weights are stacked on axis 0."""
     if cfg.embed % cfg.heads:
         raise ValueError("embed=%d not divisible by heads=%d"
                          % (cfg.embed, cfg.heads))
@@ -81,19 +65,12 @@ def init_params(cfg, seed=0, dtype=jnp.float32):
 
     L, E, F = cfg.layers, cfg.embed, cfg.ffn
     return {
-        "embed": nrm(cfg.vocab, E),
-        "pos": nrm(cfg.max_len, E),
-        "head": nrm(E, cfg.vocab),
-        "ln_f": jnp.ones((E,), dtype),
+        "embed": nrm(cfg.vocab, E), "pos": nrm(cfg.max_len, E),
+        "head": nrm(E, cfg.vocab), "ln_f": jnp.ones((E,), dtype),
         "blocks": {
-            "ln1": jnp.ones((L, E), dtype),
-            "qkv_w": nrm(L, E, 3 * E),
-            "out_w": nrm(L, E, E),
-            "ln2": jnp.ones((L, E), dtype),
-            "up_w": nrm(L, E, F),
-            "down_w": nrm(L, F, E),
-        },
-    }
+            "ln1": jnp.ones((L, E), dtype), "qkv_w": nrm(L, E, 3 * E),
+            "out_w": nrm(L, E, E), "ln2": jnp.ones((L, E), dtype),
+            "up_w": nrm(L, E, F), "down_w": nrm(L, F, E)}}
 
 
 def _rmsnorm(x, g):
@@ -102,103 +79,119 @@ def _rmsnorm(x, g):
         + 1e-6).astype(x.dtype)
 
 
-def _layer(blocks, l):
-    return {k: v[l] for k, v in blocks.items()}
+def _block(cfg, pl, x, attend):
+    """One layer over rows ``x (..., embed)``, whatever the leading axes.
+    ``attend(q, k, v)`` is the caller's cache access: handed this layer's
+    ``q``/``k``/``v (..., heads, head_dim)``, it returns ``q``'s context."""
+    h = _rmsnorm(x, pl["ln1"])
+    qkv = jnp.einsum("...e,ef->...f", h, pl["qkv_w"])
+    q, k, v = (a.reshape(x.shape[:-1] + (cfg.heads, cfg.embed // cfg.heads))
+               for a in jnp.split(qkv, 3, axis=-1))
+    ctx = attend(q, k, v)
+    x = x + jnp.einsum("...e,ef->...f", ctx.reshape(x.shape), pl["out_w"])
+    h = _rmsnorm(x, pl["ln2"])
+    up = jax.nn.gelu(jnp.einsum("...e,ef->...f", h, pl["up_w"]))
+    return x + jnp.einsum("...f,fe->...e", up, pl["down_w"])
+
+
+def _blocks(cfg, params, x, attend):
+    """Every layer in turn; ``attend(l, q, k, v)`` is told which."""
+    for l in range(cfg.layers):
+        pl = {name: w[l] for name, w in params["blocks"].items()}
+        x = _block(cfg, pl, x, functools.partial(attend, l))
+    return x
+
+
+def _embed(params, tokens, pos):
+    return params["embed"][tokens] + params["pos"][pos]
+
+
+def _logits(params, x):
+    x = _rmsnorm(x, params["ln_f"])
+    return jnp.einsum("...e,ev->...v", x, params["head"]).astype(jnp.float32)
+
+
+def _attend_rows(q, k, v, mask):
+    """Query rows ``q (..., Q, heads, d)`` over key rows ``k``/``v (..., K,
+    heads, d)`` where ``mask (Q, K)`` says so."""
+    scores = jnp.einsum("...qhd,...khd->...hqk", q, k) \
+        * (1.0 / np.sqrt(q.shape[-1]))
+    att = jax.nn.softmax(
+        jnp.where(mask[None], scores, jnp.float32(-1e30)), axis=-1)
+    return jnp.einsum("...hqk,...khd->...qhd", att, v)
+
+
+def _attend_slots(q, ck, cv, kpos, pos):
+    """One query a slot, ``q (S, heads, d)``, over that slot's rows of
+    ``ck``/``cv (S, M, heads, d)`` at positions ``kpos (M,) <= pos (S,)``."""
+    scores = jnp.einsum("shd,smhd->shm", q, ck) \
+        * (1.0 / np.sqrt(q.shape[-1]))
+    mask = kpos[None, None, :] <= pos[:, None, None]
+    att = jax.nn.softmax(
+        jnp.where(mask, scores, jnp.float32(-1e30)), axis=-1)
+    return jnp.einsum("shm,smhd->shd", att, cv)
 
 
 def forward_logits(cfg, params, tokens):
     """Teacher-forcing forward: ``tokens (B, T) int32 -> (B, T, vocab)``
     float32 logits — training/eval and the decode-parity ground truth."""
-    b, t = tokens.shape
-    pos = jnp.arange(t)
-    x = params["embed"][tokens] + params["pos"][pos][None]
+    pos = jnp.arange(tokens.shape[1])
+    x = _embed(params, tokens, pos)
     causal = pos[None, :] <= pos[:, None]            # (q, k)
-    hd = cfg.embed // cfg.heads
-    scale = 1.0 / np.sqrt(hd)
-    for l in range(cfg.layers):
-        p = _layer(params["blocks"], l)
-        h = _rmsnorm(x, p["ln1"])
-        qkv = jnp.einsum("bte,ef->btf", h, p["qkv_w"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-
-        def heads(a):
-            return a.reshape(b, t, cfg.heads, hd)
-
-        scores = jnp.einsum("bqhd,bkhd->bhqk", heads(q), heads(k)) * scale
-        att = jax.nn.softmax(
-            jnp.where(causal[None, None], scores, jnp.float32(-1e30)),
-            axis=-1)
-        ctx = jnp.einsum("bhqk,bkhd->bqhd", att, heads(v))
-        x = x + jnp.einsum("bte,ef->btf",
-                           ctx.reshape(b, t, cfg.embed), p["out_w"])
-        h = _rmsnorm(x, p["ln2"])
-        x = x + jnp.einsum("btf,fe->bte",
-                           jax.nn.gelu(jnp.einsum("bte,ef->btf", h,
-                                                  p["up_w"])), p["down_w"])
-    x = _rmsnorm(x, params["ln_f"])
-    return jnp.einsum("bte,ev->btv", x, params["head"]).astype(jnp.float32)
+    return _logits(params, _blocks(
+        cfg, params, x, lambda l, q, k, v: _attend_rows(q, k, v, causal)))
 
 
 def prefill_kv(cfg, params, tokens, length):
     """One prompt through the model: ``tokens (P,) int32`` (bucket-padded,
-    ``length`` real tokens) -> ``(last_logits (vocab,), ks, vs)`` where
-    ``ks``/``vs`` are per-layer tuples of ``(P, heads, head_dim)`` cache
-    rows for positions ``0..P-1``.  Rows past ``length`` hold pad-token
-    K/V — the decode attention mask (``position <= slot length``) never
-    reads them before the decode step itself overwrites them in place.
-    """
+    ``length`` real tokens) -> ``(last_logits (vocab,), ks, vs)``, ``ks``/
+    ``vs`` per-layer tuples of ``(P, heads, head_dim)`` cache rows for
+    positions ``0..P-1``.  Rows past ``length`` hold pad-token K/V, which
+    the decode step's mask (``position <= slot length``) never reads
+    before the step itself overwrites them."""
     (p,) = tokens.shape
     pos = jnp.arange(p)
-    x = params["embed"][tokens] + params["pos"][pos]
+    x = _embed(params, tokens, pos)
     causal = pos[None, :] <= pos[:, None]
-    hd = cfg.embed // cfg.heads
-    scale = 1.0 / np.sqrt(hd)
     ks, vs = [], []
-    for l in range(cfg.layers):
-        pl = _layer(params["blocks"], l)
-        h = _rmsnorm(x, pl["ln1"])
-        qkv = jnp.einsum("te,ef->tf", h, pl["qkv_w"])
-        q, k, v = (a.reshape(p, cfg.heads, hd)
-                   for a in jnp.split(qkv, 3, axis=-1))
-        scores = jnp.einsum("qhd,khd->hqk", q, k) * scale
-        att = jax.nn.softmax(
-            jnp.where(causal[None], scores, jnp.float32(-1e30)), axis=-1)
-        ctx = jnp.einsum("hqk,khd->qhd", att, v)
-        x = x + jnp.einsum("te,ef->tf",
-                           ctx.reshape(p, cfg.embed), pl["out_w"])
-        h = _rmsnorm(x, pl["ln2"])
-        x = x + jnp.einsum("tf,fe->te",
-                           jax.nn.gelu(jnp.einsum("te,ef->tf", h,
-                                                  pl["up_w"])),
-                           pl["down_w"])
+
+    def attend(l, q, k, v):
         ks.append(k)
         vs.append(v)
-    x = _rmsnorm(x, params["ln_f"])
-    logits = jnp.einsum("te,ev->tv", x, params["head"]).astype(jnp.float32)
+        return _attend_rows(q, k, v, causal)
+
+    logits = _logits(params, _blocks(cfg, params, x, attend))
     last = jnp.take(logits, jnp.clip(length - 1, 0, p - 1), axis=0)
     return last, tuple(ks), tuple(vs)
 
 
+def _through_cache(cfg, params, x, cache_k, cache_v, write, attend):
+    """Rows ``x`` through layers that each ``write(array, rows)`` their K/V
+    into their two cache arrays, then ``attend(q, array_k, array_v)``:
+    ``(logits, new_k, new_v)``.  The layouts differ in the two callbacks."""
+    new_k, new_v = list(cache_k), list(cache_v)
+
+    def through(l, q, k, v):
+        new_k[l], new_v[l] = write(cache_k[l], k), write(cache_v[l], v)
+        return attend(q, new_k[l], new_v[l])
+
+    logits = _logits(params, _blocks(cfg, params, x, through))
+    return logits, tuple(new_k), tuple(new_v)
+
+
 @jax.jit
 def write_rows(cache, rows, pos):
-    """``cache (S, max_len, heads, head_dim)`` with ``rows[i]`` (of
-    ``(S, heads, head_dim)``) written at ``[i, pos[i]]``; ``pos (S,)``
-    lies within ``0..max_len-1``.  Equal, as an array, to
-    ``cache.at[arange(S), pos].set(rows)``.
+    """``cache (S, max_len, heads, head_dim)`` with ``rows[i]`` (of ``(S,
+    heads, head_dim)``) at ``[i, pos[i]]``, ``pos (S,)`` within ``0..
+    max_len-1``: as an array, ``cache.at[arange(S), pos].set(rows)``.
 
-    One ``dynamic_update_slice`` a slot, and not that scatter: an
-    update-slice is done in place in whatever layout the compiler keeps
-    the cache in, while a scatter wants the cache row-major.  On the TPU
-    the dense cache lives position-minor (what both attention
-    contractions read), and the scatter cost two transposes of the whole
-    array a layer and a step, most of the step's time (PERF.md, PR 25).
-    Nothing here asks which layout or which backend it is: where the
-    cache is kept row-major the update-slices are in place just the same.
-
-    Jitted on its own so that a step traces the ``S`` update-slices once
-    and not once a layer and array: an engine traces its step twice at
-    every start, and that time is the server's set-up.
-    """
+    One ``dynamic_update_slice`` a slot, not that scatter: it is in place
+    in whatever layout the compiler keeps the cache in, while a scatter
+    wants it row-major; on the TPU, where the dense cache lives
+    position-minor, that cost two transposes of the whole array a layer
+    and a step (PERF.md, PR 25).  Jitted on its own so that a step traces
+    the ``S`` update-slices once, not once a layer and array: an engine
+    traces its step twice at every start, the server's set-up."""
     pieces = jnp.split(rows[:, None], cache.shape[0])
     for i, piece in enumerate(pieces):
         cache = jax.lax.dynamic_update_slice(
@@ -207,194 +200,95 @@ def write_rows(cache, rows, pos):
 
 
 def decode_step_math(cfg, params, cache_k, cache_v, last_tok, lengths):
-    """One decode token for all ``S`` slots.
-
-    ``cache_k``/``cache_v``: per-layer tuples of ``(S, max_len, heads,
-    head_dim)``; ``last_tok (S,) int32`` is each slot's most recent
-    token (prompt tail after prefill, previous sample afterwards);
-    ``lengths (S,) int32`` is each slot's cache fill — the position the
-    incoming token's K/V is written to, and the inclusive attention
-    horizon.  Returns ``(logits (S, vocab), new_cache_k, new_cache_v)``.
-
-    Inactive slots ride along (fixed shape => no recompile): their
-    row lands where the mask makes it unreachable until a real write
-    replaces it, and their logits are discarded host-side.
-
-    The rows go in through :func:`write_rows`, which assumes nothing
-    about the layout the cache lives in on the device and is not a
-    scatter, so the compiled step holds no copy of a cache-sized array.
-    """
-    (s, m) = cache_k[0].shape[:2]
-    hd = cfg.embed // cfg.heads
-    scale = 1.0 / np.sqrt(hd)
-    kpos = jnp.arange(m)
+    """One decode token for all ``S`` slots.  ``cache_k``/``cache_v``:
+    per-layer tuples of ``(S, max_len, heads, head_dim)``; ``last_tok (S,)
+    int32`` is each slot's most recent token; ``lengths (S,) int32`` its
+    cache fill: where the incoming K/V goes (:func:`write_rows`: no copy
+    of a cache-sized array) and the inclusive attention horizon.  Returns
+    ``(logits (S, vocab), new_cache_k, new_cache_v)``.  Inactive slots ride
+    along: their row lands where the mask hides it until a real write
+    replaces it, and the host drops their logits."""
+    kpos = jnp.arange(cache_k[0].shape[1])
     pos = jnp.clip(lengths, 0, cfg.max_len - 1)
-    x = params["embed"][last_tok] + params["pos"][pos]
-    new_k, new_v = [], []
-    for l in range(cfg.layers):
-        pl = _layer(params["blocks"], l)
-        h = _rmsnorm(x, pl["ln1"])
-        qkv = jnp.einsum("se,ef->sf", h, pl["qkv_w"])
-        q, k, v = (a.reshape(s, cfg.heads, hd)
-                   for a in jnp.split(qkv, 3, axis=-1))
-        ck = write_rows(cache_k[l], k, pos)
-        cv = write_rows(cache_v[l], v, pos)
-        scores = jnp.einsum("shd,smhd->shm", q, ck) * scale
-        mask = kpos[None, None, :] <= pos[:, None, None]
-        att = jax.nn.softmax(
-            jnp.where(mask, scores, jnp.float32(-1e30)), axis=-1)
-        ctx = jnp.einsum("shm,smhd->shd", att, cv)
-        x = x + jnp.einsum("se,ef->sf",
-                           ctx.reshape(s, cfg.embed), pl["out_w"])
-        h = _rmsnorm(x, pl["ln2"])
-        x = x + jnp.einsum("sf,fe->se",
-                           jax.nn.gelu(jnp.einsum("se,ef->sf", h,
-                                                  pl["up_w"])),
-                           pl["down_w"])
-        new_k.append(ck)
-        new_v.append(cv)
-    x = _rmsnorm(x, params["ln_f"])
-    logits = jnp.einsum("se,ev->sv", x, params["head"]).astype(jnp.float32)
-    return logits, tuple(new_k), tuple(new_v)
+    return _through_cache(
+        cfg, params, _embed(params, last_tok, pos), cache_k, cache_v,
+        lambda cache, rows: write_rows(cache, rows, pos),
+        lambda q, ck, cv: _attend_slots(q, ck, cv, kpos, pos))
+
+
+def _held(pool, table, m):
+    """The first ``m`` positions ``table (..., max_blocks)`` names in
+    ``pool (num_blocks, block_size, heads, d)``: ``(..., m, heads, d)``."""
+    shape = table.shape[:-1] + (-1,) + pool.shape[2:]
+    return pool[table].reshape(shape)[..., :m, :, :]
 
 
 def prefill_kv_paged(cfg, params, pool_k, pool_v, table, tokens, start,
                      length):
-    """Suffix prefill through a block table — the paged twin of
-    :func:`prefill_kv` (``mxnet_tpu.serving.kvblocks`` owns the block
-    bookkeeping; this is pure math).
-
-    ``pool_k``/``pool_v``: per-layer tuples of ``(num_blocks,
-    block_size, heads, head_dim)`` pool rows; ``table (max_blocks,)
-    int32`` maps the slot's logical block index to a pool row (0 = the
-    reserved scratch block, where unallocated entries point).
-    ``tokens (P,) int32`` is the bucket-padded transcript SUFFIX
-    occupying absolute positions ``start .. start+P-1``: ``start = 0``
-    is a cold prefill, ``start > 0`` is a prefix-cache hit that runs
-    ZERO compute for the shared positions — their K/V is already
-    resident in the table's blocks and is only gathered for attention.
-    ``length`` is the absolute transcript length.  Returns
-    ``(last_logits (vocab,), new_pool_k, new_pool_v)``.
-
-    Bit-identity with the dense path is by construction: K/V rows are
-    scattered into the pool, gathered back through the table and
-    statically sliced to ``max_len``, so scores, mask and softmax see
-    EXACTLY the shapes :func:`decode_step_math`'s attention sees; lanes
-    past a row's horizon are exact zeros under the ``-1e30`` mask, and
-    unallocated lanes read scratch garbage that the mask also zeroes.
-    Bucket-pad rows scatter to the scratch block or to not-yet-read
-    rows past ``length`` — the same never-read discipline as the dense
-    prefill's pad rows.
-    """
+    """The paged twin of :func:`prefill_kv`, for a transcript's suffix
+    (``serving/kvblocks.py`` owns the bookkeeping).  ``pool_k``/``pool_v``:
+    per-layer tuples of ``(num_blocks, block_size, heads, head_dim)``;
+    ``table (max_blocks,) int32`` maps the slot's logical block to a pool
+    row (0 = the scratch block, where unallocated entries point); ``tokens
+    (P,) int32``, bucket-padded, sit at absolute positions ``start ..
+    start+P-1`` (``start > 0``: a prefix-cache hit, whose shared positions
+    are only gathered); ``length`` is the whole transcript's.  Returns
+    ``(last_logits (vocab,), new_pool_k, new_pool_v)``.  Rows are scattered
+    into the pool, gathered back through the table and sliced to
+    ``max_len``, so the floats are the dense path's: lanes past a row's
+    horizon and unallocated lanes are exact zeros under the mask; pad rows
+    scatter to the scratch block or past ``length``, read by nobody."""
     (p,) = tokens.shape
-    (mb,) = table.shape
     bs = pool_k[0].shape[1]
     m = cfg.max_len
-    hd = cfg.embed // cfg.heads
-    scale = 1.0 / np.sqrt(hd)
     pos = start + jnp.arange(p)            # absolute positions
     posc = jnp.clip(pos, 0, m - 1)         # only pad rows ever clamp
     blk = table[posc // bs]
     off = posc % bs
-    x = params["embed"][tokens] + params["pos"][posc]
-    kpos = jnp.arange(m)
-    mask = kpos[None, :] <= pos[:, None]
-    new_k, new_v = [], []
-    for l in range(cfg.layers):
-        pl = _layer(params["blocks"], l)
-        h = _rmsnorm(x, pl["ln1"])
-        qkv = jnp.einsum("te,ef->tf", h, pl["qkv_w"])
-        q, k, v = (a.reshape(p, cfg.heads, hd)
-                   for a in jnp.split(qkv, 3, axis=-1))
-        pk = pool_k[l].at[blk, off].set(k)
-        pv = pool_v[l].at[blk, off].set(v)
-        ck = pk[table].reshape(mb * bs, cfg.heads, hd)[:m]
-        cv = pv[table].reshape(mb * bs, cfg.heads, hd)[:m]
-        scores = jnp.einsum("qhd,khd->hqk", q, ck) * scale
-        att = jax.nn.softmax(
-            jnp.where(mask[None], scores, jnp.float32(-1e30)), axis=-1)
-        ctx = jnp.einsum("hqk,khd->qhd", att, cv)
-        x = x + jnp.einsum("te,ef->tf",
-                           ctx.reshape(p, cfg.embed), pl["out_w"])
-        h = _rmsnorm(x, pl["ln2"])
-        x = x + jnp.einsum("tf,fe->te",
-                           jax.nn.gelu(jnp.einsum("te,ef->tf", h,
-                                                  pl["up_w"])),
-                           pl["down_w"])
-        new_k.append(pk)
-        new_v.append(pv)
-    x = _rmsnorm(x, params["ln_f"])
-    logits = jnp.einsum("te,ev->tv", x, params["head"]).astype(jnp.float32)
-    last = jnp.take(logits, jnp.clip(length - 1 - start, 0, p - 1),
-                    axis=0)
-    return last, tuple(new_k), tuple(new_v)
+    x = _embed(params, tokens, posc)
+    mask = jnp.arange(m)[None, :] <= pos[:, None]
+    logits, pool_k, pool_v = _through_cache(
+        cfg, params, x, pool_k, pool_v,
+        lambda pool, rows: pool.at[blk, off].set(rows),
+        lambda q, pk, pv: _attend_rows(q, _held(pk, table, m),
+                                       _held(pv, table, m), mask))
+    last = jnp.take(logits, jnp.clip(length - 1 - start, 0, p - 1), axis=0)
+    return last, pool_k, pool_v
 
 
 def decode_step_paged(cfg, params, pool_k, pool_v, tables, last_tok,
                       lengths):
-    """One decode token for all ``S`` slots through per-slot block
-    tables — the paged twin of :func:`decode_step_math`.
-
-    ``tables (S, max_blocks) int32`` names each slot's pool rows; the
-    incoming token's K/V scatters into the block covering position
-    ``lengths`` (the engine allocates that block before dispatch), the
-    slot's whole table is gathered and statically sliced to
-    ``(S, max_len)``, and attention proceeds exactly as the dense
-    step's — same shapes, same mask, same floats.  Inactive slots hold
-    all-zero tables: their scatter lands in the scratch block and their
-    gathered lanes are mask-dead, the paged rendition of the dense
-    step's unreachable-row idiom.  Fixed shapes throughout — ONE
-    compile per ``(S, max_len, num_blocks, block_size)``, ever.
-    """
-    s, mb = tables.shape
+    """The paged twin of :func:`decode_step_math`.  ``tables (S,
+    max_blocks) int32`` names each slot's pool rows; the incoming K/V
+    scatters into the block covering position ``lengths`` (the engine
+    allocates it before dispatch), the slot's table is gathered and sliced
+    to ``(S, max_len)``, and the attention is the dense step's, float for
+    float.  Inactive slots hold all-zero tables: their scatter lands in the
+    scratch block, their lanes are mask-dead."""
     bs = pool_k[0].shape[1]
     m = cfg.max_len
-    hd = cfg.embed // cfg.heads
-    scale = 1.0 / np.sqrt(hd)
-    rows = jnp.arange(s)
+    rows = jnp.arange(tables.shape[0])
     kpos = jnp.arange(m)
     pos = jnp.clip(lengths, 0, m - 1)
     wblk = tables[rows, pos // bs]
     woff = pos % bs
-    x = params["embed"][last_tok] + params["pos"][pos]
-    new_k, new_v = [], []
-    for l in range(cfg.layers):
-        pl = _layer(params["blocks"], l)
-        h = _rmsnorm(x, pl["ln1"])
-        qkv = jnp.einsum("se,ef->sf", h, pl["qkv_w"])
-        q, k, v = (a.reshape(s, cfg.heads, hd)
-                   for a in jnp.split(qkv, 3, axis=-1))
-        pk = pool_k[l].at[wblk, woff].set(k)
-        pv = pool_v[l].at[wblk, woff].set(v)
-        ck = pk[tables].reshape(s, mb * bs, cfg.heads, hd)[:, :m]
-        cv = pv[tables].reshape(s, mb * bs, cfg.heads, hd)[:, :m]
-        scores = jnp.einsum("shd,smhd->shm", q, ck) * scale
-        mask = kpos[None, None, :] <= pos[:, None, None]
-        att = jax.nn.softmax(
-            jnp.where(mask, scores, jnp.float32(-1e30)), axis=-1)
-        ctx = jnp.einsum("shm,smhd->shd", att, cv)
-        x = x + jnp.einsum("se,ef->sf",
-                           ctx.reshape(s, cfg.embed), pl["out_w"])
-        h = _rmsnorm(x, pl["ln2"])
-        x = x + jnp.einsum("sf,fe->se",
-                           jax.nn.gelu(jnp.einsum("se,ef->sf", h,
-                                                  pl["up_w"])),
-                           pl["down_w"])
-        new_k.append(pk)
-        new_v.append(pv)
-    x = _rmsnorm(x, params["ln_f"])
-    logits = jnp.einsum("se,ev->sv", x, params["head"]).astype(jnp.float32)
-    return logits, tuple(new_k), tuple(new_v)
+    return _through_cache(
+        cfg, params, _embed(params, last_tok, pos), pool_k, pool_v,
+        lambda pool, rows: pool.at[wblk, woff].set(rows),
+        lambda q, pk, pv: _attend_slots(q, _held(pk, tables, m),
+                                        _held(pv, tables, m), kpos, pos))
 
 
 class DecodeModel:
-    """This LM as the decode engine's model protocol
-    (:mod:`mxnet_tpu.serving.decode`): a float32 cache of ``layers`` full
-    layers, the functions above behind the protocol's names, no extra
-    device state, and the paged twins."""
+    """This LM as the decode engine's model protocol (:mod:`mxnet_tpu.
+    serving.decode`): a float32 cache of ``layers`` full layers, the
+    functions above under the protocol's names, no extra device state."""
 
     def __init__(self, cfg):
         self.cfg = cfg
+        self.prefill = functools.partial(prefill_kv, cfg)
+        self.prefill_paged = functools.partial(prefill_kv_paged, cfg)
+        self.decode_step_paged = functools.partial(decode_step_paged, cfg)
 
     def cache_spec(self):
         cfg = self.cfg
@@ -405,25 +299,11 @@ class DecodeModel:
     def extra_state(self):
         return None
 
-    def prefill(self, params, tokens, length):
-        return prefill_kv(self.cfg, params, tokens, length)
-
     def decode_step(self, params, cache_k, cache_v, last_tok, lengths,
                     active, extra):
         del active
-        logits, cache_k, cache_v = decode_step_math(
-            self.cfg, params, cache_k, cache_v, last_tok, lengths)
-        return logits, cache_k, cache_v, extra
-
-    def prefill_paged(self, params, pool_k, pool_v, table, tokens, start,
-                      length):
-        return prefill_kv_paged(self.cfg, params, pool_k, pool_v, table,
-                                tokens, start, length)
-
-    def decode_step_paged(self, params, pool_k, pool_v, tables, last_tok,
-                          lengths):
-        return decode_step_paged(self.cfg, params, pool_k, pool_v, tables,
-                                 last_tok, lengths)
+        return decode_step_math(self.cfg, params, cache_k, cache_v,
+                                last_tok, lengths) + (extra,)
 
 
 def params_to_blob(cfg, params):
@@ -431,12 +311,8 @@ def params_to_blob(cfg, params):
     payload format, :func:`mxnet_tpu.serving.save_model` convention)."""
     flat = {"__config__": np.frombuffer(
         json.dumps(cfg._asdict()).encode(), np.uint8)}
-    for k, v in params.items():
-        if isinstance(v, dict):
-            for k2, v2 in v.items():
-                flat["%s.%s" % (k, k2)] = np.asarray(v2)
-        else:
-            flat[k] = np.asarray(v)
+    flat.update((k, v) for k, v in params.items() if k != "blocks")
+    flat.update(("blocks." + k, v) for k, v in params["blocks"].items())
     buf = io.BytesIO()
     np.savez(buf, **flat)
     return buf.getvalue()
@@ -446,12 +322,8 @@ def params_from_blob(blob):
     """Inverse of :func:`params_to_blob`: ``(cfg, params)``."""
     with np.load(io.BytesIO(blob)) as z:
         cfg = LMConfig(**json.loads(bytes(z["__config__"]).decode()))
-        params = {"blocks": {}}
-        for k in z.files:
-            if k == "__config__":
-                continue
-            if k.startswith("blocks."):
-                params["blocks"][k.split(".", 1)[1]] = jnp.asarray(z[k])
-            else:
-                params[k] = jnp.asarray(z[k])
+        flat = {k: jnp.asarray(z[k]) for k in z.files if k != "__config__"}
+    params = {k: v for k, v in flat.items() if "." not in k}
+    params["blocks"] = {k.split(".", 1)[1]: v for k, v in flat.items()
+                        if k.startswith("blocks.")}
     return cfg, params

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..base import MXNetError
-from . import bn_pallas
 from .helpers import acc_dtype as _acc_dtype, simple
 from .registry import (REQUIRED, pbool, pfloat, pint, pstr, ptuple, register)
 
@@ -60,13 +59,8 @@ def _conv_f32acc(stride, padding, lhs_dilation, rhs_dilation, dn, groups):
         # cotangent into the transposed convs — that fusion miscompiles
         # on the current TPU toolchain (wrong data-gradients for any
         # Pad/Crop/slice directly after a conv; verified against CPU and
-        # finite differences).  MXNET_CONV_GRAD_BARRIER=0 disables it for
-        # toolchains without the bug.
-        g = g.astype(data.dtype)
-        import os
-
-        if os.environ.get("MXNET_CONV_GRAD_BARRIER", "1") != "0":
-            g = jax.lax.optimization_barrier(g)
+        # finite differences)
+        g = jax.lax.optimization_barrier(g.astype(data.dtype))
         return vjp(g)
 
     conv.defvjp(fwd, bwd)
@@ -156,10 +150,7 @@ def _convolution(attrs, inputs, aux, is_train, rng):
     nd = len(kernel)
     stride, dilate, pad = _norm_stp(kernel, attrs["stride"], attrs["dilate"],
                                     attrs["pad"])
-    import os as _os
-
-    if (_os.environ.get("MXNET_CONV_STEM_S2D", "1") != "0"
-            and nd == 2 and tuple(kernel) == (7, 7)
+    if (nd == 2 and tuple(kernel) == (7, 7)
             and stride == (2, 2) and pad == (3, 3) and dilate == (1, 1)
             and attrs["num_group"] == 1 and data.shape[1] <= 4
             and data.shape[2] % 2 == 0 and data.shape[3] % 2 == 0):
@@ -335,45 +326,27 @@ register("SoftmaxActivation", _softmax_activation,
 # aux moving_mean/moving_var updated in train mode (functional aux-update).
 # ---------------------------------------------------------------------------
 def _batch_norm(attrs, inputs, aux, is_train, rng, act_type=None):
-    """``act_type="relu"`` fuses the activation into the Pallas kernel —
-    set only by the executor's BN->ReLU peephole (the registered op always
-    passes None)."""
+    """``act_type="relu"`` applies the relu here, as ``jnp.maximum(out,
+    0)``: set only by the executor's BN->ReLU peephole (the registered op
+    always passes None)."""
     x, gamma, beta = inputs
     moving_mean, moving_var = aux
     red = (0,) + tuple(range(2, x.ndim))
     bshape = (1, -1) + (1,) * (x.ndim - 2)
-    import os as _os
-
-    bn_mode = _os.environ.get("MXNET_BN_ABLATION", "")
-    if bn_mode == "frozen":  # perf-ablation only: skip batch statistics
-        use_batch = False
-    else:
-        use_batch = is_train and not attrs["use_global_stats"]
-    if use_batch and not attrs["output_mean_var"] \
-            and bn_pallas.eligible(x):
-        # fused single-HBM-pass BN (+ReLU): see ops/bn_pallas.py
-        out, mean, var = bn_pallas.bn_train(
-            x, gamma, beta, attrs["eps"], attrs["fix_gamma"],
-            relu=(act_type == "relu"))
-        m = attrs["momentum"]
-        new_mean = moving_mean * m + jax.lax.stop_gradient(mean) * (1 - m)
-        new_var = moving_var * m + jax.lax.stop_gradient(var) * (1 - m)
-        return [out], [new_mean, new_var]
+    use_batch = is_train and not attrs["use_global_stats"]
     if use_batch:
         # Stats ACCUMULATE in f32 always; what varies is the dtype of the
         # elementwise read pass.  For bf16 activations the read stays
-        # bf16 (opt out: MXNET_BN_STATS_F32=1): materializing x.astype
-        # (f32) made XLA emit a second full-size f32 copy of every conv
-        # output as a fusion epilogue (+wider reduce reads) — measured
+        # bf16: materializing x.astype(f32) made XLA emit a second
+        # full-size f32 copy of every conv output as a fusion epilogue
+        # (+wider reduce reads) — measured
         # ~4 ms/step of pure bandwidth on ResNet-50 b128 (per-HLO
         # profile, tools/perf/step_profile.py).  The probe-shift below
         # bounds the bf16 rounding of d to ~2^-8 relative of the
         # *deviation*, and round-to-nearest is unbiased, so the
         # batch-mean/var error vanishes as 1/sqrt(N) — validated by the
         # bf16 convergence-parity harness.
-        keep_bf16 = (x.dtype == jnp.bfloat16
-                     and _os.environ.get("MXNET_BN_STATS_F32", "0") != "1")
-        xf = x if keep_bf16 else x.astype(jnp.float32)
+        xf = x if x.dtype == jnp.bfloat16 else x.astype(jnp.float32)
         # shifted single-pass variance: center on a per-channel probe
         # (first element, gradient-stopped — the shifts cancel exactly in
         # mean and var) so E[d^2]-E[d]^2 cancels catastrophically only
@@ -396,7 +369,7 @@ def _batch_norm(attrs, inputs, aux, is_train, rng, act_type=None):
     shift = (beta.astype(jnp.float32)
              - mean * scale.astype(jnp.float32)).astype(x.dtype)
     out = x * scale.reshape(bshape) + shift.reshape(bshape)
-    if act_type == "relu":  # peephole fallback when Pallas is ineligible
+    if act_type == "relu":
         out = jnp.maximum(out, 0)
     outs = [out, mean, var] if attrs["output_mean_var"] else [out]
     if use_batch:

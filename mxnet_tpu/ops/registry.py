@@ -245,21 +245,6 @@ def list_ops():
 # each (op, attrs, is_train) gets one jitted callable and XLA/PJRT async
 # dispatch provides the same fire-and-forget semantics.
 
-# env vars some ops read at TRACE time (conv-grad barrier, BN ablation /
-# Pallas mode): every trace cache keys on this fingerprint, otherwise a
-# mid-process toggle is silently ignored by the cached jit
-_TRACE_ENV_VARS = ("MXNET_BN_PALLAS", "MXNET_BN_ABLATION",
-                   "MXNET_BN_STATS_F32", "MXNET_CONV_STEM_S2D",
-                   "MXNET_RNN_PALLAS", "MXNET_CONV_GRAD_BARRIER",
-                   "MXNET_BACKWARD_DO_MIRROR")
-
-
-def trace_env_fingerprint():
-    import os
-
-    return tuple(os.environ.get(v, "") for v in _TRACE_ENV_VARS)
-
-
 # device the current executor trace targets ("tpu"/"cpu"/None) — set by
 # the executor/imperative dispatch around tracing so device-dependent
 # lowering decisions (Pallas vs XLA) follow the computation's actual
@@ -296,12 +281,11 @@ def jitted_apply(op_name, attrs_tuple, is_train):
     # keyed on the trace device too: the traced jaxpr bakes in
     # device-dependent lowering decisions (Pallas vs XLA), so a CPU call
     # must not reuse a TPU-traced function or vice versa
-    return _jitted_apply(op_name, attrs_tuple, is_train,
-                         trace_env_fingerprint(), trace_device.get())
+    return _jitted_apply(op_name, attrs_tuple, is_train, trace_device.get())
 
 
 @lru_cache(maxsize=None)
-def _jitted_apply(op_name, attrs_tuple, is_train, _env_key, _dev_key):
+def _jitted_apply(op_name, attrs_tuple, is_train, _dev_key):
     op = get(op_name)
     attrs = dict(attrs_tuple)
 

@@ -80,45 +80,6 @@ def _scan_layer(x, wx, wh, bx, bh, h0, c0, mode):
     xproj = jnp.einsum("tni,gi->tng", x, wx) + bx  # one big MXU matmul
 
     if mode == "lstm":
-        import os as _os
-
-        rnn_mode = _os.environ.get("MXNET_RNN_PALLAS", "0")
-        if rnn_mode in ("1", "interpret"):
-            # Fused whole-sequence Pallas cell (cudnn fused-RNN analog).
-            # OFF by default: measured at parity with the scan path on
-            # v5e, not faster (docs/how_to/perf.md, round-4 negative) —
-            # XLA's scan already runs the cell at the hardware's
-            # per-step cost.  Kept as the capability artifact with
-            # fwd+bwd parity pinned on CPU and hardware.  "1" asks for
-            # the compiled kernel (TPU traces only); "interpret" asks
-            # for the Pallas interpreter (the CPU parity tests).  A
-            # request that cannot be met takes the scan path below and
-            # is counted with its reason.
-            from . import rnn_pallas
-            from .registry import count_kernel_path, on_tpu
-
-            T, N = xproj.shape[0], xproj.shape[1]
-            H = h0.shape[-1]
-            if xproj.dtype != jnp.float32:
-                reason = "dtype"
-            elif not rnn_pallas.fits(T, N, H, xproj.dtype):
-                reason = "vmem"
-            elif rnn_mode == "1" and not on_tpu():
-                reason = "not_tpu"
-            else:
-                reason = None
-            if reason is None:
-                count_kernel_path(
-                    "RNN", "interpret" if rnn_mode == "interpret"
-                    else "pallas", "ok")
-                xp4 = xproj.reshape(T, N, 4, H).transpose(0, 2, 1, 3)
-                w4 = wh.T.reshape(H, 4, H).transpose(1, 0, 2)
-                bh4 = bh.reshape(4, H)
-                ys, h, c = rnn_pallas.lstm_seq(xp4, w4, bh4, h0, c0,
-                                               rnn_mode == "interpret")
-                return ys, h, c
-            count_kernel_path("RNN", "xla", reason)
-
         def step(carry, xp):
             h, c = carry
             gates = xp + jnp.dot(h, wh.T) + bh

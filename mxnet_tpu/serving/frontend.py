@@ -549,6 +549,15 @@ class _Handler(BaseHTTPRequestHandler):
             self.close_connection = True
 
 
+class _HTTPServer(ThreadingHTTPServer):
+    #: the listen backlog.  Clients that connect at once (a closed-loop
+    #: load generator starting, a batch job's workers) wait in the kernel's
+    #: queue for the accept loop; past ``http.server``'s default of 5 the
+    #: kernel drops or resets them
+    request_queue_size = 128
+    daemon_threads = True
+
+
 class ServingHTTPServer:
     """Threaded HTTP server over a registry; ``port=0`` binds an
     ephemeral port (read ``.port`` after construction).
@@ -561,8 +570,7 @@ class ServingHTTPServer:
     """
 
     def __init__(self, registry, host="127.0.0.1", port=8080):
-        self._httpd = ThreadingHTTPServer((host, port), _Handler)
-        self._httpd.daemon_threads = True
+        self._httpd = _HTTPServer((host, port), _Handler)
         self._httpd.serving_handle = ServingHandle(registry)
         self._httpd.draining = False
         # admission accounting for graceful drain: flag + count mutate

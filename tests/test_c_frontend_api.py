@@ -491,3 +491,25 @@ def test_c_train_client_end_to_end(tmp_path):
                        text=True, timeout=420)
     assert r.returncode == 0, (r.stdout[-500:], r.stderr[-2000:])
     assert "C TRAIN OK" in r.stdout
+
+
+def test_sync_copy_from_cpu_owns_its_copy():
+    """``MXFrontNDArraySyncCopyFromCPU`` copies: the caller may free or
+    reuse its buffer as soon as the call returns (``train.c`` frees each
+    weight's buffer).  A 64-byte-aligned host buffer is what the CPU
+    backend would alias instead of copying."""
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import _cfrontend
+
+    raw = np.zeros(64 * 64 + 16, np.float32)
+    start = (-raw.ctypes.data % 64) // 4
+    host = raw[start:start + 64 * 64]
+    assert host.ctypes.data % 64 == 0
+    host[:] = np.arange(host.size)
+    a = mx.nd.zeros((64, 64))
+    _cfrontend.nd_copy_from(a, host.ctypes.data, host.size)
+    host[:] = -1.0                      # the caller reuses its buffer
+    np.testing.assert_array_equal(
+        a.asnumpy(), np.arange(64 * 64, dtype=np.float32).reshape(64, 64))

@@ -62,6 +62,68 @@ def _compiles():
     return (c.get("kind=decode_prefill", 0), c.get("kind=decode_step", 0))
 
 
+# -- the model: one block, five callers --------------------------------------
+
+def _entry_point_calls():
+    """Each of ``transformer_lm``'s entry points as a zero-argument trace
+    over shapes alone."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg, hd = CFG_NO_EOS, EMBED // HEADS
+    sds = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: PARAMS)
+    i32 = sds((), jnp.int32)
+    dense = tuple(sds((3, MAX_LEN, HEADS, hd), jnp.float32)
+                  for _ in range(LAYERS))
+    pool = tuple(sds((9, 8, HEADS, hd), jnp.float32) for _ in range(LAYERS))
+    slots = sds((3,), jnp.int32)
+    return {
+        "forward_logits": (lambda p, t: tlm.forward_logits(cfg, p, t),
+                           params, sds((2, 5), jnp.int32)),
+        "prefill_kv": (lambda *a: tlm.prefill_kv(cfg, *a),
+                       params, sds((8,), jnp.int32), i32),
+        "decode_step_math": (lambda *a: tlm.decode_step_math(cfg, *a),
+                             params, dense, dense, slots, slots),
+        "prefill_kv_paged": (lambda *a: tlm.prefill_kv_paged(cfg, *a),
+                             params, pool, pool, sds((4,), jnp.int32),
+                             sds((8,), jnp.int32), i32, i32),
+        "decode_step_paged": (lambda *a: tlm.decode_step_paged(cfg, *a),
+                              params, pool, pool, sds((3, 4), jnp.int32),
+                              slots, slots),
+    }
+
+
+@pytest.mark.parametrize("entry", ["forward_logits", "prefill_kv",
+                                   "decode_step_math", "prefill_kv_paged",
+                                   "decode_step_paged"])
+def test_entry_point_traces_the_one_block_once_a_layer(entry, monkeypatch):
+    """The layer's mathematics lives in ``transformer_lm._block`` alone: an
+    entry point traces it once a layer, and with the block taken out what
+    is left of the trace holds one matrix product (the head's) and no
+    GELU."""
+    import jax
+
+    fn, *shapes = _entry_point_calls()[entry]
+    block, calls = tlm._block, []
+
+    def counted(*args):
+        calls.append(1)
+        return block(*args)
+
+    monkeypatch.setattr(tlm, "_block", counted)
+    jax.eval_shape(fn, *shapes)
+    assert len(calls) == LAYERS
+
+    monkeypatch.setattr(tlm, "_block", lambda cfg, pl, x, attend: x)
+    # a new function object: JAX keeps the trace of the one above
+    fn, *shapes = _entry_point_calls()[entry]
+    prims = [eqn.primitive.name
+             for eqn in jax.make_jaxpr(fn)(*shapes).jaxpr.eqns]
+    assert prims.count("dot_general") == 1, prims
+    assert not {"tanh", "erf", "logistic"} & set(prims), prims
+
+
 # -- engine: correctness ----------------------------------------------------
 
 def test_greedy_decode_matches_full_forward():
@@ -290,19 +352,26 @@ def test_streaming_callback_receives_every_token_in_order():
 def test_cancel_mid_generation_frees_the_slot():
     eng = _engine(slots=1)
     try:
-        # event-driven mid-generation detection (no sleep polling —
-        # the token callback IS the signal)
-        mid = threading.Event()
+        # the token callback runs on the engine's thread, between two
+        # steps: at the third token it holds the engine there until the
+        # cancel has landed.  Without that the 25 tokens still to come
+        # (MAX_LEN bounds the 200 asked for) can be out before this thread
+        # wakes up, and cancel() finds the session finished
+        mid, cancelled = threading.Event(), threading.Event()
         seen = []
 
         def on_tok(t):
             seen.append(t)
-            if len(seen) >= 3:
+            if len(seen) == 3:
                 mid.set()
+                cancelled.wait(60)
 
         a = eng.submit(PROMPT, max_new_tokens=200, on_token=on_tok)
         assert mid.wait(60), "engine never produced 3 tokens"
-        assert a.cancel() is True
+        try:
+            assert a.cancel() is True
+        finally:
+            cancelled.set()
         with pytest.raises(MXNetError):
             a.result(30)
         # the slot frees at the next step boundary: a follow-up request

@@ -2,6 +2,7 @@
 ``tests/python/unittest/test_executor.py``)."""
 
 import numpy as np
+import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import nd, sym
@@ -99,6 +100,58 @@ def test_monitor_callback():
     ex.set_monitor_callback(lambda name, arr: seen.append(name))
     ex.forward(data=nd.ones((2,)))
     assert seen and seen[0].endswith("_output")
+
+
+@pytest.mark.parametrize("consumers", ["relu-alone", "relu-and-a-sum"])
+def test_batchnorm_then_relu_is_the_plain_composition(consumers):
+    """BatchNorm -> relu Activation in a training graph, the relu BN's sole
+    consumer or one of two: output, gradients and moving statistics are
+    those of ``jax.grad`` over the composition written out in ``jax.numpy``
+    (two-pass statistics, ``jax.nn.relu``)."""
+    import jax
+    import jax.numpy as jnp
+
+    shape, eps, momentum = (8, 4, 6, 6), 1e-3, 0.9
+    rs = np.random.RandomState(3)
+    x = (rs.rand(*shape) * 5).astype(np.float32)
+    gamma = rs.normal(1, 0.5, shape[1]).astype(np.float32)
+    beta = rs.normal(0, 0.5, shape[1]).astype(np.float32)
+
+    bn = sym.BatchNorm(sym.Variable("data"), fix_gamma=False, eps=eps,
+                       momentum=momentum, name="bn")
+    act = sym.Activation(bn, act_type="relu")
+    net = sym.MakeLoss(sym.sum(act if consumers == "relu-alone"
+                               else act + bn))
+    ex = net.simple_bind(mx.cpu(), data=shape, grad_req="write")
+    ex.arg_dict["data"][:] = x
+    ex.arg_dict["bn_gamma"][:] = gamma
+    ex.arg_dict["bn_beta"][:] = beta
+    ex.aux_dict["bn_moving_mean"][:] = 0
+    ex.aux_dict["bn_moving_var"][:] = 1
+    out = ex.forward(is_train=True)[0].asnumpy()
+    ex.backward()
+
+    def stats(x):
+        mean = x.mean(axis=(0, 2, 3), keepdims=True)
+        return mean, ((x - mean) ** 2).mean(axis=(0, 2, 3), keepdims=True)
+
+    def plain(x, gamma, beta):
+        mean, var = stats(x)
+        y = (x - mean) * jax.lax.rsqrt(var + eps) \
+            * gamma.reshape(1, -1, 1, 1) + beta.reshape(1, -1, 1, 1)
+        return jnp.sum(jax.nn.relu(y) if consumers == "relu-alone"
+                       else jax.nn.relu(y) + y)
+
+    ref, grads = jax.value_and_grad(plain, argnums=(0, 1, 2))(x, gamma, beta)
+    assert_almost_equal(out, np.asarray(ref), rtol=1e-5)
+    for name, g in zip(("data", "bn_gamma", "bn_beta"), grads):
+        assert_almost_equal(ex.grad_dict[name], np.asarray(g), rtol=1e-3,
+                            atol=1e-4)
+    mean, var = (np.asarray(a).ravel() for a in stats(x))
+    assert_almost_equal(ex.aux_dict["bn_moving_mean"],
+                        (1 - momentum) * mean, rtol=1e-5)
+    assert_almost_equal(ex.aux_dict["bn_moving_var"],
+                        momentum + (1 - momentum) * var, rtol=1e-5)
 
 
 def test_backward_mirror_exactness(monkeypatch):

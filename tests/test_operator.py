@@ -200,34 +200,35 @@ def test_conv_stem_s2d_exact():
     """The space-to-depth stem rewrite (7x7/s2/p3, few channels ->
     s2d(2x2) + 4x4/s1) must reproduce the direct convolution exactly
     (ops/nn.py _stem_s2d_conv; MLPerf TPU stem transform), fwd and
-    grads, since it is ON by default."""
-    import os
+    grads: it is what a Convolution of that shape lowers to."""
+    import jax
+
+    from mxnet_tpu.ops import nn as ops_nn
 
     rs = np.random.RandomState(0)
     x = rs.rand(2, 3, 32, 32).astype(np.float32)
     w = rs.rand(8, 3, 7, 7).astype(np.float32)
 
-    def run():
-        data = sym.Variable("data")
-        net = sym.Convolution(data, num_filter=8, kernel=(7, 7),
-                              stride=(2, 2), pad=(3, 3), no_bias=True,
-                              name="c0")
-        ex = net.simple_bind(mx.cpu(), data=x.shape, grad_req="write")
-        ex.arg_dict["data"][:] = x
-        ex.arg_dict["c0_weight"][:] = w
-        out = ex.forward(is_train=True)[0].asnumpy()
-        ex.backward(nd.ones(out.shape))
-        return out, ex.grad_dict["c0_weight"].asnumpy()
+    data = sym.Variable("data")
+    net = sym.Convolution(data, num_filter=8, kernel=(7, 7),
+                          stride=(2, 2), pad=(3, 3), no_bias=True,
+                          name="c0")
+    ex = net.simple_bind(mx.cpu(), data=x.shape, grad_req="write")
+    ex.arg_dict["data"][:] = x
+    ex.arg_dict["c0_weight"][:] = w
+    out_s2d = ex.forward(is_train=True)[0].asnumpy()
+    ex.backward(nd.ones(out_s2d.shape))
+    g_s2d = ex.grad_dict["c0_weight"].asnumpy()
 
-    os.environ["MXNET_CONV_STEM_S2D"] = "0"
-    try:
-        out_direct, g_direct = run()
-    finally:
-        os.environ.pop("MXNET_CONV_STEM_S2D", None)
-    out_s2d, g_s2d = run()  # default path
+    # the reference: the plain convolution on the same arrays
+    direct = ops_nn._conv_f32acc((2, 2), ((3, 3), (3, 3)), (1, 1), (1, 1),
+                                 ops_nn._CONV_DIMNUMS[2], 1)
+    out_direct, vjp = jax.vjp(direct, x, w)
+    g_direct = vjp(np.ones(out_direct.shape, np.float32))[1]
     assert out_s2d.shape == out_direct.shape == (2, 8, 16, 16)
-    assert_almost_equal(out_s2d, out_direct, rtol=1e-4, atol=1e-4)
-    assert_almost_equal(g_s2d, g_direct, rtol=1e-3, atol=1e-3)
+    assert_almost_equal(out_s2d, np.asarray(out_direct), rtol=1e-4,
+                        atol=1e-4)
+    assert_almost_equal(g_s2d, np.asarray(g_direct), rtol=1e-3, atol=1e-3)
 
 
 def test_activation_grads():
@@ -256,6 +257,77 @@ def test_batchnorm_forward():
     ex.arg_dict["bn_beta"][:] = beta
     out = ex.forward(is_train=True)[0]
     assert_almost_equal(out, ref, rtol=1e-4, atol=1e-4)
+
+
+def _bn_train_reference(x, gamma, beta, w, eps, fix_gamma, relu):
+    """Train-mode BatchNorm (then ReLU) in float64, two passes over ``x``,
+    and the gradients of ``sum(out * w)`` in closed form."""
+    x, gamma, beta, w = (a.astype(np.float64) for a in (x, gamma, beta, w))
+    red = (0,) + tuple(range(2, x.ndim))
+    bshape = (1, -1) + (1,) * (x.ndim - 2)
+    m = x.size // x.shape[1]
+    mean = x.mean(axis=red).reshape(bshape)
+    var = ((x - mean) ** 2).mean(axis=red).reshape(bshape)
+    inv = 1.0 / np.sqrt(var + eps)
+    xhat = (x - mean) * inv
+    g = np.ones_like(gamma) if fix_gamma else gamma
+    y = xhat * g.reshape(bshape) + beta.reshape(bshape)
+    dy = w * (y > 0) if relu else w
+    dbeta = dy.sum(axis=red)
+    dgamma = np.zeros_like(gamma) if fix_gamma \
+        else (dy * xhat).sum(axis=red)
+    dxhat = dy * g.reshape(bshape)
+    dx = inv / m * (m * dxhat - dxhat.sum(axis=red).reshape(bshape)
+                    - xhat * (dxhat * xhat).sum(axis=red).reshape(bshape))
+    return (np.maximum(y, 0) if relu else y), dx, dgamma, dbeta, \
+        mean.ravel(), var.ravel()
+
+
+@pytest.mark.parametrize("shape,fix_gamma,relu", [
+    ((8, 6, 5, 7), False, True),     # BN -> ReLU, odd spatial
+    ((8, 16, 4, 4), True, True),     # fix_gamma (zero dgamma)
+    ((8, 12), False, False),         # 2D input, plain BN
+    ((4, 8, 3, 2, 2), False, True),  # 5D (3D-conv style)
+])
+def test_batchnorm_train_matches_float64_reference(shape, fix_gamma, relu):
+    """Train-mode BatchNorm through the executor, as a graph trains it:
+    output, the three gradients and the moving statistics against a
+    two-pass float64 reference.  The inputs sit at mean 2.5 with a spread
+    under 1, where a one-pass ``E[x^2] - E[x]^2`` in float32 would lose
+    digits the shifted sums keep."""
+    rs = np.random.RandomState(0)
+    x = (rs.rand(*shape) * 3 + 1).astype(np.float32)
+    gamma = rs.normal(1, 0.5, shape[1]).astype(np.float32)
+    beta = rs.normal(0, 0.5, shape[1]).astype(np.float32)
+    w = rs.normal(0, 1, shape).astype(np.float32)
+    eps, momentum = 1e-3, 0.9
+
+    h = sym.BatchNorm(sym.Variable("data"), fix_gamma=fix_gamma, eps=eps,
+                      momentum=momentum, name="bn")
+    if relu:
+        h = sym.Activation(h, act_type="relu")
+    ex = h.simple_bind(mx.cpu(), data=shape, grad_req="write")
+    ex.arg_dict["data"][:] = x
+    ex.arg_dict["bn_gamma"][:] = gamma
+    ex.arg_dict["bn_beta"][:] = beta
+    ex.aux_dict["bn_moving_mean"][:] = 0
+    ex.aux_dict["bn_moving_var"][:] = 1
+    out = ex.forward(is_train=True)[0].asnumpy()
+    ex.backward(nd.array(w))
+
+    ref_out, dx, dgamma, dbeta, mean, var = _bn_train_reference(
+        x, gamma, beta, w, eps, fix_gamma, relu)
+    assert_almost_equal(out, ref_out, rtol=1e-4, atol=1e-5)
+    assert_almost_equal(ex.grad_dict["data"], dx, rtol=1e-3, atol=1e-4)
+    assert_almost_equal(ex.grad_dict["bn_gamma"], dgamma, rtol=1e-3,
+                        atol=1e-4)
+    assert_almost_equal(ex.grad_dict["bn_beta"], dbeta, rtol=1e-3,
+                        atol=1e-4)
+    assert_almost_equal(ex.aux_dict["bn_moving_mean"],
+                        (1 - momentum) * mean, rtol=1e-4, atol=1e-6)
+    assert_almost_equal(ex.aux_dict["bn_moving_var"],
+                        momentum + (1 - momentum) * var, rtol=1e-4,
+                        atol=1e-6)
 
 
 def test_concat_slicechannel():

@@ -77,6 +77,110 @@ def test_fused_matches_unfused(mode):
     np.testing.assert_allclose(fout, uout, rtol=1e-4, atol=1e-5)
 
 
+def _numpy_lstm(x, blob, h0, c0, layers, hidden):
+    """A float64 LSTM over the RNN op's parameter blob (a layer: ``Wx
+    (4H, I)``, ``Wh (4H, H)``, ``bx``, ``bh``; gates ``i, f, g, o``):
+    outputs, final states, and by backpropagation through time the
+    gradients of ``sum(y) + sum(h_T) + sum(c_T)``."""
+    def sig(a):
+        return 1.0 / (1.0 + np.exp(-a))
+
+    x, blob, h0, c0 = (a.astype(np.float64) for a in (x, blob, h0, c0))
+    T, N, _ = x.shape
+    H, off, weights, tapes, cur = hidden, 0, [], [], x
+    hT, cT = [], []
+    for l in range(layers):
+        i_dim = cur.shape[2]
+        spec = []
+        for shape in ((4 * H, i_dim), (4 * H, H), (4 * H,), (4 * H,)):
+            n = int(np.prod(shape))
+            spec.append((off, shape))
+            off += n
+        wx, wh, bx, bh = (blob[o:o + int(np.prod(s))].reshape(s)
+                          for o, s in spec)
+        weights.append(spec)
+        h, c, ys, tape = h0[l], c0[l], [], []
+        for t in range(T):
+            gates = cur[t] @ wx.T + bx + h @ wh.T + bh
+            i, f, g, o = np.split(gates, 4, axis=-1)
+            i, f, g, o = sig(i), sig(f), np.tanh(g), sig(o)
+            c_new = f * c + i * g
+            tape.append((cur[t], h, c, i, f, g, o, c_new))
+            h, c = o * np.tanh(c_new), c_new
+            ys.append(h)
+        tapes.append((wx, wh, tape))
+        hT.append(h)
+        cT.append(c)
+        cur = np.stack(ys)
+    y = cur
+    # backward: every output, final h and final c carries a cotangent of 1
+    dblob = np.zeros_like(blob)
+    dh0, dc0 = np.zeros_like(h0), np.zeros_like(c0)
+    dys = np.ones_like(y)
+    for l in reversed(range(layers)):
+        wx, wh, tape = tapes[l]
+        dwx, dwh, db = np.zeros_like(wx), np.zeros_like(wh), np.zeros(4 * H)
+        dh, dc = np.ones((N, H)), np.ones((N, H))
+        dxs = [None] * T
+        for t in reversed(range(T)):
+            xt, hp, cp, i, f, g, o, c_new = tape[t]
+            dh = dh + dys[t]
+            tc = np.tanh(c_new)
+            dc = dc + dh * o * (1 - tc ** 2)
+            dgates = np.concatenate(
+                [dc * g * i * (1 - i), dc * cp * f * (1 - f),
+                 dc * i * (1 - g ** 2), dh * tc * o * (1 - o)], axis=-1)
+            dwx += dgates.T @ xt
+            dwh += dgates.T @ hp
+            db += dgates.sum(0)
+            dxs[t] = dgates @ wx
+            dh, dc = dgates @ wh, dc * f
+        dh0[l], dc0[l] = dh, dc
+        for (o_, s), d in zip(weights[l], (dwx, dwh, db, db)):
+            dblob[o_:o_ + int(np.prod(s))] = d.ravel()
+        dys = np.stack(dxs)
+    return (y, np.stack(hT), np.stack(cT)), \
+        {"x": dys, "p": dblob, "hs": dh0, "cs": dc0}
+
+
+@pytest.mark.parametrize("seq,batch,nin,nh,layers", [
+    (7, 4, 6, 8, 2),        # what the fused kernel's parity test ran
+    (35, 32, 200, 200, 1),  # PTB's sequence, batch and width
+])
+def test_lstm_scan_path_matches_numpy(seq, batch, nin, nh, layers):
+    """The RNN op's one LSTM path (input projection as one product, the
+    recurrence in ``lax.scan``): outputs, final states and every gradient
+    against a float64 LSTM written out step by step."""
+    from mxnet_tpu.ops.rnn import rnn_param_size
+
+    rs = np.random.RandomState(3)
+    psize = rnn_param_size(nin, nh, layers, "lstm", False)
+    vals = {"x": rs.randn(seq, batch, nin) * 0.5,
+            "p": rs.randn(psize) * (0.2 if nh < 100 else 0.05),
+            "hs": rs.randn(layers, batch, nh) * 0.1,
+            "cs": rs.randn(layers, batch, nh) * 0.1}
+    net = mx.sym.RNN(*(mx.sym.Variable(n) for n in ("x", "p", "hs", "cs")),
+                     state_size=nh, num_layers=layers, mode="lstm",
+                     state_outputs=True, name="rnn")
+    ex = net.simple_bind(mx.cpu(), grad_req="write",
+                         **{n: v.shape for n, v in vals.items()})
+    for n, v in vals.items():
+        ex.arg_dict[n][:] = v.astype(np.float32)
+    outs = [o.asnumpy() for o in ex.forward(is_train=True)]
+    ex.backward([mx.nd.ones(o.shape) for o in ex.outputs])
+
+    ref_outs, ref_grads = _numpy_lstm(
+        *(vals[n].astype(np.float32) for n in ("x", "p", "hs", "cs")),
+        layers, nh)
+    assert len(outs) == 3
+    for got, want in zip(outs, ref_outs):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    for n, want in ref_grads.items():
+        np.testing.assert_allclose(
+            ex.grad_dict[n].asnumpy(), want, rtol=1e-3,
+            atol=1e-4 * max(1.0, np.abs(want).max()), err_msg=n)
+
+
 def test_fused_bidirectional_matches_unfused():
     T, N, I, H = 4, 3, 5, 6
     fused = mx.rnn.FusedRNNCell(H, num_layers=1, mode="lstm", prefix="f_",

@@ -267,6 +267,35 @@ def test_compile_count_and_fn_cache_hits():
     assert telemetry.counter_total("xla.compile.seconds") > 0
 
 
+def test_get_fn_cache_key_reads_the_mirror_mode_and_nothing_else(
+        monkeypatch):
+    """What a bound executor's function cache is keyed on: its kind and the
+    mirror mode, the one knob a trace depends on.  A look-up that hits
+    reads that one variable of the environment; a second call of the same
+    kind is a hit and hands back the same function."""
+    import os
+
+    ex = _small_exec()
+    fn = ex._get_fn("train")
+    hits = telemetry.counter_total("xla.compile.fn_cache_hits")
+    read = []
+
+    class Recording(dict):
+        def get(self, key, default=None):
+            read.append(key)
+            return super().get(key, default)
+
+        def __getitem__(self, key):
+            read.append(key)
+            return super().__getitem__(key)
+
+    monkeypatch.setattr(os, "environ", Recording(os.environ))
+    assert ex._get_fn("train") is fn
+    monkeypatch.undo()
+    assert read == ["MXNET_BACKWARD_DO_MIRROR"]
+    assert telemetry.counter_total("xla.compile.fn_cache_hits") == hits + 1
+
+
 def test_recompile_detector_warns_on_same_program_rebuild(monkeypatch,
                                                           caplog):
     monkeypatch.setenv("MXNET_RECOMPILE_WARN_THRESHOLD", "1")

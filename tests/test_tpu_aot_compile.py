@@ -28,8 +28,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from mxnet_tpu.ops import attention, bn_pallas, registry  # noqa: E402
-from mxnet_tpu.ops import rnn_pallas  # noqa: E402
+from mxnet_tpu.ops import attention, registry  # noqa: E402
 
 #: exit code of a child that could not describe the chip
 _SKIP = 77
@@ -64,6 +63,21 @@ def _one_chip():
         print("cannot describe a v5e topology: %s" % e)
         sys.exit(_SKIP)
     return SingleDeviceSharding(topo.devices[0])
+
+
+def _unbuilt_engine(model, **opts):
+    """A ``DecodeEngine`` over ``model`` that builds its programs, allocates
+    no state and runs nothing: what a case lowers for shapes of its own."""
+    from mxnet_tpu.serving import DecodeEngine
+
+    class Unbuilt(DecodeEngine):
+        def _fresh_state(self):
+            return None
+
+        def _warm(self, state):
+            return state
+
+    return Unbuilt(model, {}, autostart=False, **opts)
 
 
 def _compile(fn, *shapes):
@@ -115,55 +129,6 @@ def _flash_refusal():
 
 
 CASES["flash_attention-refuses-what-the-compiler-refuses"] = _flash_refusal
-
-
-# -- fused BatchNorm -----------------------------------------------------------
-#: ResNet-50 BatchNorm inputs at batch 128: (channels, spatial side)
-_RESNET50_BN = [(64, 112), (64, 56), (256, 56), (128, 56), (128, 28),
-                (512, 28), (256, 28), (256, 14), (1024, 14), (512, 14),
-                (512, 7), (2048, 7)]
-
-
-def _bn_stages(dtype):
-    """Every ResNet-50 b128 stage ``bn_pallas`` admits under its block
-    budget, as the kernel's ``(S, N, C)`` view."""
-    n = 128
-    return [(side * side, n, c) for c, side in _RESNET50_BN
-            if bn_pallas._refusal(n, c, side * side,
-                                  jnp.dtype(dtype).itemsize) is None]
-
-
-def _bn_case(snc):
-    def run():
-        x = (snc, jnp.bfloat16)
-        vec = ((1, snc[2]), jnp.float32)
-        _compile(lambda xt, g, b: bn_pallas._bn_fwd_call(
-            xt, g, b, 2e-5, False, True, False), x, vec, vec)
-        _compile(lambda xt, gt, m, v, g, b: bn_pallas._bn_bwd_call(
-            xt, gt, m, v, g, b, 2e-5, False, True, False),
-            x, x, vec, vec, vec, vec)
-    return run
-
-
-for _snc in _bn_stages(jnp.bfloat16):
-    CASES["batchnorm-fwd-bwd-%dx%dx%d" % _snc] = _bn_case(_snc)
-
-
-# -- fused LSTM ----------------------------------------------------------------
-def _lstm_case():
-    """PTB 2x200, batch 32, sequence 35 (bench_extra.lstm_score)."""
-    t, n, h = 35, 32, 200
-    assert rnn_pallas.fits(t, n, h, jnp.float32)
-    f32 = jnp.float32
-    xp, w4, bh = ((t, 4, n, h), f32), ((4, h, h), f32), ((4, h), f32)
-    state, seq = ((n, h), f32), ((t, n, h), f32)
-    _compile(lambda *a: rnn_pallas._run_fwd(*a, False),
-             xp, w4, bh, state, state)
-    _compile(lambda *a: rnn_pallas._run_bwd(*a, False),
-             xp, seq, seq, w4, state, state, seq, state, state)
-
-
-CASES["lstm-fwd-bwd-ptb-35x32x200"] = _lstm_case
 
 
 # -- the dense decode tier's two cache writes ------------------------------------
@@ -246,6 +211,77 @@ CASES["decode-dense-prefill-768-gpt2-large-no-cache-copy"] = \
     _dense_engine_case("prefill")
 
 
+def _paged_engine_case(program):
+    """The engine's paged ``jit_step`` / ``jit_prefill`` at the same widths,
+    16-row blocks, a pool the size of the dense cache (+ the scratch
+    block): the paged programs compile for the chip and fit it.  Their
+    temporaries are printed and bounded, not judged: a step gathers every
+    slot's table into ``(slots, max_len, heads, head_dim)`` a layer and
+    scatters its rows into a pool the compiler keeps in another layout, so
+    it holds cache-sized copies the dense step no longer does.  Taking them
+    out is ROADMAP S1(b); this is its baseline."""
+    def run():
+        import re
+
+        from mxnet_tpu.models import transformer_lm as tlm
+
+        cfg = tlm.LMConfig(*_GPT2_LARGE, eos_id=_GPT2_LARGE[0])
+        engine = _unbuilt_engine(cfg, slots=_SLOTS,
+                                 prefill_buckets=(_BUCKET,),
+                                 kv_layout="paged", kv_block_size=16)
+        nb, bs, mb = (engine._kv.num_blocks, engine._kv.block_size,
+                      engine._kv.max_blocks)
+        assert (nb, bs, mb) == (_SLOTS * 64 + 1, 16, 64)
+        one_chip = _one_chip()
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        s = _SLOTS
+        pool = sds((nb, bs, cfg.heads, cfg.embed // cfg.heads), jnp.float32)
+        state = (tuple(pool for _ in range(cfg.layers)),
+                 tuple(pool for _ in range(cfg.layers)),
+                 sds((s,), jnp.int32), sds((s,), jnp.int32),
+                 sds((s,), jnp.int32), sds((s,), jnp.bool_),
+                 sds((s,), jnp.float32), sds((s,), jnp.uint32))
+        params = jax.tree_util.tree_map(
+            lambda a: sds(a.shape, a.dtype),
+            jax.eval_shape(lambda: tlm.init_params(cfg)))
+        i32, f32 = sds((), jnp.int32), sds((), jnp.float32)
+        if program == "step":
+            lowered = engine._step_fn.lower(
+                params, state, sds((s,), jnp.bool_), sds((s, mb), jnp.int32))
+        else:
+            lowered = engine._prefill_fns[_BUCKET].lower(
+                params, state, sds((_BUCKET,), jnp.int32), i32, i32, i32,
+                sds((mb,), jnp.int32), i32, f32, sds((), jnp.uint32),
+                sds((), jnp.bool_), i32, i32)
+        compiled = lowered.compile()
+        ma = compiled.memory_analysis()
+        pool_bytes = pool.size * pool.dtype.itemsize
+        copies = re.findall(
+            r"= f32\[%d,%d,%d,%d\]\{[^}]*\} copy\(" % pool.shape,
+            compiled.as_text())
+        print("paged %s: temporaries %d bytes, %.2f of one pool array "
+              "(%d); %d copies of a pool-shaped array"
+              % (program, ma.temp_size_in_bytes,
+                 ma.temp_size_in_bytes / pool_bytes, pool_bytes,
+                 len(copies)))
+        # as of PR 29: two copies an array (into the scatter's layout and
+        # back), 8 at two layers, and temporaries of 7.7 (step) and 7.5
+        # (prefill) pool arrays.  More than two an array is a regression
+        arrays = 2 * cfg.layers
+        assert len(copies) <= 2 * arrays, len(copies)
+        own = 4 * _BUCKET * cfg.vocab if program == "prefill" else 0
+        assert ma.temp_size_in_bytes - own < 2 * arrays * pool_bytes
+        assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.0e9
+    return run
+
+
+CASES["decode-paged-step-gpt2-large-12-slots"] = _paged_engine_case("step")
+CASES["decode-paged-prefill-768-gpt2-large"] = _paged_engine_case("prefill")
+
+
 def _gpt2_step_as_the_benchmark_lowers_it():
     """``benchmark/families/decode_engine.py`` ``scratch_bytes`` lowers
     ``engine._step_fn`` after every window for shapes it writes out by
@@ -253,18 +289,9 @@ def _gpt2_step_as_the_benchmark_lowers_it():
     ``(slots, max_len, heads, head_dim)`` a layer.  The engine's model
     protocol must leave that signature as it is."""
     from mxnet_tpu.models import transformer_lm as tlm
-    from mxnet_tpu.serving import DecodeEngine
-
-    class Unwarmed(DecodeEngine):
-        def _fresh_state(self):
-            return None
-
-        def _warm(self, state):
-            return state
 
     cfg = tlm.LMConfig(*_GPT2_LARGE, eos_id=_GPT2_LARGE[0])
-    engine = Unwarmed(cfg, {}, slots=_SLOTS, prefill_buckets=(_BUCKET,),
-                      autostart=False)
+    engine = _unbuilt_engine(cfg, slots=_SLOTS, prefill_buckets=(_BUCKET,))
     one_chip = _one_chip()
 
     def sds(shape, dtype):
@@ -376,22 +403,14 @@ def _exaone_step_with_the_kernel():
     from benchmark.reference import exaone_moe_engine as ref
     from benchmark.tools import aot_compile_moe as tool
     from mxnet_tpu.models import exaone_moe as xm
-    from mxnet_tpu.serving import DecodeEngine
-
-    class Unbuilt(DecodeEngine):
-        def _fresh_state(self):
-            return None
-
-        def _warm(self, state):
-            return state
 
     config = harness.load_json(os.path.join(
         ROOT, "benchmark", "configs", "k-exaone-236b-a23b.json"))
     model = xm.ExaoneMoE(family.model_config(xm, ref.sizes(config)),
                          jnp.dtype(config["precision"]["kv_cache"]))
-    engine = Unbuilt(model, {}, slots=config["engine"]["slots"],
-                     prefill_buckets=config["engine"]["prefill_buckets"],
-                     autostart=False)
+    engine = _unbuilt_engine(
+        model, slots=config["engine"]["slots"],
+        prefill_buckets=config["engine"]["prefill_buckets"])
     one_chip = _one_chip()
     params, state, keep, extra = family.step_shapes(
         engine, jax.eval_shape(
@@ -416,13 +435,6 @@ CASES["decode-step-k-exaone-256-slots-with-the-decode-kernel"] = \
 
 
 # -- the tests -----------------------------------------------------------------
-def test_bn_budget_admits_the_late_stages():
-    assert _bn_stages(jnp.bfloat16) == [
-        (196, 128, 256), (196, 128, 1024), (196, 128, 512),
-        (49, 128, 512), (49, 128, 2048)]
-    assert _bn_stages(jnp.float32) == [(49, 128, 512), (49, 128, 2048)]
-
-
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_a_described_v5e(case):
     env = dict(os.environ, TPU_LOG_DIR="disabled",

@@ -511,31 +511,25 @@ def test_multibox_detection_and_identity_rhs_parity():
 
 def test_imperative_jit_cache_keys_on_device():
     """An imperative op traced for one backend must not be replayed for
-    the other: with the opt-in Pallas BN, a TPU-traced mosaic kernel
-    reused on CPU arrays would fail outright (the jit cache keys on the
-    trace device)."""
-    import os
+    the other: a trace bakes in what its ops decided for the device they
+    asked about (``registry.on_tpu()``), so the jit cache keys on the
+    trace device."""
+    rs = np.random.RandomState(0)
+    x = rs.rand(8, 16, 4, 4).astype(np.float32)
+    g = np.ones((16,), np.float32)
+    b = np.zeros((16,), np.float32)
+    mm = np.zeros((16,), np.float32)
+    mv = np.ones((16,), np.float32)
 
-    os.environ["MXNET_BN_PALLAS"] = "1"
-    try:
-        rs = np.random.RandomState(0)
-        x = rs.rand(8, 16, 4, 4).astype(np.float32)
-        g = np.ones((16,), np.float32)
-        b = np.zeros((16,), np.float32)
-        mm = np.zeros((16,), np.float32)
-        mv = np.ones((16,), np.float32)
+    def run(ctx):
+        return mx.nd.BatchNorm(
+            mx.nd.array(x, ctx=ctx), mx.nd.array(g, ctx=ctx),
+            mx.nd.array(b, ctx=ctx), mx.nd.array(mm, ctx=ctx),
+            mx.nd.array(mv, ctx=ctx), fix_gamma=False).asnumpy()
 
-        def run(ctx):
-            return mx.nd.BatchNorm(
-                mx.nd.array(x, ctx=ctx), mx.nd.array(g, ctx=ctx),
-                mx.nd.array(b, ctx=ctx), mx.nd.array(mm, ctx=ctx),
-                mx.nd.array(mv, ctx=ctx), fix_gamma=False).asnumpy()
-
-        out_tpu = run(mx.tpu())   # traces the TPU (Pallas-eligible) path
-        out_cpu = run(mx.cpu())   # must retrace for CPU, not reuse
-        assert_almost_equal(out_cpu, out_tpu, rtol=2e-3, atol=2e-3)
-    finally:
-        os.environ.pop("MXNET_BN_PALLAS", None)
+    out_tpu = run(mx.tpu())   # traces for the TPU
+    out_cpu = run(mx.cpu())   # must retrace for CPU, not reuse
+    assert_almost_equal(out_cpu, out_tpu, rtol=2e-3, atol=2e-3)
 
 
 def test_census_tail_ops_execute_tpu():
