@@ -6,7 +6,9 @@ naive batching waits for the slowest sequence while the rest of the
 batch pads along dead.  The TPU-native answer is the same move the
 sync-free fit loop made for training (docs/how_to/perf.md): make the
 decode loop ONE fixed-shape jitted step that never recompiles and never
-syncs beyond a single packed host read per token.
+syncs beyond a single packed host read per token — a read the loop makes
+with the NEXT step already dispatched, so the device is not left empty
+while the host waits, fans tokens out and walks its queue.
 
 :class:`DecodeEngine` owns a device-resident KV cache of fixed shape
 ``(S slots, max_len)`` per layer and exactly TWO compiled programs:
@@ -42,6 +44,18 @@ out through per-session callbacks.  Inactive slots ride along at fixed
 shape; their scatter rows are unreachable under the attention mask
 until a real write replaces them.
 
+**The loop is a pipeline one step deep** (:meth:`DecodeEngine._serve_loop`;
+docs/serving.md "The loop's order"): a turn dispatches the admissions'
+prefills and step N+1, THEN reads step N and fans it out, then reads the
+admissions' first tokens.  The step decides ``done`` and the next
+``active`` mask on the device, so step N+1 needs nothing the host learns
+from step N; the host's one input, ``keep`` (cancel, deadline), reaches
+the device a step late, a finished session's slot is re-admitted a step
+late, and a device fault surfaces a dispatch late — when every unread
+step is dropped whole, so a transcript never differs from what
+``on_token`` saw.  The streams are those of a plain serial loop over the
+same programs (``tools/perf/serial_loop.py``).
+
 **The model protocol.**  The engine learns nothing of an architecture; it
 is given a *model object* and asks it for four things:
 
@@ -72,7 +86,7 @@ with :class:`UnsupportedKVLayout`.
 The engine is single-device; multi-replica throughput is
 :class:`~mxnet_tpu.serving.pool.ReplicaPool`'s job.  The hot loop is
 covered by the graftlint host-sync pass (``ci/graftlint``): the packed
-per-step read is the one sanctioned transfer.
+per-step read is the one sanctioned transfer, one read a step.
 """
 
 from __future__ import annotations
@@ -81,7 +95,7 @@ import logging
 import os
 import threading
 import time
-from collections import deque
+from collections import deque, namedtuple
 
 import numpy as np
 
@@ -125,6 +139,23 @@ class UnsupportedKVLayout(MXNetError):
     """The model's cache specification cannot be held in the KV layout
     asked for: the paged block pool holds full float32 layers of one
     shape, through a model's ``prefill_paged``/``decode_step_paged``."""
+
+
+class _Poisoned(Exception):
+    """A dispatch or a device read of the loop raised ``cause``: the
+    donated chain is lost and the turn ends in ``_fail_all``.  Never
+    leaves the engine's thread."""
+
+    def __init__(self, cause):
+        super().__init__(type(cause).__name__)
+        self.cause = cause
+
+
+#: a decode step dispatched and not read yet: its packed buffer, the
+#: slots' sessions and ``keep`` as they were at its dispatch (its fan-out
+#: serves that snapshot), its dispatch time and the extra device state
+#: it was given
+_Step = namedtuple("_Step", "packed sessions keep t0 extra_in")
 
 
 class GenerateSession:
@@ -230,9 +261,10 @@ class GenerateSession:
 
     def cancel(self):
         """Abandon the request: queued sessions are dropped at the next
-        admission scan, an active session is retired (its slot freed) at
-        the next step boundary.  Returns False when the session already
-        finished.  ONE cancellation flag — the embedded Future's (the
+        admission scan; an active session is taken out of the next step
+        the engine DISPATCHES and retired (its slot freed) when that step
+        is read — the token of the step already in flight still arrives.
+        Returns False when the session already finished.  ONE cancellation flag — the embedded Future's (the
         same machinery the batcher honors), so ``sess.future.cancel()``
         and ``sess.cancel()`` cannot diverge."""
         return self.future.cancel()
@@ -340,8 +372,19 @@ class DecodeEngine:
         self._closed = False
         self._thread = None
         self._beat = time.monotonic()
-        #: total decode steps (tests pin continuous admission on it)
+        #: decode steps dispatched and not read yet, oldest first: one
+        #: between turns (THE pipeline depth, a constant of the loop's
+        #: order and not an option), two between a dispatch and the read
+        #: that follows it.  The loop's thread alone touches it.
+        self._unread = deque()
+        #: when the last packed read ended (the token gap's left end)
+        self._read_t = 0.0
+        #: decode steps read (tests pin continuous admission on it); a
+        #: session's last step is followed by one it rides inactive
         self.steps = 0
+        #: ... and how many of them were read with their successor
+        #: already dispatched (``serving.decode.overlap_share``)
+        self._reads_ahead = 0
         #: total generated tokens
         self.tokens_out = 0
         #: sessions re-admitted here by failover (describe/healthz card)
@@ -949,7 +992,10 @@ class DecodeEngine:
         shedding: queued and slot-holding sessions are handed over
         intact for the pool to re-admit elsewhere (quarantine takeover,
         version-swap straggler migration) and do not mark the stop
-        unclean.  Returns True when the stop lost nothing."""
+        unclean.  Either way the worker reads and fans out the step it
+        has in flight before it goes, so what is handed over or shed
+        holds every token its client saw.  Returns True when the stop
+        lost nothing."""
         if deadline is None:
             deadline = float(os.environ.get(
                 "MXNET_PREEMPT_DRAIN_DEADLINE", "30") or 30)
@@ -1013,9 +1059,21 @@ class DecodeEngine:
         return self.stop(drain=drain)
 
     def _serve_loop(self):
+        """The engine's one loop, a software pipeline ONE step deep: a
+        turn walks the queue, dispatches the prefill of each admission
+        and then step N+1, and only then reads step N's packed buffer and
+        fans it out — so the device always has a step queued behind the
+        read the host waits on, and the fan-out, the retirements and the
+        next queue walk run beside it.  Nothing the host learns from
+        step N is an input of step N+1 (``finish_step`` decides ``done``
+        and the next ``active`` on the device); ``keep`` — cancel,
+        deadline — is the host's one input and reaches the device a step
+        late.  An idle engine, a drain and a stop land what is in flight
+        before they rest or return."""
         with self._cond:
             state = self._boot_state
             self._boot_state = None
+        self._unread.clear()
         while True:
             admits = []
             shed = []  # (session, reason) — finished OUTSIDE the lock:
@@ -1027,15 +1085,17 @@ class DecodeEngine:
             qsp = _tracing.start_span("serving.decode.queue")
             with self._cond:
                 if not self._running:
-                    qsp.drop()
-                    isp.drop()
-                    return
+                    break
                 # liveness heartbeat: stamped every loop iteration (the
                 # idle wait below is 20ms, so an IDLE engine still beats)
                 # — only a wedged dispatch or a dead worker goes stale.
                 # The fleet controller's per-replica supervision reads it
                 # through heartbeat_age().
                 self._beat = time.monotonic()
+                # a slot is free once the host has READ the step that
+                # retired its session (the fan-out clears it): a slot is
+                # never re-admitted under a step that may still emit for
+                # its previous holder
                 free = [i for i, x in enumerate(self._slot_sessions)
                         if x is None]
                 # walk the WHOLE queue every iteration: abandoned or
@@ -1062,7 +1122,8 @@ class DecodeEngine:
                 self._queue = keep
                 have_active = any(x is not None
                                   for x in self._slot_sessions)
-                if not admits and not shed and not have_active:
+                if not admits and not shed and not have_active \
+                        and not self._unread:
                     # an iteration without work leaves no record
                     qsp.drop()
                     isp.drop()
@@ -1086,115 +1147,157 @@ class DecodeEngine:
                                "queued")
                 sess.trace.end("shed", reason=reason, where="queued")
                 self._finish(sess, error=err)
+            state = self._turn(state, admits, isp)
+        # stopped: the step in flight is read and fanned out before the
+        # worker goes, so what stop() hands over or sheds next holds
+        # every token the device had finished for it
+        qsp.drop()
+        if self._unread:
+            self._turn(state, (), isp, dispatch=False)
+        else:
+            isp.drop()
+
+    def _turn(self, state, admits, isp, dispatch=True):
+        """One turn of the pipeline, in the order that keeps the device
+        fed: the admissions' prefills (dispatched, not waited for), the
+        next step, THEN the read and fan-out of the step dispatched a
+        turn ago, then the admissions' first tokens.  A dispatch or a
+        read that raises ends the turn in :meth:`_fail_all`.  Returns the
+        state the next turn donates."""
+        active, ahead = 0, 0
+        try:
+            firsts = []
             for sess in admits:
-                state, aborted = self._admit(sess, state)
-                if aborted:
-                    # _fail_all already resolved EVERY reserved slot —
-                    # including admits not yet prefilled; touching them
-                    # again would double-fire the pool's on_done hook
-                    break
-            with self._cond:
-                active = sum(x is not None for x in self._slot_sessions)
-            if active:
-                state = self._step(state)
-            isp.end("ok", admits=len(admits), active=active)
+                state, first = self._admit(sess, state)
+                if first is not None:
+                    firsts.append(first)
+            if dispatch:
+                with self._cond:
+                    active = sum(x is not None
+                                 for x in self._slot_sessions)
+            landing = bool(self._unread)
+            if active or landing:
+                with _tracing.start_span("serving.decode.step") as ssp:
+                    if active:
+                        state = self._dispatch(state)
+                    if landing:
+                        ahead = self._land_step(ssp)
+            for first in firsts:
+                self._land_first(*first)
+        except _Poisoned as p:
+            state = self._fail_all(p.cause)
+        isp.end("ok", admits=len(admits), active=active, ahead=ahead)
+        return state
 
     def _admit(self, sess, state):
-        """:meth:`_prefill_session` inside its ``serving.admit`` span,
-        which covers ALL of an admission: padding, the dispatch, the
-        first-token read, the emit and the bookkeeping.  Runs on the
-        ENGINE thread, so the span parents explicitly off the session
-        root (the thread-local stack belongs to the loop iteration) and
-        is stacked, for its children and its place in a device profile."""
+        """Dispatch the prefill of ``sess`` into its (already reserved)
+        slot inside its ``serving.admit`` span, which covers the host's
+        part of an admission up to the dispatch: padding, the block plan,
+        the bucket-shaped call.  Nothing here waits for the device: the
+        prefill queues behind the step in flight, ``arm_slot`` arms the
+        slot on the device, and the first token is a pending read that
+        :meth:`_land_first` makes once the next step is queued behind
+        it.  Runs on the ENGINE thread, so the span parents explicitly
+        off the session root (the thread-local stack belongs to the loop
+        iteration) and is stacked, for its children and its place in a
+        device profile.
+
+        A migrated session re-prefills its whole transcript (prompt +
+        generated-so-far) — the ``limit`` stays derived from the
+        ORIGINAL prompt length, so total generation length is unchanged
+        by any number of migrations.  Returns ``(state, first)``:
+        ``first`` is :meth:`_land_first`'s arguments, or None when the
+        session was shed typed before anything was dispatched."""
+        cfg = self.cfg
         with _tracing.start_span(
                 "serving.admit", parent=sess.trace, replica=self.replica,
                 resumed=len(sess.tokens) > 0,
                 queue_wait_ms=round(1e3 * (sess.queue_wait() or 0.0),
                                     3)) as asp:
-            return self._prefill_session(sess, state, asp)
-
-    def _prefill_session(self, sess, state, asp):
-        """Prefill ``sess`` into its (already reserved) slot: one
-        bucket-shaped dispatch + one tiny admission-time host read for
-        the first token (TTFT); the hot loop's own budget is untouched.
-        A migrated session re-prefills its whole transcript (prompt +
-        generated-so-far) — the ``limit`` stays derived from the
-        ORIGINAL prompt length, so total generation length is unchanged
-        by any number of migrations.  Returns ``(state, aborted)`` —
-        aborted=True means the dispatch poisoned the donated state and
-        :meth:`_fail_all` already resolved every held session."""
-        cfg = self.cfg
-        p0 = int(sess.prompt.size)
-        resumed = len(sess.tokens) > 0
-        if resumed:
-            gen = np.asarray(sess.tokens, np.int32)  # lint: ok[host-sync] host-list -> ndarray conversion of the transcript, no device value involved
-            full = np.concatenate([sess.prompt, gen])
-        else:
-            full = sess.prompt
-        n = int(full.size)
-        limit = np.int32(min(p0 + sess.max_new_tokens - 1, cfg.max_len))
-        if self._kv is not None:
+            p0 = int(sess.prompt.size)
+            resumed = len(sess.tokens) > 0
+            if resumed:
+                gen = np.asarray(sess.tokens, np.int32)  # lint: ok[host-sync] host-list -> ndarray conversion of the transcript, no device value involved
+                full = np.concatenate([sess.prompt, gen])
+            else:
+                full = sess.prompt
+            n = int(full.size)
+            limit = np.int32(min(p0 + sess.max_new_tokens - 1,
+                                 cfg.max_len))
+            if self._kv is not None:
+                try:
+                    plan = self._kv.admit(sess.slot, full)
+                except Overloaded as e:
+                    # typed KV shed: even evicting the prefix cache
+                    # cannot cover this transcript right now — nothing
+                    # was dispatched (state unpoisoned, no blocks held),
+                    # the session sheds typed and the engine keeps
+                    # serving
+                    _telemetry.inc("serving.shed.count", model=self.name,
+                                   reason="kv_blocks")
+                    sess.trace.end("shed", reason="kv_blocks",
+                                   where="admit")
+                    asp.end("shed", reason="kv_blocks")
+                    self._retire(sess, error=e)
+                    self._occupancy_gauge()
+                    return state, None
+                # prefix-hit admissions re-/prefill ONLY the unshared
+                # suffix: the bucket is chosen by suffix length, so a
+                # long shared prompt rides a small prefill program
+                suffix = n - plan.start
+                bucket = next(b for b in self.prefill_buckets
+                              if suffix <= b)
+                tokens = np.zeros((bucket,), np.int32)
+                tokens[:suffix] = full[plan.start:]
+            else:
+                plan = None
+                bucket = next(b for b in self.prefill_buckets if n <= b)
+                tokens = np.zeros((bucket,), np.int32)
+                tokens[:n] = full
+            asp.annotate(bucket=bucket,
+                         reprefilled=n if resumed else 0,
+                         prefix_reused=plan.reused_tokens if plan else 0)
             try:
-                plan = self._kv.admit(sess.slot, full)
-            except Overloaded as e:
-                # typed KV shed: even evicting the prefix cache cannot
-                # cover this transcript right now — nothing was
-                # dispatched (state unpoisoned, no blocks held), the
-                # session sheds typed and the engine keeps serving
-                _telemetry.inc("serving.shed.count", model=self.name,
-                               reason="kv_blocks")
-                sess.trace.end("shed", reason="kv_blocks",
-                               where="admit")
-                asp.end("shed", reason="kv_blocks")
-                self._retire(sess, error=e)
-                self._occupancy_gauge()
-                return state, False
-            # prefix-hit admissions re-/prefill ONLY the unshared
-            # suffix: the bucket is chosen by suffix length, so a long
-            # shared prompt rides a small prefill program
-            suffix = n - plan.start
-            bucket = next(b for b in self.prefill_buckets
-                          if suffix <= b)
-            tokens = np.zeros((bucket,), np.int32)
-            tokens[:suffix] = full[plan.start:]
-        else:
-            plan = None
-            bucket = next(b for b in self.prefill_buckets if n <= b)
-            tokens = np.zeros((bucket,), np.int32)
-            tokens[:n] = full
-        asp.annotate(bucket=bucket)
+                with _tracing.start_span("serving.prefill.dispatch"):
+                    if plan is not None:
+                        state, out = self._prefill_fns[bucket](
+                            self._params, state, tokens,
+                            np.int32(plan.start), np.int32(n),
+                            np.int32(sess.slot),
+                            np.ascontiguousarray(
+                                self._kv.tables[sess.slot]),
+                            limit, np.float32(sess.temperature),
+                            np.uint32(sess.seed), np.bool_(True),
+                            np.int32(plan.cow_src),
+                            np.int32(plan.cow_dst))
+                    else:
+                        state, out = self._prefill_fns[bucket](
+                            self._params, state, tokens, np.int32(n),
+                            np.int32(sess.slot), limit,
+                            np.float32(sess.temperature),
+                            np.uint32(sess.seed), np.bool_(True))
+            except Exception as e:
+                # a poisoned prefill poisons the whole donated state:
+                # the turn ends in _fail_all (the queue is untouched)
+                asp.end("error", error=type(e).__name__)
+                raise _Poisoned(e) from e
+            if plan is not None:
+                self._slot_len[sess.slot] = n
+                # index the (now dispatched) prompt prefix for future
+                # admissions — insertion AFTER a successful dispatch only
+                self._kv.offer(sess.slot, sess.prompt)
+        return state, (sess, out, n, resumed)
+
+    def _land_first(self, sess, out, n, resumed):
+        """Read, emit and account an admission's first token (TTFT): one
+        tiny admission-time host read, made after the turn's step was
+        dispatched, so the device has that step queued while the host
+        waits for the prefill."""
         try:
-            with _tracing.start_span("serving.prefill.dispatch"):
-                if plan is not None:
-                    state, out = self._prefill_fns[bucket](
-                        self._params, state, tokens,
-                        np.int32(plan.start), np.int32(n),
-                        np.int32(sess.slot),
-                        np.ascontiguousarray(self._kv.tables[sess.slot]),
-                        limit, np.float32(sess.temperature),
-                        np.uint32(sess.seed), np.bool_(True),
-                        np.int32(plan.cow_src), np.int32(plan.cow_dst))
-                else:
-                    state, out = self._prefill_fns[bucket](
-                        self._params, state, tokens, np.int32(n),
-                        np.int32(sess.slot), limit,
-                        np.float32(sess.temperature),
-                        np.uint32(sess.seed), np.bool_(True))
             with _tracing.host_read("prefill.first_token"):
-                out = np.asarray(out)  # lint: ok[host-sync] admission-time first-token read (TTFT), not the per-step hot loop
+                out = np.asarray(out)  # lint: ok[host-sync] admission-time first-token read (TTFT), made with the turn's step already queued behind the prefill; not the per-step read
         except Exception as e:
-            # a poisoned prefill poisons the whole donated state: fail
-            # every session this engine holds and restart from zeros
-            # (the queue is untouched)
-            asp.end("error", error=type(e).__name__)
-            return self._fail_all(e, state), True
-        if plan is not None:
-            self._slot_len[sess.slot] = n
-            # index the (now device-resident) prompt prefix for future
-            # admissions — insertion AFTER a successful dispatch only
-            self._kv.offer(sess.slot, sess.prompt)
-        asp.annotate(reprefilled=n if resumed else 0,
-                     prefix_reused=plan.reused_tokens if plan else 0)
+            raise _Poisoned(e) from e
         now = time.monotonic()
         tok = int(out[0])
         sess.tokens.append(tok)
@@ -1229,16 +1332,11 @@ class DecodeEngine:
         if out[1]:  # EOS or max_new_tokens == 1: done at prefill
             self._retire(sess)
         self._occupancy_gauge()
-        return state, False
 
-    def _step(self, state):
-        """:meth:`_step_slots` inside its ``serving.decode.step`` span."""
-        with _tracing.start_span("serving.decode.step") as ssp:
-            return self._step_slots(state, ssp)
-
-    def _step_slots(self, state, ssp):
-        """ONE fixed-shape decode dispatch for all slots + the single
-        packed host read; host bookkeeping fans tokens out to sessions."""
+    def _dispatch(self, state):
+        """ONE fixed-shape decode dispatch for all slots, queued behind
+        whatever the device is still running.  The snapshot of the slots'
+        sessions taken here is the one this step's fan-out uses."""
         keep = np.ones((self.slots,), bool)
         with self._cond:
             sessions = list(self._slot_sessions)
@@ -1253,15 +1351,27 @@ class DecodeEngine:
         if self._kv is not None:
             # block-boundary appends: the step scatters each live
             # slot's K/V at position ``lengths`` — make sure that
-            # block exists BEFORE dispatch.  A dry pool (even after
-            # prefix-cache eviction) sheds the session typed instead
-            # of corrupting a shared scratch row.
+            # block exists BEFORE dispatch.  The host's mirror lags the
+            # device by the unread step a session rode: reserve for the
+            # position after it, unless that step is the session's last
+            # by length (one it ends by EOS over-reserves a block, which
+            # its retirement releases).  A dry pool (even after
+            # prefix-cache eviction) sheds the session typed, and at
+            # once, so that its blocks serve the slots behind it; the
+            # token it has in flight is not delivered.
+            riding = self._unread[-1].sessions if self._unread \
+                else (None,) * self.slots
             for i, sess in enumerate(sessions):
                 if sess is None or not keep[i]:
                     continue
+                pos = self._slot_len[i]
+                if riding[i] is sess:
+                    pos += 1
+                    if pos >= min(sess.prompt.size + sess.max_new_tokens
+                                  - 1, self.cfg.max_len):
+                        continue
                 try:
-                    self._kv.append(i, min(self._slot_len[i],
-                                           self.cfg.max_len - 1))
+                    self._kv.append(i, min(pos, self.cfg.max_len - 1))
                 except Overloaded as e:
                     keep[i] = False
                     sessions[i] = None
@@ -1271,6 +1381,7 @@ class DecodeEngine:
                                    where="active")
                     self._retire(sess, error=e)
         t0 = time.perf_counter()
+        extra_in = self._extra
         try:
             if _faults.should_fire("serving.decode"):
                 raise _faults.FaultInjected(
@@ -1297,45 +1408,69 @@ class DecodeEngine:
                         np.ascontiguousarray(self._kv.tables))
                 else:
                     state, packed = self._dispatch_step(state, keep)
-            with _tracing.host_read("decode.packed"):
-                packed = np.asarray(packed)  # lint: ok[host-sync] THE one sanctioned host read per decode step (packed token/done/active buffer)
         except Exception as e:
-            ssp.end("error", error=type(e).__name__)
-            return self._fail_all(e, state)
-        dt = time.perf_counter() - t0
+            raise _Poisoned(e) from e
+        self._unread.append(_Step(packed, sessions, keep, t0, extra_in))
+        return state
+
+    def _land_step(self, ssp):
+        """The single packed host read of the oldest unread step, and the
+        host bookkeeping that fans its tokens out to the sessions that
+        still hold the slot they held at its dispatch."""
+        step = self._unread[0]
+        ahead = int(len(self._unread) > 1)
+        try:
+            with _tracing.host_read("decode.packed"):
+                packed = np.asarray(step.packed)  # lint: ok[host-sync] THE one sanctioned host read per decode step (packed token/done/active buffer), made with the next step already dispatched
+        except Exception as e:
+            raise _Poisoned(e) from e
+        self._unread.popleft()
+        t_read = time.perf_counter()
+        # one token gap as a client sees it: read to read; a step that
+        # was dispatched with nothing in flight counts from its dispatch
+        dt = t_read - max(step.t0, self._read_t)
+        self._read_t = t_read
+        with self._cond:
+            holders = list(self._slot_sessions)
         emitted = 0
-        fsp = _tracing.start_span("serving.decode.fanout")
-        for i, sess in enumerate(sessions):
-            if sess is None:
-                continue
-            if not keep[i]:
-                reason = "abandoned" if sess.cancelled() else "deadline"
-                _telemetry.inc("serving.shed.count", model=self.name,
-                               reason=reason)
-                err = DeadlineExceeded("session deadline expired "
-                                       "mid-generation") \
-                    if reason == "deadline" else \
-                    MXNetError("session abandoned by the client")
-                sess.trace.end("shed", reason=reason, where="active")
-                self._retire(sess, error=err)
-                continue
-            tok = int(packed[0, i])
-            if tok >= 0:
-                emitted += 1
-                sess.tokens.append(tok)
-                self._emit(sess, tok)
-                # host mirror of the device ``lengths`` advance
-                # (new_len = lengths + active): the next step's write
-                # position for this slot
-                self._slot_len[i] += 1
-            if packed[1, i]:
-                self._retire(sess)
-        fsp.end("ok", emitted=emitted)
+        with _tracing.start_span("serving.decode.fanout") as fsp:
+            for i, sess in enumerate(step.sessions):
+                # a session that an earlier fan-out retired (it finished
+                # at the step before, or at its prefill) rode this step
+                # inactive: tok -1, done 0, nothing to deliver
+                if sess is None or holders[i] is not sess:
+                    continue
+                if not step.keep[i]:
+                    reason = "abandoned" if sess.cancelled() \
+                        else "deadline"
+                    _telemetry.inc("serving.shed.count", model=self.name,
+                                   reason=reason)
+                    err = DeadlineExceeded("session deadline expired "
+                                           "mid-generation") \
+                        if reason == "deadline" else \
+                        MXNetError("session abandoned by the client")
+                    sess.trace.end("shed", reason=reason, where="active")
+                    self._retire(sess, error=err)
+                    continue
+                tok = int(packed[0, i])
+                if tok >= 0:
+                    emitted += 1
+                    sess.tokens.append(tok)
+                    self._emit(sess, tok)
+                    # host mirror of the device ``lengths`` advance
+                    # (new_len = lengths + active): the write position
+                    # of the slot's next step
+                    self._slot_len[i] += 1
+                if packed[1, i]:
+                    self._retire(sess)
+            fsp.annotate(emitted=emitted)
         ssp.annotate(live=emitted)
         with self._cond:
             self.steps += 1
             self.tokens_out += emitted
             self._rate_tokens += emitted
+            self._reads_ahead += ahead
+            share = self._reads_ahead / float(self.steps)
             rate_t0, rate_tokens = self._rate_t0, self._rate_tokens
         _telemetry.inc("serving.decode.steps.count", model=self.name,
                        replica=self.replica)
@@ -1344,6 +1479,8 @@ class DecodeEngine:
                            model=self.name, replica=self.replica)
         _telemetry.observe("serving.decode.token_latency_seconds", dt,
                            buckets=LATENCY_BUCKETS, model=self.name)
+        _telemetry.set_gauge("serving.decode.overlap_share", share,
+                             model=self.name, replica=self.replica)
         elapsed = time.monotonic() - rate_t0
         if elapsed >= 0.5:
             _telemetry.set_gauge("serving.decode.tokens_per_sec",
@@ -1355,16 +1492,23 @@ class DecodeEngine:
         self._occupancy_gauge()
         if self._on_step_ok is not None:
             self._on_step_ok()
-        return state
+        return ahead
 
-    def _fail_all(self, exc, _poisoned_state):
-        """A failed device dispatch poisons the donated state: every
-        held session is handed to the pool's migration hook (or, with
-        no pool above, gets the error — the batcher's batch-error
-        contract), the state restarts from zeros (same shapes — no
-        recompile), and the worker survives to serve the queue (unless
-        a :class:`ReplicaKilled` closed it)."""
+    def _fail_all(self, exc):
+        """A failed dispatch or read poisons the donated chain, the
+        unread steps queued on it included: they are dropped WHOLE (a
+        step is fanned out entirely or not at all, so ``sess.tokens`` is
+        exactly what ``on_token`` saw), the model's extra state falls
+        back to the last one a read proved good, every held session is
+        handed to the pool's migration hook (or, with no pool above,
+        gets the error — the batcher's batch-error contract), the state
+        restarts from zeros (same shapes — no recompile), and the worker
+        survives to serve the queue (unless a :class:`ReplicaKilled`
+        closed it)."""
         _telemetry.inc("serving.error.count", model=self.name)
+        if self._unread:
+            self._extra = self._unread[0].extra_in  # lint: ok[lock-discipline] same hand-off as _dispatch_step: the loop's thread is the one writer, and the array it puts back was never donated
+            self._unread.clear()
         with self._cond:
             held = [x for x in self._slot_sessions if x is not None]
             self._slot_sessions = [None] * self.slots

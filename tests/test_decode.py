@@ -19,13 +19,14 @@ import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import faults, telemetry
+from mxnet_tpu import faults, telemetry, tracing
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models import transformer_lm as tlm
 from mxnet_tpu.serving import (DeadlineExceeded, DecodeEngine,
                                InvalidRequest, ModelRegistry, Overloaded,
                                QuotaExceeded, ReplicaPool,
                                ServingHTTPServer, lm_pool)
+from tools.perf.serial_loop import serial_loop
 
 # tiny LM: every compile stays sub-second on the CPU CI host
 VOCAB, EMBED, HEADS, LAYERS, FFN, MAX_LEN = 32, 16, 2, 2, 32, 32
@@ -446,6 +447,237 @@ def test_telemetry_families_present_after_traffic():
             "serving.decode.tokens.count") >= 5
     finally:
         eng.close()
+
+
+# -- the loop keeps one step queued on the device ---------------------------
+
+def _rest(eng):
+    """A session's last step is followed by one it rides inactive: wait
+    until the loop has read that one too and rests."""
+    steps = -1
+    while steps != eng.steps or eng.pending_rows():
+        steps = eng.steps
+        time.sleep(0.05)
+
+
+def test_a_step_is_read_with_the_next_one_dispatched(monkeypatch):
+    """The order that keeps the device fed: every packed read begins with
+    a later step already dispatched, an admission's first token is read
+    with a step queued behind its prefill, and only the step behind the
+    last session's last one is read with nothing after it."""
+    eng = _engine(slots=2, autostart=False)
+    log = []
+    step_fn, host_read = eng._step_fn, tracing.host_read
+
+    def logged_step(*args):
+        log.append("dispatch")
+        return step_fn(*args)
+
+    def logged_read(site):
+        log.append(site)
+        return host_read(site)
+
+    eng._step_fn = logged_step
+    monkeypatch.setattr(tracing, "host_read", logged_read)
+    try:
+        eng.start()
+        five = threading.Event()
+        seen = []
+
+        def on_a(tok):
+            seen.append(tok)
+            if len(seen) == 5:
+                five.set()
+
+        a = eng.submit(PROMPT, max_new_tokens=24, on_token=on_a)
+        assert five.wait(60)
+        # both inside A's lifetime: one that its prefill already finishes
+        # (the step dispatched for it is one it rides inactive), one that
+        # joins the running batch
+        b = eng.submit([3, 4], max_new_tokens=1)
+        c = eng.submit([3, 4, 6], max_new_tokens=4)
+        assert len(a.result(60)) == 24
+        assert len(b.result(60)) == 1 and len(c.result(60)) == 4
+        _rest(eng)
+    finally:
+        eng.close()
+    dispatched = read = firsts = 0
+    behind = []    # steps dispatched and unread as each packed read began
+    for what in log:
+        if what == "dispatch":
+            dispatched += 1
+        elif what == "decode.packed":
+            behind.append(dispatched - read)
+            read += 1
+        else:
+            assert what == "prefill.first_token"
+            firsts += 1
+            assert dispatched > read, "first token read on an empty queue"
+    assert firsts == 3 and read == dispatched == eng.steps
+    assert read == 23 + 1     # A's 23 steps and the one behind its last
+    assert behind == [2] * (read - 1) + [1], behind
+    assert telemetry.snapshot()["gauges"][
+        "serving.decode.overlap_share"]["model=lm,replica=0"] \
+        == pytest.approx((read - 1) / read)
+
+
+#: (prompt, max_new_tokens, seed): more sessions than slots, lengths that
+#: free slots at different steps, one that finishes at its prefill
+REQUESTS = [([5, 7, 9, 2], 9, 11), ([1, 2, 3], 4, 12), ([9, 9, 1, 0, 4], 1, 13),
+            ([3, 0, 8, 8, 1, 6], 12, 14), ([7], 6, 15), ([2, 4], 3, 16),
+            ([6, 1, 6, 1, 6, 1, 6], 8, 17)]
+PIPE_OPTS = {"slots": 3, "prefill_buckets": (4, 8, 32), "max_queue": 64}
+_REFERENCES = {}
+
+
+def _serial_reference(layout, temperature):
+    """``REQUESTS`` through ``tools/perf/serial_loop.py``'s plain serial
+    loop over an engine's own ``jit_prefill`` and ``jit_step``: what the
+    engine's loop has to equal, session by session."""
+    key = (layout, temperature)
+    if key not in _REFERENCES:
+        eng = _engine(autostart=False, kv_layout=layout, **PIPE_OPTS)
+        try:
+            tokens = serial_loop(eng, [(p, new, temperature, seed)
+                                       for p, new, seed in REQUESTS])
+        finally:
+            eng.close(drain=False)
+        assert [len(t) for t in tokens] == [new for _p, new, _s in REQUESTS]
+        _REFERENCES[key] = tokens
+    return _REFERENCES[key]
+
+
+def _submit_all(target, temperature, streams, **first_kw):
+    """``REQUESTS`` into an engine or a pool, each with a stream that
+    logs what ``on_token`` saw; ``first_kw`` goes to the first alone."""
+    submit = getattr(target, "submit", None) or target.generate
+    sessions = []
+    for r, (prompt, new, seed) in enumerate(REQUESTS):
+        streams.append([])
+        kw = first_kw if r == 0 else {}
+        on_token = kw.pop("on_token", None)
+
+        def stream(tok, log=streams[-1], also=on_token):
+            log.append(tok)
+            if also is not None:
+                also(len(log))
+
+        sessions.append(submit(prompt, max_new_tokens=new, seed=seed,
+                               temperature=temperature, on_token=stream,
+                               **kw))
+    return sessions
+
+
+@pytest.mark.parametrize("scenario", ["plain", "cancel", "deadline",
+                                      "fault", "migration"])
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+@pytest.mark.parametrize("temperature", [0.0, 0.8],
+                         ids=["greedy", "sampled"])
+def test_streams_equal_the_serial_loops(temperature, layout, scenario):
+    """Token for token, and in the order ``on_token`` saw them, every
+    session's stream is what a plain serial loop over the same programs
+    gives — whole where the session finished, a prefix where a cancel, a
+    deadline or a step fault ended it; a stream is never ahead of or
+    behind its transcript."""
+    want = _serial_reference(layout, temperature)
+    opts = dict(PIPE_OPTS, kv_layout=layout)
+    streams, cut = [], {}
+    if scenario == "migration":
+        target = lm_pool(CFG_NO_EOS, PARAMS, n_replicas=2, name="lm",
+                         engine_opts=opts)
+    else:
+        target = _engine(autostart=False, **opts)
+    try:
+        first_kw = {}
+        if scenario == "cancel":
+            # on the engine's thread, at the fan-out of the third token:
+            # the step behind it is in flight and still delivers
+            first_kw["on_token"] = lambda n: n == 3 and sessions[0].cancel()
+            cut[0] = (MXNetError, 4)
+        elif scenario == "deadline":
+            first_kw["deadline_ms"] = 60000
+            first_kw["on_token"] = lambda n: n == 3 and setattr(
+                sessions[0], "deadline", time.monotonic() - 1.0)
+            cut[0] = (DeadlineExceeded, 4)
+        elif scenario == "fault":
+            # the fourth dispatch dies: whoever holds a slot then gets the
+            # error with the stream it had, the queue is served after it
+            faults.arm("serving.decode", at=4)
+        elif scenario == "migration":
+            faults.arm("serving.replica.kill", at=4)
+        sessions = _submit_all(target, temperature, streams, **first_kw)
+        if scenario != "migration":
+            target.start()
+        failed = 0
+        for r, sess in enumerate(sessions):
+            if r in cut:
+                err, n = cut[r]
+                with pytest.raises(err):
+                    sess.result(120)
+                assert sess.tokens == want[r][:n]
+            elif scenario == "fault":
+                try:
+                    assert sess.result(120) == want[r]
+                except faults.FaultInjected:
+                    failed += 1
+                    assert sess.tokens == want[r][:len(sess.tokens)]
+                    assert len(sess.tokens) < len(want[r])
+            else:
+                assert sess.result(120) == want[r]
+            assert streams[r] == sess.tokens
+        if scenario == "fault":
+            assert 1 <= failed <= PIPE_OPTS["slots"]
+        if scenario == "migration":
+            assert sum(s.migrations for s in sessions) >= 1
+    finally:
+        faults.disarm()
+        target.close(drain=False)
+
+
+@pytest.mark.parametrize("how", ["drain", "stop", "hand_off"])
+def test_a_stop_loses_no_delivered_token_and_delivers_none_twice(how):
+    """A drain finishes what holds a slot; a plain stop and a hand-over
+    land the step in flight first, so a transcript is exactly the stream
+    its client saw, and a handed-over session resumed elsewhere ends with
+    the serial loop's tokens, none lost and none repeated."""
+    want = _serial_reference("dense", 0.8)
+    streams, handed = [], []
+    mid = threading.Event()
+    eng = _engine(autostart=False, **PIPE_OPTS)
+    other = None
+    try:
+        sessions = _submit_all(eng, 0.8, streams,
+                               on_token=lambda n: n == 3 and mid.set())
+        eng.start()
+        assert mid.wait(60)
+        if how == "drain":
+            eng.stop(drain=True)
+        elif how == "stop":
+            eng.stop(drain=False)
+        else:
+            assert eng.stop(drain=False, hand_off=handed.extend) is True
+            assert handed
+            other = _engine(**PIPE_OPTS)
+            for sess in handed:
+                other.resume(sess)
+        whole = 0
+        for r, sess in enumerate(sessions):
+            try:
+                assert sess.result(120) == want[r]
+                whole += 1
+            except MXNetError:
+                assert how != "hand_off"
+                assert sess.tokens == want[r][:len(sess.tokens)]
+            assert streams[r] == sess.tokens
+        if how == "drain":
+            # whoever held a slot at the stop was let to its end
+            assert whole >= 1
+        elif how == "hand_off":
+            assert whole == len(REQUESTS)
+    finally:
+        eng.close(drain=False)
+        if other is not None:
+            other.close(drain=False)
 
 
 # -- pool: routing, quotas, priority, health --------------------------------

@@ -236,15 +236,26 @@ def test_step_and_prefill_lower_to_the_pinned_module_names(engine, params):
 
 # -- the loops' spans --------------------------------------------------------
 
+def _rest(engine):
+    """A session's last step is followed by one it rides inactive: wait
+    until the loop has read that one too and rests."""
+    steps = -1
+    while steps != engine.steps or engine.pending_rows():
+        steps = engine.steps
+        time.sleep(0.1)
+
+
 def test_decode_engine_leaves_its_loop_spans_under_a_profile(engine,
                                                              tmp_path):
     engine.generate(np.array([5, 7, 9], np.int32), max_new_tokens=2)
+    _rest(engine)
     assert tracing.spans_recent() == []     # nothing on: nothing recorded
     with _Profile(tmp_path) as prof:
         sessions = [engine.submit(np.arange(2, 2 + n, dtype=np.int32),
                                   max_new_tokens=5) for n in (3, 9, 4, 6)]
         for s in sessions:
             s.result(60)
+        _rest(engine)
     spans = tracing.spans_recent(1 << 20)
     nest = _children(spans)
     iters = nest[(None, "serving.decode.iter")]
@@ -252,12 +263,18 @@ def test_decode_engine_leaves_its_loop_spans_under_a_profile(engine,
     assert nest[("serving.decode.iter", "serving.decode.queue")] == iters
     steps = nest[("serving.decode.iter", "serving.decode.step")]
     assert 5 <= steps <= iters
+    # the engine never rested in between, so one pipeline ran from end to
+    # end: the first turn dispatches and has nothing to read, the last
+    # reads and dispatches nothing, every other one does both
     for child in ("serving.decode.dispatch", "host_read",
                   "serving.decode.fanout"):
-        assert nest[("serving.decode.step", child)] == steps
+        assert nest[("serving.decode.step", child)] == steps - 1
     assert nest[("serving.generate", "serving.admit")] == 4
     assert nest[("serving.admit", "serving.prefill.dispatch")] == 4
-    assert nest[("serving.admit", "host_read")] == 4
+    # an admission's first token is read outside its span, under the
+    # iteration, after the iteration's step was dispatched
+    assert ("serving.admit", "host_read") not in nest
+    assert nest[("serving.decode.iter", "host_read")] == 4
     by_name = {}
     for r in spans:
         by_name.setdefault(r["name"], []).append(r)
@@ -265,6 +282,24 @@ def test_decode_engine_leaves_its_loop_spans_under_a_profile(engine,
         "decode.packed", "prefill.first_token"}
     assert sum(r["attrs"]["admits"]
                for r in by_name["serving.decode.iter"]) == 4
+    # within a turn: the dispatch of the next step, then the packed read
+    # of the one before, then the first tokens; ``ahead`` says so
+    assert sum(r["attrs"]["ahead"]
+               for r in by_name["serving.decode.iter"]) == steps - 2
+    for it in by_name["serving.decode.iter"]:
+        mine = [r for r in spans if r["t0_ns"] >= it["t0_ns"]
+                and r["t1_ns"] <= it["t1_ns"] and r["tid"] == it["tid"]]
+        sent = [r["t1_ns"] for r in mine
+                if r["name"] == "serving.decode.dispatch"]
+        reads = [r for r in mine if r["name"] == "host_read"]
+        assert len(sent) <= 1
+        assert it["attrs"]["ahead"] == int(bool(sent) and any(
+            r["attrs"]["site"] == "decode.packed" for r in reads))
+        if it["attrs"]["active"]:
+            assert sent and all(sent[0] <= r["t0_ns"] for r in reads)
+        sites = [r["attrs"]["site"]
+                 for r in sorted(reads, key=lambda r: r["t0_ns"])]
+        assert sites == sorted(sites), sites     # decode.packed first
     for r in by_name["serving.admit"]:
         assert r["attrs"]["bucket"] in BUCKETS
         assert r["attrs"]["queue_wait_ms"] >= 0 and not r["attrs"]["resumed"]
