@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["LMConfig", "CacheLayer", "slot_shape", "DecodeModel",
+__all__ = ["LMConfig", "CacheLayer", "StateLayer", "slot_shape",
+           "slot_arrays", "DecodeModel",
            "init_params", "forward_logits", "prefill_kv",
            "write_rows", "decode_step_math", "prefill_kv_paged",
            "decode_step_paged", "params_to_blob", "params_from_blob"]
@@ -44,11 +45,27 @@ CacheLayer = namedtuple("CacheLayer", ["kind", "rows", "kv_heads",
                         defaults=(False,))
 
 
+#: one layer's recurrent slot state, as the decode engine builds it: two
+#: per-slot arrays of fixed shape, ``(slots,) + shapes[i]`` of ``dtypes[i]``
+#: (a state-space layer's state and the tail of its convolution).  ``kind``
+#: is "state": no length masks it, so an admission overwrites both whole
+StateLayer = namedtuple("StateLayer", ["kind", "shapes", "dtypes"])
+
+
 def slot_shape(layer):
     """The shape of one slot of a :class:`CacheLayer`."""
     if layer.heads_major:
         return (layer.kv_heads, layer.rows, layer.head_dim)
     return (layer.rows, layer.kv_heads, layer.head_dim)
+
+
+def slot_arrays(entry):
+    """``((shape, dtype), (shape, dtype))`` of the two per-slot arrays an
+    entry of a cache specification describes: a :class:`CacheLayer`'s K
+    and V, a :class:`StateLayer`'s own two."""
+    if entry.kind == "state":
+        return tuple(zip(entry.shapes, entry.dtypes))
+    return ((slot_shape(entry), entry.dtype),) * 2
 
 
 def init_params(cfg, seed=0, dtype=jnp.float32):

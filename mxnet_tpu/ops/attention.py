@@ -508,6 +508,8 @@ def decode_attention_plan(q, cache_k):
     call takes the plain path, which reads all ``rows`` of every slot as
     one block (``ops.kernel_path`` reasons).
 
+    Rows of whole 128-lane width only (``lanes``: a model of narrower
+    heads caches them in pairs, as :mod:`~mxnet_tpu.models.sambay` does).
     The block follows what can be seen here: all K/V heads of as many rows
     as make :data:`_DECODE_BLOCK_BYTES`, a power of two that divides
     ``rows``, 128 at the least (a block's scores are ``(group, block)``
@@ -521,6 +523,11 @@ def decode_attention_plan(q, cache_k):
     if cache_k.dtype not in (jnp.bfloat16, jnp.float32) \
             or q.dtype != cache_k.dtype:
         return rows, "dtype"
+    if d % 128:
+        # a cache of rows narrower than the 128 lanes lives rows-minor on
+        # the chip; the kernel wants it row-major, and the compiler then
+        # copies the whole cache to it and back at every call
+        return rows, "lanes"
     block = 128
     while block * 2 * kv * d * cache_k.dtype.itemsize <= _DECODE_BLOCK_BYTES \
             and rows % (block * 2) == 0:

@@ -60,20 +60,41 @@ same programs (``tools/perf/serial_loop.py``).
 is given a *model object* and asks it for four things:
 
 * ``cfg`` with ``vocab``, ``max_len`` and ``eos_id``, and
-  ``cache_spec()``: one :class:`~mxnet_tpu.models.transformer_lm.CacheLayer`
-  ``(kind, rows, kv_heads, head_dim, dtype, heads_major)`` a layer, from
-  which :meth:`DecodeEngine._fresh_state` builds K and V as ``(slots, rows,
-  kv_heads, head_dim)`` (``heads_major``: ``(slots, kv_heads, rows,
-  head_dim)``) — ``kind`` "full" (``rows`` = ``max_len``) or "ring"
-  (``rows`` = a window); layers may differ;
-* ``prefill(params, tokens, length) -> (last_logits, ks, vs)``: the rows
-  to write into ONE slot of each layer's cache, from row 0;
-* ``decode_step(params, cache_k, cache_v, last_tok, lengths, active,
-  extra) -> (logits, cache_k, cache_v, extra)``: one token for all slots;
+  ``cache_spec()``: what the model keeps for a slot between steps, one
+  entry for each layer that keeps anything (a layer may keep nothing).
+  An entry describes TWO per-slot arrays, which
+  :meth:`DecodeEngine._fresh_state` builds with ``slots`` in front
+  (:func:`~mxnet_tpu.models.transformer_lm.slot_arrays`):
+
+  - a :class:`~mxnet_tpu.models.transformer_lm.CacheLayer` ``(kind, rows,
+    kv_heads, head_dim, dtype, heads_major)``: a K and a V of ``(rows,
+    kv_heads, head_dim)`` (``heads_major``: ``(kv_heads, rows,
+    head_dim)``) — ``kind`` "full" (``rows`` = ``max_len``) or "ring"
+    (``rows`` = a window).  Rows a session has not written are hidden by
+    its length, so a slot is reused as it stands;
+  - a :class:`~mxnet_tpu.models.transformer_lm.StateLayer` ``(kind,
+    shapes, dtypes)``, ``kind`` "state": two arrays of fixed shape and
+    dtype of their own (a state-space layer's recurrent state and the
+    tail of its convolution).  No length hides what a slot's last session
+    left in them: an admission overwrites both WHOLE, and so does the
+    re-prefill of a migrated transcript (:meth:`DecodeEngine.resume`),
+    which is how a recurrent state is restored on another replica.
+
+  Entries may differ in every field;
+* ``prefill(params, tokens, length) -> (last_logits, firsts, seconds)``:
+  for each entry the two values to put into ONE slot, written from row 0
+  (a K and a V may be shorter than the slot) or whole (a state);
+* ``decode_step(params, firsts, seconds, last_tok, lengths, active,
+  extra) -> (logits, firsts, seconds, extra)``: one token for all slots,
+  given and returning every entry's two arrays;
 * ``extra_state()``: optional extra device state the step carries beside
   the cache (routing counters), or None.  It is an argument of its own,
   never donated, so :meth:`DecodeEngine.model_counters` may read the
   latest from any thread; ``model.counters(extra)`` names what it holds.
+
+The engine's donated state is ``(firsts, seconds, last_tok, lengths,
+limits, active, temps, seeds)``: every entry's first array, every entry's
+second, six arrays of ``(slots,)``.
 
 A bare :class:`~mxnet_tpu.models.transformer_lm.LMConfig` stands for
 :class:`~mxnet_tpu.models.transformer_lm.DecodeModel`, the first
@@ -403,6 +424,8 @@ class DecodeEngine:
                 "got %r" % layout)
         self.kv_layout = layout
         self._spec = tuple(self.model.cache_spec())
+        #: whether an admission overwrites recurrent state (a "state" entry)
+        self._resets_state = any(c.kind == "state" for c in self._spec)
         if layout == "paged" and not (
                 hasattr(self.model, "decode_step_paged")
                 and all(c.kind == "full" for c in self._spec)):
@@ -579,20 +602,24 @@ class DecodeEngine:
 
             def prefill(params, state, tokens, length, slot, limit,
                         temp, seed, activate):
-                cache_k, cache_v = state[0], state[1]
-                last_logits, ks, vs = model.prefill(params, tokens,
-                                                    length)
-                cache_k = tuple(
-                    jax.lax.dynamic_update_slice(ck, k[None],
-                                                 (slot, 0, 0, 0))
-                    for ck, k in zip(cache_k, ks))
-                cache_v = tuple(
-                    jax.lax.dynamic_update_slice(cv, v[None],
-                                                 (slot, 0, 0, 0))
-                    for cv, v in zip(cache_v, vs))
+                last_logits, firsts, seconds = model.prefill(
+                    params, tokens, length)
+
+                def into_slot(held, values):
+                    # from row 0 of the slot: a K or a V as far as the
+                    # bucket reaches, a state whole (its value has the
+                    # slot's own shape, so nothing of the last session's
+                    # is left)
+                    return tuple(
+                        jax.lax.dynamic_update_slice(
+                            h, v[None], (slot,) + (0,) * v.ndim)
+                        for h, v in zip(held, values))
+
+                cache = (into_slot(state[0], firsts),
+                         into_slot(state[1], seconds))
                 rest, out = arm_slot(state[2:], slot, last_logits,
                                      length, limit, temp, seed, activate)
-                return (cache_k, cache_v) + rest, out
+                return cache + rest, out
 
             self._step_fn = self._instrument(
                 jax.jit(step, donate_argnums=(1,)), "decode_step",
@@ -622,6 +649,11 @@ class DecodeEngine:
                 "serving.decode.warmup.cache_loads",
                 cc1["hits"] - cc0["hits"], model=self.name,
                 replica=self.replica)
+        if self._kv is None:
+            for kind, held in self._cache_bytes().items():
+                _telemetry.set_gauge("serving.cache.bytes", held,
+                                     model=self.name, replica=self.replica,
+                                     kind=kind)
         _telemetry.event("serving.decode.warm", model=self.name,
                          replica=self.replica, slots=s,
                          buckets=len(self.prefill_buckets))
@@ -657,13 +689,16 @@ class DecodeEngine:
             lead = None
         self._slot_len = [0] * s
 
-        def zeros():
-            return tuple(jnp.zeros(
-                lead + (c.kv_heads, c.head_dim) if lead
-                else (s,) + _tlm.slot_shape(c), c.dtype)
-                for c in self._spec)
+        def zeros(i):
+            made = []
+            for c in self._spec:
+                shape, dtype = _tlm.slot_arrays(c)[i]
+                made.append(jnp.zeros(
+                    lead + (c.kv_heads, c.head_dim) if lead
+                    else (s,) + shape, dtype))
+            return tuple(made)
 
-        state = (zeros(), zeros(),
+        state = (zeros(0), zeros(1),
                  jnp.zeros((s,), jnp.int32),        # last_tok
                  jnp.zeros((s,), jnp.int32),        # lengths
                  jnp.zeros((s,), jnp.int32),        # limits
@@ -909,9 +944,7 @@ class DecodeEngine:
             kv = self._kv.describe()
         else:
             kv = {"layout": "dense",
-                  "hbm_bytes": sum(
-                      2 * self.slots * c.rows * c.kv_heads * c.head_dim
-                      * np.dtype(c.dtype).itemsize for c in self._spec)}
+                  "hbm_bytes": sum(self._cache_bytes().values())}
         model = self.model_counters()
         return {"name": self.name, "kind": "generate",
                 **({"model_counters": model} if model else {}),
@@ -923,6 +956,16 @@ class DecodeEngine:
                 "reprefilled_tokens": reprefilled,
                 "prefill_buckets": list(self.prefill_buckets),
                 "max_len": self.cfg.max_len, "kv": kv}
+
+    def _cache_bytes(self):
+        """Bytes of the dense slot state by kind of entry (``full``,
+        ``ring``, ``state``), both arrays of every entry over all slots."""
+        out = {}
+        for c in self._spec:
+            out[c.kind] = out.get(c.kind, 0) + self.slots * sum(
+                int(np.prod(shape)) * np.dtype(dtype).itemsize
+                for shape, dtype in _tlm.slot_arrays(c))
+        return out
 
     def model_counters(self):
         """What the model's extra device state has counted since the
@@ -1281,6 +1324,11 @@ class DecodeEngine:
                 # the turn ends in _fail_all (the queue is untouched)
                 asp.end("error", error=type(e).__name__)
                 raise _Poisoned(e) from e
+            if self._resets_state:
+                # the prefill just dispatched put its own recurrent state
+                # over whatever the slot's last session left
+                _telemetry.inc("serving.ssm.state_resets", model=self.name,
+                               replica=self.replica)
             if plan is not None:
                 self._slot_len[sess.slot] = n
                 # index the (now dispatched) prompt prefix for future

@@ -309,13 +309,19 @@ def test_mid_decode_admission_joins_running_batch():
     the batch to complete."""
     eng = _engine(slots=2)
     try:
-        five = threading.Event()
+        # the token callback runs on the engine's thread: at A's fifth
+        # token it holds the engine there until B is queued.  Without that
+        # A's twenty tokens still to come (a millisecond a step) can be out
+        # before this thread wakes up under a loaded host, B then finds A
+        # gone, and the test fails for the race and not for the engine
+        five, queued = threading.Event(), threading.Event()
         a_tok = []
 
         def on_a(t):
             a_tok.append(t)
-            if len(a_tok) >= 5:
+            if len(a_tok) == 5:
                 five.set()
+                queued.wait(60)
 
         a = eng.submit(PROMPT, max_new_tokens=25, on_token=on_a)
         assert five.wait(60), "A never started decoding"
@@ -323,6 +329,7 @@ def test_mid_decode_admission_joins_running_batch():
         b = eng.submit([3, 4], max_new_tokens=3,
                        on_done=lambda _s: a_len_at_b_done.append(
                            len(a.tokens)))
+        queued.set()
         out_b = b.result(60)
         assert len(out_b) == 3
         # B completed while A was still decoding: it joined the running

@@ -434,6 +434,140 @@ CASES["decode-step-k-exaone-256-slots-with-the-decode-kernel"] = \
     _exaone_step_with_the_kernel
 
 
+def _decode_attention_pairs():
+    """The call of ``models/sambay.py``'s full layer and of each of its
+    seven cross layers at the Phi-4-mini-flash cell's sizes: 128 slots, 10
+    K/V pairs of 128 lanes (two heads of 64 side by side) with 4 queries
+    each, 4096 rows, bfloat16.  The plan admits it in blocks of 256 rows,
+    and with the cache donated nothing the size of it is copied."""
+    import re
+
+    q = jax.ShapeDtypeStruct((128, 10, 4, 128), jnp.bfloat16)
+    cache = jax.ShapeDtypeStruct((128, 10, 4096, 128), jnp.bfloat16)
+    with _tpu_trace():
+        assert attention.decode_attention_plan(q, cache) == (256, None)
+        text = _decode_attention_over_a_donated_cache(q, cache)
+    assert "tpu_custom_call" in text
+    assert not re.findall(r"= bf16\[128,10,4096,128\]\{[^}]*\} copy\(", text)
+
+
+def _decode_attention_over_a_donated_cache(q, cache):
+    """Compiled text of a step's use of the kernel: the new row written
+    into the donated cache, then the attention over it."""
+    from mxnet_tpu.models.exaone_moe import write_full
+
+    one_chip = _one_chip()
+
+    def step(q, ck, cv, k, v, n):
+        ck, cv = write_full(ck, k, n), write_full(cv, v, n)
+        return attention._decode_pallas(
+            q, ck, cv, n, 0.125, 256), ck, cv
+
+    sds = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+           for shape, dtype in (
+               (q.shape, q.dtype), (cache.shape, cache.dtype),
+               (cache.shape, cache.dtype),
+               (cache.shape[:2] + cache.shape[3:], cache.dtype),
+               (cache.shape[:2] + cache.shape[3:], cache.dtype),
+               (cache.shape[:1], jnp.int32))]
+    return jax.jit(step, donate_argnums=(1, 2)).lower(*sds).compile() \
+        .as_text()
+
+
+CASES["decode_attention-128x10x4x128-pairs-over-4096-rows"] = \
+    _decode_attention_pairs
+
+
+def _decode_attention_lanes():
+    """Heads of 64 cached on their own, ``(128, 20, 4096, 64)``: the plan
+    says ``lanes`` and the call takes the plain path, because the compiler
+    keeps such a cache rows-minor and, made to hand it to the kernel
+    row-major, copies the whole of it there and back at every call."""
+    import re
+
+    q = jax.ShapeDtypeStruct((128, 20, 2, 64), jnp.bfloat16)
+    cache = jax.ShapeDtypeStruct((128, 20, 4096, 64), jnp.bfloat16)
+    with _tpu_trace():
+        assert attention.decode_attention_plan(q, cache) == (4096, "lanes")
+        text = _decode_attention_over_a_donated_cache(q, cache)
+    assert re.findall(r"= bf16\[128,20,4096,64\]\{[^}]*\} copy\(", text)
+
+
+CASES["decode_attention-refuses-rows-narrower-than-the-lanes"] = \
+    _decode_attention_lanes
+
+
+def _ssm_scan_case():
+    """``ops.ssm.ssm_scan`` alone at the Phi-4-mini-flash prefill's
+    largest bucket (1024 positions, ``d_inner`` 5120, ``d_state`` 16,
+    float32): what the plan admits (512 lanes of state a grid step, 128
+    positions a chunk) the compiler takes, and nothing the size of the
+    ``(positions, d_state, d_inner)`` products is held."""
+    from mxnet_tpu.ops import ssm
+
+    f32 = jnp.float32
+    shapes = [((1024, 5120), f32), ((1024, 5120), f32), ((1024, 16), f32),
+              ((1024, 16), f32), ((16, 5120), f32), ((16, 5120), f32)]
+    with _tpu_trace():
+        assert ssm.ssm_scan_plan(
+            jax.ShapeDtypeStruct(*shapes[0]),
+            jax.ShapeDtypeStruct(*shapes[4])) == ((512, 128), None)
+        _compile(ssm.ssm_scan, *shapes)
+
+
+CASES["ssm_scan-1024-positions-5120x16"] = _ssm_scan_case
+
+
+def _sambay_case(which):
+    """The engine's programs over ``models/sambay.py`` at
+    ``benchmark/configs/phi-4-mini-flash-reasoning.json``'s sizes (all 32
+    layers, 200,064 rows of vocabulary, 128 slots x 4096, bfloat16): each
+    fits the chip beside the 13.5 GB it is handed, the step's attention
+    over the shared layer is eight calls of ``decode_attention`` and a
+    prefill's recurrences nine of ``ssm_scan``, and nothing the size of a
+    ring, the full layer or a recurrent state is copied."""
+    def run():
+        from benchmark import harness
+        from benchmark.tools import aot_compile_sambay as tool
+
+        config = harness.load_json(os.path.join(
+            ROOT, "benchmark", "configs",
+            "phi-4-mini-flash-reasoning.json"))
+        engine, params, state, keep, extra, sds = tool.engine_programs(
+            config, _one_chip())
+        assert config["engine"]["slots"] == 128
+        big = [a for side in state[:2] for a in side
+               if a.size * a.dtype.itemsize > 30e6]
+        assert sorted({a.shape for a in big}) == [
+            (128, 10, 512, 128), (128, 10, 4096, 128), (128, 16, 5120)]
+        with _tpu_trace():
+            if which == "step":
+                compiled = engine._step_fn.lower(params, state, keep,
+                                                 extra).compile()
+            else:
+                compiled = engine._prefill_fns[which].lower(
+                    *tool.prefill_shapes(params, state, which,
+                                         sds)).compile()
+        ma = compiled.memory_analysis()
+        assert 13.4e9 < ma.argument_size_in_bytes < 13.6e9
+        assert ma.temp_size_in_bytes < 0.6e9, ma.temp_size_in_bytes
+        assert ma.argument_size_in_bytes + ma.output_size_in_bytes \
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes < 15.5e9
+        text = compiled.as_text()
+        # the step: the eight readers of the shared layer; a prefill: the
+        # nine state-space layers' scans
+        assert text.count("tpu_custom_call") == (8 if which == "step"
+                                                 else 9)
+        copies = tool.cache_copies(text, (big, ()))
+        assert not copies, "%d copies of slot state, the first: %s" \
+            % (len(copies), copies[0][:200])
+    return run
+
+
+CASES["decode-step-phi-4-mini-flash-128-slots"] = _sambay_case("step")
+CASES["decode-prefill-1024-phi-4-mini-flash-128-slots"] = _sambay_case(1024)
+
+
 # -- the tests -----------------------------------------------------------------
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_a_described_v5e(case):

@@ -1,0 +1,109 @@
+#!/usr/bin/env python3
+"""Fingerprints (``perfdebug.fingerprint_text``) of the decode engine's
+``jit_step`` and every ``jit_prefill`` bucket as they lower for each decode
+configuration of the benchmark, at the configuration's own sizes: nothing
+is allocated and nothing runs.  A change to the engine or to the model
+protocol that is not meant to change a model's programs shows here as the
+same digests before and after.
+
+    JAX_PLATFORMS=cpu python tools/perf/program_fingerprints.py [config ...]
+"""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+
+def programs(config):
+    """(name, jitted function, argument shapes) of every program the
+    engine builds for ``config``."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import harness
+    from mxnet_tpu.serving import DecodeEngine
+
+    class Unbuilt(DecodeEngine):
+        def _fresh_state(self):
+            return None
+
+        def _warm(self, state):
+            return state
+
+    family = harness.find("families", config["family"])
+    ref = harness.find("reference", config["family"])
+    eng = config["engine"]
+    engine = Unbuilt(_model(config, family, ref), {}, slots=eng["slots"],
+                     prefill_buckets=eng["prefill_buckets"],
+                     autostart=False)
+    params = jax.eval_shape(
+        lambda: ref.init_weights(config, 0, jax.devices()[0]))
+    sds = jax.ShapeDtypeStruct
+    shapes = family.step_shapes(engine, params, sds) \
+        if hasattr(family, "step_shapes") else _gpt2_shapes(engine, params)
+    out = [("jit_step", engine._step_fn, shapes)]
+    for b in engine.prefill_buckets:
+        out.append(("jit_prefill %d" % b, engine._prefill_fns[b], (
+            shapes[0], shapes[1], sds((b,), jnp.int32), sds((), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32), sds((), jnp.float32),
+            sds((), jnp.uint32), sds((), jnp.bool_))))
+    return out
+
+
+def _model(config, family, ref):
+    """The model object each family hands the engine."""
+    import jax.numpy as jnp
+
+    if hasattr(family, "model_of"):
+        return family.model_of(config)
+    if config["family"] == "exaone_moe_engine":
+        from mxnet_tpu.models import exaone_moe as xm
+
+        return xm.ExaoneMoE(family.model_config(xm, ref.sizes(config)),
+                            jnp.dtype(config["precision"]["kv_cache"]))
+    from mxnet_tpu.models import transformer_lm as tlm
+
+    return tlm.LMConfig(*ref.sizes(config), eos_id=ref.sizes(config)[0])
+
+
+def _gpt2_shapes(engine, params):
+    """``families/decode_engine.py`` ``scratch_bytes`` writes these out by
+    hand: the same shapes."""
+    import jax
+    import jax.numpy as jnp
+
+    sds = jax.ShapeDtypeStruct
+    cfg, s = engine.cfg, engine.slots
+    kv = tuple(sds((s, cfg.max_len, cfg.heads, cfg.embed // cfg.heads),
+                   jnp.float32) for _ in range(cfg.layers))
+    state = (kv, kv, sds((s,), jnp.int32), sds((s,), jnp.int32),
+             sds((s,), jnp.int32), sds((s,), jnp.bool_),
+             sds((s,), jnp.float32), sds((s,), jnp.uint32))
+    params = jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), params)
+    return params, state, sds((s,), jnp.bool_)
+
+
+def main():
+    from benchmark import harness
+    from mxnet_tpu import perfdebug
+
+    names = sys.argv[1:] or sorted(
+        f[:-5] for f in os.listdir(os.path.join(ROOT, "benchmark",
+                                                "configs")))
+    for name in names:
+        config = harness.load_json(os.path.join(
+            ROOT, "benchmark", "configs", name + ".json"))
+        if "engine" not in config:
+            continue
+        for what, fn, shapes in programs(config):
+            print("%s %s %s" % (name, what, perfdebug.fingerprint_text(
+                fn.lower(*shapes).as_text())), flush=True)
+
+
+if __name__ == "__main__":
+    main()
