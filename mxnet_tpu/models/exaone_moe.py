@@ -53,6 +53,8 @@ back (PERF.md section 6, PR 27).  The decode step reads a full layer
 through :func:`ops.attention.decode_attention`: on the TPU only the blocks
 of rows at or below each slot's length (PERF.md section 6, PR 28); a ring
 is read whole, every row of it live once a session is past the window.
+A step's new row goes into either kind through
+:func:`ops.attention.write_slot_rows` (PERF.md section 6, PR 32).
 
 :func:`forward_logits` is the in-repo plain reference: float32, ``highest``
 precision, no cache, one sequence, written out on its own.  Prefill and
@@ -69,12 +71,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.attention import decode_attention, decode_attention_plan
+from ..ops.attention import (decode_attention, decode_attention_plan,
+                             write_slot_rows)
 from .transformer_lm import CacheLayer
 
 __all__ = ["ExaoneConfig", "ExaoneMoE", "init_params",
-           "forward_logits", "sparse_mlp", "route", "routed_experts",
-           "write_ring", "write_full"]
+           "forward_logits", "sparse_mlp", "route", "routed_experts"]
 
 #: ``layers`` is how many are held; ``layer_types`` / ``mlp_types`` name
 #: each ("sliding_attention" | "full_attention", "dense" | "sparse").
@@ -252,31 +254,6 @@ def forward_logits(cfg, params, tokens, with_choices=False):
             x = x + y
         logits = _rms(x, params["ln_f"]) @ params["head"]
     return (logits, choices) if with_choices else logits
-
-
-# -- cache writes --------------------------------------------------------------
-def write_ring(cache, rows, at):
-    """``cache (S, n, W, d)`` with ``rows[i] (n, d)`` at ``[i, :, at[i]]``:
-    a select over the whole ring, in place under donation.  A ring is small
-    (W rows a slot), so one pass over it costs less than S separate writes
-    (PERF.md section 6, PR 27)."""
-    hit = jnp.arange(cache.shape[2])[None, :] == at[:, None]
-    return jnp.where(hit[:, None, :, None],
-                     rows[:, :, None].astype(cache.dtype), cache)
-
-
-@jax.jit
-def write_full(cache, rows, at):
-    """``cache (S, n, max_len, d)`` with ``rows[i] (n, d)`` at
-    ``[i, :, at[i]]``: one update-slice a slot, in place in whatever layout
-    the cache lives in (:func:`transformer_lm.write_rows` has why, and why
-    this is a ``jit`` of its own); a pass over all ``max_len`` rows would
-    move the whole cache."""
-    pieces = jnp.split(rows[:, :, None].astype(cache.dtype), cache.shape[0])
-    for i, piece in enumerate(pieces):
-        cache = jax.lax.dynamic_update_slice(
-            cache, piece, (i, 0, at[i], 0), allow_negative_indices=False)
-    return cache
 
 
 # -- the block, shared by prefill and decode step ------------------------------
@@ -458,16 +435,16 @@ class ExaoneMoE:
             q = _grouped(cfg, q)
             if cfg.layer_types[l] == "sliding_attention":
                 at = pos % cfg.window
-                ck = write_ring(cache_k[l], k, at)
-                cv = write_ring(cache_v[l], v, at)
+                ck = write_slot_rows(cache_k[l], k, at)
+                cv = write_slot_rows(cache_v[l], v, at)
                 new_k[l], new_v[l] = ck, cv
                 scores = jnp.einsum("skgd,skmd->skgm", q, ck,
                                     preferred_element_type=jnp.float32) \
                     * scale
                 return _softmax_ctx(scores, ring_mask, cv, "skgm,skmd->skgd")
             # a full layer reads the rows each slot holds, in blocks
-            ck = write_full(cache_k[l], k, pos)
-            cv = write_full(cache_v[l], v, pos)
+            ck = write_slot_rows(cache_k[l], k, pos)
+            cv = write_slot_rows(cache_v[l], v, pos)
             new_k[l], new_v[l] = ck, cv
             blocks.append(decode_attention_plan(q, ck)[0])
             return decode_attention(q, ck, cv, pos, scale)

@@ -82,9 +82,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.attention import decode_attention
+from ..ops.attention import decode_attention, write_slot_rows
 from ..ops.ssm import ssm_scan
-from .exaone_moe import write_full
 from .transformer_lm import CacheLayer, StateLayer
 
 __all__ = ["SambaYConfig", "SambaY", "layer_kinds", "init_params",
@@ -499,12 +498,11 @@ class _Step:
         if kind == "window":
             i = model.entry[l]
             at = pos % self.cfg.window
-            # one update-slice a slot, not ``exaone_moe.write_ring``'s
-            # pass over the ring: at 128 slots of 512 rows the pass took
-            # 0.52 ms an array on the chip, the update-slices 0.17
-            # (PERF.md section 6, PR 31)
-            ck = self.firsts[i] = write_full(self.firsts[i], k, at)
-            cv = self.seconds[i] = write_full(self.seconds[i], v, at)
+            # the slot's one row, not a select over the whole ring: at
+            # 128 slots of 512 rows that pass took 0.51 ms an array on
+            # the chip, the row writer 0.02 (PERF.md section 6, PR 32)
+            ck = self.firsts[i] = write_slot_rows(self.firsts[i], k, at)
+            cv = self.seconds[i] = write_slot_rows(self.seconds[i], v, at)
             scores = jnp.einsum("sgjd,sgmd->sgjm", q, ck,
                                 preferred_element_type=jnp.float32) \
                 * self.scale
@@ -512,8 +510,8 @@ class _Step:
                                 "sgjm,sgmd->sgjd")
         i = model.entry[model.shared]
         if kind == "full":
-            self.firsts[i] = write_full(self.firsts[i], k, pos)
-            self.seconds[i] = write_full(self.seconds[i], v, pos)
+            self.firsts[i] = write_slot_rows(self.firsts[i], k, pos)
+            self.seconds[i] = write_slot_rows(self.seconds[i], v, pos)
         # the full layer and every cross layer read the rows each slot
         # holds of the same two arrays
         return decode_attention(q, self.firsts[i], self.seconds[i], pos,

@@ -27,6 +27,11 @@ Design:
   rows below each slot's length (``_decode_pallas``), else the two einsums
   and the softmax over every row (``_decode_xla``); not differentiated.
 
+* ``write_slot_rows`` — the decode step's one new K (or V) row a slot,
+  put into a heads-major cache: on a TPU trace one Pallas kernel an array
+  with every slot's tile of rows in flight at once (``_slot_write_pallas``),
+  else one update-slice a slot (``_slot_write_xla``).
+
 Shapes follow (batch, heads, seq, head_dim) throughout.
 """
 
@@ -567,6 +572,204 @@ def decode_attention(q, cache_k, cache_v, lengths, scale):
                               block)
     count_kernel_path("decode_attention", "xla", reason)
     return _decode_xla(q, cache_k, cache_v, lengths, scale)
+
+
+# ---------------------------------------------------------------------------
+# slot rows: one new row a slot into a heads-major cache
+# ---------------------------------------------------------------------------
+
+#: bytes of VMEM the row writer's tiles may take: every slot's tile at once
+#: where they fit, else two buffers of half as much, one group of slots
+#: fetched while the other is changed and written back; with the rows
+#: beside them the kernel stays inside the 16 MiB one may use on a v5e
+_SLOT_WRITE_TILE_BYTES = 12 << 20
+#: ... and of the rows themselves, which the kernel holds whole
+_SLOT_WRITE_ROWS_BYTES = 2 << 20
+
+
+def _slot_write_xla(cache, rows, at):
+    """One update-slice a slot, in place in whatever layout the cache
+    lives in (:func:`transformer_lm.write_rows` has why); a pass over all
+    ``R`` rows would move the whole cache."""
+    pieces = jnp.split(rows[:, :, None], cache.shape[0])
+    for i, piece in enumerate(pieces):
+        cache = jax.lax.dynamic_update_slice(
+            cache, piece, (i, 0, at[i], 0), allow_negative_indices=False)
+    return cache
+
+
+def _slot_write_tile(dtype):
+    """Rows of the least tile of ``dtype`` that can be written alone: the
+    sublanes of a vector register times the values packed in one."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def _slot_write_kernel(at_ref, rows_hbm, cache_hbm, out_hbm, tiles, rows,
+                       fetched, sems):
+    """One invocation an array.  ``cache_hbm`` and ``out_hbm`` are the
+    same buffer and stay in HBM; what moves is, of each slot, the aligned
+    ``(n, tile, d)`` tile that holds row ``at[i]`` (a bfloat16 row is half
+    a packed sublane, so the tile and not the row is the least that can be
+    written).  A group's fetches are all started before one is waited for;
+    then a slot at a time the fetch is waited for, the row replaced in
+    VMEM and the write-back started, so nothing waits in turn but the
+    scalar core that issues them.  A fetch has a semaphore of its own
+    (``fetched``): copies of one size on one semaphore cannot be told
+    apart, and a tile must not be changed before its own copy has landed.
+    The write-backs of a buffer share one, since they are only ever waited
+    for all together.  With more than one group (``tiles`` is then two
+    buffers of a group) the next group's fetches are in flight meanwhile,
+    into the other buffer."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, n, _, d = cache_hbm.shape
+    group, tile = tiles.shape[1], tiles.shape[3]
+    groups = -(-s // group)
+    written, rows_in = sems.at[0], sems.at[1, 0]
+
+    def tile_of(ref, i):
+        first = pl.multiple_of((at_ref[i] // tile) * tile, tile)
+        return ref.at[i, :, pl.ds(first, tile), :]
+
+    def fetch(g, j):
+        return pltpu.make_async_copy(tile_of(cache_hbm, g * group + j),
+                                     tiles.at[g % 2, j], fetched.at[g % 2, j])
+
+    def write_back(g, j):
+        return pltpu.make_async_copy(tiles.at[g % 2, j],
+                                     tile_of(out_hbm, g * group + j),
+                                     written.at[g % 2])
+
+    def each_slot_of(g, body):
+        def step(j, carry):
+            body(g, j)
+            return carry
+        jax.lax.fori_loop(0, min(group, s - g * group), step, 0)
+
+    def through_vmem(g, j):
+        i = g * group + j
+        fetch(g, j).wait()
+        hit = jax.lax.broadcasted_iota(jnp.int32, (tile, d), 0) \
+            == at_ref[i] % tile
+        for h in range(n):
+            row = jnp.broadcast_to(rows[i, pl.ds(h, 1), :], (tile, d))
+            tiles[g % 2, j, h] = jnp.where(hit, row, tiles[g % 2, j, h])
+        write_back(g, j).start()
+
+    all_rows = pltpu.make_async_copy(rows_hbm, rows, rows_in)
+    all_rows.start()
+    each_slot_of(0, lambda g, j: fetch(g, j).start())
+    all_rows.wait()
+    for g in range(groups):
+        if g + 1 < groups:
+            if g:
+                # the buffer the next group lands in is the last group's
+                each_slot_of(g - 1, lambda g, j: write_back(g, j).wait())
+            each_slot_of(g + 1, lambda g, j: fetch(g, j).start())
+        each_slot_of(g, through_vmem)
+    for g in range(max(0, groups - 2), groups):
+        each_slot_of(g, lambda g, j: write_back(g, j).wait())
+
+
+def _slot_write_pallas(cache, rows, at, group, interpret=False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, n, _, d = cache.shape
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    buffers = 1 if group >= s else 2
+    return pl.pallas_call(
+        _slot_write_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(),
+            in_specs=[hbm, hbm],
+            out_specs=hbm,
+            scratch_shapes=[
+                pltpu.VMEM((buffers, group, n,
+                            _slot_write_tile(cache.dtype), d), cache.dtype),
+                pltpu.VMEM((s, n, d), cache.dtype),
+                pltpu.SemaphoreType.DMA((buffers, group)),
+                pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+        # operands: at, rows, cache; the cache is written where it lies
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=16 << 20),
+        name="slot_write",
+        interpret=interpret,
+    )(at, rows, cache)
+
+
+def write_slot_rows_plan(cache, rows):
+    """``(group, reason)``: the slots whose tiles the Pallas kernel holds
+    in VMEM at once with ``reason`` None (all ``S`` where they fit, else a
+    group that is fetched while another is written back), or
+    ``(0, reason)`` with why the call takes the plain path
+    (``ops.kernel_path`` reasons).  Decided from what can be seen here:
+    bfloat16 and float32 caches only (``dtype``), rows of whole 128-lane
+    width (``lanes``: a narrower cache lives rows-minor on the chip, as
+    :func:`decode_attention_plan` has it), ``R`` a multiple of the tile's
+    rows (``tile``), and the rows and one tile a buffer inside the
+    kernel's VMEM (``vmem``)."""
+    from .registry import on_tpu
+
+    s, n, r, d = cache.shape
+    if rows.shape != (s, n, d):
+        raise ValueError("rows %s for a cache %s: one (n, d) a slot"
+                         % (rows.shape, cache.shape))
+    if not on_tpu():
+        return 0, "not_tpu"
+    if cache.dtype not in (jnp.bfloat16, jnp.float32):
+        return 0, "dtype"
+    if d % 128:
+        return 0, "lanes"
+    tile = _slot_write_tile(cache.dtype)
+    if r % tile:
+        return 0, "tile"
+    itemsize = cache.dtype.itemsize
+    tile_bytes = n * tile * d * itemsize
+    # in VMEM a slot's ``(n, d)`` rows fill whole tiles of ``tile`` sublanes
+    rows_bytes = s * -(-n // tile) * tile * d * itemsize
+    if rows_bytes > _SLOT_WRITE_ROWS_BYTES \
+            or 2 * tile_bytes > _SLOT_WRITE_TILE_BYTES:
+        return 0, "vmem"
+    if s * tile_bytes <= _SLOT_WRITE_TILE_BYTES:
+        return s, None
+    return _SLOT_WRITE_TILE_BYTES // (2 * tile_bytes), None
+
+
+def write_slot_rows(cache, rows, at):
+    """``cache (S, n, R, d)`` with ``rows[i] (n, d)``, cast to the cache's
+    dtype, at ``[i, :, at[i]]``; ``at (S,)`` int32 within ``0 .. R - 1``
+    (a ring's caller passes ``pos % R``).  Every other element is bit for
+    bit what it was, and under donation the cache is written in place.
+
+    On a TPU trace one Pallas kernel an array moves each slot's tile of
+    rows through VMEM, all slots' tiles in flight at once
+    (:func:`write_slot_rows_plan` has the sizes and the refusals);
+    elsewhere one update-slice a slot.  The choice is counted under
+    ``ops.kernel_path``."""
+    from .registry import count_kernel_path
+
+    group, reason = write_slot_rows_plan(cache, rows)
+    if reason is None:
+        count_kernel_path("slot_write", "pallas", "ok")
+    else:
+        count_kernel_path("slot_write", "xla", reason)
+    return _write_slot_rows(cache, rows, at, group)
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def _write_slot_rows(cache, rows, at, group):
+    """Either path as the plan chose (``group`` 0: the update-slices).
+    Jitted on its own so that a step which writes many arrays traces the
+    writer once a shape, not once a layer and array: an engine traces its
+    step twice at every start, the server's set-up."""
+    rows = rows.astype(cache.dtype)
+    if group:
+        return _slot_write_pallas(cache, rows, at, group)
+    return _slot_write_xla(cache, rows, at)
 
 
 # ---------------------------------------------------------------------------

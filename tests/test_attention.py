@@ -7,6 +7,8 @@ reference's cross-backend ``check_consistency`` harness
 (``python/mxnet/test_utils.py:677``).
 """
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,7 +17,9 @@ import pytest
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops.attention import (
     _attn_reference, _decode_pallas, _decode_xla, _flash_pallas,
-    _flash_scan, decode_attention, decode_attention_plan, flash_attention)
+    _flash_scan, _slot_write_pallas, _slot_write_xla, decode_attention,
+    decode_attention_plan, flash_attention, write_slot_rows,
+    write_slot_rows_plan)
 
 
 def _rand_qkv(b=2, h=3, lq=64, lk=64, d=16, dtype=np.float32, seed=0):
@@ -203,6 +207,24 @@ def test_ulysses_head_count_guard():
                                jnp.asarray(v), mesh, seq_axis="data")
 
 
+@contextlib.contextmanager
+def _counting(label):
+    """Yields a function that gives how often ``ops.kernel_path{label}``
+    was counted since the block was entered."""
+    def total():
+        return telemetry.snapshot()["counters"].get(
+            "ops.kernel_path", {}).get(label, 0)
+
+    was = telemetry.enabled()
+    telemetry.enable()
+    before = total()
+    try:
+        yield lambda: total() - before
+    finally:
+        if not was:
+            telemetry.disable()
+
+
 # -- decode attention ----------------------------------------------------------
 _ROWS, _BLOCK = 64, 16
 
@@ -258,20 +280,9 @@ def test_decode_attention_off_the_tpu_reads_every_row_and_says_so():
     q, k, v, _, _, lengths = _decode_case([3, 40], 8, 16, jnp.float32)
     assert decode_attention_plan(q, k) == (_ROWS, "not_tpu")
 
-    def counted():
-        return telemetry.snapshot()["counters"].get(
-            "ops.kernel_path", {}).get(
-                "op=decode_attention,path=xla,reason=not_tpu", 0)
-
-    was = telemetry.enabled()
-    telemetry.enable()
-    try:
-        before = counted()
+    with _counting("op=decode_attention,path=xla,reason=not_tpu") as counted:
         got = decode_attention(q, k, v, lengths, 0.25)
-        assert counted() == before + 1
-    finally:
-        if not was:
-            telemetry.disable()
+    assert counted() == 1
     np.testing.assert_array_equal(
         np.asarray(got), np.asarray(_decode_xla(q, k, v, lengths, 0.25)))
 
@@ -298,5 +309,113 @@ def test_decode_attention_plan_on_a_tpu_trace(shape, dtype, want):
     token = registry.trace_device.set("tpu")
     try:
         assert decode_attention_plan(q, cache) == want
+    finally:
+        registry.trace_device.reset(token)
+
+
+# -- slot rows: one new row a slot into a heads-major cache --------------------
+def _bits(x):
+    """The array's bytes as integers: equality that a NaN cannot pass or
+    fail by being a NaN, and that tells -0.0 from 0.0."""
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+def _slot_write_case(at, rows_a_slot, dtype, n=3, d=128, seed=0):
+    """``(cache, rows, at, want)``: a cache of seeded noise with a NaN, an
+    infinity and a negative zero a slot among what must not change, and
+    ``cache.at[arange(S), :, at].set(rows)`` computed on the host."""
+    rs = np.random.RandomState(seed)
+    s = len(at)
+    cache = rs.normal(0, 1, (s, n, rows_a_slot, d)).astype(np.float32)
+    cache[:, 0, 0, 0], cache[:, 1, -1, 1], cache[:, 2, 1, 2] = \
+        np.nan, np.inf, -0.0
+    cache = jnp.asarray(cache, dtype)
+    rows = jnp.asarray(rs.normal(0, 1, (s, n, d)), dtype)
+    want = np.array(cache)
+    want[np.arange(s), :, np.asarray(at)] = np.asarray(rows)
+    return cache, rows, jnp.asarray(at, jnp.int32), want
+
+
+_SLOT_WRITE_CASES = {
+    # rows of a ring and of a full layer; at 0, the two sides of a
+    # bfloat16 tile's edge (15, 16), of a float32 tile's (7, 8), R - 1
+    "ring-edges": ([0, 15, 16, 31, 7, 8], 32, 6),
+    "ring-wrapped": ([p % 32 for p in (0, 31, 32, 47, 48, 1000, 4095)],
+                     32, 7),
+    "full-edges": ([0, 15, 16, 255, 128, 17], 256, 6),
+    "full-one-tile-for-all": ([40] * 5, 256, 5),
+    # slot counts the group does not divide: 7 in groups of 3, of 2 (the
+    # last group one slot), and a group a slot (both buffers by turns)
+    "groups-of-3": ([5, 50, 21, 33, 62, 16, 47], 64, 3),
+    "groups-of-2": ([5, 50, 21, 33, 62, 16, 47], 64, 2),
+    "groups-of-1": ([63, 0, 15, 16, 1], 64, 1),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("case", sorted(_SLOT_WRITE_CASES))
+def test_slot_write_kernel_interpret_writes_one_row_a_slot(case, dtype):
+    """The kernel (interpreter) against the scatter, bit for bit: the row
+    where it belongs, every other element what it was."""
+    at, rows_a_slot, group = _SLOT_WRITE_CASES[case]
+    cache, rows, at, want = _slot_write_case(at, rows_a_slot, dtype)
+    got = _slot_write_pallas(cache, rows, at, group, interpret=True)
+    assert got.dtype == cache.dtype and got.shape == cache.shape
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    # the plain path is held to the same
+    np.testing.assert_array_equal(
+        _bits(_slot_write_xla(cache, rows, at)), _bits(want))
+
+
+@pytest.mark.parametrize("rows_dtype", [jnp.float32, jnp.bfloat16])
+def test_write_slot_rows_off_the_tpu_is_the_update_slices_and_says_so(
+        rows_dtype):
+    """``exaone_moe.write_full``'s semantics where it lives now: rows are
+    cast to the cache's dtype, the call is counted as the plain path."""
+    cache, rows, at, _ = _slot_write_case([3, 40, 0, 63], 64, jnp.bfloat16)
+    rows = rows.astype(rows_dtype) * 1.001
+    assert write_slot_rows_plan(cache, rows) == (0, "not_tpu")
+    with _counting("op=slot_write,path=xla,reason=not_tpu") as counted:
+        got = write_slot_rows(cache, rows, at)
+    assert counted() == 1
+    want = cache.at[jnp.arange(4), :, at].set(rows.astype(jnp.bfloat16))
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("shape,dtype,want,counted_as", [
+    # the Phi-4-mini-flash cell's rings and shared layer, K-EXAONE's full
+    # layer and rings: every slot's tile at once
+    ((128, 10, 512, 128), jnp.bfloat16, (128, None), "pallas,reason=ok"),
+    ((128, 10, 4096, 128), jnp.bfloat16, (128, None), "pallas,reason=ok"),
+    ((256, 8, 4096, 128), jnp.bfloat16, (256, None), "pallas,reason=ok"),
+    ((256, 8, 128, 128), jnp.bfloat16, (256, None), "pallas,reason=ok"),
+    ((128, 10, 512, 128), jnp.float32, (128, None), "pallas,reason=ok"),
+    # 512 slots' tiles are 16.8 MB: two buffers of 6 MiB, 192 slots each
+    ((512, 8, 4096, 128), jnp.bfloat16, (192, None), "pallas,reason=ok"),
+    ((12, 20, 1024, 64), jnp.float32, (0, "lanes"), "xla,reason=lanes"),
+    ((8, 8, 4096, 128), jnp.float16, (0, "dtype"), "xla,reason=dtype"),
+    ((8, 8, 200, 128), jnp.bfloat16, (0, "tile"), "xla,reason=tile"),
+    ((8, 8, 200, 128), jnp.float32, (8, None), "pallas,reason=ok"),
+    ((4096, 8, 128, 128), jnp.bfloat16, (0, "vmem"), "xla,reason=vmem"),
+])
+def test_write_slot_rows_plan_on_a_tpu_trace(shape, dtype, want,
+                                             counted_as):
+    """What the plan decides from shapes and dtype, and the count each
+    decision leaves (the trace is abstract: nothing runs)."""
+    from mxnet_tpu.ops import registry
+
+    s, n, _, d = shape
+    cache = jax.ShapeDtypeStruct(shape, dtype)
+    rows = jax.ShapeDtypeStruct((s, n, d), dtype)
+    at = jax.ShapeDtypeStruct((s,), jnp.int32)
+    token = registry.trace_device.set("tpu")
+    try:
+        assert write_slot_rows_plan(cache, rows) == want
+        with _counting("op=slot_write,path=" + counted_as) as counted:
+            out = jax.eval_shape(write_slot_rows, cache, rows, at)
+        assert counted() == 1
+        assert (out.shape, out.dtype) == (shape, dtype)
     finally:
         registry.trace_device.reset(token)

@@ -1081,22 +1081,46 @@ def test_queued_cancel_released_while_all_slots_busy():
     for a slot to free."""
     eng = _engine(slots=1, max_queue=2)
     try:
-        # admitted (prefill done) == slot taken; the first token
-        # callback signals it without sleep polling
-        admitted = threading.Event()
-        a = eng.submit(PROMPT, max_new_tokens=27,
-                       on_token=lambda _t: admitted.set())
-        assert admitted.wait(60), "session A was never admitted"
-        q1 = eng.submit(PROMPT, max_new_tokens=27)
-        q2 = eng.submit(PROMPT, max_new_tokens=27)
-        with pytest.raises(Overloaded):
-            eng.submit(PROMPT, max_new_tokens=2)  # bound reached
-        assert q1.cancel() and q2.cancel()
-        with pytest.raises(MXNetError):
-            q1.result(30)  # resolved while A still decodes
-        assert not a.done()
-        # the bound released mid-generation: a new submit is admitted
-        fresh = eng.submit(PROMPT, max_new_tokens=2)
+        # the token callback runs on the engine's thread, between two
+        # steps.  At the first token (prefill done == slot taken) it holds
+        # the engine until q1 and q2 are queued, the bound is seen and both
+        # are cancelled: on a loaded host A's 27 tiny steps can otherwise
+        # be over before this thread has queued them.  Released, the
+        # engine purges the queue at its next admission scans; at the
+        # fourth token it is held again, with A mid-generation, until the
+        # assertions on that are done
+        admitted, queued = threading.Event(), threading.Event()
+        mid, checked = threading.Event(), threading.Event()
+        seen = []
+
+        def on_tok(t):
+            seen.append(t)
+            if len(seen) == 1:
+                admitted.set()
+                queued.wait(60)
+            elif len(seen) == 4:
+                mid.set()
+                checked.wait(60)
+
+        a = eng.submit(PROMPT, max_new_tokens=27, on_token=on_tok)
+        try:
+            assert admitted.wait(60), "session A was never admitted"
+            q1 = eng.submit(PROMPT, max_new_tokens=27)
+            q2 = eng.submit(PROMPT, max_new_tokens=27)
+            with pytest.raises(Overloaded):
+                eng.submit(PROMPT, max_new_tokens=2)  # bound reached
+            assert q1.cancel() and q2.cancel()
+        finally:
+            queued.set()
+        try:
+            assert mid.wait(60), "session A never reached its 4th token"
+            with pytest.raises(MXNetError):
+                q1.result(30)  # resolved while A still decodes
+            assert not a.done()
+            # the bound released mid-generation: a new submit is admitted
+            fresh = eng.submit(PROMPT, max_new_tokens=2)
+        finally:
+            checked.set()
         a.result(120)
         assert len(fresh.result(60)) == 2
     finally:

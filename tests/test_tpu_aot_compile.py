@@ -421,7 +421,10 @@ def _exaone_step_with_the_kernel():
         compiled = engine._step_fn.lower(params, state, keep,
                                          extra).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 1
+    # the full layer's attention, and the row writes of K and V: the full
+    # layer's and the four rings'
+    assert text.count("tpu_custom_call") == 1 + 2 * 5
+    assert "dynamic-update-slice" not in text
     assert "f32[256,8,8,4096]" not in text
     assert state[0][3].shape == (256, 8, 4096, 128)
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6
@@ -447,19 +450,20 @@ def _decode_attention_pairs():
     with _tpu_trace():
         assert attention.decode_attention_plan(q, cache) == (256, None)
         text = _decode_attention_over_a_donated_cache(q, cache)
-    assert "tpu_custom_call" in text
+    # the two row writes and the attention, and no update-slice left
+    assert text.count("tpu_custom_call") == 3
+    assert "dynamic-update-slice" not in text
     assert not re.findall(r"= bf16\[128,10,4096,128\]\{[^}]*\} copy\(", text)
 
 
 def _decode_attention_over_a_donated_cache(q, cache):
     """Compiled text of a step's use of the kernel: the new row written
     into the donated cache, then the attention over it."""
-    from mxnet_tpu.models.exaone_moe import write_full
-
     one_chip = _one_chip()
 
     def step(q, ck, cv, k, v, n):
-        ck, cv = write_full(ck, k, n), write_full(cv, v, n)
+        ck = attention.write_slot_rows(ck, k, n)
+        cv = attention.write_slot_rows(cv, v, n)
         return attention._decode_pallas(
             q, ck, cv, n, 0.125, 256), ck, cv
 
@@ -495,6 +499,48 @@ def _decode_attention_lanes():
 
 CASES["decode_attention-refuses-rows-narrower-than-the-lanes"] = \
     _decode_attention_lanes
+
+
+def _slot_write_case(shape, group):
+    """``write_slot_rows`` alone over a donated cache at the shapes the
+    decode cells write (the Phi-4-mini-flash rings and shared layer,
+    K-EXAONE's full layer and rings: the plan holds every slot's tile at
+    once) and at more slots than the kernel's VMEM holds tiles for (two
+    buffers of ``group`` slots, the last group shorter).  The compiler
+    takes the kernel, nothing the size of the cache is copied and nothing
+    is held beside it (the parent's whole step took 0.13 GB of temporaries
+    in the Phi-4-mini-flash cell; the tiles are the kernel's own VMEM)."""
+    def run():
+        import re
+
+        s, n, _, d = shape
+        one_chip = _one_chip()
+        cache, rows, at = (
+            jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+            for sh, dt in ((shape, jnp.bfloat16), ((s, n, d), jnp.bfloat16),
+                           ((s,), jnp.int32)))
+        with _tpu_trace():
+            assert attention.write_slot_rows_plan(cache, rows) \
+                == (group, None)
+            compiled = jax.jit(attention.write_slot_rows,
+                               donate_argnums=(0,)).lower(
+                                   cache, rows, at).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1 and "slot_write" in text
+        assert "dynamic-update-slice" not in text
+        assert not re.findall(
+            r"= bf16\[%d,%d,%d,%d\]\{[^}]*\} copy\(" % shape, text)
+        ma = compiled.memory_analysis()
+        assert ma.alias_size_in_bytes >= cache.size * 2
+        assert ma.temp_size_in_bytes < 1e6, ma.temp_size_in_bytes
+    return run
+
+
+for _shape, _group in [((128, 10, 512, 128), 128), ((128, 10, 4096, 128), 128),
+                       ((256, 8, 4096, 128), 256), ((256, 8, 128, 128), 256),
+                       ((512, 8, 4096, 128), 192)]:
+    CASES["slot_write-%dx%dx%dx%d" % _shape] = _slot_write_case(_shape,
+                                                                _group)
 
 
 def _ssm_scan_case():
@@ -554,10 +600,15 @@ def _sambay_case(which):
         assert ma.argument_size_in_bytes + ma.output_size_in_bytes \
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes < 15.5e9
         text = compiled.as_text()
-        # the step: the eight readers of the shared layer; a prefill: the
-        # nine state-space layers' scans
-        assert text.count("tpu_custom_call") == (8 if which == "step"
+        # the step: the eight readers of the shared layer and the row
+        # writes of K and V, eight rings' and the shared layer's; a
+        # prefill: the nine state-space layers' scans
+        assert text.count("tpu_custom_call") == (8 + 2 * 9 if which == "step"
                                                  else 9)
+        if which == "step":
+            assert "dynamic-update-slice" not in text
+            # no more beside its arguments than the parent's step took
+            assert ma.temp_size_in_bytes < 0.14e9, ma.temp_size_in_bytes
         copies = tool.cache_copies(text, (big, ()))
         assert not copies, "%d copies of slot state, the first: %s" \
             % (len(copies), copies[0][:200])
