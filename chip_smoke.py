@@ -501,8 +501,11 @@ def _flash_kernel_check(device, heads, head_dim, lengths, seed):
     head_dim)``, through its public entry so the dispatch that users get
     decides: on a TPU it must lower to ``tpu_custom_call`` and agree with
     the quadratic reference; elsewhere the counted reason says why the
-    XLA path ran.  (The engine's own prefill still scores with a plain
-    einsum; ROADMAP S3 moves it onto this kernel.)"""
+    XLA path ran.  (The prefill of the LM this smoke serves,
+    ``transformer_lm``, still scores with a plain einsum, as do
+    ``exaone_moe``'s and ``sambay``'s; ``models/smallthinker.py``'s calls
+    this kernel, with a window and grouped K/V heads, for every layer.
+    ROADMAP S4's third step moves the others.)"""
     import jax
     import jax.numpy as jnp
     import numpy as np

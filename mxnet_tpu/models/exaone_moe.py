@@ -23,12 +23,25 @@ and gets every one of them.  :func:`sparse_mlp` over each share, the shared
 expert counted once, adds up to the uncut layer
 (``tests/test_exaone_moe.py``).
 
-**The routed product** is :func:`routed_experts`: every held expert's
-SwiGLU over all rows, combined with weights that are zero where a row did
-not choose the expert.  Fixed shapes, no sort, no capacity.  At a decode
-step (some hundred rows) the held experts' weights are what the product
-reads and their bytes bind it; PERF.md section 6 (PR 27) has what was
-measured against it.
+**The routed product** is :func:`routed_experts`, which chooses one of two
+from the shapes it is given (:func:`expert_product`; no option).  *Every*:
+every held expert's gated unit over all rows, combined with weights that
+are zero where a row did not choose the expert; fixed shapes, no sort.  At
+a decode step (some hundred rows) the held experts' weights are what the
+product reads and their bytes bind it; PERF.md section 6 (PR 27) has what
+was measured against it.  *Grouped*, for the many rows of a long prompt
+over many held experts: the picks sorted by expert and one product a group
+(``lax.ragged_dot``), which does the operations the routing asks for and
+not ``experts_held / top_k`` times as many.  Neither has a capacity and
+neither drops a pick at any imbalance.
+
+**Two routers, two gates** (``cfg.router``, ``cfg.activation``): ``sigmoid``
+with selection bias and scale as above, or ``softmax`` over the chosen
+(``chosen = top_k(h Wr)``, ``w = softmax`` of the chosen scores); the gated
+unit's activation is ``silu`` or ``relu``.  :func:`route` takes the rows
+the router reads, which need not be the rows the experts are fed
+(:mod:`~mxnet_tpu.models.smallthinker` routes a layer's input before its
+attention).
 
 **Precision.**  Weights and cache bfloat16 (whatever dtype ``params`` come
 in is used as it is: the tests run float32).  Every matrix product takes
@@ -51,8 +64,11 @@ the order both attention products read: compiled for the chip with
 positions first, every step copied every cache array into this order and
 back (PERF.md section 6, PR 27).  The decode step reads a full layer
 through :func:`ops.attention.decode_attention`: on the TPU only the blocks
-of rows at or below each slot's length (PERF.md section 6, PR 28); a ring
-is read whole, every row of it live once a session is past the window.
+of rows at or below each slot's length (PERF.md section 6, PR 28); this
+model's ring of 128 rows is read whole, every row of it live once a session
+is past the window (a ring of thousands of rows goes through the same
+kernel as a full layer: :mod:`~mxnet_tpu.models.smallthinker`, and PERF.md
+section 6, PR 33, has what the chip read for rings of 128 and 512 rows).
 A step's new row goes into either kind through
 :func:`ops.attention.write_slot_rows` (PERF.md section 6, PR 32).
 
@@ -76,17 +92,21 @@ from ..ops.attention import (decode_attention, decode_attention_plan,
 from .transformer_lm import CacheLayer
 
 __all__ = ["ExaoneConfig", "ExaoneMoE", "init_params",
-           "forward_logits", "sparse_mlp", "route", "routed_experts"]
+           "forward_logits", "sparse_mlp", "route", "routed_experts",
+           "expert_product"]
 
 #: ``layers`` is how many are held; ``layer_types`` / ``mlp_types`` name
 #: each ("sliding_attention" | "full_attention", "dense" | "sparse").
 #: ``first_expert`` / ``experts_held`` are this chip's share of
-#: ``num_experts``; ``vocab`` is the slice of the vocabulary held here.
+#: ``num_experts``; ``vocab`` is the slice of the vocabulary held here;
+#: ``router`` ("sigmoid" | "softmax") and ``activation`` ("silu" | "relu")
+#: name the expert layer's two choices.
 ExaoneConfig = namedtuple("ExaoneConfig", [
     "vocab", "embed", "heads", "kv_heads", "head_dim", "layers",
     "layer_types", "mlp_types", "dense_ffn", "expert_ffn", "num_experts",
     "top_k", "first_expert", "experts_held", "window", "rope_theta",
-    "routed_scale", "max_len", "eos_id"])
+    "routed_scale", "max_len", "eos_id", "router", "activation"],
+    defaults=("sigmoid", "silu"))
 
 EPS = 1e-5
 _NEG = jnp.float32(-1e30)
@@ -130,9 +150,9 @@ def init_params(cfg, seed=0, dtype=jnp.bfloat16):
 
 
 # -- pieces both the program and the reference are written from ----------------
-def _rms(x, g):
+def _rms(x, g, eps=EPS):
     x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + EPS) * g
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
 
 
 def _mm(a, w):
@@ -141,8 +161,11 @@ def _mm(a, w):
                    preferred_element_type=jnp.float32)
 
 
-def _swiglu(h, w):
-    a = jax.nn.silu(_mm(h, w["gate"])) * _mm(h, w["up"])
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _swiglu(h, w, act=jax.nn.silu):
+    a = act(_mm(h, w["gate"])) * _mm(h, w["up"])
     return _mm(a, w["down"])
 
 
@@ -158,10 +181,19 @@ def _rope(cfg, x, pos):
 
 
 def route(cfg, h, moe):
-    """``h (T, embed)`` float32 -> ``(chosen (T, top_k) int32 over all
-    num_experts, weights (T, top_k) float32)``."""
+    """``h (T, embed)`` float32, the rows the router reads -> ``(chosen (T,
+    top_k) int32 over all num_experts, weights (T, top_k) float32)``, by
+    the router ``cfg.router`` names."""
     with jax.default_matmul_precision("highest"):
-        s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32), moe["router"]))
+        s = jnp.dot(h.astype(jnp.float32), moe["router"])
+    if cfg.router == "softmax":
+        # a softmax over all the scores, renormed over the chosen, is the
+        # softmax over the chosen
+        picked, chosen = jax.lax.top_k(s, cfg.top_k)
+        return chosen.astype(jnp.int32), jax.nn.softmax(picked, axis=-1)
+    if cfg.router != "sigmoid":
+        raise ValueError("no router %r (sigmoid | softmax)" % (cfg.router,))
+    s = jax.nn.sigmoid(s)
     _, chosen = jax.lax.top_k(s + moe["bias"], cfg.top_k)
     picked = jnp.take_along_axis(s, chosen, axis=-1)
     w = picked / (picked.sum(-1, keepdims=True) + 1e-20) * cfg.routed_scale
@@ -176,34 +208,135 @@ def _combine(cfg, chosen, w):
     return (hit * w[..., None]).sum(1)
 
 
-def routed_experts(h, comb, moe):
-    """The held experts' part: ``sum_x comb[t, x] * E_x(h[t])``.  Every
-    held expert over every row, the combine weight applied before the down
-    projection so that experts and inner width contract in one product."""
+#: the grouped product takes over where the every-expert product's spare
+#: operations cost more than the sort, the gather and the ragged product's
+#: own overhead.  One layer alone on the v5e, every | grouped, ms
+#: (benchmark/tools/expert_product_variants.py; PERF.md section 6, PR 33):
+#: 64 held, 6 picked, 2560 -> 768: 48 rows 1.08 | 1.70, 256 1.19 | 2.96,
+#: 512 2.13 | 3.19, 1024 4.55 | 3.99, 2048 8.84 | 6.16, 4096 17.67 | 10.32,
+#: 8192 35.04 | 17.99: grouped from 1024 rows.  16 held, 8 picked, 6144 ->
+#: 2048 (K-EXAONE's share): 256 rows 1.90 | 4.15, 512 3.37 | 4.81, 1024 6.82
+#: | 6.79: twice the needed operations is never worth the sort, so a layer
+#: that holds under four times what a row picks keeps the every-expert
+#: product at any number of rows.
+GROUPED_FROM_ROWS = 1024
+GROUPED_FROM_RATIO = 4
+
+
+def expert_product(cfg, rows):
+    """"every" or "grouped": which product :func:`routed_experts` runs over
+    ``rows`` rows, from the shapes alone.  The every-expert product does
+    ``experts_held / top_k`` times the operations the routing asks for and
+    reads every held weight once; that is free while the weights' bytes
+    bind (a step's few rows) and is what a prompt's thousands of rows pay
+    for."""
+    if rows >= GROUPED_FROM_ROWS \
+            and cfg.experts_held >= GROUPED_FROM_RATIO * cfg.top_k:
+        return "grouped"
+    return "every"
+
+
+def _every_expert(act, h, comb, moe):
+    """``sum_x comb[t, x] * E_x(h[t])``: every held expert over every row,
+    the combine weight applied before the down projection so that experts
+    and inner width contract in one product."""
     dt = moe["gate"].dtype
     hb = h.astype(dt)
     g = jnp.einsum("te,xef->txf", hb, moe["gate"],
                    preferred_element_type=jnp.float32)
     u = jnp.einsum("te,xef->txf", hb, moe["up"],
                    preferred_element_type=jnp.float32)
-    a = (jax.nn.silu(g) * u * comb[:, :, None]).astype(dt)
+    a = (act(g) * u * comb[:, :, None]).astype(dt)
     return jnp.einsum("txf,xfe->te", a, moe["down"],
                       preferred_element_type=jnp.float32)
 
 
-def sparse_mlp(cfg, h, moe, shared=True):
+def _grouped_experts(cfg, act, h, chosen, w, moe):
+    """The same sum with the picks sorted by expert and one product a
+    group: a pick of an expert held elsewhere sorts behind every group and
+    adds nothing; no group has a capacity."""
+    dt = moe["gate"].dtype
+    t, k = chosen.shape
+    held = cfg.experts_held
+    local = chosen - cfg.first_expert
+    mine = (local >= 0) & (local < held)
+    key = jnp.where(mine, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    hs = h.astype(dt)[order // k]                       # (T top_k, embed)
+    ws = jnp.where(mine, w, 0.0).reshape(-1)[order]
+
+    def product(a, name):
+        return jax.lax.ragged_dot(a, moe[name], sizes,
+                                  preferred_element_type=jnp.float32)
+
+    a = (act(product(hs, "gate")) * product(hs, "up")
+         * ws[:, None]).astype(dt)
+    y = jnp.where((jnp.arange(t * k) < sizes.sum())[:, None],
+                  product(a, "down"), 0.0)
+    back = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    return y[back].reshape(t, k, -1).sum(1)
+
+
+def routed_experts(cfg, h, chosen, w, moe):
+    """The held experts' part of ``h (T, embed)``: ``sum over the chosen
+    and held x of w[t, x] * E_x(h[t])``, by the product
+    :func:`expert_product` chooses for ``T`` rows (counted under
+    ``ops.kernel_path``)."""
+    from ..ops.registry import count_kernel_path
+
+    act = _ACTIVATIONS[cfg.activation]
+    path = expert_product(cfg, h.shape[0])
+    count_kernel_path("routed_experts", path, "rows")
+    if path == "grouped":
+        with jax.named_scope("moe.group"):
+            return _grouped_experts(cfg, act, h, chosen, w, moe)
+    return _every_expert(act, h, _combine(cfg, chosen, w), moe)
+
+
+def sparse_mlp(cfg, h, moe, shared=True, router_input=None):
     """The sparse MLP of this share: ``(y (T, embed), chosen (T, top_k))``.
+    The router reads ``router_input`` (``h`` where none is given).
     ``shared=False`` leaves the shared expert out (a share other than the
-    one that counts it, when shares are added up)."""
+    one that counts it, when shares are added up; a model that has none)."""
     with jax.named_scope("moe.route"):
-        chosen, w = route(cfg, h, moe)
-        comb = _combine(cfg, chosen, w)
+        chosen, w = route(cfg, h if router_input is None else router_input,
+                          moe)
     with jax.named_scope("moe.experts"):
-        y = routed_experts(h, comb, moe)
+        y = routed_experts(cfg, h, chosen, w, moe)
     if shared:
         with jax.named_scope("moe.shared"):
             y = y + _swiglu(h, moe["shared"])
     return y, chosen
+
+
+def ring_src(length, window, p_len):
+    """Where a prefill's ring takes its rows from: ring row ``j`` holds the
+    last position below ``length`` that is ``j`` modulo the window; rows no
+    position has reached yet hold what the decode step never reads."""
+    return jnp.clip(
+        (length - 1) - ((length - 1 - jnp.arange(window)) % window),
+        0, p_len - 1)
+
+
+def count_picks(cfg, chosen, live):
+    """``(experts_held,)`` uint32: the picks the live rows gave each held
+    expert."""
+    hit = jax.nn.one_hot(chosen - cfg.first_expert, cfg.experts_held,
+                         dtype=jnp.uint32)
+    return (hit * live[:, None, None]).sum((0, 1))
+
+
+def routing_gauges(picks, steps):
+    """The gauges of ``picks (layers, experts_held)`` counted over ``steps``
+    steps: picks a held expert sees a step, and the busiest held expert's
+    picks over the mean's; none while nothing was counted."""
+    if not (steps and picks.sum()):
+        return {}
+    return {"serving.moe.tokens_per_expert":
+            float(picks.sum()) / (picks.size * steps),
+            "serving.moe.imbalance": float(picks.max() / picks.mean())}
 
 
 # -- the plain reference -------------------------------------------------------
@@ -361,13 +494,9 @@ class ExaoneMoE:
         out = {"moe_picks": picks.tolist(), "moe_picks_total": total,
                "rows": int(extra["rows"]), "steps": steps,
                "attn_blocks_read": read, "attn_blocks_held": held}
-        gauges = {}
-        if steps and picks.sum():
-            gauges = {
-                "serving.moe.tokens_per_expert":
-                    float(picks.sum()) / (picks.size * steps),
-                "serving.moe.local_share": float(picks.sum()) / total,
-                "serving.moe.imbalance": float(picks.max() / picks.mean())}
+        gauges = routing_gauges(picks, steps)
+        if gauges:
+            gauges["serving.moe.local_share"] = float(picks.sum()) / total
         if held:
             gauges["serving.attn.rows_read_share"] = read / held
         if gauges:
@@ -385,12 +514,7 @@ class ExaoneMoE:
         pos = jnp.arange(p_len)
         causal = pos[None, :] <= pos[:, None]
         near = pos[None, :] > pos[:, None] - cfg.window
-        # ring row j holds the last position below ``length`` that is j
-        # modulo the window; rows no position has reached yet hold what the
-        # decode step's mask never reads
-        ring_src = jnp.clip(
-            (length - 1) - ((length - 1 - jnp.arange(cfg.window))
-                            % cfg.window), 0, p_len - 1)
+        src = ring_src(length, cfg.window, p_len)
         ks, vs = [], []
 
         def attend(l, q, k, v):
@@ -402,7 +526,7 @@ class ExaoneMoE:
                                else causal, v, "kgqm,mkd->qkgd")
             for rows, held in ((k, ks), (v, vs)):
                 held.append(jnp.swapaxes(
-                    rows[ring_src] if window else rows, 0, 1).astype(
+                    rows[src] if window else rows, 0, 1).astype(
                         self.cache_dtype))
             return ctx
 
@@ -450,9 +574,7 @@ class ExaoneMoE:
             return decode_attention(q, ck, cv, pos, scale)
 
         def counts(l, chosen):
-            local = chosen - cfg.first_expert
-            hit = jax.nn.one_hot(local, cfg.experts_held, dtype=jnp.uint32)
-            picks.append((hit * live[:, None, None]).sum((0, 1)))
+            picks.append(count_picks(cfg, chosen, live))
 
         x = params["embed"][last_tok].astype(jnp.float32)
         for l, p in enumerate(params["layers"]):

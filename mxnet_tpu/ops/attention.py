@@ -15,6 +15,10 @@ Design:
   K innermost ("arbitrary" dimension semantics) with VMEM scratch carrying
   (m, l, acc) across K steps — the canonical TPU flash-attention schedule
   (MXU for the two dots, VPU for the online-softmax rescale).
+* ``_flash_blocks`` — the same online softmax with the queries in blocks
+  too, so that a *window* (a query at ``p`` reads ``p-window+1..p``) skips
+  the K/V blocks wholly outside it, and with K/V of fewer heads than Q read
+  by their group of query heads without a repeated copy.
 * ``flash_attention`` — ``jax.custom_vjp``: forward picks the Pallas kernel
   on a TPU trace (``_kernel_refusal`` says when not, and the choice is
   counted under ``ops.kernel_path``) else the scan; backward recomputes blockwise
@@ -48,15 +52,18 @@ from .registry import REQUIRED, pbool, pfloat, pint, register
 NEG_INF = -1e30
 
 
-def _attn_reference(q, k, v, causal=False, scale=None, kv_offset=0):
+def _attn_reference(q, k, v, causal=False, scale=None, kv_offset=0,
+                    window=None):
     """Quadratic-memory reference attention (numerics oracle for tests)."""
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
+    qi = jnp.arange(q.shape[2])[:, None]
+    ki = jnp.arange(k.shape[2])[None, :] + kv_offset
     if causal:
-        qi = jnp.arange(q.shape[2])[:, None]
-        ki = jnp.arange(k.shape[2])[None, :] + kv_offset
         s = jnp.where(qi >= ki, s, NEG_INF)
+    if window is not None:
+        s = jnp.where(ki > qi - window, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
 
@@ -121,14 +128,96 @@ def _flash_scan(q, k, v, causal, scale, block_k=512):
     return out, lse
 
 
+def _k_block_range(qb, block_q, block_k, num_kb, causal, window):
+    """``(first, last)`` K blocks that hold a position some query of Q
+    block ``qb`` reads: up to the diagonal when causal, from the window's
+    far edge when there is one.  Works on Python and traced integers."""
+    last = num_kb - 1
+    if causal:
+        last = jnp.minimum(last, (qb * block_q + block_q - 1) // block_k)
+    first = 0
+    if window is not None:
+        first = jnp.maximum(0, qb * block_q - window + 1) // block_k
+    return first, last
+
+
+def _flash_blocks(q, k, v, causal, scale, block_q, block_k, window):
+    """Blockwise attention with the queries in blocks too: ``lax.map`` over
+    Q blocks, and inside it a loop over the K/V blocks of
+    :func:`_k_block_range` alone, so a block wholly outside the window (or
+    above the diagonal) costs nothing.  ``k``/``v (B, kv_heads, Lk, D)`` may
+    have fewer heads than ``q (B, H, Lq, D)``: query head ``i`` reads K/V
+    head ``i // (H // kv_heads)``, by a grouped product and no copy.
+    Products take operands in the inputs' dtype and accumulate in float32;
+    the weights are rounded to ``v``'s dtype before their product.
+    Returns (out, lse); forward only (the loop's trip count is traced)."""
+    b, h, lq, d = q.shape
+    n, lk = k.shape[1], k.shape[2]
+    g = h // n
+    block_q, block_k = min(block_q, lq), min(block_k, lk)
+    if lq % block_q or lk % block_k:
+        raise ValueError("blocks (%d, %d) do not divide lengths (%d, %d)"
+                         % (block_q, block_k, lq, lk))
+    num_qb, num_kb = lq // block_q, lk // block_k
+    # lint: ok[recompile-hazard] block_q/block_k are blocking-tuning knobs with one value a caller — per-value specialization is the intent
+    qr = jnp.moveaxis(q.reshape(b, n, g, num_qb, block_q, d), 3, 0)
+    # lint: ok[recompile-hazard] as above
+    kr = k.reshape(b, n, num_kb, block_k, d)
+    # lint: ok[recompile-hazard] as above
+    vr = v.reshape(b, n, num_kb, block_k, d)
+
+    def one_q_block(args):
+        qb, q_blk = args                         # (b, n, g, block_q, d)
+        qi = qb * block_q + jnp.arange(block_q)[:, None]
+
+        def one_k_block(kb, carry):
+            o, m, l = carry
+            k_blk = jax.lax.dynamic_index_in_dim(kr, kb, 2, keepdims=False)
+            v_blk = jax.lax.dynamic_index_in_dim(vr, kb, 2, keepdims=False)
+            s = jnp.einsum("bngqd,bnkd->bngqk", q_blk, k_blk,
+                           preferred_element_type=jnp.float32) * scale
+            ki = kb * block_k + jnp.arange(block_k)[None, :]
+            valid = jnp.ones((block_q, block_k), bool)
+            if causal:
+                valid = valid & (qi >= ki)
+            if window is not None:
+                valid = valid & (ki > qi - window)
+            s = jnp.where(valid, s, NEG_INF)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
+            l_new = l * alpha + p.sum(axis=-1)
+            o_new = o * alpha[..., None] + jnp.einsum(
+                "bngqk,bnkd->bngqd", p.astype(v.dtype), v_blk,
+                preferred_element_type=jnp.float32)
+            return o_new, m_new, l_new
+
+        first, last = _k_block_range(qb, block_q, block_k, num_kb, causal,
+                                     window)
+        o, m, l = jax.lax.fori_loop(
+            first, last + 1, one_k_block,
+            (jnp.zeros((b, n, g, block_q, d), jnp.float32),
+             jnp.full((b, n, g, block_q), NEG_INF, jnp.float32),
+             jnp.zeros((b, n, g, block_q), jnp.float32)))
+        l = jnp.maximum(l, 1e-30)
+        return (o / l[..., None]).astype(q.dtype), m + jnp.log(l)
+
+    out, lse = jax.lax.map(one_q_block, (jnp.arange(num_qb), qr))
+    return (jnp.moveaxis(out, 0, 3).reshape(b, h, lq, d),
+            jnp.moveaxis(lse, 0, 3).reshape(b, h, lq))
+
+
 # ---------------------------------------------------------------------------
 # Pallas TPU forward kernel
 # ---------------------------------------------------------------------------
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-               scale, causal, block_q, block_k, num_kb):
+               scale, causal, window, block_q, block_k, num_kb):
     """Online-softmax flash attention body; grid = (BH, num_qb, num_kb),
-    K innermost with scratch (m, l, acc) carried across K steps."""
+    K innermost with scratch (m, l, acc) carried across K steps.  Both
+    products take their operands as they come (bfloat16 stays bfloat16 on
+    the MXU) and accumulate in float32; the weights are rounded to ``v``'s
+    dtype before theirs."""
     from jax.experimental import pallas as pl
 
     qb = pl.program_id(1)
@@ -141,31 +230,43 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def _body():
-        q = q_ref[0].astype(jnp.float32)          # (block_q, d)
-        k = k_ref[0].astype(jnp.float32)          # (block_k, d)
-        v = v_ref[0].astype(jnp.float32)
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]    # (block_q | block_k, d)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        if causal:
+        valid = None
+        if causal or window is not None:
             qi = qb * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             ki = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qi >= ki, s, NEG_INF)
+            valid = qi >= ki if causal else None
+            if window is not None:
+                near = ki > qi - window
+                valid = near if valid is None else valid & near
+            s = jnp.where(valid, s, NEG_INF)
         m_prev = m_scr[:, 0]                       # (block_q,)
         l_prev = l_scr[:, 0]
         m_cur = jnp.maximum(m_prev, s.max(axis=-1))
         alpha = jnp.exp(m_prev - m_cur)
         p = jnp.exp(s - m_cur[:, None])
+        if window is not None:
+            # a query whose window starts past this block has read nothing
+            # yet: its maximum is still NEG_INF and exp(0) must not count
+            p = jnp.where(valid, p, 0.0)
         l_cur = l_prev * alpha + p.sum(axis=-1)
         m_scr[:, 0] = m_cur
         l_scr[:, 0] = l_cur
         acc_scr[:] = acc_scr[:] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    if causal:
-        # skip fully-masked K blocks (block above the diagonal)
-        @pl.when(kb * block_k <= qb * block_q + (block_q - 1))
+    if causal or window is not None:
+        # skip the K blocks no query of this Q block reads: above the
+        # diagonal, and wholly beyond the window's far edge
+        first, last = _k_block_range(qb, block_q, block_k, num_kb, causal,
+                                     window)
+
+        @pl.when((kb >= first) & (kb <= last))
         def _():
             _body()
     else:
@@ -179,32 +280,42 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 
 
 def _flash_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
-                  interpret=False):
+                  interpret=False, window=None):
+    """``k``/``v`` may have fewer heads than ``q``: a query head's grid
+    steps are handed its K/V head's blocks by the index map, and no copy of
+    K or V is made.  A skipped step (see :func:`_fa_kernel`) asks for the
+    nearest block that is read, so it fetches nothing new."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, lq, d = q.shape
-    lk = k.shape[2]
+    n, lk = k.shape[1], k.shape[2]
+    group = h // n
     block_q = min(block_q, lq)
     block_k = min(block_k, lk)
     num_qb = lq // block_q
     num_kb = lk // block_k
     bh = b * h
     qr = q.reshape(bh, lq, d)
-    kr = k.reshape(bh, lk, d)
-    vr = v.reshape(bh, lk, d)
+    kr = k.reshape(b * n, lk, d)
+    vr = v.reshape(b * n, lk, d)
 
     kernel = functools.partial(
-        _fa_kernel, scale=scale, causal=causal,
+        _fa_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, num_kb=num_kb)
+
+    def kv_block(b_, q_, k_):
+        first, last = _k_block_range(q_, block_q, block_k, num_kb, causal,
+                                     window)
+        return (b_ // group, jnp.clip(k_, first, last), 0)
 
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, num_qb, num_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b_, q_, k_: (b_, q_, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, q_, k_: (b_, k_, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b_, q_, k_: (b_, k_, 0)),
+            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, d), kv_block),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b_, q_, k_: (b_, q_, 0)),
@@ -225,6 +336,7 @@ def _flash_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention",
         interpret=interpret,
     )(qr, kr, vr)
     # the reshape drops the trailing singleton the lse BlockSpec needed
@@ -260,37 +372,51 @@ def _kernel_refusal(q, k, block_q, block_k):
 # custom-vjp flash attention (public functional API)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, scale, block_q, block_k):
-    out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, scale, block_q, block_k, window=None):
+    out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
     from .registry import count_kernel_path
 
+    if q.shape[1] % k.shape[1]:
+        raise ValueError("%d query heads over %d K/V heads"
+                         % (q.shape[1], k.shape[1]))
+    op = "FlashAttention" if window is None else "FlashAttention.window"
     reason = _kernel_refusal(q, k, block_q, block_k)
     if reason is None:
-        count_kernel_path("FlashAttention", "pallas", "ok")
-        out, lse = _flash_pallas(q, k, v, causal, scale, block_q, block_k)
+        count_kernel_path(op, "pallas", "ok")
+        out, lse = _flash_pallas(q, k, v, causal, scale, block_q, block_k,
+                                 window=window)
     else:
-        count_kernel_path("FlashAttention", "xla", reason)
-        out, lse = _flash_scan(q, k, v, causal, scale, block_k)
+        count_kernel_path(op, "xla", reason)
+        if window is None and q.shape[1] == k.shape[1]:
+            out, lse = _flash_scan(q, k, v, causal, scale, block_k)
+        else:
+            out, lse = _flash_blocks(q, k, v, causal, scale, block_q,
+                                     block_k, window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_core(causal, scale, block_q, block_k, res, do, dlse=None):
+def _flash_bwd_core(causal, scale, block_q, block_k, res, do, dlse=None,
+                    window=None):
     """FA2 backward: blockwise over K, plain-JAX matmuls (MXU via XLA).
 
     ``dlse`` (optional, (B,H,Lq) f32) is the cotangent of the logsumexp
     output: d lse_i / d s_ij = p_ij, so it enters as ``ds += p * dlse``
     — the one extra term that makes the (out, lse) PAIR differentiable
     (ring attention merges blocks through lse, so lse carries real
-    gradients there)."""
+    gradients there).  K/V of fewer heads than Q are repeated here, and
+    their gradients summed over each group."""
     q, k, v, out, lse = res
+    group = q.shape[1] // k.shape[1]
     qf = q.astype(jnp.float32)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
+    if group > 1:
+        kf, vf = (jnp.repeat(a, group, axis=1) for a in (kf, vf))
     dof = do.astype(jnp.float32)
     of = out.astype(jnp.float32)
     delta = (dof * of).sum(axis=-1)                  # (B,H,Lq)
@@ -311,12 +437,12 @@ def _flash_bwd_core(causal, scale, block_q, block_k, res, do, dlse=None):
         i, k_blk, v_blk = kv
         s = jnp.einsum("bhqd,bhkd->bhqk", qf, k_blk) * scale
         kpos = i * bk + jnp.arange(bk)
-        valid = kpos < lk
+        valid = jnp.broadcast_to((kpos < lk)[None, :], (lq, bk))
+        qi = jnp.arange(lq)[:, None]
         if causal:
-            qi = jnp.arange(lq)[:, None]
-            valid = valid[None, :] & (qi >= kpos[None, :])
-        else:
-            valid = jnp.broadcast_to(valid[None, :], (lq, bk))
+            valid = valid & (qi >= kpos[None, :])
+        if window is not None:
+            valid = valid & (kpos[None, :] > qi - window)
         p = jnp.where(valid, jnp.exp(s - lse[..., None]), 0.0)
         dv_blk = jnp.einsum("bhqk,bhqd->bhkd", p, dof)
         dp = jnp.einsum("bhqd,bhkd->bhqk", dof, v_blk)
@@ -330,13 +456,20 @@ def _flash_bwd_core(causal, scale, block_q, block_k, res, do, dlse=None):
 
     dq0 = jnp.zeros_like(qf)
     dq, (dk_b, dv_b) = jax.lax.scan(step, dq0, (jnp.arange(nb), kb, vb))
-    dk = jnp.moveaxis(dk_b, 0, 2).reshape(kf.shape)[:, :, :lk]
-    dv = jnp.moveaxis(dv_b, 0, 2).reshape(vf.shape)[:, :, :lk]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+    def of_kv_heads(d_b):
+        d = jnp.moveaxis(d_b, 0, 2).reshape(kf.shape)[:, :, :lk]
+        if group == 1:
+            return d
+        return d.reshape(k.shape[0], k.shape[1], group, lk, -1).sum(2)
+
+    return (dq.astype(q.dtype), of_kv_heads(dk_b).astype(k.dtype),
+            of_kv_heads(dv_b).astype(v.dtype))
 
 
-def _flash_bwd(causal, scale, block_q, block_k, res, do):
-    return _flash_bwd_core(causal, scale, block_q, block_k, res, do)
+def _flash_bwd(causal, scale, block_q, block_k, window, res, do):
+    return _flash_bwd_core(causal, scale, block_q, block_k, res, do,
+                           window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -379,12 +512,18 @@ def flash_attention_with_lse(q, k, v, causal=False, softmax_scale=None,
 
 
 def flash_attention(q, k, v, causal=False, softmax_scale=None,
-                    block_q=256, block_k=512):
-    """Memory-efficient attention. q/k/v: (batch, heads, seq, head_dim)."""
+                    block_q=256, block_k=512, window=None):
+    """Memory-efficient attention.  ``q (batch, heads, seq, head_dim)``;
+    ``k``/``v (batch, kv_heads, seq, head_dim)`` with ``kv_heads`` a
+    divisor of ``heads`` (query head ``i`` reads K/V head ``i // (heads //
+    kv_heads)``, with no repeated copy of K and V).  ``window``: a query at
+    position ``p`` reads ``p - window + 1 .. p`` (with ``causal``), and the
+    K/V blocks wholly outside are skipped."""
     if softmax_scale is None:
         softmax_scale = float(1.0 / np.sqrt(q.shape[-1]))
     return _flash(q, k, v, bool(causal), float(softmax_scale),
-                  int(block_q), int(block_k))
+                  int(block_q), int(block_k),
+                  None if window is None else int(window))
 
 
 # ---------------------------------------------------------------------------

@@ -456,7 +456,7 @@ def _decode_attention_pairs():
     assert not re.findall(r"= bf16\[128,10,4096,128\]\{[^}]*\} copy\(", text)
 
 
-def _decode_attention_over_a_donated_cache(q, cache):
+def _decode_attention_over_a_donated_cache(q, cache, block=256):
     """Compiled text of a step's use of the kernel: the new row written
     into the donated cache, then the attention over it."""
     one_chip = _one_chip()
@@ -465,7 +465,7 @@ def _decode_attention_over_a_donated_cache(q, cache):
         ck = attention.write_slot_rows(ck, k, n)
         cv = attention.write_slot_rows(cv, v, n)
         return attention._decode_pallas(
-            q, ck, cv, n, 0.125, 256), ck, cv
+            q, ck, cv, n, 0.125, block), ck, cv
 
     sds = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
            for shape, dtype in (
@@ -480,6 +480,123 @@ def _decode_attention_over_a_donated_cache(q, cache):
 
 CASES["decode_attention-128x10x4x128-pairs-over-4096-rows"] = \
     _decode_attention_pairs
+
+
+def _decode_attention_group_of_seven(rows):
+    """The call of ``models/smallthinker.py``'s step at the SmallThinker
+    cell's sizes: 48 slots, 4 K/V heads of 128 with SEVEN queries each (a
+    group that fills no whole sublane tile), bfloat16, over a ring of 4096
+    rows and over a full layer of 16,384.  The plan admits both in blocks
+    of 1024 rows (1 MiB of K a grid step), the compiler takes what the plan
+    admits, and with the cache donated nothing the size of it is copied."""
+    def run():
+        import re
+
+        q = jax.ShapeDtypeStruct((48, 4, 7, 128), jnp.bfloat16)
+        cache = jax.ShapeDtypeStruct((48, 4, rows, 128), jnp.bfloat16)
+        with _tpu_trace():
+            assert attention.decode_attention_plan(q, cache) == (1024, None)
+            text = _decode_attention_over_a_donated_cache(q, cache, 1024)
+        assert text.count("tpu_custom_call") == 3
+        assert "dynamic-update-slice" not in text
+        assert not re.findall(
+            r"= bf16\[48,4,%d,128\]\{[^}]*\} copy\(" % rows, text)
+    return run
+
+
+for _rows in (4096, 16384):
+    CASES["decode_attention-48x4x7x128-over-%d-rows" % _rows] = \
+        _decode_attention_group_of_seven(_rows)
+
+
+def _flash_window_case(length, window):
+    """The prompt's attention of ``models/smallthinker.py`` at its largest
+    bucket and at the window's own length: 28 query heads over 4 K/V heads
+    of 128 (read through the index map: no repeated copy of K or V is an
+    operand), blocks of 512 rows, a window of 4096 or none, bfloat16.  No
+    array of ``length x length`` scores is in the program."""
+    def run():
+        q = ((1, 28, length, 128), jnp.bfloat16)
+        kv = ((1, 4, length, 128), jnp.bfloat16)
+        with _tpu_trace():
+            assert attention._kernel_refusal(
+                jax.ShapeDtypeStruct(*q), jax.ShapeDtypeStruct(*kv),
+                512, 512) is None
+        sharding = _one_chip()
+        args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+                for s, d in (q, kv, kv)]
+        text = jax.jit(lambda q, k, v: attention._flash_pallas(
+            q, k, v, True, 128 ** -0.5, 512, 512, window=window)).lower(
+                *args).compile().as_text()
+        assert "tpu_custom_call" in text and "flash_attention" in text
+        assert "[1,28,%d,128]" % length in text
+        assert "%d,%d]" % (length, length) not in text
+        assert "bf16[1,28,%d,128]{3,2,1,0} broadcast" % length not in text
+    return run
+
+
+for _length, _window in [(8192, 4096), (8192, None), (4096, 4096),
+                         (3072, 4096)]:
+    CASES["flash_attention-28-over-4-heads-%d-window-%s"
+          % (_length, _window)] = _flash_window_case(_length, _window)
+
+
+def _smallthinker_case(which):
+    """The engine's programs over ``models/smallthinker.py`` at
+    ``benchmark/configs/smallthinker-21ba3b-instruct.json``'s sizes (8
+    layers of 64 experts, 151,936 rows of vocabulary, the configuration's
+    slots x 16,384, bfloat16): each fits the chip beside what it is handed
+    (under 15.5 GB in all).  The step reads rings and full layers through
+    eight calls of ``decode_attention`` and writes sixteen arrays' rows
+    with ``slot_write``; no ``(48, 4, 7, 4096)`` scores, no update-slice,
+    no copy of a cache-sized array.  The largest prefill holds under 2 GB
+    of temporaries: no ``(heads, P, P)`` scores and no ``(P, 64, 768)``
+    product of every expert over every row (eight calls of
+    ``flash_attention``, the experts a ragged product)."""
+    def run():
+        from benchmark import harness
+        from benchmark.tools import aot_compile_smallthinker as tool
+
+        config = harness.load_json(os.path.join(
+            ROOT, "benchmark", "configs",
+            "smallthinker-21ba3b-instruct.json"))
+        engine, params, state, keep, extra, sds = tool.engine_programs(
+            config, _one_chip())
+        s = config["engine"]["slots"]
+        assert sorted({a.shape for a in state[0]}) == [
+            (s, 4, 4096, 128), (s, 4, 16384, 128)]
+        with _tpu_trace():
+            if which == "step":
+                compiled = engine._step_fn.lower(params, state, keep,
+                                                 extra).compile()
+            else:
+                compiled = engine._prefill_fns[which].lower(
+                    *tool.prefill_shapes(params, state, which,
+                                         sds)).compile()
+        ma = compiled.memory_analysis()
+        assert ma.argument_size_in_bytes + ma.output_size_in_bytes \
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes < 15.5e9
+        text = compiled.as_text()
+        if which == "step":
+            assert text.count("tpu_custom_call") == 8 + 2 * 8
+            assert "dynamic-update-slice" not in text
+            assert "f32[%d,4,7,4096]" % s not in text
+            assert ma.temp_size_in_bytes < 64e6, ma.temp_size_in_bytes
+        else:
+            assert text.count("flash_attention") >= 8
+            assert "ragged" in text
+            assert ma.temp_size_in_bytes < 2e9, ma.temp_size_in_bytes
+            assert "%d,%d]" % (which, which) not in text
+            assert "[%d,64,768]" % which not in text
+        copies = tool.cache_copies(text, state)
+        assert not copies, "%d copies of a cache array, the first: %s" \
+            % (len(copies), copies[0][:200])
+    return run
+
+
+CASES["decode-step-smallthinker-no-cache-copy"] = _smallthinker_case("step")
+CASES["decode-prefill-8192-smallthinker-under-2-GB-of-temporaries"] = \
+    _smallthinker_case(8192)
 
 
 def _decode_attention_lanes():
