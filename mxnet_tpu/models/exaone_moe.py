@@ -63,8 +63,8 @@ positions in the ring.  K/V heads come before positions because that is
 the order both attention products read: compiled for the chip with
 positions first, every step copied every cache array into this order and
 back (PERF.md section 6, PR 27).  The decode step reads a full layer
-through :func:`ops.attention.decode_attention`: on the TPU only the blocks
-of rows at or below each slot's length (PERF.md section 6, PR 28); this
+through :func:`ops.attention.decode_attention`: on the TPU only the rows at
+or below each slot's length, to 128 (PERF.md section 6, PR 28, 35); this
 model's ring of 128 rows is read whole, every row of it live once a session
 is past the window (a ring of thousands of rows goes through the same
 kernel as a full layer: :mod:`~mxnet_tpu.models.smallthinker`, and PERF.md
@@ -583,7 +583,7 @@ class ExaoneMoE:
             logits = _mm(_rms(x, params["ln_f"]), params["head"])
         rows = live.sum()
         # a full layer read ``pos // b + 1`` of a slot's ``max_len // b``
-        # blocks, ``b`` the rows of a block on the path its trace took
+        # blocks, ``b`` the rows to whose multiple its trace's path reads
         read = sum((live * (pos // b + 1).astype(jnp.uint32)).sum()
                    for b in blocks)
         held = rows * np.uint32(sum(cfg.max_len // b for b in blocks))

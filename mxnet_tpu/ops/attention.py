@@ -27,8 +27,8 @@ Design:
 
 * ``decode_attention`` — one query a slot over a dense K/V cache whose
   slots hold different numbers of rows (the decode step's full-attention
-  layer): on a TPU trace a Pallas kernel that fetches only the blocks of
-  rows below each slot's length (``_decode_pallas``), else the two einsums
+  layer): on a TPU trace a Pallas kernel that walks only the rows up to
+  each slot's length, to 128 (``_decode_pallas``), else the two einsums
   and the softmax over every row (``_decode_xla``); not differentiated.
 
 * ``write_slot_rows`` — the decode step's one new K (or V) row a slot,
@@ -530,11 +530,13 @@ def flash_attention(q, k, v, causal=False, softmax_scale=None,
 # decode attention: one query a slot, over the rows the slot holds
 # ---------------------------------------------------------------------------
 
-#: bytes of K (and as many of V) one grid step of the decode kernel moves:
-#: a grid step costs about 0.35 us whatever it moves, so it should move a
-#: megabyte; K and V double-buffered are then 4 MiB of the 16 MiB a kernel
-#: may use on a v5e
-_DECODE_BLOCK_BYTES = 1 << 20
+#: bytes of K (and as many of V) one copy of the decode kernel moves: a
+#: loop turn costs its waits and the issue of the next copies whatever they
+#: move, so it should move a megabyte; two buffers of K and two of V are
+#: then 4 MiB of the 16 MiB a kernel may use on a v5e
+_DECODE_CHUNK_BYTES = 1 << 20
+#: rows to which a slot's edge is read: the lanes of a tile of scores
+_DECODE_PIECE = 128
 
 
 def _decode_xla(q, cache_k, cache_v, lengths, scale):
@@ -550,39 +552,69 @@ def _decode_xla(q, cache_k, cache_v, lengths, scale):
                       preferred_element_type=jnp.float32)
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, *, scale, block):
-    """Grid ``(slots, blocks of rows)``, blocks innermost: the running
-    maximum, sum and accumulator of one slot's ``(kv_heads, group)``
-    queries stay in VMEM across its blocks.  A step wholly above the
-    slot's length does nothing (what the caller's index map gives it is
-    the next slot's first block, fetched ahead of its turn)."""
+def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+                   turns, m_scr, l_scr, acc_scr, *, scale, chunk, piece):
+    """One grid step a slot; the caches stay in HBM.  A slot of length
+    ``n`` is walked in ``n // chunk + 1`` turns of a loop: ``n // chunk``
+    whole chunks of ``chunk`` rows, one copy each of K and of V, then the
+    edge, of which only the pieces of ``piece`` rows at or below ``n`` are
+    copied and multiplied, the rows above ``n`` masked.  The turns of all
+    slots alternate between two buffers (``turns`` counts them across grid
+    steps): a turn first starts the copies of the next one, which after a
+    slot's last turn is the next slot's first, then waits for its own.
+    The running maximum, sum and accumulator of the slot's ``(kv_heads,
+    group)`` queries stay in VMEM across its turns."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    j = pl.program_id(1)
-    length = len_ref[pl.program_id(0)]
-    last = length // block
+    i, slots = pl.program_id(0), pl.num_programs(0)
+    per = chunk // piece
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def copies(slot, first, rows, buf, at):
+        """K's and V's copy of ``rows`` rows from row ``first`` of
+        ``slot`` into row ``at`` of buffer ``buf``."""
+        return [pltpu.make_async_copy(
+            hbm.at[slot, :, pl.ds(first, rows), :],
+            vmem.at[buf, :, pl.ds(at, rows), :], sems.at[which, buf])
+            for which, (hbm, vmem) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf)))]
 
-    def accumulate(edge):
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]     # (kv, g | block, d)
+    def whole(slot, c, buf):
+        return copies(slot, pl.multiple_of(c * chunk, chunk), chunk, buf, 0)
+
+    def edge(slot, c, p, buf):
+        return copies(slot, pl.multiple_of(c * chunk + p * piece, piece),
+                      piece, buf, p * piece)
+
+    def start(slot, c, buf):
+        n = len_ref[slot]
+
+        @pl.when(c < n // chunk)
+        def _whole():
+            for copy in whole(slot, c, buf):
+                copy.start()
+
+        @pl.when(c == n // chunk)
+        def _edge():
+            for p in range(per):
+                @pl.when(p <= n % chunk // piece)
+                def _piece():
+                    for copy in edge(slot, c, p, buf):
+                        copy.start()
+
+    def accumulate(k, v, first, n):
+        """``k``, ``v (kv, rows, d)`` from row ``first`` join the running
+        softmax; with ``n`` given, the rows above it take no part: they
+        hold whatever an earlier session, or an earlier turn, left."""
         s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale   # (kv, g, block)
-        if edge:
-            # the block the length falls in: rows above it hold whatever
-            # an earlier session left, and take no part
-            at = j * block + jax.lax.broadcasted_iota(
-                jnp.int32, (1, 1, block), 2)
-            s = jnp.where(at <= length, s, NEG_INF)
-            rows = j * block + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block, 1), 1)
-            v = jnp.where(rows <= length, v, jnp.zeros_like(v))
+            q_ref[0], k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale    # (kv, g, rows)
+        if n is not None:
+            rows = k.shape[1]
+            at = first + jax.lax.broadcasted_iota(jnp.int32, (1, 1, rows), 2)
+            s = jnp.where(at <= n, s, NEG_INF)
+            at = first + jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1)
+            v = jnp.where(at <= n, v, jnp.zeros_like(v))
         m_prev = m_scr[...]
         m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
@@ -591,74 +623,119 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         m_scr[...] = m_cur
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)           # (kv, g, d)
+            preferred_element_type=jnp.float32)            # (kv, g, d)
 
-    @pl.when(j < last)
-    def _whole():
-        accumulate(False)
+    @pl.when(i == 0)
+    def _cold():
+        turns[0] = 0
+        start(0, 0, 0)
 
-    @pl.when(j == last)
-    def _edge():
-        accumulate(True)
+    length = len_ref[i]
+    last = length // chunk
+    turn0 = turns[0]
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        o_ref[0] = acc_scr[...] / l_scr[...]
+    def turn(c, carry):
+        buf = (turn0 + c) % 2
+        more = c < last
+
+        @pl.when(more | (i + 1 < slots))
+        def _ahead():
+            start(jnp.where(more, i, jnp.minimum(i + 1, slots - 1)),
+                  jnp.where(more, c + 1, 0), 1 - buf)
+
+        @pl.when(more)
+        def _whole():
+            for copy in whole(i, c, buf):
+                copy.wait()
+            accumulate(k_buf[buf], v_buf[buf], c * chunk, None)
+
+        @pl.when(c == last)
+        def _edge():
+            def piece_of(p, carry):
+                for copy in edge(i, c, p, buf):
+                    copy.wait()
+                rows = pl.ds(pl.multiple_of(p * piece, piece), piece)
+                accumulate(k_buf[buf, :, rows, :], v_buf[buf, :, rows, :],
+                           c * chunk + p * piece, length)
+                return carry
+            jax.lax.fori_loop(0, length % chunk // piece + 1, piece_of, 0)
+        return carry
+
+    jax.lax.fori_loop(0, last + 1, turn, 0)
+    turns[0] = turn0 + last + 1
+    o_ref[0] = acc_scr[...] / l_scr[...]
 
 
-def _decode_pallas(q, cache_k, cache_v, lengths, scale, block,
+@functools.partial(jax.jit, static_argnames=("scale", "chunk", "piece",
+                                             "interpret"))
+def _decode_pallas(q, cache_k, cache_v, lengths, scale, chunk, piece,
                    interpret=False):
+    """Jitted on its own, as :func:`_write_slot_rows` is: a step that calls
+    it for eight layers traces and lowers the kernel once a shape, not once
+    a layer, each of the times an engine's set-up lowers its step."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     s, kv, g, d = q.shape
-    rows = cache_k.shape[2]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
 
-    def rows_of(i, j, lens):
-        # the steps above a slot's last block ask for the next slot's first
-        # block, all of them the same one: it is fetched once, behind the
-        # last block's arithmetic, and is in VMEM when its slot's turn
-        # comes; nothing else is fetched in a skipped step (the last slot
-        # has no next: its skipped steps fetch its own first block, once)
-        ahead = j > lens[i] // block
-        return (jnp.minimum(i + ahead, s - 1), 0, jnp.where(ahead, 0, j), 0)
-
-    def whole(i, j, lens):
+    def whole(i, lens):
         return (i, 0, 0, 0)
 
     return pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, block=block),
+        functools.partial(_decode_kernel, scale=scale, chunk=chunk,
+                          piece=piece),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(s, rows // block),
-            in_specs=[pl.BlockSpec((1, kv, g, d), whole),
-                      pl.BlockSpec((1, kv, block, d), rows_of),
-                      pl.BlockSpec((1, kv, block, d), rows_of)],
+            grid=(s,),
+            in_specs=[pl.BlockSpec((1, kv, g, d), whole), hbm, hbm],
             out_specs=pl.BlockSpec((1, kv, g, d), whole),
-            scratch_shapes=[pltpu.VMEM((kv, g, 1), jnp.float32),    # m
+            scratch_shapes=[pltpu.VMEM((2, kv, chunk, d), cache_k.dtype),
+                            pltpu.VMEM((2, kv, chunk, d), cache_v.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((1,), jnp.int32),            # turns
+                            pltpu.VMEM((kv, g, 1), jnp.float32),    # m
                             pltpu.VMEM((kv, g, 1), jnp.float32),    # l
                             pltpu.VMEM((kv, g, d), jnp.float32)]),  # acc
         out_shape=jax.ShapeDtypeStruct((s, kv, g, d), jnp.float32),
+        # the buffers, their semaphores and the count of turns carry over
+        # from a slot to the next: the slots run in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         name="decode_attention",
         interpret=interpret,
     )(lengths, q, cache_k, cache_v)
 
 
+def _decode_chunk(cache_k):
+    """Rows of a whole chunk at these shapes: all K/V heads of as many rows
+    as make :data:`_DECODE_CHUNK_BYTES`, a power of two of pieces, no more
+    than the cache has."""
+    kv, rows, d = cache_k.shape[1:]
+    chunk = _DECODE_PIECE
+    while chunk * 2 * kv * d * cache_k.dtype.itemsize <= _DECODE_CHUNK_BYTES \
+            and chunk * 2 <= rows:
+        chunk *= 2
+    return chunk
+
+
 def decode_attention_plan(q, cache_k):
-    """``(block, reason)``: the rows of a block the Pallas kernel reads at
-    these shapes with ``reason`` None, or ``(rows, reason)`` with why the
-    call takes the plain path, which reads all ``rows`` of every slot as
-    one block (``ops.kernel_path`` reasons).
+    """``(granule, reason)``: the rows to whose multiple the Pallas kernel
+    reads a slot at these shapes (a slot of length ``n`` costs ``n //
+    granule + 1`` granules of K and of V) with ``reason`` None, or ``(rows,
+    reason)`` with why the call takes the plain path, which reads all
+    ``rows`` of every slot (``ops.kernel_path`` reasons).
 
     Rows of whole 128-lane width only (``lanes``: a model of narrower
-    heads caches them in pairs, as :mod:`~mxnet_tpu.models.sambay` does).
-    The block follows what can be seen here: all K/V heads of as many rows
-    as make :data:`_DECODE_BLOCK_BYTES`, a power of two that divides
-    ``rows``, 128 at the least (a block's scores are ``(group, block)``
-    float32 tiles, whole lanes).  The rules are the v5e compiler's, asked
-    shape by shape (``tests/test_tpu_aot_compile.py``)."""
+    heads caches them in pairs, as :mod:`~mxnet_tpu.models.sambay` does),
+    in a cache of whole granules (``tile``), a granule of all K/V heads
+    within what one buffer may hold (``vmem``).  The kernel moves whole
+    chunks (:func:`_decode_chunk`) below a slot's edge and granules at it.
+    The rules are the v5e compiler's, asked shape by shape
+    (``tests/test_tpu_aot_compile.py``)."""
     from .registry import on_tpu
 
     kv, rows, d = cache_k.shape[1:]
@@ -669,18 +746,14 @@ def decode_attention_plan(q, cache_k):
         return rows, "dtype"
     if d % 128:
         # a cache of rows narrower than the 128 lanes lives rows-minor on
-        # the chip; the kernel wants it row-major, and the compiler then
-        # copies the whole cache to it and back at every call
+        # the chip, and the kernel's copies move whole tiles of 128 lanes
         return rows, "lanes"
-    block = 128
-    while block * 2 * kv * d * cache_k.dtype.itemsize <= _DECODE_BLOCK_BYTES \
-            and rows % (block * 2) == 0:
-        block *= 2
-    if rows % block:
+    if rows % _DECODE_PIECE:
         return rows, "tile"
-    if block * kv * d * cache_k.dtype.itemsize > 2 * _DECODE_BLOCK_BYTES:
+    if _DECODE_PIECE * kv * d * cache_k.dtype.itemsize \
+            > 2 * _DECODE_CHUNK_BYTES:
         return rows, "vmem"
-    return block, None
+    return _DECODE_PIECE, None
 
 
 def decode_attention(q, cache_k, cache_v, lengths, scale):
@@ -696,19 +769,19 @@ def decode_attention(q, cache_k, cache_v, lengths, scale):
     weights are rounded to the cache's dtype before the product with V,
     which accumulates in float32.
 
-    On a TPU trace the Pallas kernel reads, of each slot, only the blocks
-    of rows at or below its length, and keeps the softmax in VMEM across
-    them: neither rows nobody holds nor the ``(S, kv_heads, group, rows)``
-    scores touch HBM.  Elsewhere, and at shapes the kernel refuses
-    (:func:`decode_attention_plan`), every row is read and masked.  The
-    choice is counted under ``ops.kernel_path``."""
+    On a TPU trace the Pallas kernel walks, of each slot, only the rows at
+    or below its length, to a multiple of 128, and keeps the softmax in
+    VMEM across them: neither rows nobody holds nor the ``(S, kv_heads,
+    group, rows)`` scores touch HBM.  Elsewhere, and at shapes the kernel
+    refuses (:func:`decode_attention_plan`), every row is read and masked.
+    The choice is counted under ``ops.kernel_path``."""
     from .registry import count_kernel_path
 
-    block, reason = decode_attention_plan(q, cache_k)
+    _, reason = decode_attention_plan(q, cache_k)
     if reason is None:
         count_kernel_path("decode_attention", "pallas", "ok")
         return _decode_pallas(q, cache_k, cache_v, lengths, float(scale),
-                              block)
+                              _decode_chunk(cache_k), _DECODE_PIECE)
     count_kernel_path("decode_attention", "xla", reason)
     return _decode_xla(q, cache_k, cache_v, lengths, scale)
 

@@ -363,6 +363,9 @@ class _FirstCallHook:
     def lower(self, *args, **kwargs):
         return self._fn.lower(*args, **kwargs)
 
+    def trace(self, *args, **kwargs):
+        return self._fn.trace(*args, **kwargs)
+
 
 def first_call_hook(fn, hook):
     """Wrap jitted ``fn`` so ``hook(fn, args, kwargs, seconds)`` fires

@@ -226,43 +226,84 @@ def _counting(label):
 
 
 # -- decode attention ----------------------------------------------------------
-_ROWS, _BLOCK = 64, 16
+_ROWS, _CHUNK, _PIECE = 64, 32, 16
 
 
-def _decode_case(lengths, group, d, dtype, seed=0):
+def _decode_case(lengths, group, d, dtype, seed=0, rows=_ROWS):
     """``(q, clean K, clean V, K and V with NaN in every row above each
-    slot's length, lengths)``: 2 K/V heads, ``_ROWS`` rows a slot."""
+    slot's length, lengths)``: 2 K/V heads, ``rows`` rows a slot."""
     rs = np.random.RandomState(seed)
     s = len(lengths)
     q, k, v = (jnp.asarray(rs.normal(0, 1, shape), dtype) for shape in
-               [(s, 2, group, d)] + [(s, 2, _ROWS, d)] * 2)
+               [(s, 2, group, d)] + [(s, 2, rows, d)] * 2)
     lengths = jnp.asarray(lengths, jnp.int32)
-    dead = (jnp.arange(_ROWS)[None, :] > lengths[:, None])[:, None, :, None]
+    dead = (jnp.arange(rows)[None, :] > lengths[:, None])[:, None, :, None]
     return (q, k, v, jnp.where(dead, jnp.nan, k), jnp.where(dead, jnp.nan, v),
             lengths)
+
+
+def _check_decode_kernel(lengths, group, d, dtype, tol, rows, chunk, piece):
+    """The kernel (interpreter) against the two einsums and the softmax
+    over every row.  The cache the kernel is given holds NaN in every row
+    above each slot's length: a chunk or a piece it should not copy, or a
+    row of the length's own piece that it should mask, would show in the
+    result."""
+    q, k, v, k_nan, v_nan, lengths = _decode_case(lengths, group, d, dtype,
+                                                  rows=rows)
+    want = _decode_xla(q, k, v, lengths, 0.25)
+    got = _decode_pallas(q, k_nan, v_nan, lengths, 0.25, chunk, piece,
+                         interpret=True)
+    assert got.dtype == jnp.float32 and got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
                                        (jnp.bfloat16, 2e-2)])
 @pytest.mark.parametrize("group,d", [(1, 64), (8, 64), (1, 128), (8, 128)])
 @pytest.mark.parametrize("lengths", [
-    [0, 1, _BLOCK - 2, _BLOCK - 1, _BLOCK, _ROWS - 1],  # a block's edge
+    [0, 1, _PIECE - 1, _PIECE, _CHUNK - 1, _CHUNK, _ROWS - 1],  # the edges
     [0, 0, 0], [_ROWS - 1] * 3, [37] * 4,               # all equal
     [5, 50, 21, 33, 62, 16, 47],                        # all different
 ], ids=["edges", "empty", "full", "equal", "different"])
 def test_decode_kernel_interpret_reads_no_row_above_a_length(
         lengths, group, d, dtype, tol):
-    """The kernel (interpreter) against the two einsums and the softmax
-    over every row.  The cache the kernel is given holds NaN in every row
-    above each slot's length: a block it should skip, or a row of the
-    length's own block that it should mask, would show in the result."""
-    q, k, v, k_nan, v_nan, lengths = _decode_case(lengths, group, d, dtype)
-    want = _decode_xla(q, k, v, lengths, 0.25)
-    got = _decode_pallas(q, k_nan, v_nan, lengths, 0.25, _BLOCK,
-                         interpret=True)
-    assert got.dtype == jnp.float32 and got.shape == q.shape
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=tol, atol=tol)
+    _check_decode_kernel(lengths, group, d, dtype, tol, _ROWS, _CHUNK, _PIECE)
+
+
+def _walk_lengths(kind, rows, chunk):
+    """Lengths about a chunk's and a 128-row piece's edges, and the orders
+    of slots in which a copy started for "the next slot" goes wrong: the
+    last slot the longest or the shortest, a full slot before an empty one
+    and after it."""
+    if kind == "edges":
+        return [min(n, rows - 1)
+                for n in (0, chunk - 1, chunk, chunk + 1, 127, 128, rows - 1)]
+    if kind == "last-longest":
+        return [0, chunk - 1, 0, min(127, rows - 2), 5, 0, rows - 1]
+    if kind == "last-shortest":
+        return [rows - 1, min(chunk + 1, rows - 1), rows - 1, 3, rows - 1,
+                rows - 1, 0]
+    assert kind == "ring"
+    # a ring's horizon ``min(pos, window - 1)``: under, at and past the wrap
+    return [min(pos, rows - 1) for pos in
+            (0, 7, rows - 2, rows - 1, rows, 2 * rows, 3 * rows + 5)]
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("group", [4, 7, 8])
+@pytest.mark.parametrize("kind", ["edges", "last-longest", "last-shortest",
+                                  "ring"])
+@pytest.mark.parametrize("rows,chunk,piece", [
+    (64, 16, 16),       # a chunk is a piece: every turn one copy
+    (64, 64, 8),        # one chunk a slot: every turn an edge of pieces
+    (512, 128, 128), (512, 256, 128),   # the chip's pieces, whole lanes
+], ids=["16x16", "64x8", "128x128", "256x128"])
+def test_decode_kernel_walks_whole_chunks_then_the_edge_in_pieces(
+        rows, chunk, piece, kind, group, dtype, tol):
+    _check_decode_kernel(_walk_lengths(kind, rows, chunk), group, 128, dtype,
+                         tol, rows, chunk, piece)
 
 
 def test_decode_xla_is_the_softmax_over_the_rows_a_slot_holds():
@@ -288,13 +329,14 @@ def test_decode_attention_off_the_tpu_reads_every_row_and_says_so():
 
 
 @pytest.mark.parametrize("shape,dtype,want", [
-    # the K-EXAONE cell: all 8 K/V heads of 512 rows are 1 MiB
-    ((8, 8, 128, 4096), jnp.bfloat16, (512, None)),
-    ((1, 8, 128, 32768), jnp.bfloat16, (4096, None)),
+    # the K-EXAONE cell: whatever a chunk holds (512 rows of all 8 K/V
+    # heads are 1 MiB), a slot is read to a multiple of 128 rows
+    ((8, 8, 128, 4096), jnp.bfloat16, (128, None)),
+    ((1, 8, 128, 32768), jnp.bfloat16, (128, None)),
     # heads of 64 on their own live rows-minor on the chip: the kernel
     # would have the cache copied; cached in pairs they are whole lanes
     ((20, 1, 64, 1024), jnp.float32, (1024, "lanes")),
-    ((10, 4, 128, 4096), jnp.bfloat16, (256, None)),
+    ((10, 4, 128, 4096), jnp.bfloat16, (128, None)),
     ((4, 4, 128, 384), jnp.bfloat16, (128, None)),
     ((4, 4, 128, 200), jnp.bfloat16, (200, "tile")),
     ((32, 4, 256, 1024), jnp.float32, (1024, "vmem")),

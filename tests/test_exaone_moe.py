@@ -15,22 +15,22 @@ from mxnet_tpu.ops import attention
 from mxnet_tpu.serving.decode import UnsupportedKVLayout
 
 WINDOW = 8
-#: rows of a block when a test runs the decode-attention kernel (three
-#: blocks of the tests' ``max_len``)
+#: rows to whose multiple a test's decode-attention kernel reads a slot
+#: (three of them in the tests' ``max_len``): its chunk and its piece
 BLOCK = 16
 
 
 @pytest.fixture
 def decode_kernel(monkeypatch):
     """The full layer's attention through the Pallas kernel, run by the
-    interpreter in blocks of ``BLOCK`` rows: the choice of path is patched
+    interpreter in chunks of ``BLOCK`` rows: the choice of path is patched
     where the model reads it (off the TPU it reads every row)."""
     monkeypatch.setattr(xm, "decode_attention_plan",
                         lambda q, cache_k: (BLOCK, None))
     monkeypatch.setattr(
         xm, "decode_attention",
         lambda q, ck, cv, lengths, scale: attention._decode_pallas(
-            q, ck, cv, lengths, scale, BLOCK, interpret=True))
+            q, ck, cv, lengths, scale, BLOCK, BLOCK, interpret=True))
 
 
 def _cfg(first_expert=0, experts_held=16, max_len=48):

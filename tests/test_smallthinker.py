@@ -90,12 +90,12 @@ def test_prefill_then_decode_equal_the_reference_logits(prompt, bucket, new):
 
 def test_the_decode_kernel_reads_rings_and_full_layers(monkeypatch):
     """The same session with both kinds of layer through the Pallas kernel
-    (interpreter) in blocks of 8 rows: a ring is one block, read up to the
+    (interpreter) in chunks of 8 rows: a ring is one chunk, read up to the
     rows it holds."""
     monkeypatch.setattr(
         st, "decode_attention",
         lambda q, ck, cv, lengths, scale: attention._decode_pallas(
-            q, ck, cv, lengths, scale, 8, interpret=True))
+            q, ck, cv, lengths, scale, 8, 8, interpret=True))
     worst, _counted = _prefill_then_decode(_cfg(), 5, 8, 9)
     assert worst < 1e-4
 
@@ -314,7 +314,7 @@ def test_a_ring_read_through_decode_attention_is_the_masked_ring(kernel):
                            "skgm,skmd->skgd")
     horizon = jnp.minimum(pos, window - 1)
     if kernel == "pallas":
-        got = attention._decode_pallas(q, ck, cv, horizon, 0.25, 8,
+        got = attention._decode_pallas(q, ck, cv, horizon, 0.25, 8, 4,
                                        interpret=True)
     else:
         got = attention.decode_attention(q, ck, cv, horizon, 0.25)

@@ -350,12 +350,14 @@ CASES["decode-step-k-exaone-256-slots-no-cache-copy"] = _exaone_step_case
 def _decode_attention_case():
     """``ops.attention.decode_attention`` alone at the K-EXAONE cell's
     full layer (256 slots, 8 K/V heads of 128 with 8 queries each, 4096
-    rows, bfloat16): the plan admits it in blocks of 512 rows (1 MiB of K
-    a grid step), and what the plan admits the compiler takes."""
+    rows, bfloat16): the plan admits it, read to a multiple of 128 rows a
+    slot in chunks of 512 (1 MiB of K a copy), and what the plan admits
+    the compiler takes."""
     q = jax.ShapeDtypeStruct((256, 8, 8, 128), jnp.bfloat16)
     cache = jax.ShapeDtypeStruct((256, 8, 4096, 128), jnp.bfloat16)
     with _tpu_trace():
-        assert attention.decode_attention_plan(q, cache) == (512, None)
+        assert attention.decode_attention_plan(q, cache) == (128, None)
+        assert attention._decode_chunk(cache) == 512
         _compile(lambda q, k, v, n: attention.decode_attention(
             q, k, v, n, 128 ** -0.5),
             (q.shape, q.dtype), (cache.shape, cache.dtype),
@@ -366,9 +368,9 @@ CASES["decode_attention-256x8x8x128-over-4096-rows"] = _decode_attention_case
 
 
 def _decode_attention_vmem():
-    """Where the plan says ``vmem`` the compiler does: K and V of one
-    block, double-buffered, are most of the 16 MiB a kernel may use once a
-    block passes 2 MiB."""
+    """Where the plan says ``vmem`` the compiler does: two buffers of K
+    and two of V are most of the 16 MiB a kernel may use once the least
+    chunk, 128 rows, passes 2 MiB."""
     shape, cache = (4, 32, 4, 256), (4, 32, 1024, 256)
     with _tpu_trace():
         assert attention.decode_attention_plan(
@@ -376,13 +378,13 @@ def _decode_attention_vmem():
             jax.ShapeDtypeStruct(cache, jnp.float32)) == (1024, "vmem")
     try:
         _compile(lambda q, k, v, n: attention._decode_pallas(
-            q, k, v, n, 0.0625, 128),
+            q, k, v, n, 0.0625, 128, 128),
             (shape, jnp.float32), (cache, jnp.float32),
             (cache, jnp.float32), ((4,), jnp.int32))
     except Exception as e:  # noqa: broad-except — the compiler's refusal
         assert "vmem" in str(e), e
     else:
-        raise AssertionError("the compiler took 4 MiB blocks of K and V")
+        raise AssertionError("the compiler took 4 MiB chunks of K and V")
 
 
 CASES["decode_attention-refuses-what-the-compiler-refuses"] = \
@@ -441,14 +443,15 @@ def _decode_attention_pairs():
     """The call of ``models/sambay.py``'s full layer and of each of its
     seven cross layers at the Phi-4-mini-flash cell's sizes: 128 slots, 10
     K/V pairs of 128 lanes (two heads of 64 side by side) with 4 queries
-    each, 4096 rows, bfloat16.  The plan admits it in blocks of 256 rows,
+    each, 4096 rows, bfloat16.  The plan admits it, in chunks of 256 rows,
     and with the cache donated nothing the size of it is copied."""
     import re
 
     q = jax.ShapeDtypeStruct((128, 10, 4, 128), jnp.bfloat16)
     cache = jax.ShapeDtypeStruct((128, 10, 4096, 128), jnp.bfloat16)
     with _tpu_trace():
-        assert attention.decode_attention_plan(q, cache) == (256, None)
+        assert attention.decode_attention_plan(q, cache) == (128, None)
+        assert attention._decode_chunk(cache) == 256
         text = _decode_attention_over_a_donated_cache(q, cache)
     # the two row writes and the attention, and no update-slice left
     assert text.count("tpu_custom_call") == 3
@@ -456,7 +459,7 @@ def _decode_attention_pairs():
     assert not re.findall(r"= bf16\[128,10,4096,128\]\{[^}]*\} copy\(", text)
 
 
-def _decode_attention_over_a_donated_cache(q, cache, block=256):
+def _decode_attention_over_a_donated_cache(q, cache):
     """Compiled text of a step's use of the kernel: the new row written
     into the donated cache, then the attention over it."""
     one_chip = _one_chip()
@@ -465,7 +468,8 @@ def _decode_attention_over_a_donated_cache(q, cache, block=256):
         ck = attention.write_slot_rows(ck, k, n)
         cv = attention.write_slot_rows(cv, v, n)
         return attention._decode_pallas(
-            q, ck, cv, n, 0.125, block), ck, cv
+            q, ck, cv, n, 0.125, attention._decode_chunk(cache),
+            attention._DECODE_PIECE), ck, cv
 
     sds = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
            for shape, dtype in (
@@ -486,8 +490,8 @@ def _decode_attention_group_of_seven(rows):
     """The call of ``models/smallthinker.py``'s step at the SmallThinker
     cell's sizes: 48 slots, 4 K/V heads of 128 with SEVEN queries each (a
     group that fills no whole sublane tile), bfloat16, over a ring of 4096
-    rows and over a full layer of 16,384.  The plan admits both in blocks
-    of 1024 rows (1 MiB of K a grid step), the compiler takes what the plan
+    rows and over a full layer of 16,384.  The plan admits both, in chunks
+    of 1024 rows (1 MiB of K a copy), the compiler takes what the plan
     admits, and with the cache donated nothing the size of it is copied."""
     def run():
         import re
@@ -495,8 +499,9 @@ def _decode_attention_group_of_seven(rows):
         q = jax.ShapeDtypeStruct((48, 4, 7, 128), jnp.bfloat16)
         cache = jax.ShapeDtypeStruct((48, 4, rows, 128), jnp.bfloat16)
         with _tpu_trace():
-            assert attention.decode_attention_plan(q, cache) == (1024, None)
-            text = _decode_attention_over_a_donated_cache(q, cache, 1024)
+            assert attention.decode_attention_plan(q, cache) == (128, None)
+            assert attention._decode_chunk(cache) == 1024
+            text = _decode_attention_over_a_donated_cache(q, cache)
         assert text.count("tpu_custom_call") == 3
         assert "dynamic-update-slice" not in text
         assert not re.findall(
@@ -601,17 +606,19 @@ CASES["decode-prefill-8192-smallthinker-under-2-GB-of-temporaries"] = \
 
 def _decode_attention_lanes():
     """Heads of 64 cached on their own, ``(128, 20, 4096, 64)``: the plan
-    says ``lanes`` and the call takes the plain path, because the compiler
-    keeps such a cache rows-minor and, made to hand it to the kernel
-    row-major, copies the whole of it there and back at every call."""
-    import re
-
+    says ``lanes`` and the call takes the plain path.  The compiler keeps
+    such a cache rows-minor, and the kernel's copies move whole tiles of
+    128 lanes: made to walk it, the compiler refuses the slice."""
     q = jax.ShapeDtypeStruct((128, 20, 2, 64), jnp.bfloat16)
     cache = jax.ShapeDtypeStruct((128, 20, 4096, 64), jnp.bfloat16)
     with _tpu_trace():
         assert attention.decode_attention_plan(q, cache) == (4096, "lanes")
-        text = _decode_attention_over_a_donated_cache(q, cache)
-    assert re.findall(r"= bf16\[128,20,4096,64\]\{[^}]*\} copy\(", text)
+        try:
+            _decode_attention_over_a_donated_cache(q, cache)
+        except Exception as e:  # noqa: broad-except — the compiler's refusal
+            assert "aligned to tiling (128), but is 64" in str(e), e
+        else:
+            raise AssertionError("the compiler took rows of 64 lanes")
 
 
 CASES["decode_attention-refuses-rows-narrower-than-the-lanes"] = \

@@ -4,9 +4,13 @@
 configuration of the benchmark, at the configuration's own sizes: nothing
 is allocated and nothing runs.  A change to the engine or to the model
 protocol that is not meant to change a model's programs shows here as the
-same digests before and after.
+same digests before and after.  With ``--tpu`` the programs are traced as
+they are for the chip (``ops.registry.trace_device``) and lowered for it, so
+the Pallas kernels are part of the text (with the paths and lines of their
+call stacks: compare two trees through one path, a symbolic link that is
+pointed at each in turn); without, as the CPU runs them.
 
-    JAX_PLATFORMS=cpu python tools/perf/program_fingerprints.py [config ...]
+    JAX_PLATFORMS=cpu python tools/perf/program_fingerprints.py [--tpu] [config ...]
 """
 
 import os
@@ -89,10 +93,18 @@ def _gpt2_shapes(engine, params):
 
 
 def main():
+    import jax
+
     from benchmark import harness
     from mxnet_tpu import perfdebug
+    from mxnet_tpu.ops import registry
 
-    names = sys.argv[1:] or sorted(
+    names = [a for a in sys.argv[1:] if a != "--tpu"]
+    platform = "cpu"
+    if "--tpu" in sys.argv:
+        platform = "tpu"
+        registry.trace_device.set(platform)
+    names = names or sorted(
         f[:-5] for f in os.listdir(os.path.join(ROOT, "benchmark",
                                                 "configs")))
     for name in names:
@@ -101,8 +113,12 @@ def main():
         if "engine" not in config:
             continue
         for what, fn, shapes in programs(config):
+            # a kernel's text carries the call stack of its FIRST trace in
+            # the process: each program is traced as if alone
+            jax.clear_caches()
             print("%s %s %s" % (name, what, perfdebug.fingerprint_text(
-                fn.lower(*shapes).as_text())), flush=True)
+                fn.trace(*shapes).lower(lowering_platforms=(platform,))
+                .as_text())), flush=True)
 
 
 if __name__ == "__main__":
