@@ -77,11 +77,12 @@ import numpy as np
 from . import faults as _faults
 from . import perfdebug as _perfdebug
 from . import telemetry as _telemetry
+from . import tracing as _tracing
 from .base import MXNetError, atomic_write
 
 __all__ = [
     "DEFAULT_DIR", "enabled", "recording", "enable", "disable",
-    "cache_dir", "stats",
+    "cache_dir", "stats", "phases", "programs",
     "cache_entries", "cache_size_bytes", "gc", "verify", "note_build",
     "instrument", "records", "recording_scope", "reset_records",
     "manifest_path", "save_manifest", "save_manifest_if_changed",
@@ -123,10 +124,17 @@ _hits = 0
 _misses = 0
 _saved_seconds = 0.0
 _program_seconds = 0.0
-#: ``(monotonic_s at the end, seconds, hit)`` of the newest programs that
-#: went through ``compile_or_get_cached``, so that a reader can cut at a
-#: moment of its own (the benchmark: the opening of its window)
-_programs = deque(maxlen=4096)
+_trace_seconds = 0.0
+_lower_seconds = 0.0
+_lowerings = 0
+#: ``(phase, fun_name, t0, t1, tid)`` of the newest things JAX said it
+#: did on the way to a program, ``time.monotonic()`` seconds and the
+#: thread's native id: a ``trace`` (to a jaxpr; the outermost only, see
+#: :func:`_on_duration`), a ``lower`` (jaxpr to MLIR module), and a pass
+#: through ``compile_or_get_cached``, a ``load`` where the persistent
+#: cache had the program and a ``compile`` where it had not.  A reader
+#: cuts at a moment of its own (the benchmark: the opening of its window)
+_phases = deque(maxlen=4096)
 _evictions = 0
 _corrupt_dropped = 0
 
@@ -278,6 +286,15 @@ _EVENT_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
 #: brackets ``compile_or_get_cached`` in JAX 0.9.0, hit or miss: one
 #: event a program.  A hit's retrieval time is a part of it
 _EVENT_PROGRAM = "/jax/core/compile/backend_compile_duration"
+#: brackets one trace of a jitted function to a jaxpr (``pjit.py``).  A
+#: function traced inside another's trace publishes its own, inside the
+#: outer one's interval: a model's step is some 1,500 of these.  A hit in
+#: the trace cache publishes one of a few microseconds
+_EVENT_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+#: brackets one lowering of a jaxpr to an MLIR module that really ran
+#: (``pxla.py``): one event a top-level lowering, none for a lowering
+#: JAX had cached
+_EVENT_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 
 def _on_event(event, **_kw):
@@ -295,15 +312,53 @@ def _on_event(event, **_kw):
         _telemetry.inc("xla.compile.persistent_cache_misses")
 
 
-def _on_duration(event, duration, **_kw):
-    global _saved_seconds, _program_seconds
-    if event == _EVENT_PROGRAM:
-        # counted with the cache off too: a compile is a compile
-        hit = getattr(_tls, "hit", False)
-        _tls.hit = False
+def _absorb_traces(t0, tid):
+    """Drop the newest ``trace`` records of thread ``tid`` that started at
+    or after ``t0``: the record about to be kept holds them.  They
+    arrived first, so they are the newest; the walk stops at the first
+    record that is not one of them.  Lock held by the caller."""
+    global _trace_seconds
+    while _phases:
+        last = _phases[-1]
+        if last[0] != "trace" or last[4] != tid or last[2] < t0:
+            return
+        _trace_seconds -= last[3] - last[2]
+        _phases.pop()
+
+
+def _on_duration(event, duration, fun_name=None, **_kw):
+    """Every duration JAX publishes ends here.  The three of
+    ``dispatch.py`` (trace, lower, ``compile_or_get_cached``) are kept in
+    :data:`_phases` with the end stamped on arrival and the duration taken
+    off (JAX's own stamps are ``time.time()``), counted with the cache off
+    too.  Of traces the outermost is kept: a step's trace holds some 1,500
+    traces of the functions it calls and a kernel's lowering some 500 of
+    its own, each published before the one that holds it, so a trace or a
+    lowering that arrives takes the place of the traces inside it
+    (:func:`_absorb_traces`) and its length is the union of them all.
+    Listening only: nothing here calls into JAX."""
+    global _saved_seconds, _program_seconds, _trace_seconds, \
+        _lower_seconds, _lowerings
+    if event in (_EVENT_TRACE, _EVENT_LOWER, _EVENT_PROGRAM):
+        t1 = time.monotonic()
+        duration = float(duration)
+        t0, tid = t1 - duration, threading.get_native_id()
+        if event == _EVENT_PROGRAM:
+            phase = "load" if getattr(_tls, "hit", False) else "compile"
+            _tls.hit = False
+        else:
+            phase = "trace" if event == _EVENT_TRACE else "lower"
         with _lock:
-            _program_seconds += float(duration)
-            _programs.append((time.monotonic(), float(duration), hit))
+            if event == _EVENT_PROGRAM:
+                _program_seconds += duration
+            else:
+                _absorb_traces(t0, tid)
+                if event == _EVENT_TRACE:
+                    _trace_seconds += duration
+                else:
+                    _lower_seconds += duration
+                    _lowerings += 1
+            _phases.append((phase, fun_name, t0, t1, tid))
         return
     if _dir is None:
         return
@@ -550,20 +605,37 @@ def stats():
             "misses": _misses,
             "compile_time_saved_seconds": round(_saved_seconds, 3),
             "program_seconds": round(_program_seconds, 6),
+            "trace_seconds": round(_trace_seconds, 6),
+            "lower_seconds": round(_lower_seconds, 6),
+            "lowerings": _lowerings,
             "evictions": _evictions,
             "corrupt_dropped": _corrupt_dropped,
             "recorded_builds": len(_records),
         }
 
 
+def phases():
+    """``(phase, fun_name, t0, t1, tid)`` of the newest traces, lowerings
+    and passes through XLA's ``compile_or_get_cached`` in this process (at
+    most 4096, oldest first; :data:`_phases` says what each is).  Times
+    are ``time.monotonic()`` seconds, ``tid`` the thread's native id, as a
+    span's.  Of nested traces the outermost is kept, but where two threads
+    trace at once some inner ones may stay: a reader takes the union of a
+    phase's intervals, never their sum.  ``lower`` records are the
+    lowerings that ran; ``trace`` records are not a count of anything (a
+    hit in the trace cache leaves one)."""
+    with _lock:
+        return list(_phases)
+
+
 def programs():
     """``(monotonic_s, seconds, hit)`` of the newest programs XLA compiled
-    or loaded from the persistent cache in this process (at most 4096),
-    oldest first: the seconds are those inside JAX's
-    ``compile_or_get_cached``, the moment is its end on
-    ``time.monotonic()``."""
-    with _lock:
-        return list(_programs)
+    or loaded from the persistent cache in this process, oldest first: the
+    seconds are those inside JAX's ``compile_or_get_cached``, the moment
+    is its end on ``time.monotonic()``.  A view of :func:`phases`."""
+    return [(t1, t1 - t0, phase == "load")
+            for phase, _name, t0, t1, _tid in phases()
+            if phase in ("load", "compile")]
 
 
 # -- tier 2: build recording ------------------------------------------------
@@ -711,13 +783,21 @@ def note_build(exec_name, kind, lower_fn, args, kwargs=None, seconds=None):
     if not recording():
         return None
     try:
-        return _note_build_impl(exec_name, kind, lower_fn, args,
-                                kwargs or {}, seconds)
+        # what is traced and lowered inside this span is done for the
+        # manifest's fingerprint alone (``setup.relower_s``)
+        with _tracing.setup_span("compile_cache.note_build",
+                                 exec=exec_name, kind=_kind_name(kind)):
+            return _note_build_impl(exec_name, kind, lower_fn, args,
+                                    kwargs or {}, seconds)
     except Exception as e:  # noqa: broad-except — recording failure
         # must never break the dispatch that triggered it
         _log.debug("compile_cache: note_build failed for %s/%s: %s",
                    exec_name, kind, e)
         return None
+
+
+def _kind_name(kind):
+    return kind if isinstance(kind, str) else str(kind[0])
 
 
 def _note_build_impl(exec_name, kind, lower_fn, args, kwargs, seconds):
@@ -735,7 +815,7 @@ def _note_build_impl(exec_name, kind, lower_fn, args, kwargs, seconds):
             # loses invalidation detection
             _log.debug("compile_cache: fingerprint of %s/%s failed: %s",
                        exec_name, kind, e)
-    kind_name = kind if isinstance(kind, str) else str(kind[0])
+    kind_name = _kind_name(kind)
     entry = {
         "exec": exec_name,
         "kind": kind_to_json(kind),

@@ -286,6 +286,17 @@ class Module(BaseModule):
         if self.binded:
             self.logger.warning("Already binded, ignoring bind()")
             return
+        # recorded with tracing off, as init_params and init_optimizer
+        # are: a job does each a bounded number of times
+        with _tracing.setup_span("module.setup.bind"):
+            self._bind(data_shapes, label_shapes, for_training,
+                       inputs_need_grad, shared_module, grad_req)
+        if saved_params is not None:
+            self.set_params(saved_params[0], saved_params[1],
+                            force_init=True)
+
+    def _bind(self, data_shapes, label_shapes, for_training,
+              inputs_need_grad, shared_module, grad_req):
         self.for_training = for_training
         self.inputs_need_grad = inputs_need_grad
         self._grad_req = grad_req
@@ -374,9 +385,6 @@ class Module(BaseModule):
         self.binded = True
         if shared_module is not None and shared_module.params_initialized:
             self.params_initialized = True
-        if saved_params is not None:
-            self.set_params(saved_params[0], saved_params[1],
-                            force_init=True)
 
     def reshape(self, data_shapes, label_shapes=None):
         """reference module.py reshape"""
@@ -394,6 +402,12 @@ class Module(BaseModule):
         assert self.binded, "call bind before initializing the parameters"
         if self.params_initialized and not force_init:
             return
+        with _tracing.setup_span("module.setup.init_params"):
+            self._init_params(initializer, arg_params, aux_params,
+                              allow_missing)
+
+    def _init_params(self, initializer, arg_params, aux_params,
+                     allow_missing):
         attrs = self._symbol.attr_dict()
 
         def _impl(name, arr, cache):
@@ -426,6 +440,10 @@ class Module(BaseModule):
         if self.optimizer_initialized and not force_init:
             self.logger.warning("optimizer already initialized, ignoring...")
             return
+        with _tracing.setup_span("module.setup.init_optimizer"):
+            self._init_optimizer(kvstore, optimizer, optimizer_params)
+
+    def _init_optimizer(self, kvstore, optimizer, optimizer_params):
         arg_params = {n: self._exec.arg_dict[n] for n in self._param_names}
         (kvstore, update_on_kvstore) = _create_kvstore(
             kvstore, len(self._context), arg_params)

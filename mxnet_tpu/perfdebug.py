@@ -337,27 +337,27 @@ def analyze_signature(sig):
 
 
 class _FirstCallHook:
-    """Minimal first-call wrapper for jitted functions built outside
-    the executor's ``_get_fn`` path (e.g. ``Module``'s fused
-    multi-tensor update): ``hook(fn, args, kwargs, seconds)`` runs once
-    after the first call, then the wrapper is one boolean check per
-    dispatch.  Shared by perfdebug attribution and compile_cache
-    manifest recording (:func:`first_call_hook`)."""
+    """First-call wrapper for jitted functions built outside the executor's
+    ``_get_fn`` path (e.g. ``Module``'s fused update): ``hook(fn, args,
+    kwargs, seconds)`` runs once after the first call, inside the span
+    ``span()`` opens; then one boolean check a dispatch."""
 
-    __slots__ = ("_fn", "_hook", "_pending")
+    __slots__ = ("_fn", "_hook", "_pending", "_span")
 
-    def __init__(self, fn, hook):
+    def __init__(self, fn, hook, span=None):
         self._fn = fn
         self._hook = hook
         self._pending = True
+        self._span = span
 
     def __call__(self, *args, **kwargs):
         if not self._pending:
             return self._fn(*args, **kwargs)
         self._pending = False
         t0 = time.perf_counter()
-        out = self._fn(*args, **kwargs)
-        self._hook(self._fn, args, kwargs, time.perf_counter() - t0)
+        with self._span() if self._span else _tracing.NULL_SPAN:
+            out = self._fn(*args, **kwargs)
+            self._hook(self._fn, args, kwargs, time.perf_counter() - t0)
         return out
 
     def lower(self, *args, **kwargs):
@@ -367,10 +367,16 @@ class _FirstCallHook:
         return self._fn.trace(*args, **kwargs)
 
 
-def first_call_hook(fn, hook):
+def first_call_hook(fn, hook, span=None):
     """Wrap jitted ``fn`` so ``hook(fn, args, kwargs, seconds)`` fires
-    once after its first call."""
-    return _FirstCallHook(fn, hook)
+    once after its first call.  Shared by perfdebug attribution and
+    compile_cache manifest recording.  ``span``, a function of no
+    arguments that opens a span (``tracing.setup_span``), makes that call
+    and the hook one span: whose trace, lowering and load it was.  (The
+    wrapper's ``lower`` stands on the line it stood on: a kernel's lowered
+    text carries the line numbers of its call stack, and
+    ``tools/perf/program_fingerprints.py --tpu`` lowers through it.)"""
+    return _FirstCallHook(fn, hook, span)
 
 
 def instrument(fn, name, kind):
@@ -644,9 +650,13 @@ def _flight_dump_impl(reason, fields):
         },
     }
     safe_reason = re.sub(r"[^A-Za-z0-9_.-]", "_", str(reason))[:40]
-    spans = _tracing.spans_recent() if _tracing.enabled() else ()
+    # set-up's spans are there with tracing off: why this start took as
+    # long as it did is asked of a dump too
+    spans = _tracing.setup_spans()
+    if _tracing.enabled():
+        spans += _tracing.spans_recent()
     if spans:
-        # the span ring rides every dump as ndjson (one span per line,
+        # the span rings ride every dump as ndjson (one span per line,
         # joinable against the events' trace_id fields) — a post-mortem
         # of a failover carries the request trees that crossed it
         span_path = os.path.join(

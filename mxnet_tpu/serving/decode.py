@@ -449,9 +449,9 @@ class DecodeEngine:
         #: appends) from it without a device read
         self._slot_len = [0] * self.slots
 
-        self._step_fn = None       # built in _build()
-        self._prefill_fns = {}
-        self._boot_state = self._build()
+        self._step_fn, self._prefill_fns = None, {}    # built in _build()
+        with self._setup_span():
+            self._boot_state = self._build()
         labels = {"model": name, "replica": self.replica}
         _telemetry.inc("serving.decode.sessions.count", 0, **labels)
         _telemetry.inc("serving.decode.tokens.count", 0, **labels)
@@ -629,11 +629,11 @@ class DecodeEngine:
                 b: self._instrument(pf_jit, "decode_prefill",
                                     ("decode_prefill", b, s, m))
                 for b in self.prefill_buckets}
-
-        state = self._fresh_state()
-        with _compile_cache.recording_scope() as rec:
-            cc0 = _compile_cache.stats() if _compile_cache.enabled() \
-                else None
+        with _tracing.setup_span("serving.setup.state"):
+            state = self._fresh_state()
+        cc0 = _compile_cache.stats() if _compile_cache.enabled() else None
+        with _compile_cache.recording_scope() as rec, \
+                _tracing.setup_span("serving.setup.warm"):
             state = self._warm(state)
             cc1 = _compile_cache.stats() if cc0 is not None else None
         self.warmup_entries = rec.entries
@@ -660,9 +660,8 @@ class DecodeEngine:
         return state
 
     def _instrument(self, fn, kind, build_kind):
-        """First-call hook: count the compile (``xla.compile.count``,
-        the recompile-detector's family) and record the build into the
-        PR 7 warm-up manifest registry."""
+        """First-call hook (a ``serving.setup.program`` span): count the
+        compile and record the build into the warm-up manifest registry."""
         def hook(f, args, kwargs, dt):
             _telemetry.inc("xla.compile.count", kind=kind)
             _telemetry.inc("xla.compile.seconds", dt, kind=kind)
@@ -670,7 +669,8 @@ class DecodeEngine:
                 _compile_cache.note_build(
                     "serving:%s" % self.name, build_kind, f.lower, args,
                     kwargs, dt)
-        return _perfdebug.first_call_hook(fn, hook)
+        return _perfdebug.first_call_hook(
+            fn, hook, span=self._program_span(kind, build_kind))
 
     def _fresh_state(self):
         """Zeroed device-resident slot state, committed to the replica
@@ -771,10 +771,32 @@ class DecodeEngine:
             if self._closed:
                 raise MXNetError("decode engine %r is closed"
                                  % self.name)
-        state = self._build()
+        with self._setup_span():
+            state = self._build()
         with self._cond:
             self._boot_state = state
             self._draining = False
+
+    def _setup_span(self):
+        """``serving.setup.engine``: what a :meth:`_build` lies in, recorded
+        with tracing off (``tracing.setup_span``); under it
+        ``serving.setup.state``, ``serving.setup.warm`` and, under that
+        one, a ``serving.setup.program`` a first call.  Opened at
+        ``_build``'s two call sites, and no line added above ``_build``'s
+        closures: a kernel's lowered text carries the line numbers of its
+        call stack, theirs among them, and the account is held to leaving
+        every program's text as it was
+        (``tools/perf/program_fingerprints.py --tpu``)."""
+        return _tracing.setup_span(
+            "serving.setup.engine", model=self.name, replica=self.replica,
+            slots=self.slots, buckets=list(self.prefill_buckets))
+
+    @staticmethod
+    def _program_span(kind, build_kind):
+        """Opens ``serving.setup.program`` round a program's first call."""
+        bucket = build_kind[1] if kind == "decode_prefill" else None
+        return lambda: _tracing.setup_span("serving.setup.program",
+                                           kind=kind, bucket=bucket)
 
     # -- client side -------------------------------------------------------
     def _validate_admission(self, n, what):

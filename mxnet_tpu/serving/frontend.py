@@ -132,7 +132,8 @@ class ServingHandle:
             cc = _compile_cache.stats()
             payload["compile_cache"] = {
                 k: cc[k] for k in ("entries", "bytes", "hits", "misses",
-                                   "evictions")}
+                                   "evictions", "trace_seconds",
+                                   "lower_seconds", "lowerings")}
         # per-model KV-storage occupancy (paged decode tiers): the
         # capacity number an operator reads before anything else —
         # blocks_free hitting 0 is the "admissions will shed typed"

@@ -1092,12 +1092,15 @@ def lm_pool(cfg, params, n_replicas=2, devices=None, *, name="lm",
     """Build a :class:`ReplicaPool` of
     :class:`~mxnet_tpu.serving.decode.DecodeEngine` replicas over a
     :mod:`~mxnet_tpu.models.transformer_lm` — the standard LM-serving
-    stack (each replica gets the params committed to ITS device)."""
+    stack (each replica gets the params committed to ITS device).  The
+    construction, to every replica's loop thread running, is the span
+    ``serving.setup.pool`` (recorded with tracing off)."""
     opts = dict(engine_opts or {})
 
     def factory(device, replica_id):
         return DecodeEngine(cfg, params, device=device, name=name,
                             replica=replica_id, autostart=True, **opts)
 
-    return ReplicaPool(factory, n_replicas=n_replicas, devices=devices,
-                       name=name, version=version, **pool_opts)
+    with _tracing.setup_span("serving.setup.pool", model=name):
+        return ReplicaPool(factory, n_replicas=n_replicas, devices=devices,
+                           name=name, version=version, **pool_opts)

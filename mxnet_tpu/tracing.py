@@ -35,9 +35,11 @@ serving frontend assembles — live spans included — via :func:`tree`;
 spans whose root is a loop iteration (``loop=True``: a ``fit`` batch, a
 decode-engine iteration, a lone phase) go to a second ring of the same
 bound, so a busy loop never shortens how long a request's tree stays
-readable.  And a span opened with ``stack=True`` — opened and closed on
-one thread — is also a ``jax.profiler.TraceAnnotation`` named
-``mx.<name>`` while a device profile is being taken, so it lies in the
+readable, and the spans of :func:`setup_span` (recorded with tracing off)
+to a third, read by :func:`setup_spans`.  And a span opened with
+``stack=True`` — opened and closed on one thread — is also a
+``jax.profiler.TraceAnnotation`` named ``mx.<name>`` while a device
+profile is being taken, so it lies in the
 ``.xplane.pb`` beside the device's operations, on the profiler's own
 clock (event times there are relative to the session's start: no Python
 clock can be matched to them afterwards).
@@ -49,7 +51,11 @@ first, returning the shared falsy :data:`NULL_SPAN` — a disabled entry
 point pays one call and two branches, no clock read, no allocation.
 Enable with ``MXNET_TRACE=1`` (or :func:`enable`), or start a
 ``jax.profiler`` trace; tests/test_tracing.py and
-tests/test_tracing_profile.py pin the disabled overhead.
+tests/test_tracing_profile.py pin the disabled overhead.  The one
+exception is :func:`setup_span`, which always records: a dozen spans a
+start (the import, an engine's build and each program's first call, a
+module's ``bind``), none in a loop, so that set-up, which runs before
+anything is enabled, can be accounted for afterwards.
 
 See docs/observability.md "Distributed tracing & fleet aggregation".
 """
@@ -65,9 +71,9 @@ from collections import deque
 
 from jax.profiler import TraceAnnotation as _Annotation
 
-__all__ = ["enabled", "enable", "disable", "start_span", "host_read",
-           "frame", "current", "ctx", "tree", "spans_recent", "reset",
-           "Span", "NULL_SPAN", "STATUSES"]
+__all__ = ["enabled", "enable", "disable", "start_span", "setup_span",
+           "host_read", "frame", "current", "ctx", "tree", "spans_recent",
+           "setup_spans", "reset", "Span", "NULL_SPAN", "STATUSES"]
 
 #: the typed span statuses (``in_flight`` is synthesized for live
 #: spans in :func:`tree` reads, never stored)
@@ -92,6 +98,7 @@ def _ring_size():
 _lock = threading.Lock()
 _ring = deque(maxlen=_ring_size())   # finished spans, oldest first
 _loop_ring = deque(maxlen=_ring_size())  # ... of traces a loop rooted
+_setup_ring = deque(maxlen=_ring_size())  # ... opened by setup_span
 _live = {}                           # span_id -> Span (in flight)
 _tls = threading.local()
 
@@ -179,10 +186,10 @@ class Span:
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "t0_ns",
                  "t1_ns", "tid", "status", "attrs", "_stacked", "_loop",
-                 "_recorded", "_ann", "_ended")
+                 "_setup", "_recorded", "_ann", "_ended")
 
     def __init__(self, name, trace_id, parent_id, loop=False,
-                 recorded=True):
+                 recorded=True, setup=False):
         self.name = name
         self.trace_id = trace_id
         self.span_id = _new_id(4) if recorded else None
@@ -192,6 +199,7 @@ class Span:
         self.attrs = {}
         self._stacked = False
         self._loop = loop
+        self._setup = setup
         self._recorded = recorded
         self._ann = None
         self._ended = False
@@ -230,6 +238,11 @@ class Span:
                 "status": "in_flight" if live else self.status,
                 "attrs": dict(self.attrs)}
 
+    def _home(self):
+        """The finished ring this span belongs in (lock held)."""
+        return _setup_ring if self._setup \
+            else _loop_ring if self._loop else _ring
+
     def end(self, status="ok", **attrs):
         """Finish the span with a typed ``status``; moves it from the
         live set into its bounded finished ring and closes its
@@ -257,7 +270,7 @@ class Span:
                 self.attrs.update(attrs)
             _live.pop(self.span_id, None)
             if status is not None:
-                (_loop_ring if self._loop else _ring).append(self)
+                self._home().append(self)
         if self._stacked:
             st = getattr(_tls, "stack", None)
             # only pop when ending on the opening thread with this
@@ -308,6 +321,30 @@ def start_span(name, parent=None, trace_id=None, parent_id=None,
     if not _enabled and not live:
         return Span(name, None, None, recorded=False) if timed \
             else NULL_SPAN
+    return _open(name, parent, trace_id, parent_id, stack, loop, live,
+                 attrs)
+
+
+def setup_span(name, **attrs):
+    """Open a stacked span that records **whether tracing is on or not**:
+    set-up happens before anybody enables anything, and where a start's
+    seconds went is asked afterwards (``setup.*`` in the benchmark, a
+    flight-recorder dump, docs/observability.md "Set-up's account").  For
+    work a process does a bounded number of times (an import, an engine's
+    build, a program's first call, a ``bind``), NEVER in a loop: each one
+    costs a recorded span (some 20 us) and a place in the ring.  Same
+    :class:`Span`, same record and parenting as :func:`start_span`, an
+    ``mx.`` annotation while a profile is live; finished, it lies in a
+    ring of its own that :func:`setup_spans` reads (and :func:`tree`, and
+    the flight recorder's dump), so that traffic traced later never
+    pushes a start's account out, and :func:`spans_recent` stays what
+    tracing recorded while it was on."""
+    return _open(name, None, None, None, True, False, _profile_live(),
+                 attrs, setup=True)
+
+
+def _open(name, parent, trace_id, parent_id, stack, loop, live, attrs,
+          setup=False):
     cur = _stack()
     if parent is not None and parent:
         tid, pid, loop = parent.trace_id, parent.span_id, parent._loop
@@ -318,7 +355,7 @@ def start_span(name, parent=None, trace_id=None, parent_id=None,
         tid, pid, loop = top.trace_id, top.span_id, top._loop
     else:
         tid, pid = _new_id(8), None
-    sp = Span(name, tid, pid, loop=loop)
+    sp = Span(name, tid, pid, loop=loop, setup=setup)
     sp.tid = _tls.tid
     if attrs:
         sp.attrs.update(attrs)
@@ -332,7 +369,7 @@ def start_span(name, parent=None, trace_id=None, parent_id=None,
             oldest._ended = True
             oldest.status = "error"
             oldest.attrs["dropped"] = "live-ring-full"
-            (_loop_ring if oldest._loop else _ring).append(oldest)
+            oldest._home().append(oldest)
         _live[sp.span_id] = sp
     if stack:
         sp._stacked = True
@@ -384,8 +421,8 @@ def ctx():
 def _trace_spans(trace_id):
     """Every recorded span of one trace: finished (from the rings) plus
     live (synthesized ``in_flight``), lock held by caller."""
-    out = [sp._record() for ring in (_ring, _loop_ring) for sp in ring
-           if sp.trace_id == trace_id]
+    out = [sp._record() for ring in (_ring, _loop_ring, _setup_ring)
+           for sp in ring if sp.trace_id == trace_id]
     out.extend(sp._record(live=True) for sp in _live.values()
                if sp.trace_id == trace_id)
     return out
@@ -442,13 +479,23 @@ def spans_recent(n=1000):
     return [sp._record() for sp in done[-int(n):]]
 
 
+def setup_spans():
+    """Every finished span that :func:`setup_span` opened (copies, in the
+    order they ended; at most the ring's bound): a start's account, there
+    with tracing off."""
+    with _lock:
+        done = list(_setup_ring)
+    return [sp._record() for sp in done]
+
+
 def reset():
     """Clear the finished rings and the live set (tests; enablement and
     other threads' stacks are unchanged)."""
-    global _ring, _loop_ring
+    global _ring, _loop_ring, _setup_ring
     with _lock:
         _ring = deque(maxlen=_ring_size())
         _loop_ring = deque(maxlen=_ring_size())
+        _setup_ring = deque(maxlen=_ring_size())
         _live.clear()
     st = getattr(_tls, "stack", None)
     if st:
