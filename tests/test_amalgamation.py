@@ -52,7 +52,7 @@ def test_amalgamated_lenet_matches_framework(tmp_path):
     env = {k: v for k, v in os.environ.items()
            if k not in ("PYTHONPATH",)}
     subprocess.run([sys.executable, "-c", script], check=True, env=env,
-                   cwd=str(tmp_path))
+                   cwd=str(tmp_path), timeout=300)
     got = np.load(str(tmp_path / "out.npy"))
 
     # framework reference forward

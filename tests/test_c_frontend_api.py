@@ -446,7 +446,7 @@ def test_c_frontend_api_end_to_end(tmp_path):
         ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
          os.path.join(REPO, "src", "frontend_capi.cc"),
          "-I", inc, "-o", str(lib)],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-2000:]
     driver = tmp_path / "driver.py"
     driver.write_text(_DRIVER)
@@ -454,7 +454,7 @@ def test_c_frontend_api_end_to_end(tmp_path):
     env.pop("XLA_FLAGS", None)
     r = subprocess.run(
         [sys.executable, str(driver), str(lib), str(tmp_path)],
-        env=env, capture_output=True, text=True, timeout=420)
+        env=env, capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, (r.stdout[-800:], r.stderr[-2500:])
     assert "C FRONTEND ABI OK" in r.stdout
 
@@ -473,7 +473,7 @@ def test_c_train_client_end_to_end(tmp_path):
         ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
          os.path.join(REPO, "src", "frontend_capi.cc"),
          "-I", inc, "-o", str(lib)],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-2000:]
     exe = tmp_path / "c_train"
     r = subprocess.run(
@@ -483,12 +483,12 @@ def test_c_train_client_end_to_end(tmp_path):
          "-L", libdir, "-l" + pylib,
          "-Wl,-rpath," + str(tmp_path), "-Wl,-rpath," + libdir,
          "-lm", "-o", str(exe)],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-2000:]
     env = dict(os.environ, MXNET_TPU_HOME=REPO, JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([str(exe)], env=env, capture_output=True,
-                       text=True, timeout=420)
+                       text=True, timeout=300)
     assert r.returncode == 0, (r.stdout[-500:], r.stderr[-2000:])
     assert "C TRAIN OK" in r.stdout
 

@@ -83,7 +83,7 @@ def test_c_predict_api_end_to_end(tmp_path):
         ["g++", "-O2", "-shared", "-fPIC", "-std=c++17",
          os.path.join(REPO, "src", "predict_capi.cc"),
          "-I", inc, "-o", str(lib)],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-1500:]
     exe = tmp_path / "cpred_test"
     csrc = tmp_path / "t.c"
@@ -94,7 +94,7 @@ def test_c_predict_api_end_to_end(tmp_path):
          "-L", str(tmp_path), "-lmxnet_tpu_predict",
          "-L", libdir, "-l" + pylib,
          "-Wl,-rpath," + str(tmp_path), "-Wl,-rpath," + libdir],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-1500:]
     env = dict(os.environ, MXNET_TPU_HOME=REPO, JAX_PLATFORMS="cpu")
     r = subprocess.run([str(exe), prefix + "-symbol.json",

@@ -30,12 +30,12 @@ def test_cpp_frontend_trains(tmp_path):
     else:
         env.pop("LD_LIBRARY_PATH", None)
     subprocess.run(["cmake", "-B", build, "-G", "Ninja", CPP],
-                   check=True, capture_output=True, text=True)
+                   check=True, capture_output=True, text=True, timeout=300)
     subprocess.run(["ninja", "-C", build], check=True,
-                   capture_output=True, text=True)
+                   capture_output=True, text=True, timeout=300)
     proc = subprocess.run(
         [os.path.join(build, "train_mlp"), ROOT],
-        capture_output=True, text=True, timeout=400, env=env)
+        capture_output=True, text=True, timeout=300, env=env)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "C++ frontend training OK" in proc.stdout
 
@@ -58,12 +58,12 @@ def test_cpp_convnet_generated_ops_trains(tmp_path):
     else:
         env.pop("LD_LIBRARY_PATH", None)
     subprocess.run(["cmake", "-B", build, "-G", "Ninja", CPP],
-                   check=True, capture_output=True, text=True)
+                   check=True, capture_output=True, text=True, timeout=300)
     subprocess.run(["ninja", "-C", build, "train_convnet"], check=True,
-                   capture_output=True, text=True)
+                   capture_output=True, text=True, timeout=300)
     proc = subprocess.run(
         [os.path.join(build, "train_convnet"), ROOT],
-        capture_output=True, text=True, timeout=400, env=env)
+        capture_output=True, text=True, timeout=300, env=env)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "C++ convnet (generated op wrappers) OK" in proc.stdout
 
@@ -83,7 +83,8 @@ def test_generated_op_header_is_fresh(tmp_path):
     subprocess.run([sys.executable,
                     os.path.join(CPP, "OpWrapperGenerator.py"),
                     "--out", fresh],
-                   check=True, capture_output=True, text=True, env=env)
+                   check=True, capture_output=True, text=True, env=env,
+                   timeout=300)
     with open(committed) as f:
         before = f.read()
     with open(fresh) as f:

@@ -15,6 +15,7 @@ import time
 import urllib.error
 import urllib.request
 
+import jax
 import numpy as np
 import pytest
 
@@ -56,6 +57,38 @@ def _engine(cfg=CFG_NO_EOS, **kw):
     opts = dict(ENGINE_OPTS)
     opts.update(kw)
     return DecodeEngine(cfg, PARAMS, name="lm", **opts)
+
+
+@pytest.fixture(scope="module")
+def shared():
+    """ONE running engine at ``ENGINE_OPTS`` for the tests that only send
+    it traffic: tier-1 runs with the compile cache off, so every engine
+    built compiles its step and its buckets anew.  A test that stops it
+    starts it again; one that needs slots, buckets, a layout or telemetry
+    at the build of its own builds its own."""
+    eng = _engine()
+    yield eng
+    eng.close(drain=False)
+
+
+#: the full forward under one jit
+_forward = jax.jit(tlm.forward_logits, static_argnums=0)
+
+
+def _teacher_forced(cfg, prompt, new):
+    """Iterated argmax of the full forward, up to ``new`` tokens or the
+    EOS.  The model is causal, so each token is read at the last real
+    position of a row padded to ``MAX_LEN``: ONE shape, where a row a
+    length longer each time compiles every operation anew a token."""
+    toks = list(prompt)
+    for _ in range(new):
+        padded = np.zeros((1, MAX_LEN), np.int32)
+        padded[0, :len(toks)] = toks
+        toks.append(int(_forward(cfg, PARAMS, padded)[0, len(toks) - 1]
+                        .argmax()))
+        if toks[-1] == cfg.eos_id:
+            break
+    return toks[len(prompt):]
 
 
 def _compiles():
@@ -127,23 +160,11 @@ def test_entry_point_traces_the_one_block_once_a_layer(entry, monkeypatch):
 
 # -- engine: correctness ----------------------------------------------------
 
-def test_greedy_decode_matches_full_forward():
+def test_greedy_decode_matches_full_forward(shared):
     """The slot decode path is bit-compatible with teacher forcing:
     greedy generation == iterated argmax of the full forward."""
-    import jax.numpy as jnp
-
-    eng = _engine()
-    try:
-        out = eng.generate(PROMPT, max_new_tokens=6, timeout=120)
-        ref_tokens = list(PROMPT)
-        for _ in range(6):
-            logits = tlm.forward_logits(
-                CFG_NO_EOS, PARAMS,
-                jnp.asarray(np.array([ref_tokens], np.int32)))
-            ref_tokens.append(int(jnp.argmax(logits[0, -1])))
-        assert out == ref_tokens[len(PROMPT):]
-    finally:
-        eng.close()
+    out = shared.generate(PROMPT, max_new_tokens=6, timeout=120)
+    assert out == _teacher_forced(CFG_NO_EOS, PROMPT, 6)
 
 
 def _scatter_rows(cache, rows, pos):
@@ -200,7 +221,7 @@ def test_dense_step_writes_the_rows_the_scatter_wrote(lengths, monkeypatch):
         np.testing.assert_array_equal(g, w)
 
 
-def test_step_lowers_for_the_shapes_the_benchmark_lowers_it_with():
+def test_step_lowers_for_the_shapes_the_benchmark_lowers_it_with(shared):
     """``benchmark/families/decode_engine.py`` ``scratch_bytes`` lowers
     ``engine._step_fn`` again after every window, on the chip only, from
     shapes alone: per-layer K and V of ``(slots, max_len, heads,
@@ -209,49 +230,35 @@ def test_step_lowers_for_the_shapes_the_benchmark_lowers_it_with():
     import jax
     import jax.numpy as jnp
 
-    eng = _engine(autostart=False)
-    try:
-        cfg, s = eng.cfg, eng.slots
+    eng = shared
+    cfg, s = eng.cfg, eng.slots
 
-        def sds(shape, dtype):
-            return jax.ShapeDtypeStruct(shape, dtype)
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
 
-        kv = sds((s, cfg.max_len, cfg.heads, cfg.embed // cfg.heads),
-                 jnp.float32)
-        state = (tuple(kv for _ in range(cfg.layers)),
-                 tuple(kv for _ in range(cfg.layers)),
-                 sds((s,), jnp.int32), sds((s,), jnp.int32),
-                 sds((s,), jnp.int32), sds((s,), jnp.bool_),
-                 sds((s,), jnp.float32), sds((s,), jnp.uint32))
-        params = jax.tree_util.tree_map(
-            lambda a: sds(a.shape, a.dtype), eng._params)
-        lowered = eng._step_fn.lower(params, state, sds((s,), jnp.bool_))
-        assert lowered.compile().memory_analysis() \
-            .temp_size_in_bytes >= 0
-        new_state, packed = lowered.out_info
-        assert jax.tree_util.tree_map(
-            lambda a: (a.shape, a.dtype), new_state) == \
-            jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), state)
-        assert packed.shape == (3, s)
-    finally:
-        eng.close()
+    kv = sds((s, cfg.max_len, cfg.heads, cfg.embed // cfg.heads),
+             jnp.float32)
+    state = (tuple(kv for _ in range(cfg.layers)),
+             tuple(kv for _ in range(cfg.layers)),
+             sds((s,), jnp.int32), sds((s,), jnp.int32),
+             sds((s,), jnp.int32), sds((s,), jnp.bool_),
+             sds((s,), jnp.float32), sds((s,), jnp.uint32))
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), eng._params)
+    lowered = eng._step_fn.lower(params, state, sds((s,), jnp.bool_))
+    assert lowered.compile().memory_analysis().temp_size_in_bytes >= 0
+    new_state, packed = lowered.out_info
+    assert jax.tree_util.tree_map(
+        lambda a: (a.shape, a.dtype), new_state) == \
+        jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), state)
+    assert packed.shape == (3, s)
 
 
 def test_eos_retires_early_and_is_included():
     """With a reachable EOS the sequence stops at it (EOS is the last
     token) instead of running to max_new_tokens; either way the decode
     path tracks the teacher-forcing reference exactly."""
-    import jax.numpy as jnp
-
-    ref, toks = [], list(PROMPT)
-    for _ in range(20):
-        logits = tlm.forward_logits(
-            CFG_EOS, PARAMS, jnp.asarray(np.array([toks], np.int32)))
-        nxt = int(jnp.argmax(logits[0, -1]))
-        ref.append(nxt)
-        toks.append(nxt)
-        if nxt == CFG_EOS.eos_id:
-            break
+    ref = _teacher_forced(CFG_EOS, PROMPT, 20)
     eng = _engine(cfg=CFG_EOS)
     try:
         out = eng.generate(PROMPT, max_new_tokens=20, timeout=120)
@@ -262,42 +269,31 @@ def test_eos_retires_early_and_is_included():
         eng.close()
 
 
-def test_temperature_stream_is_seeded_and_valid():
+def test_temperature_stream_is_seeded_and_valid(shared):
     """Temperature sampling draws through mx.random key material: same
     seed => same stream, and every token is a valid id."""
     mx.random.seed(11)
-    eng = _engine()
-    try:
-        a = eng.generate(PROMPT, max_new_tokens=8, temperature=0.8,
-                         timeout=120)
-    finally:
-        eng.close()
+    a = shared.generate(PROMPT, max_new_tokens=8, temperature=0.8,
+                        timeout=120)
     mx.random.seed(11)
-    eng = _engine()
-    try:
-        b = eng.generate(PROMPT, max_new_tokens=8, temperature=0.8,
-                         timeout=120)
-    finally:
-        eng.close()
+    b = shared.generate(PROMPT, max_new_tokens=8, temperature=0.8,
+                        timeout=120)
     assert a == b and len(a) == 8
     assert all(0 <= t < VOCAB for t in a)
 
 
-def test_invalid_requests_fail_at_submit():
-    eng = _engine()
-    try:
-        with pytest.raises(InvalidRequest):
-            eng.submit([], max_new_tokens=3)
-        with pytest.raises(InvalidRequest):
-            eng.submit(list(range(1, 10)), max_new_tokens=3)  # > bucket 8
-        with pytest.raises(InvalidRequest):
-            eng.submit([VOCAB + 3], max_new_tokens=3)  # bad token id
-        with pytest.raises(InvalidRequest):
-            eng.submit(PROMPT, max_new_tokens=0)
-        with pytest.raises(InvalidRequest):
-            eng.submit(PROMPT, max_new_tokens=3, temperature=-1.0)
-    finally:
-        eng.close()
+def test_invalid_requests_fail_at_submit(shared):
+    eng = shared
+    with pytest.raises(InvalidRequest):
+        eng.submit([], max_new_tokens=3)
+    with pytest.raises(InvalidRequest):
+        eng.submit(list(range(1, 10)), max_new_tokens=3)  # > bucket 8
+    with pytest.raises(InvalidRequest):
+        eng.submit([VOCAB + 3], max_new_tokens=3)  # bad token id
+    with pytest.raises(InvalidRequest):
+        eng.submit(PROMPT, max_new_tokens=0)
+    with pytest.raises(InvalidRequest):
+        eng.submit(PROMPT, max_new_tokens=3, temperature=-1.0)
 
 
 # -- engine: continuous batching lifecycle ----------------------------------
@@ -345,16 +341,12 @@ def test_mid_decode_admission_joins_running_batch():
         eng.close()
 
 
-def test_streaming_callback_receives_every_token_in_order():
+def test_streaming_callback_receives_every_token_in_order(shared):
     got = []
-    eng = _engine()
-    try:
-        sess = eng.submit(PROMPT, max_new_tokens=6, on_token=got.append)
-        out = sess.result(60)
-        assert got == out and len(out) == 6
-        assert sess.ttft() is not None and sess.ttft() >= 0
-    finally:
-        eng.close()
+    sess = shared.submit(PROMPT, max_new_tokens=6, on_token=got.append)
+    out = sess.result(60)
+    assert got == out and len(out) == 6
+    assert sess.ttft() is not None and sess.ttft() >= 0
 
 
 def test_cancel_mid_generation_frees_the_slot():
@@ -416,11 +408,11 @@ def test_queue_overload_and_deadline_shed():
         eng.close()
 
 
-def test_decode_fault_fails_batch_and_engine_survives():
+def test_decode_fault_fails_batch_and_engine_survives(shared):
     """The serving.decode fault point kills one step: every active
     session gets the error, the worker survives and serves the next
     request from a clean slot state."""
-    eng = _engine()
+    eng = shared
     try:
         faults.arm("serving.decode", at=1)
         sess = eng.submit(PROMPT, max_new_tokens=6)
@@ -428,14 +420,14 @@ def test_decode_fault_fails_batch_and_engine_survives():
             sess.result(60)
         faults.disarm()
         out = eng.generate(PROMPT, max_new_tokens=6, timeout=60)
-        assert len(out) == 6
+        assert out == _teacher_forced(CFG_NO_EOS, PROMPT, 6)
         assert telemetry.counter_total("serving.error.count") == 1
     finally:
         faults.disarm()
-        eng.close()
 
 
 def test_telemetry_families_present_after_traffic():
+    # its own engine: the gauges' first values are set at the build
     eng = _engine()
     try:
         eng.generate(PROMPT, max_new_tokens=5, timeout=60)
@@ -537,18 +529,42 @@ PIPE_OPTS = {"slots": 3, "prefill_buckets": (4, 8, 32), "max_queue": 64}
 _REFERENCES = {}
 
 
-def _serial_reference(layout, temperature):
+@pytest.fixture(scope="module")
+def piped():
+    """``piped(layout)``: ONE engine a layout at ``PIPE_OPTS``, built at
+    first use and not started.  The serial reference runs over its
+    programs; a test queues its sessions on it, starts it, and at its end
+    puts it back as it was built (:func:`_as_built`)."""
+    built = {}
+
+    def get(layout):
+        if layout not in built:
+            built[layout] = _engine(autostart=False, kv_layout=layout,
+                                    **PIPE_OPTS)
+        return built[layout]
+
+    yield get
+    for eng in built.values():
+        eng.close(drain=False)
+
+
+def _as_built(eng):
+    """Stops a shared engine and lets it take sessions again before its
+    next start, as one just built does: the next start makes the slot
+    state (and the block pool) anew."""
+    eng.stop(drain=False)
+    eng._draining = False
+    assert eng.outstanding() == 0 and eng._boot_state is None
+
+
+def _serial_reference(eng, temperature):
     """``REQUESTS`` through ``tools/perf/serial_loop.py``'s plain serial
-    loop over an engine's own ``jit_prefill`` and ``jit_step``: what the
-    engine's loop has to equal, session by session."""
-    key = (layout, temperature)
+    loop over a stopped engine's own ``jit_prefill`` and ``jit_step``:
+    what the engine's loop has to equal, session by session."""
+    key = (eng.kv_layout, temperature)
     if key not in _REFERENCES:
-        eng = _engine(autostart=False, kv_layout=layout, **PIPE_OPTS)
-        try:
-            tokens = serial_loop(eng, [(p, new, temperature, seed)
-                                       for p, new, seed in REQUESTS])
-        finally:
-            eng.close(drain=False)
+        tokens = serial_loop(eng, [(p, new, temperature, seed)
+                                   for p, new, seed in REQUESTS])
         assert [len(t) for t in tokens] == [new for _p, new, _s in REQUESTS]
         _REFERENCES[key] = tokens
     return _REFERENCES[key]
@@ -580,20 +596,20 @@ def _submit_all(target, temperature, streams, **first_kw):
 @pytest.mark.parametrize("layout", ["dense", "paged"])
 @pytest.mark.parametrize("temperature", [0.0, 0.8],
                          ids=["greedy", "sampled"])
-def test_streams_equal_the_serial_loops(temperature, layout, scenario):
+def test_streams_equal_the_serial_loops(temperature, layout, scenario,
+                                        piped):
     """Token for token, and in the order ``on_token`` saw them, every
     session's stream is what a plain serial loop over the same programs
     gives — whole where the session finished, a prefix where a cancel, a
     deadline or a step fault ended it; a stream is never ahead of or
     behind its transcript."""
-    want = _serial_reference(layout, temperature)
-    opts = dict(PIPE_OPTS, kv_layout=layout)
+    want = _serial_reference(piped(layout), temperature)
     streams, cut = [], {}
     if scenario == "migration":
         target = lm_pool(CFG_NO_EOS, PARAMS, n_replicas=2, name="lm",
-                         engine_opts=opts)
+                         engine_opts=dict(PIPE_OPTS, kv_layout=layout))
     else:
-        target = _engine(autostart=False, **opts)
+        target = piped(layout)
     try:
         first_kw = {}
         if scenario == "cancel":
@@ -638,19 +654,22 @@ def test_streams_equal_the_serial_loops(temperature, layout, scenario):
             assert sum(s.migrations for s in sessions) >= 1
     finally:
         faults.disarm()
-        target.close(drain=False)
+        if scenario == "migration":
+            target.close(drain=False)
+        else:
+            _as_built(target)
 
 
 @pytest.mark.parametrize("how", ["drain", "stop", "hand_off"])
-def test_a_stop_loses_no_delivered_token_and_delivers_none_twice(how):
+def test_a_stop_loses_no_delivered_token_and_delivers_none_twice(how, piped):
     """A drain finishes what holds a slot; a plain stop and a hand-over
     land the step in flight first, so a transcript is exactly the stream
     its client saw, and a handed-over session resumed elsewhere ends with
     the serial loop's tokens, none lost and none repeated."""
-    want = _serial_reference("dense", 0.8)
+    eng = piped("dense")
+    want = _serial_reference(eng, 0.8)
     streams, handed = [], []
     mid = threading.Event()
-    eng = _engine(autostart=False, **PIPE_OPTS)
     other = None
     try:
         sessions = _submit_all(eng, 0.8, streams,
@@ -682,7 +701,7 @@ def test_a_stop_loses_no_delivered_token_and_delivers_none_twice(how):
         elif how == "hand_off":
             assert whole == len(REQUESTS)
     finally:
-        eng.close(drain=False)
+        _as_built(eng)
         if other is not None:
             other.close(drain=False)
 
@@ -1127,21 +1146,18 @@ def test_queued_cancel_released_while_all_slots_busy():
         eng.close()
 
 
-def test_engine_stop_start_restarts_without_recompile():
+def test_engine_stop_start_restarts_without_recompile(shared):
     """A plain stop()+start() cycle restarts the engine: compiled
     programs survive, slot state rebuilds from zeros, and traffic flows
     again with ZERO new compiles."""
-    eng = _engine()
-    try:
-        assert len(eng.generate(PROMPT, max_new_tokens=3, timeout=60)) == 3
-        c0 = _compiles()
-        assert eng.stop() is True
-        eng.start()
-        out = eng.generate(PROMPT, max_new_tokens=3, timeout=60)
-        assert len(out) == 3
-        assert _compiles() == c0, "restart must not recompile"
-    finally:
-        eng.close()
+    eng = shared
+    first = eng.generate(PROMPT, max_new_tokens=3, timeout=60)
+    assert len(first) == 3
+    c0 = _compiles()
+    assert eng.stop() is True
+    eng.start()
+    assert eng.generate(PROMPT, max_new_tokens=3, timeout=60) == first
+    assert _compiles() == c0, "restart must not recompile"
 
 
 def test_pool_init_failure_closes_built_replicas():

@@ -93,7 +93,7 @@ def test_dist_sync_in_graph_two_workers(tmp_path, opt_name):
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "launch.py"),
          "-n", "2", sys.executable, str(script)],
-        env=env, timeout=540, capture_output=True, text=True)
+        env=env, timeout=300, capture_output=True, text=True)
     assert proc.returncode == 0, (proc.stdout[-1500:], proc.stderr[-3000:])
     assert (tmp_path / "ok.0").exists() and (tmp_path / "ok.1").exists()
 
@@ -244,7 +244,7 @@ def test_dist_sync_in_graph_bn_dropout(tmp_path):
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "launch.py"),
          "-n", "2", sys.executable, str(script)],
-        env=env, timeout=540, capture_output=True, text=True)
+        env=env, timeout=300, capture_output=True, text=True)
     assert proc.returncode == 0, (proc.stdout[-1500:], proc.stderr[-3000:])
     p0 = dict(np.load(tmp_path / "bnp.0.npz"))
     p1 = dict(np.load(tmp_path / "bnp.1.npz"))
@@ -316,7 +316,7 @@ def test_four_workers_two_servers_both_planes(tmp_path):
          "-n", "4", "-s", "2",
          "--env", "MXNET_KVSTORE_BIGARRAY_BOUND=8",
          sys.executable, str(script)],
-        env=env, timeout=540, capture_output=True, text=True)
+        env=env, timeout=300, capture_output=True, text=True)
     assert proc.returncode == 0, (proc.stdout[-1500:], proc.stderr[-3000:])
     ws = [np.load(tmp_path / ("w%d.npy" % r)) for r in range(4)]
     for r in range(1, 4):

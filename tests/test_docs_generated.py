@@ -19,7 +19,7 @@ def test_generated_doc_is_fresh(tool, committed, tmp_path):
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run([sys.executable, os.path.join(ROOT, tool),
                            "--out", fresh],
-                          capture_output=True, text=True, env=env)
+                          capture_output=True, text=True, env=env, timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
     with open(os.path.join(ROOT, committed)) as f:
         want = f.read()

@@ -20,6 +20,12 @@ WINDOW = 8
 BLOCK = 16
 
 
+#: the plain reference under one jit: a new length compiles one program,
+#: where the bare call compiles each of its operations anew
+_forward = jax.jit(xm.forward_logits, static_argnums=0,
+                   static_argnames="with_choices")
+
+
 @pytest.fixture
 def decode_kernel(monkeypatch):
     """The full layer's attention through the Pallas kernel, run by the
@@ -64,21 +70,41 @@ _SESSIONS = [
 ]
 
 
+@pytest.fixture(scope="module")
+def programs():
+    """``programs(path)``: the model of :func:`_prefill_then_decode` with
+    ONE jit of its prefill and of its step a path (``plain``, or
+    ``kernel``: traced under :func:`decode_kernel`), so that a session
+    compiles only the bucket no session before it had."""
+    built = {}
+
+    def get(path):
+        if path not in built:
+            model = xm.ExaoneMoE(_cfg(first_expert=4, experts_held=8),
+                                 jnp.float32)
+            built[path] = (model, jax.jit(model.prefill),
+                           jax.jit(model.decode_step))
+        return built[path]
+
+    return get
+
+
 @pytest.mark.parametrize("prompt,bucket,new", _SESSIONS)
-def test_prefill_then_decode_equal_the_reference_logits(prompt, bucket, new):
+def test_prefill_then_decode_equal_the_reference_logits(prompt, bucket, new,
+                                                        programs):
     """Off the TPU the full layer reads every row: a slot is one block."""
-    counted = _prefill_then_decode(prompt, bucket, new)
+    counted = _prefill_then_decode(programs("plain"), prompt, bucket, new)
     assert counted["attn_blocks_read"] == counted["attn_blocks_held"] == new
     assert counted["gauges"]["serving.attn.rows_read_share"] == 1.0
 
 
 @pytest.mark.parametrize("prompt,bucket,new", _SESSIONS)
 def test_prefill_then_decode_through_the_decode_kernel(
-        prompt, bucket, new, decode_kernel):
+        prompt, bucket, new, decode_kernel, programs):
     """The same sessions with the full layer's attention in the Pallas
     kernel (interpreter), beside two idle slots of length 0 that ride
     along; the counters say which blocks of the slot's 3 it read."""
-    counted = _prefill_then_decode(prompt, bucket, new)
+    counted = _prefill_then_decode(programs("kernel"), prompt, bucket, new)
     read = sum(p // BLOCK + 1 for p in range(prompt, prompt + new))
     assert counted["attn_blocks_read"] == read
     assert counted["attn_blocks_held"] == 3 * new
@@ -87,26 +113,24 @@ def test_prefill_then_decode_through_the_decode_kernel(
         == pytest.approx(read / (3.0 * new))
 
 
-def _prefill_then_decode(prompt, bucket, new):
-    cfg = _cfg(first_expert=4, experts_held=8)
+def _prefill_then_decode(programs, prompt, bucket, new):
+    model, prefill, step = programs
+    cfg = model.cfg
     params = xm.init_params(cfg, seed=prompt, dtype=jnp.float32)
-    model = xm.ExaoneMoE(cfg, jnp.float32)
     tokens = np.random.RandomState(prompt).randint(0, cfg.vocab,
                                                    prompt + new)
-    want = np.asarray(xm.forward_logits(cfg, params, jnp.asarray(tokens)))
+    want = np.asarray(_forward(cfg, params, jnp.asarray(tokens)))
     slots, slot = 3, 1
     cache = [[jnp.zeros((slots,) + tlm.slot_shape(c), c.dtype)
               for c in model.cache_spec()] for _ in range(2)]
     padded = np.zeros((bucket,), np.int32)
     padded[:prompt] = tokens[:prompt]
-    last, ks, vs = jax.jit(model.prefill)(params, jnp.asarray(padded),
-                                          jnp.int32(prompt))
+    last, ks, vs = prefill(params, jnp.asarray(padded), jnp.int32(prompt))
     np.testing.assert_allclose(last, want[prompt - 1], atol=1e-4)
     for side, rows in zip(cache, (ks, vs)):
         for l, r in enumerate(rows):
             side[l] = jax.lax.dynamic_update_slice(side[l], r[None],
                                                    (slot, 0, 0, 0))
-    step = jax.jit(model.decode_step)
     extra = model.extra_state()
     active = jnp.arange(slots) == slot
     ck, cv = tuple(cache[0]), tuple(cache[1])
@@ -204,8 +228,7 @@ def _through_the_pool(seed, block):
         rows = blocks_read = 0
         for (prompt, new), out in zip(asked, served):
             seq = jnp.asarray(np.concatenate([prompt, out]))
-            logits, choices = xm.forward_logits(cfg, params, seq,
-                                                with_choices=True)
+            logits, choices = _forward(cfg, params, seq, with_choices=True)
             n = len(prompt)
             assert np.asarray(jnp.argmax(logits, -1))[n - 1:-1].tolist() \
                 == list(out)

@@ -375,7 +375,7 @@ def _run_host_sync(*args):
     return subprocess.run(
         [sys.executable, "-m", "ci.graftlint", "--pass", "host-sync",
          *[str(a) for a in args]],
-        capture_output=True, text=True, cwd=ROOT)
+        capture_output=True, text=True, cwd=ROOT, timeout=300)
 
 
 def test_check_host_sync_hot_path_is_clean():

@@ -1624,7 +1624,7 @@ def test_server_of_routing_is_hashseed_stable():
         env = dict(os.environ, PYTHONHASHSEED=seed,
                    JAX_PLATFORMS="cpu")
         proc = subprocess.run([sys.executable, "-c", code], env=env,
-                              capture_output=True, text=True)
+                              capture_output=True, text=True, timeout=300)
         assert proc.returncode == 0, proc.stderr
         assert proc.stdout.strip() == str(list(want.values()))
 

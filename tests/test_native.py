@@ -251,5 +251,6 @@ int main(void) {
     exe = tmp_path / "t"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     subprocess.run(["gcc", "-I", os.path.join(repo, "include"), str(src),
-                    "-o", str(exe), _LIB_PATH, "-lpthread"], check=True)
-    subprocess.run([str(exe)], check=True)
+                    "-o", str(exe), _LIB_PATH, "-lpthread"], check=True,
+                   timeout=300)
+    subprocess.run([str(exe)], check=True, timeout=300)

@@ -234,7 +234,7 @@ def test_speedometer_logs_smoothed_rate(caplog):
 def _run_check_print(path):
     return subprocess.run(
         [sys.executable, "-m", "ci.graftlint", "--pass", "print",
-         str(path)], capture_output=True, text=True, cwd=ROOT)
+         str(path)], capture_output=True, text=True, cwd=ROOT, timeout=300)
 
 
 def test_check_print_flags_bare_print(tmp_path):
@@ -255,7 +255,7 @@ def test_check_print_honors_noqa_and_strings(tmp_path):
 def test_check_print_clean_on_framework_tree():
     proc = subprocess.run(
         [sys.executable, "-m", "ci.graftlint", "--pass", "print"],
-        capture_output=True, text=True, cwd=ROOT)
+        capture_output=True, text=True, cwd=ROOT, timeout=300)
     assert proc.returncode == 0, proc.stdout
 
 
@@ -265,7 +265,7 @@ def _run_check_env_docs(*paths):
     return subprocess.run(
         [sys.executable, "-m", "ci.graftlint", "--pass", "env-docs"]
         + [str(p) for p in paths], capture_output=True, text=True,
-        cwd=ROOT)
+        cwd=ROOT, timeout=300)
 
 
 def test_check_env_docs_flags_undocumented_var(tmp_path):

@@ -414,7 +414,7 @@ GATE = os.path.join(ROOT, "ci", "check_bench_gate.py")
 
 def _run_gate(*args):
     return subprocess.run([sys.executable, GATE, *args],
-                          capture_output=True, text=True)
+                          capture_output=True, text=True, timeout=300)
 
 
 def _bench_file(tmp_path, rows):
@@ -480,7 +480,7 @@ def test_flight_recorder_env_implies_telemetry(tmp_path):
          "from mxnet_tpu import telemetry, perfdebug; "
          "assert telemetry.enabled(); "
          "assert perfdebug.flight_enabled()"],
-        capture_output=True, text=True, env=env, cwd=ROOT)
+        capture_output=True, text=True, env=env, cwd=ROOT, timeout=300)
     assert r.returncode == 0, r.stderr
 
 

@@ -38,7 +38,7 @@ def _build_xs_module(tmp_path, capi_src, pkg_dir, libname):
          os.path.join(REPO, "src", capi_src),
          "-I", inc, "-o", str(lib),
          "-L", libdir, "-l" + pylib, "-Wl,-rpath," + libdir],
-        capture_output=True, text=True)
+        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-1500:]
 
     build = tmp_path / "perlbuild"
@@ -55,10 +55,10 @@ def _build_xs_module(tmp_path, capi_src, pkg_dir, libname):
     else:
         env.pop("LD_LIBRARY_PATH", None)
     r = subprocess.run(["perl", "Makefile.PL"], cwd=build, env=env,
-                       capture_output=True, text=True)
+                       capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stdout + r.stderr
     r = subprocess.run(["make"], cwd=build, env=env,
-                       capture_output=True, text=True)
+                       capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stdout[-1500:] + r.stderr[-1500:]
     return build, env
 
@@ -193,7 +193,7 @@ def test_perl_training_matches_python(tmp_path):
          "-I", str(build / "blib" / "arch"),
          script, init_file, data_file, out_file,
          str(epochs), str(lr), str(batch)],
-        env=env, capture_output=True, text=True, timeout=600)
+        env=env, capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-2500:])
     assert "TRAIN DONE" in r.stdout
     perl_losses = [float(line.split()[3])

@@ -415,13 +415,13 @@ def test_signal_restore_lint(tmp_path):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     lint = [sys.executable, "-m", "ci.graftlint", "--pass",
             "signal-restore"]
-    assert subprocess.run(lint, cwd=root).returncode == 0
+    assert subprocess.run(lint, cwd=root, timeout=300).returncode == 0
     bad = tmp_path / "bad.py"
     bad.write_text("import signal\n"
                    "def f():\n"
                    "    signal.signal(signal.SIGTERM, None)\n")
     proc = subprocess.run(lint + [str(bad)], capture_output=True,
-                          text=True, cwd=root)
+                          text=True, cwd=root, timeout=300)
     assert proc.returncode == 1
     assert "without a matching restore" in proc.stdout
 

@@ -171,9 +171,10 @@ def test_im2rec_tool(tmp_path):
     prefix = str(tmp_path / "ds")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     subprocess.run([sys.executable, os.path.join(REPO, "tools/im2rec.py"),
-                    "--list", prefix, str(root)], check=True, env=env)
+                    "--list", prefix, str(root)], check=True, env=env,
+                   timeout=300)
     subprocess.run([sys.executable, os.path.join(REPO, "tools/im2rec.py"),
-                    prefix, str(root)], check=True, env=env)
+                    prefix, str(root)], check=True, env=env, timeout=300)
     it = image.ImageIter(batch_size=4, data_shape=(3, 20, 20),
                          path_imgrec=prefix + ".rec")
     n = sum(b.data[0].shape[0] - b.pad for b in it)

@@ -47,8 +47,14 @@ def _params(seed=0):
 PARAMS = _params()
 
 
+#: the plain reference under one jit: a new length compiles one program,
+#: where the bare call compiles each of its operations anew (and lies 6e-6
+#: further from the programs, which are jitted too)
+_forward = jax.jit(sb.forward_logits, static_argnums=0)
+
+
 def _reference(tokens, params=PARAMS):
-    return np.asarray(sb.forward_logits(CFG, params, jnp.asarray(tokens)))
+    return np.asarray(_forward(CFG, params, jnp.asarray(tokens)))
 
 
 def test_the_layer_kinds_of_the_published_depth():
@@ -137,6 +143,14 @@ def test_the_scans_plan_follows_the_shapes():
 
 
 # -- prefill and decode step on their own --------------------------------------
+@pytest.fixture(scope="module")
+def programs():
+    """The model with ONE jit of its prefill and of its step for every
+    case below: a case compiles only the shapes no case before it had."""
+    model = sb.SambaY(CFG, jnp.float32)
+    return model, jax.jit(model.prefill), jax.jit(model.decode_step)
+
+
 def _slot_state(model, slots, fill):
     return [[jnp.full((slots,) + shape, fill, dtype)
              for shape, dtype in (tlm.slot_arrays(c)[i]
@@ -152,19 +166,19 @@ def _slot_state(model, slots, fill):
     (12, 32, 10),    # longer than the window, in a padded bucket
     (30, 32, 20)])   # the ring wraps several times
 def test_prefill_then_decode_steps_give_the_references_logits(prompt, bucket,
-                                                              new):
+                                                              new, programs):
     """A padded prompt through ``prefill`` (the layers after the full one
     for the last position alone) gives the reference's last logits, and
     the state it leaves in a slot that held another session's carries the
     decode steps to the reference's logits at every later position."""
-    model = sb.SambaY(CFG, jnp.float32)
+    model, prefill, step = programs
     tokens = np.random.RandomState(prompt).randint(0, CFG.vocab,
                                                    prompt + new)
     want = _reference(tokens)
     padded = np.full((bucket,), 7, np.int32)       # padding is not token 0
     padded[:prompt] = tokens[:prompt]
-    last, firsts, seconds = jax.jit(model.prefill)(
-        PARAMS, jnp.asarray(padded), jnp.int32(prompt))
+    last, firsts, seconds = prefill(PARAMS, jnp.asarray(padded),
+                                    jnp.int32(prompt))
     np.testing.assert_allclose(last, want[prompt - 1], atol=2e-5)
     slots, slot = 3, 1
     held = _slot_state(model, slots, 0.5)          # what a session left
@@ -172,7 +186,6 @@ def test_prefill_then_decode_steps_give_the_references_logits(prompt, bucket,
         for i, v in enumerate(values):
             side[i] = jax.lax.dynamic_update_slice(
                 side[i], v[None], (slot,) + (0,) * v.ndim)
-    step = jax.jit(model.decode_step)
     extra = model.extra_state()
     firsts, seconds = tuple(held[0]), tuple(held[1])
     active = jnp.arange(slots) == slot
@@ -284,6 +297,33 @@ def _engine(model=None, **kw):
     return DecodeEngine(model or sb.SambaY(CFG, jnp.float32), PARAMS, **opts)
 
 
+@pytest.fixture(scope="module")
+def recording():
+    """``(model, engine)``: ONE running engine over a :class:`Recording`
+    model for the tests that send it sessions one after another (tier-1
+    compiles every engine's step and buckets anew).  A test that stops
+    it starts it again."""
+    model = Recording(CFG, jnp.float32)
+    eng = _engine(model)
+    yield model, eng
+    eng.close(drain=False)
+
+
+@pytest.fixture(scope="module")
+def plain():
+    """ONE running engine over the model as it is."""
+    eng = _engine()
+    yield eng
+    eng.close(drain=False)
+
+
+def _forget(model):
+    """Empties a shared :class:`Recording` of what earlier sessions left."""
+    jax.effects_barrier()
+    model.prefills.clear()
+    model.steps.clear()
+
+
 def _served_logits(model, slot, first, count):
     """The logits the engine computed for the session in ``slot`` at
     positions ``first .. first + count - 1`` (a step's logits at ``lengths
@@ -297,77 +337,76 @@ def _served_logits(model, slot, first, count):
 
 
 @pytest.mark.parametrize("prompt", [2, 6, 8, 20])
-def test_the_engine_serves_the_references_logits(prompt):
+def test_the_engine_serves_the_references_logits(prompt, recording):
     """Prefill and decoding through ``DecodeEngine``, greedy: the logits
     its programs computed are the reference's over prompt and served
     tokens, position for position."""
-    model = Recording(CFG, jnp.float32)
-    eng = _engine(model)
-    try:
-        tokens = np.random.RandomState(prompt).randint(0, CFG.vocab, prompt)
-        new = 14
-        out = eng.generate(tokens, max_new_tokens=new)
-        assert len(out) == new
-        slot = 0
-        want = _reference(np.concatenate([tokens, out]))
-        jax.effects_barrier()
-        mine = [lg for n, lg in model.prefills if n == prompt]
-        np.testing.assert_allclose(mine[-1], want[prompt - 1], atol=2e-5)
-        np.testing.assert_allclose(
-            _served_logits(model, slot, prompt, new - 1),
-            want[prompt:prompt + new - 1], atol=2e-5)
-        assert out == [int(t) for t in want[prompt - 1:-1].argmax(-1)]
-    finally:
-        eng.close(drain=False)
+    model, eng = recording
+    _forget(model)
+    tokens = np.random.RandomState(prompt).randint(0, CFG.vocab, prompt)
+    new = 14
+    sess = eng.submit(tokens, max_new_tokens=new)
+    out = sess.result(60)
+    assert len(out) == new
+    want = _reference(np.concatenate([tokens, out]))
+    jax.effects_barrier()
+    mine = [lg for n, lg in model.prefills if n == prompt]
+    np.testing.assert_allclose(mine[-1], want[prompt - 1], atol=2e-5)
+    np.testing.assert_allclose(
+        _served_logits(model, sess.slot, prompt, new - 1),
+        want[prompt:prompt + new - 1], atol=2e-5)
+    assert out == [int(t) for t in want[prompt - 1:-1].argmax(-1)]
 
 
-def test_a_slots_second_session_does_not_see_the_firsts_state():
+def test_a_slots_second_session_does_not_see_the_firsts_state(recording):
     """Two sessions in turn in ONE slot: the second's logits are those of
-    a fresh engine, so the admission overwrote the recurrent state and
-    the convolution's tail the first left (no length masks them), and the
-    engine counted both overwrites."""
+    an engine whose state is new, so the admission overwrote the
+    recurrent state and the convolution's tail the first left (no length
+    masks them), and the engine counted both overwrites."""
     first = np.random.RandomState(1).randint(0, CFG.vocab, 9)
     second = np.random.RandomState(2).randint(0, CFG.vocab, 3)
+    model, eng = recording
+
+    def resets():
+        return sum(telemetry.snapshot()["counters"].get(
+            "serving.ssm.state_resets", {}).values())
+
     telemetry.enable()
     try:
-        used = Recording(CFG, jnp.float32)
-        eng = _engine(used, slots=1)
-        try:
-            eng.generate(first, max_new_tokens=20)
-            used.steps.clear()
-            out = eng.generate(second, max_new_tokens=10)
-            resets = telemetry.snapshot()["counters"][
-                "serving.ssm.state_resets"]
-            assert sum(resets.values()) == 2
-        finally:
-            eng.close(drain=False)
-        fresh = Recording(CFG, jnp.float32)
-        eng = _engine(fresh, slots=1)
-        try:
-            assert eng.generate(second, max_new_tokens=10) == out
-        finally:
-            eng.close(drain=False)
+        before = resets()
+        one = eng.submit(first, max_new_tokens=20)
+        one.result(60)
+        _forget(model)
+        two = eng.submit(second, max_new_tokens=10)
+        out = two.result(60)
+        assert one.slot == two.slot
+        assert resets() - before == 2
     finally:
         telemetry.disable()
-    np.testing.assert_array_equal(_served_logits(used, 0, 3, 9),
-                                  _served_logits(fresh, 0, 3, 9))
+    used = _served_logits(model, two.slot, 3, 9)
+    # a stop and a start make the slot state anew, from zeros
+    eng.stop(drain=False)
+    eng.start()
+    _forget(model)
+    fresh = eng.submit(second, max_new_tokens=10)
+    assert fresh.result(60) == out
+    np.testing.assert_array_equal(
+        used, _served_logits(model, fresh.slot, 3, 9))
     np.testing.assert_allclose(
-        _served_logits(used, 0, 3, 9),
-        _reference(np.concatenate([second, out]))[3:12], atol=2e-5)
+        used, _reference(np.concatenate([second, out]))[3:12], atol=2e-5)
 
 
 @pytest.mark.parametrize("temperature", [0.0, 0.9])
-def test_resume_of_a_migrated_transcript_restores_the_state(temperature):
+def test_resume_of_a_migrated_transcript_restores_the_state(temperature,
+                                                            recording,
+                                                            plain):
     """A session stopped mid-generation and resumed on another engine by
     re-prefilling its transcript ends with the stream the first engine
     would have given: the re-prefill rebuilt the recurrent state."""
     prompt = np.random.RandomState(5).randint(0, CFG.vocab, 6)
-    eng = _engine()
-    try:
-        want = eng.generate(prompt, max_new_tokens=18,
-                            temperature=temperature, seed=11)
-    finally:
-        eng.close(drain=False)
+    (_, eng), other = recording, plain
+    want = eng.generate(prompt, max_new_tokens=18, temperature=temperature,
+                        seed=11)
     mid, go_on = threading.Event(), threading.Event()
     seen, handed = [], []
 
@@ -377,7 +416,6 @@ def test_resume_of_a_migrated_transcript_restores_the_state(temperature):
             mid.set()
             go_on.wait(60)
 
-    eng, other = _engine(), _engine()
     try:
         sess = eng.submit(prompt, max_new_tokens=18, temperature=temperature,
                           seed=11, on_token=on_token)
@@ -392,8 +430,9 @@ def test_resume_of_a_migrated_transcript_restores_the_state(temperature):
         assert sess.result(60) == want
         assert seen == want
     finally:
-        eng.close(drain=False)
-        other.close(drain=False)
+        go_on.set()
+        eng.stop(drain=False)
+        eng.start()
 
 
 def test_the_paged_layout_refuses_the_model():

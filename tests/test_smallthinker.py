@@ -38,27 +38,44 @@ _SESSIONS = [
 ]
 
 
-def _prefill_then_decode(cfg, prompt, bucket, new, model_cfg=None):
-    """The worst difference of the served logits from the reference's over
-    a session in slot 1 of 3, and the model's counters."""
+#: the plain reference under one jit: a new length compiles one program,
+#: where the bare call compiles each of its operations anew
+_forward = jax.jit(st.forward_logits, static_argnums=0)
+
+
+def _programs(cfg):
+    """The model at ``cfg`` with a jit of its prefill and of its step, as
+    they are traced now (a planted fault is in the trace)."""
+    model = st.SmallThinker(cfg, jnp.float32)
+    return model, jax.jit(model.prefill), jax.jit(model.decode_step)
+
+
+@pytest.fixture(scope="module")
+def sound():
+    """ONE :func:`_programs` of the sound model for every session below:
+    a session compiles only the bucket no session before it had."""
+    return _programs(_cfg())
+
+
+def _prefill_then_decode(cfg, prompt, bucket, new, programs):
+    """The worst difference of the served logits from the reference's (at
+    ``cfg``) over a session in slot 1 of 3, and the model's counters."""
+    model, prefill, step = programs
     params = st.init_params(cfg, seed=prompt, dtype=jnp.float32)
-    model = st.SmallThinker(model_cfg or cfg, jnp.float32)
     tokens = np.random.RandomState(prompt).randint(0, cfg.vocab,
                                                    prompt + new)
-    want = np.asarray(st.forward_logits(cfg, params, jnp.asarray(tokens)))
+    want = np.asarray(_forward(cfg, params, jnp.asarray(tokens)))
     slots, slot = 3, 1
     cache = [[jnp.zeros((slots,) + tlm.slot_shape(c), c.dtype)
               for c in model.cache_spec()] for _ in range(2)]
     padded = np.zeros((bucket,), np.int32)
     padded[:prompt] = tokens[:prompt]
-    last, ks, vs = jax.jit(model.prefill)(params, jnp.asarray(padded),
-                                          jnp.int32(prompt))
+    last, ks, vs = prefill(params, jnp.asarray(padded), jnp.int32(prompt))
     worst = float(np.abs(np.asarray(last) - want[prompt - 1]).max())
     for side, rows in zip(cache, (ks, vs)):
         for l, r in enumerate(rows):
             side[l] = jax.lax.dynamic_update_slice(side[l], r[None],
                                                    (slot, 0, 0, 0))
-    step = jax.jit(model.decode_step)
     extra = model.extra_state()
     active = jnp.arange(slots) == slot
     ck, cv = tuple(cache[0]), tuple(cache[1])
@@ -73,9 +90,10 @@ def _prefill_then_decode(cfg, prompt, bucket, new, model_cfg=None):
 
 
 @pytest.mark.parametrize("prompt,bucket,new", _SESSIONS)
-def test_prefill_then_decode_equal_the_reference_logits(prompt, bucket, new):
+def test_prefill_then_decode_equal_the_reference_logits(prompt, bucket, new,
+                                                        sound):
     cfg = _cfg()
-    worst, counted = _prefill_then_decode(cfg, prompt, bucket, new)
+    worst, counted = _prefill_then_decode(cfg, prompt, bucket, new, sound)
     assert worst < 1e-4
     assert counted["rows"] == counted["steps"] == new
     assert counted["moe_picks_total"] == new * cfg.top_k * cfg.layers \
@@ -96,7 +114,8 @@ def test_the_decode_kernel_reads_rings_and_full_layers(monkeypatch):
         st, "decode_attention",
         lambda q, ck, cv, lengths, scale: attention._decode_pallas(
             q, ck, cv, lengths, scale, 8, 8, interpret=True))
-    worst, _counted = _prefill_then_decode(_cfg(), 5, 8, 9)
+    worst, _counted = _prefill_then_decode(_cfg(), 5, 8, 9,
+                                           _programs(_cfg()))
     assert worst < 1e-4
 
 
@@ -137,7 +156,8 @@ def test_a_planted_fault_moves_the_logits(monkeypatch, fault):
         model_cfg = cfg._replace(window=WINDOW - 1)
     else:
         monkeypatch.setattr(st, "decode_attention", _past_live_rows)
-    worst, _counted = _prefill_then_decode(cfg, 3, 4, 12, model_cfg)
+    worst, _counted = _prefill_then_decode(cfg, 3, 4, 12,
+                                           _programs(model_cfg))
     assert worst > 1e-3, fault
 
 
@@ -343,8 +363,7 @@ def test_the_engine_serves_the_model_by_the_protocol_alone():
         outs = [s.result(120) for s in sessions]
         for prompt, out in zip(prompts, outs):
             seq = np.concatenate([prompt, out])
-            logits = np.asarray(st.forward_logits(cfg, params,
-                                                  jnp.asarray(seq)))
+            logits = np.asarray(_forward(cfg, params, jnp.asarray(seq)))
             # greedy: each served token is the reference's best one
             np.testing.assert_array_equal(
                 out, logits[len(prompt) - 1:-1].argmax(-1))

@@ -130,7 +130,8 @@ def test_dump_env_var_writes_at_exit(tmp_path):
             "mx.telemetry.inc('sub.proc', 2)\n"
             "mx.telemetry.event('sub_event', k='v')\n")
     proc = subprocess.run([sys.executable, "-c", code], env=env,
-                          capture_output=True, text=True, cwd=ROOT)
+                          capture_output=True, text=True, cwd=ROOT,
+                          timeout=300)
     assert proc.returncode == 0, proc.stderr[-2000:]
     with open(out) as f:
         assert json.load(f)["counters"]["sub.proc"][""] == 2
