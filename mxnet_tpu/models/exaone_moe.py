@@ -30,15 +30,20 @@ are zero where a row did not choose the expert; fixed shapes, no sort.  At
 a decode step (some hundred rows) the held experts' weights are what the
 product reads and their bytes bind it; PERF.md section 6 (PR 27) has what
 was measured against it.  *Grouped*, for the many rows of a long prompt
-over many held experts: the picks sorted by expert and one product a group
-(``lax.ragged_dot``), which does the operations the routing asks for and
-not ``experts_held / top_k`` times as many.  Neither has a capacity and
+where a row gives this share far fewer picks than it holds experts: the
+picks sorted by expert and one product a group (``lax.ragged_dot``), which
+does the operations the routing asks of this share and not ``num_experts
+/ top_k`` times as many.  Neither has a capacity and
 neither drops a pick at any imbalance.
 
-**Two routers, two gates** (``cfg.router``, ``cfg.activation``): ``sigmoid``
-with selection bias and scale as above, or ``softmax`` over the chosen
-(``chosen = top_k(h Wr)``, ``w = softmax`` of the chosen scores); the gated
-unit's activation is ``silu`` or ``relu``.  :func:`route` takes the rows
+**Three routers, two gates** (``cfg.router``, ``cfg.activation``):
+``sigmoid`` with selection bias and scale as above; ``softmax`` over the
+chosen (``chosen = top_k(h Wr)``, ``w = softmax`` of the chosen scores); or
+``group_limited`` (``s = softmax(h Wr)`` over all; of the ``cfg.n_group``
+equal groups the best ``cfg.topk_group`` by their best expert stay; the
+best ``top_k`` experts among theirs; ``w = s routed_scale``, not renormed:
+:mod:`~mxnet_tpu.models.deepseek_v2`); the gated unit's activation is
+``silu`` or ``relu``.  :func:`route` takes the rows
 the router reads, which need not be the rows the experts are fed
 (:mod:`~mxnet_tpu.models.smallthinker` routes a layer's input before its
 attention).
@@ -191,8 +196,21 @@ def route(cfg, h, moe):
         # softmax over the chosen
         picked, chosen = jax.lax.top_k(s, cfg.top_k)
         return chosen.astype(jnp.int32), jax.nn.softmax(picked, axis=-1)
+    if cfg.router == "group_limited":
+        # softmax over all; a group's score is its best expert's; the best
+        # ``topk_group`` groups stay and the rest score 0; the best
+        # ``top_k`` of what stays, weights not renormed
+        s = jax.nn.softmax(s, axis=-1)
+        groups = s.reshape(s.shape[0], cfg.n_group, -1)
+        _, best = jax.lax.top_k(groups.max(-1), cfg.topk_group)
+        stays = jax.nn.one_hot(best, cfg.n_group, dtype=bool).any(1)
+        picked, chosen = jax.lax.top_k(
+            jnp.where(stays[:, :, None], groups, 0.0).reshape(s.shape),
+            cfg.top_k)
+        return chosen.astype(jnp.int32), picked * cfg.routed_scale
     if cfg.router != "sigmoid":
-        raise ValueError("no router %r (sigmoid | softmax)" % (cfg.router,))
+        raise ValueError("no router %r (sigmoid | softmax | group_limited)"
+                         % (cfg.router,))
     s = jax.nn.sigmoid(s)
     _, chosen = jax.lax.top_k(s + moe["bias"], cfg.top_k)
     picked = jnp.take_along_axis(s, chosen, axis=-1)
@@ -212,26 +230,39 @@ def _combine(cfg, chosen, w):
 #: operations cost more than the sort, the gather and the ragged product's
 #: own overhead.  One layer alone on the v5e, every | grouped, ms
 #: (benchmark/tools/expert_product_variants.py; PERF.md section 6, PR 33):
-#: 64 held, 6 picked, 2560 -> 768: 48 rows 1.08 | 1.70, 256 1.19 | 2.96,
-#: 512 2.13 | 3.19, 1024 4.55 | 3.99, 2048 8.84 | 6.16, 4096 17.67 | 10.32,
-#: 8192 35.04 | 17.99: grouped from 1024 rows.  16 held, 8 picked, 6144 ->
-#: 2048 (K-EXAONE's share): 256 rows 1.90 | 4.15, 512 3.37 | 4.81, 1024 6.82
-#: | 6.79: twice the needed operations is never worth the sort, so a layer
-#: that holds under four times what a row picks keeps the every-expert
-#: product at any number of rows.
+#: 64 held of 64, 6 picked, 2560 -> 768: 48 rows 1.08 | 1.70, 256 1.19 |
+#: 2.96, 512 2.13 | 3.19, 1024 4.55 | 3.99, 2048 8.84 | 6.16, 4096 17.67 |
+#: 10.32, 8192 35.04 | 17.99: grouped from 1024 rows.  16 held of 128, 8
+#: picked, 6144 -> 2048 (K-EXAONE's share): 256 rows 1.90 | 4.15, 512 3.37
+#: | 4.81, 1024 6.82 | 6.79: a tie at the most rows that cell sends.
+#: A row costs the every-expert product ``experts_held`` units (an expert
+#: over a row at the MXU's peak, which that product reaches).  The grouped
+#: product is handed all of a row's ``top_k`` picks (those of experts held
+#: elsewhere sort behind every group) and multiplies the ``top_k x
+#: experts_held / num_experts`` of them that a row gives THIS share: from
+#: the two rows of readings above at their most rows, 1.4 units a pick
+#: handed and 4.8 a pick of its own; taken as 1.5 and 5, so that the tie
+#: stays with the product that sorts nothing.  20 held of 160, 6 picked,
+#: 5120 -> 1536 (one of DeepSeek-V2's eight groups, 0.75 picks a row;
+#: tools/perf/mla_variants.py, PERF.md section 6, PR 39), read after the
+#: rule was set: 128 rows 1.34 | 2.06, 1024 5.34 | 5.18, 2560 13.28 | 8.77,
+#: 3072 15.80 | 9.99, 3584 18.48 | 11.12, 4096 21.30 | 12.22: 11.5 units a
+#: row at 4096 where the rule reckons 12.75, against the 20 of every.
 GROUPED_FROM_ROWS = 1024
-GROUPED_FROM_RATIO = 4
+GROUPED_COST_HANDED = 1.5
+GROUPED_COST_OWN = 5.0
 
 
 def expert_product(cfg, rows):
     """"every" or "grouped": which product :func:`routed_experts` runs over
     ``rows`` rows, from the shapes alone.  The every-expert product does
-    ``experts_held / top_k`` times the operations the routing asks for and
-    reads every held weight once; that is free while the weights' bytes
-    bind (a step's few rows) and is what a prompt's thousands of rows pay
-    for."""
-    if rows >= GROUPED_FROM_ROWS \
-            and cfg.experts_held >= GROUPED_FROM_RATIO * cfg.top_k:
+    ``experts_held`` experts a row where the routing asks this share for
+    ``top_k x experts_held / num_experts``, and reads every held weight
+    once; that is free while the weights' bytes bind (a step's few rows)
+    and is what a prompt's thousands of rows pay for."""
+    own = cfg.top_k * cfg.experts_held / cfg.num_experts
+    if rows >= GROUPED_FROM_ROWS and cfg.experts_held \
+            > GROUPED_COST_HANDED * cfg.top_k + GROUPED_COST_OWN * own:
         return "grouped"
     return "every"
 

@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["LMConfig", "CacheLayer", "StateLayer", "slot_shape",
+__all__ = ["LMConfig", "CacheLayer", "StateLayer", "LatentLayer",
+           "slot_shape",
            "slot_arrays", "DecodeModel",
            "init_params", "forward_logits", "prefill_kv",
            "write_rows", "decode_step_math", "prefill_kv_paged",
@@ -52,6 +53,18 @@ CacheLayer = namedtuple("CacheLayer", ["kind", "rows", "kv_heads",
 StateLayer = namedtuple("StateLayer", ["kind", "shapes", "dtypes"])
 
 
+#: one layer's latent slot state, as the decode engine builds it: ``kind``
+#: "latent"; of each of ``rows`` positions one compressed row that stands
+#: for the keys and the values of every head, and beside it the one rotated
+#: key all heads read: two arrays ``(1, rows, widths[i])`` of ``dtype`` a
+#: slot (the 1 where a K or a V has its heads: the row writer's layout).
+#: Row ``p`` holds position ``p``; a session's length hides what the slot's
+#: last session left, and an admission writes from row 0, as in a "full"
+#: layer.  ``widths`` are what lies in memory, padding to whole lanes
+#: included where a model pads
+LatentLayer = namedtuple("LatentLayer", ["kind", "rows", "widths", "dtype"])
+
+
 def slot_shape(layer):
     """The shape of one slot of a :class:`CacheLayer`."""
     if layer.heads_major:
@@ -62,9 +75,13 @@ def slot_shape(layer):
 def slot_arrays(entry):
     """``((shape, dtype), (shape, dtype))`` of the two per-slot arrays an
     entry of a cache specification describes: a :class:`CacheLayer`'s K
-    and V, a :class:`StateLayer`'s own two."""
+    and V, a :class:`StateLayer`'s own two, a :class:`LatentLayer`'s
+    latent rows and rotated key rows."""
     if entry.kind == "state":
         return tuple(zip(entry.shapes, entry.dtypes))
+    if entry.kind == "latent":
+        return tuple(((1, entry.rows, w), entry.dtype)
+                     for w in entry.widths)
     return ((slot_shape(entry), entry.dtype),) * 2
 
 

@@ -31,6 +31,11 @@ Design:
   each slot's length, to 128 (``_decode_pallas``), else the two einsums
   and the softmax over every row (``_decode_xla``); not differentiated.
 
+* ``latent_attention`` — the absorbed step of latent (MLA) attention: all
+  heads of a slot over the slot's latent rows, which serve as keys and as
+  values; on a TPU trace ``_latent_pallas`` (the decode kernel's walk, one
+  copy of a chunk for both products), else ``_latent_xla``.
+
 * ``write_slot_rows`` — the decode step's one new K (or V) row a slot,
   put into a heads-major cache: on a TPU trace one Pallas kernel an array
   with every slot's tile of rows in flight at once (``_slot_write_pallas``),
@@ -91,12 +96,12 @@ def _flash_scan(q, k, v, causal, scale, block_k=512):
         vf = jnp.pad(vf, ((0, 0), (0, 0), (0, pad), (0, 0)))
     # lint: ok[recompile-hazard] block_k is a blocking-tuning knob with one default — per-value specialization is the intent
     kb = kf.reshape(kf.shape[0], kf.shape[1], nb, block_k, kf.shape[3])
-    vb = vf.reshape(*kb.shape)
+    vb = vf.reshape(*kb.shape[:4], vf.shape[3])
     kb = jnp.moveaxis(kb, 2, 0)  # (nb, B, H, block_k, D)
     vb = jnp.moveaxis(vb, 2, 0)
 
-    b, h, lq, d = q.shape
-    o0 = jnp.zeros((b, h, lq, d), jnp.float32)
+    b, h, lq, _ = q.shape
+    o0 = jnp.zeros((b, h, lq, v.shape[3]), jnp.float32)
     m0 = jnp.full((b, h, lq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, lq), jnp.float32)
 
@@ -152,7 +157,7 @@ def _flash_blocks(q, k, v, causal, scale, block_q, block_k, window):
     the weights are rounded to ``v``'s dtype before their product.
     Returns (out, lse); forward only (the loop's trip count is traced)."""
     b, h, lq, d = q.shape
-    n, lk = k.shape[1], k.shape[2]
+    n, lk, dv = k.shape[1], k.shape[2], v.shape[3]
     g = h // n
     block_q, block_k = min(block_q, lq), min(block_k, lk)
     if lq % block_q or lk % block_k:
@@ -164,7 +169,7 @@ def _flash_blocks(q, k, v, causal, scale, block_q, block_k, window):
     # lint: ok[recompile-hazard] as above
     kr = k.reshape(b, n, num_kb, block_k, d)
     # lint: ok[recompile-hazard] as above
-    vr = v.reshape(b, n, num_kb, block_k, d)
+    vr = v.reshape(b, n, num_kb, block_k, dv)
 
     def one_q_block(args):
         qb, q_blk = args                         # (b, n, g, block_q, d)
@@ -196,14 +201,14 @@ def _flash_blocks(q, k, v, causal, scale, block_q, block_k, window):
                                      window)
         o, m, l = jax.lax.fori_loop(
             first, last + 1, one_k_block,
-            (jnp.zeros((b, n, g, block_q, d), jnp.float32),
+            (jnp.zeros((b, n, g, block_q, dv), jnp.float32),
              jnp.full((b, n, g, block_q), NEG_INF, jnp.float32),
              jnp.zeros((b, n, g, block_q), jnp.float32)))
         l = jnp.maximum(l, 1e-30)
         return (o / l[..., None]).astype(q.dtype), m + jnp.log(l)
 
     out, lse = jax.lax.map(one_q_block, (jnp.arange(num_qb), qr))
-    return (jnp.moveaxis(out, 0, 3).reshape(b, h, lq, d),
+    return (jnp.moveaxis(out, 0, 3).reshape(b, h, lq, dv),
             jnp.moveaxis(lse, 0, 3).reshape(b, h, lq))
 
 
@@ -283,13 +288,14 @@ def _flash_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
                   interpret=False, window=None):
     """``k``/``v`` may have fewer heads than ``q``: a query head's grid
     steps are handed its K/V head's blocks by the index map, and no copy of
-    K or V is made.  A skipped step (see :func:`_fa_kernel`) asks for the
+    K or V is made.  ``v``'s rows may be of another width than ``q``'s and
+    ``k``'s, and the context is of ``v``'s.  A skipped step (see :func:`_fa_kernel`) asks for the
     nearest block that is read, so it fetches nothing new."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, lq, d = q.shape
-    n, lk = k.shape[1], k.shape[2]
+    n, lk, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // n
     block_q = min(block_q, lq)
     block_k = min(block_k, lk)
@@ -298,7 +304,7 @@ def _flash_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
     bh = b * h
     qr = q.reshape(bh, lq, d)
     kr = k.reshape(b * n, lk, d)
-    vr = v.reshape(b * n, lk, d)
+    vr = v.reshape(b * n, lk, dv)
 
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal, window=window,
@@ -315,10 +321,10 @@ def _flash_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b_, q_, k_: (b_, q_, 0)),
             pl.BlockSpec((1, block_k, d), kv_block),
-            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, dv), kv_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b_, q_, k_: (b_, q_, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b_, q_, k_: (b_, q_, 0)),
             # lse rides as (bh, lq, 1) so the block's minor-two dims are
             # (block_q, 1) — sublane divisible by 8, lane equal to the
             # array dim.  A (1, block_q) block puts 1 in the sublane
@@ -326,13 +332,13 @@ def _flash_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
             pl.BlockSpec((1, block_q, 1), lambda b_, q_, k_: (b_, q_, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, lq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),   # m
             pltpu.VMEM((block_q, 128), jnp.float32),   # l
-            pltpu.VMEM((block_q, d), jnp.float32),     # acc
+            pltpu.VMEM((block_q, dv), jnp.float32),    # acc
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -340,7 +346,7 @@ def _flash_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
         interpret=interpret,
     )(qr, kr, vr)
     # the reshape drops the trailing singleton the lse BlockSpec needed
-    return out.reshape(b, h, lq, d), lse.reshape(b, h, lq)
+    return out.reshape(b, h, lq, dv), lse.reshape(b, h, lq)
 
 
 def _kernel_refusal(q, k, block_q, block_k):
@@ -458,7 +464,8 @@ def _flash_bwd_core(causal, scale, block_q, block_k, res, do, dlse=None,
     dq, (dk_b, dv_b) = jax.lax.scan(step, dq0, (jnp.arange(nb), kb, vb))
 
     def of_kv_heads(d_b):
-        d = jnp.moveaxis(d_b, 0, 2).reshape(kf.shape)[:, :, :lk]
+        d = jnp.moveaxis(d_b, 0, 2).reshape(
+            kf.shape[:3] + d_b.shape[-1:])[:, :, :lk]
         if group == 1:
             return d
         return d.reshape(k.shape[0], k.shape[1], group, lk, -1).sum(2)
@@ -516,7 +523,9 @@ def flash_attention(q, k, v, causal=False, softmax_scale=None,
     """Memory-efficient attention.  ``q (batch, heads, seq, head_dim)``;
     ``k``/``v (batch, kv_heads, seq, head_dim)`` with ``kv_heads`` a
     divisor of ``heads`` (query head ``i`` reads K/V head ``i // (heads //
-    kv_heads)``, with no repeated copy of K and V).  ``window``: a query at
+    kv_heads)``, with no repeated copy of K and V).  ``v`` may have rows of
+    a width of its own (latent attention's expanded heads score over 192
+    values and carry 128), which is then the context's.  ``window``: a query at
     position ``p`` reads ``p - window + 1 .. p`` (with ``causal``), and the
     K/V blocks wholly outside are skipped."""
     if softmax_scale is None:
@@ -552,18 +561,26 @@ def _decode_xla(q, cache_k, cache_v, lengths, scale):
                       preferred_element_type=jnp.float32)
 
 
-def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
-                   turns, m_scr, l_scr, acc_scr, *, scale, chunk, piece):
-    """One grid step a slot; the caches stay in HBM.  A slot of length
-    ``n`` is walked in ``n // chunk + 1`` turns of a loop: ``n // chunk``
-    whole chunks of ``chunk`` rows, one copy each of K and of V, then the
-    edge, of which only the pieces of ``piece`` rows at or below ``n`` are
-    copied and multiplied, the rows above ``n`` masked.  The turns of all
-    slots alternate between two buffers (``turns`` counts them across grid
-    steps): a turn first starts the copies of the next one, which after a
-    slot's last turn is the next slot's first, then waits for its own.
-    The running maximum, sum and accumulator of the slot's ``(kv_heads,
-    group)`` queries stay in VMEM across its turns."""
+def _walk_slot(len_ref, pairs, sems, turns, softmax, chunk, piece,
+               accumulate):
+    """The walk a decode kernel makes of its slot's rows: one grid step a
+    slot, the caches in HBM.  A slot of length ``n`` is walked in ``n //
+    chunk + 1`` turns of a loop: ``n // chunk`` whole chunks of ``chunk``
+    rows, one copy each of every cache array, then the edge, of which only
+    the pieces of ``piece`` rows at or below ``n`` are copied and
+    multiplied.  The turns of all slots alternate between two buffers
+    (``turns`` counts them across grid steps): a turn first starts the
+    copies of the next one, which after a slot's last turn is the next
+    slot's first, then waits for its own.
+
+    ``pairs``: of each cache array ``(S, n, rows, d)`` in HBM, it and its
+    two buffers ``(2, n, chunk, d)`` in VMEM; ``sems (len(pairs), 2)``.
+    ``softmax``: the slot's running maximum, sum and accumulator in VMEM,
+    set to nothing seen before the first turn.  ``accumulate(rows, first,
+    n)`` is handed each array's rows ``(n, rows, d)`` from row ``first`` of
+    the slot, and in the edge the slot's length ``n`` above which rows take
+    no part (they hold whatever an earlier session, or an earlier turn,
+    left); None in a whole chunk."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -571,13 +588,12 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
     per = chunk // piece
 
     def copies(slot, first, rows, buf, at):
-        """K's and V's copy of ``rows`` rows from row ``first`` of
+        """Every array's copy of ``rows`` rows from row ``first`` of
         ``slot`` into row ``at`` of buffer ``buf``."""
         return [pltpu.make_async_copy(
             hbm.at[slot, :, pl.ds(first, rows), :],
             vmem.at[buf, :, pl.ds(at, rows), :], sems.at[which, buf])
-            for which, (hbm, vmem) in enumerate(((k_hbm, k_buf),
-                                                 (v_hbm, v_buf)))]
+            for which, (hbm, vmem) in enumerate(pairs)]
 
     def whole(slot, c, buf):
         return copies(slot, pl.multiple_of(c * chunk, chunk), chunk, buf, 0)
@@ -602,10 +618,59 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
                     for copy in edge(slot, c, p, buf):
                         copy.start()
 
-    def accumulate(k, v, first, n):
-        """``k``, ``v (kv, rows, d)`` from row ``first`` join the running
-        softmax; with ``n`` given, the rows above it take no part: they
-        hold whatever an earlier session, or an earlier turn, left."""
+    @pl.when(i == 0)
+    def _cold():
+        turns[0] = 0
+        start(0, 0, 0)
+
+    length = len_ref[i]
+    last = length // chunk
+    turn0 = turns[0]
+    m_scr, l_scr, acc_scr = softmax
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def turn(c, carry):
+        buf = (turn0 + c) % 2
+        more = c < last
+
+        @pl.when(more | (i + 1 < slots))
+        def _ahead():
+            start(jnp.where(more, i, jnp.minimum(i + 1, slots - 1)),
+                  jnp.where(more, c + 1, 0), 1 - buf)
+
+        @pl.when(more)
+        def _whole():
+            for copy in whole(i, c, buf):
+                copy.wait()
+            accumulate([vmem[buf] for _, vmem in pairs], c * chunk, None)
+
+        @pl.when(c == last)
+        def _edge():
+            def piece_of(p, carry):
+                for copy in edge(i, c, p, buf):
+                    copy.wait()
+                rows = pl.ds(pl.multiple_of(p * piece, piece), piece)
+                accumulate([vmem[buf, :, rows, :] for _, vmem in pairs],
+                           c * chunk + p * piece, length)
+                return carry
+            jax.lax.fori_loop(0, length % chunk // piece + 1, piece_of, 0)
+        return carry
+
+    jax.lax.fori_loop(0, last + 1, turn, 0)
+    turns[0] = turn0 + last + 1
+
+
+def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+                   turns, m_scr, l_scr, acc_scr, *, scale, chunk, piece):
+    """:func:`_walk_slot` over K and V: the running maximum, sum and
+    accumulator of the slot's ``(kv_heads, group)`` queries stay in VMEM
+    across its turns."""
+
+    def accumulate(held, first, n):
+        """``k``, ``v (kv, rows, d)`` join the running softmax."""
+        k, v = held
         s = jax.lax.dot_general(
             q_ref[0], k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale    # (kv, g, rows)
@@ -625,47 +690,8 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
             p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)            # (kv, g, d)
 
-    @pl.when(i == 0)
-    def _cold():
-        turns[0] = 0
-        start(0, 0, 0)
-
-    length = len_ref[i]
-    last = length // chunk
-    turn0 = turns[0]
-    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[...] = jnp.zeros_like(l_scr)
-    acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    def turn(c, carry):
-        buf = (turn0 + c) % 2
-        more = c < last
-
-        @pl.when(more | (i + 1 < slots))
-        def _ahead():
-            start(jnp.where(more, i, jnp.minimum(i + 1, slots - 1)),
-                  jnp.where(more, c + 1, 0), 1 - buf)
-
-        @pl.when(more)
-        def _whole():
-            for copy in whole(i, c, buf):
-                copy.wait()
-            accumulate(k_buf[buf], v_buf[buf], c * chunk, None)
-
-        @pl.when(c == last)
-        def _edge():
-            def piece_of(p, carry):
-                for copy in edge(i, c, p, buf):
-                    copy.wait()
-                rows = pl.ds(pl.multiple_of(p * piece, piece), piece)
-                accumulate(k_buf[buf, :, rows, :], v_buf[buf, :, rows, :],
-                           c * chunk + p * piece, length)
-                return carry
-            jax.lax.fori_loop(0, length % chunk // piece + 1, piece_of, 0)
-        return carry
-
-    jax.lax.fori_loop(0, last + 1, turn, 0)
-    turns[0] = turn0 + last + 1
+    _walk_slot(len_ref, ((k_hbm, k_buf), (v_hbm, v_buf)), sems, turns,
+               (m_scr, l_scr, acc_scr), chunk, piece, accumulate)
     o_ref[0] = acc_scr[...] / l_scr[...]
 
 
@@ -784,6 +810,177 @@ def decode_attention(q, cache_k, cache_v, lengths, scale):
                               _decode_chunk(cache_k), _DECODE_PIECE)
     count_kernel_path("decode_attention", "xla", reason)
     return _decode_xla(q, cache_k, cache_v, lengths, scale)
+
+
+# ---------------------------------------------------------------------------
+# latent attention: one query a head and slot, over the latent rows it holds
+# ---------------------------------------------------------------------------
+
+#: bytes of latent rows one copy of the latent kernel moves (the rotated
+#: keys' copy beside it moves a quarter as much): tools/perf/mla_variants.py
+#: has the readings the size was chosen from, and the other layout's, one
+#: array of 640 values a row and one copy a chunk, which read 2% faster
+#: (1.866 against 1.910 ms) and was not taken (PERF.md section 6, PR 39)
+_LATENT_CHUNK_BYTES = 1 << 20
+
+
+def _latent_xla(q_lat, q_rope, cache_lat, cache_rope, lengths):
+    """Every row of the cache, masked: both products of the scores over all
+    ``rows``, their softmax in float32, the weights rounded to the cache's
+    dtype, the weighted sum of the same latent rows in float32."""
+    lat, rope = cache_lat[:, 0], cache_rope[:, 0]
+    scores = jnp.einsum("shc,smc->shm", q_lat, lat,
+                        preferred_element_type=jnp.float32) \
+        + jnp.einsum("shr,smr->shm", q_rope, rope,
+                     preferred_element_type=jnp.float32)
+    mask = jnp.arange(lat.shape[1])[None, :] <= lengths[:, None]
+    att = jax.nn.softmax(jnp.where(mask[:, None, :], scores, NEG_INF), -1)
+    return jnp.einsum("shm,smc->shc", att.astype(lat.dtype), lat,
+                      preferred_element_type=jnp.float32)
+
+
+def _latent_kernel(len_ref, ql_ref, qr_ref, lat_hbm, rope_hbm, o_ref,
+                   lat_buf, rope_buf, sems, turns, m_scr, l_scr, acc_scr, *,
+                   chunk, piece):
+    """:func:`_walk_slot` over a cache whose row is one latent vector: ONE
+    copy of a chunk of latent rows is the right-hand side of the scores and
+    of the weighted sum, all ``heads`` queries of the slot against it at
+    once; the rotated keys' narrower rows ride in a copy of their own and
+    add their product to the scores."""
+
+    def accumulate(held, first, n):
+        """``lat (1, rows, c)``, ``rope (1, rows, r)`` join the running
+        softmax."""
+        lat, rope = held[0][0], held[1][0]
+        contract = (((1,), (1,)), ((), ()))
+        s = jax.lax.dot_general(ql_ref[0], lat, contract,
+                                preferred_element_type=jnp.float32) \
+            + jax.lax.dot_general(qr_ref[0], rope, contract,
+                                  preferred_element_type=jnp.float32)
+        if n is not None:                                   # (heads, rows)
+            rows = lat.shape[0]
+            at = first + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+            s = jnp.where(at <= n, s, NEG_INF)
+            at = first + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+            lat = jnp.where(at <= n, lat, jnp.zeros_like(lat))
+        m_prev = m_scr[...]
+        m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
+        m_scr[...] = m_cur
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(lat.dtype), lat, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)             # (heads, c)
+
+    _walk_slot(len_ref, ((lat_hbm, lat_buf), (rope_hbm, rope_buf)), sems,
+               turns, (m_scr, l_scr, acc_scr), chunk, piece, accumulate)
+    o_ref[0] = acc_scr[...] / l_scr[...]
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "piece", "interpret"))
+def _latent_pallas(q_lat, q_rope, cache_lat, cache_rope, lengths, chunk,
+                   piece, interpret=False):
+    """Jitted on its own, as :func:`_decode_pallas` is."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, h, c = q_lat.shape
+    r = q_rope.shape[2]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+
+    def whole(i, lens):
+        return (i, 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, chunk=chunk, piece=piece),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(s,),
+            in_specs=[pl.BlockSpec((1, h, c), whole),
+                      pl.BlockSpec((1, h, r), whole), hbm, hbm],
+            out_specs=pl.BlockSpec((1, h, c), whole),
+            scratch_shapes=[pltpu.VMEM((2, 1, chunk, c), cache_lat.dtype),
+                            pltpu.VMEM((2, 1, chunk, r), cache_rope.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((1,), jnp.int32),         # turns
+                            pltpu.VMEM((h, 1), jnp.float32),     # m
+                            pltpu.VMEM((h, 1), jnp.float32),     # l
+                            pltpu.VMEM((h, c), jnp.float32)]),   # acc
+        out_shape=jax.ShapeDtypeStruct((s, h, c), jnp.float32),
+        # buffers, semaphores and the count of turns carry over from a slot
+        # to the next: the slots run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="latent_attention",
+        interpret=interpret,
+    )(lengths, q_lat, q_rope, cache_lat, cache_rope)
+
+
+def _latent_chunk(cache_lat):
+    """Rows of a whole chunk: as many latent rows as make
+    :data:`_LATENT_CHUNK_BYTES`, a power of two of pieces, no more than the
+    cache has."""
+    rows, c = cache_lat.shape[2:]
+    chunk = _DECODE_PIECE
+    while chunk * 2 * c * cache_lat.dtype.itemsize <= _LATENT_CHUNK_BYTES \
+            and chunk * 2 <= rows:
+        chunk *= 2
+    return chunk
+
+
+def latent_attention_plan(q_lat, cache_lat, cache_rope):
+    """``(granule, reason)`` as :func:`decode_attention_plan` gives them:
+    the rows to whose multiple the Pallas kernel reads a slot, or all
+    ``rows`` with why the call takes the plain path.  Latent and rotated
+    rows both of whole 128-lane width (``lanes``: a model pads its 64
+    rotated values to 128, and counts the padding in what its cache
+    holds), one dtype, bfloat16 or float32, for queries and both caches
+    (``dtype``), a cache of whole granules (``tile``)."""
+    from .registry import on_tpu
+
+    rows = cache_lat.shape[2]
+    if not on_tpu():
+        return rows, "not_tpu"
+    if cache_lat.dtype not in (jnp.bfloat16, jnp.float32) \
+            or not q_lat.dtype == cache_lat.dtype == cache_rope.dtype:
+        return rows, "dtype"
+    if cache_lat.shape[3] % 128 or cache_rope.shape[3] % 128:
+        return rows, "lanes"
+    if rows % _DECODE_PIECE:
+        return rows, "tile"
+    return _DECODE_PIECE, None
+
+
+def latent_attention(q_lat, q_rope, cache_lat, cache_rope, lengths):
+    """Absorbed latent attention of one decode step: all heads of a slot
+    read the slot's latent rows, which are their keys and their values.
+
+    ``q_lat (S, heads, c)``: each head's query carried into the latent
+    space; ``q_rope (S, heads, r)``: its rotated part, both with the
+    softmax's scale already on them; ``cache_lat (S, 1, rows, c)`` the
+    latent rows and ``cache_rope (S, 1, rows, r)`` the one rotated key a row
+    that every head reads; ``lengths (S,)`` int32 within ``0 .. rows - 1``,
+    the inclusive horizon.  ``score[s, h, m] = q_lat[s, h] . lat[s, m] +
+    q_rope[s, h] . rope[s, m]``,
+    softmax over ``m <= lengths[s]`` in float32, and the context ``(S,
+    heads, c)`` float32 is the weights (rounded to the cache's dtype) over
+    the same latent rows.  Neither a key nor a value of any head is built.
+
+    On a TPU trace the Pallas kernel walks, of each slot, only the rows at
+    or below its length, to a multiple of 128; one copy of a chunk serves
+    both products and the softmax stays in VMEM.  Elsewhere, and where the
+    kernel refuses (:func:`latent_attention_plan`), every row is read and
+    masked.  The choice is counted under ``ops.kernel_path``."""
+    from .registry import count_kernel_path
+
+    _, reason = latent_attention_plan(q_lat, cache_lat, cache_rope)
+    if reason is None:
+        count_kernel_path("latent_attention", "pallas", "ok")
+        return _latent_pallas(q_lat, q_rope, cache_lat, cache_rope, lengths,
+                              _latent_chunk(cache_lat), _DECODE_PIECE)
+    count_kernel_path("latent_attention", "xla", reason)
+    return _latent_xla(q_lat, q_rope, cache_lat, cache_rope, lengths)
 
 
 # ---------------------------------------------------------------------------

@@ -78,7 +78,13 @@ is given a *model object* and asks it for four things:
     tail of its convolution).  No length hides what a slot's last session
     left in them: an admission overwrites both WHOLE, and so does the
     re-prefill of a migrated transcript (:meth:`DecodeEngine.resume`),
-    which is how a recurrent state is restored on another replica.
+    which is how a recurrent state is restored on another replica;
+  - a :class:`~mxnet_tpu.models.transformer_lm.LatentLayer` ``(kind, rows,
+    widths, dtype)``, ``kind`` "latent": of each position one compressed
+    row that stands for every head's key and value, and the one rotated
+    key all heads read, ``(1, rows, widths[i])`` each (latent attention;
+    :mod:`~mxnet_tpu.models.deepseek_v2`).  Row ``p`` holds position
+    ``p`` and a session's length hides the rest, as in a "full" layer.
 
   Entries may differ in every field;
 * ``prefill(params, tokens, length) -> (last_logits, firsts, seconds)``:
@@ -98,7 +104,9 @@ second, six arrays of ``(slots,)``.
 
 A bare :class:`~mxnet_tpu.models.transformer_lm.LMConfig` stands for
 :class:`~mxnet_tpu.models.transformer_lm.DecodeModel`, the first
-implementer; :class:`~mxnet_tpu.models.exaone_moe.ExaoneMoE` is the second.
+implementer; :class:`~mxnet_tpu.models.exaone_moe.ExaoneMoE` is the second
+(:mod:`~mxnet_tpu.models.sambay`, :mod:`~mxnet_tpu.models.smallthinker` and
+:mod:`~mxnet_tpu.models.deepseek_v2` the others).
 The model object is the only choice of a model path.  The paged layout
 asks for the two further methods ``prefill_paged``/``decode_step_paged``
 and a cache of full float32 layers alike; a model without them is refused
@@ -981,7 +989,8 @@ class DecodeEngine:
 
     def _cache_bytes(self):
         """Bytes of the dense slot state by kind of entry (``full``,
-        ``ring``, ``state``), both arrays of every entry over all slots."""
+        ``ring``, ``state``, ``latent``), both arrays of every entry over
+        all slots, as they lie (a model's padding to whole lanes counted)."""
         out = {}
         for c in self._spec:
             out[c.kind] = out.get(c.kind, 0) + self.slots * sum(

@@ -219,11 +219,13 @@ def test_routed_experts_chooses_its_product_from_the_shapes(monkeypatch):
                         lambda *a: taken.append("grouped") or 0.0)
     monkeypatch.setattr(xm, "_every_expert",
                         lambda *a: taken.append("every") or 0.0)
-    moe, h = _moe_and_rows(_cfg(), 0, rows=4)
-    chosen, w = xm.route(_cfg(), h, moe)
-    xm.routed_experts(_cfg(), h, chosen, w, moe)
+    # 16 held, 2 picked: 16 units a row against 1.5 x 2 + 5 x 2
+    two = _cfg()._replace(top_k=2)
+    moe, h = _moe_and_rows(two, 0, rows=4)
+    chosen, w = xm.route(two, h, moe)
+    xm.routed_experts(two, h, chosen, w, moe)
     monkeypatch.setattr(xm, "GROUPED_FROM_ROWS", 4)
-    xm.routed_experts(_cfg(), h, chosen, w, moe)
+    xm.routed_experts(two, h, chosen, w, moe)
     assert taken == ["every", "grouped"]
 
 

@@ -627,6 +627,122 @@ CASES["decode-prefill-8192-smallthinker-under-2-GB-of-temporaries"] = \
     _smallthinker_case(8192)
 
 
+# -- latent attention ----------------------------------------------------------
+def _latent_attention_case():
+    """``ops.attention.latent_attention`` over a donated cache at the
+    DeepSeek-V2 cell's shapes (128 slots, 128 heads, latent rows of 512 and
+    rotated rows padded to 128 lanes, 8192 rows, bfloat16): the plan admits
+    it, chunks of 1024 rows (1 MiB of latent rows a copy), the new rows
+    written by ``slot_write``, and no ``(128, 128, 8192)`` scores."""
+    s, h, rows = 128, 128, 8192
+    one_chip = _one_chip()
+    lat = jax.ShapeDtypeStruct((s, 1, rows, 512), jnp.bfloat16)
+    rope = jax.ShapeDtypeStruct((s, 1, rows, 128), jnp.bfloat16)
+    ql = jax.ShapeDtypeStruct((s, h, 512), jnp.bfloat16)
+
+    def step(ql, qr, cl, cr, new_l, new_r, n):
+        cl = attention.write_slot_rows(cl, new_l, n)
+        cr = attention.write_slot_rows(cr, new_r, n)
+        return attention.latent_attention(ql, qr, cl, cr, n), cl, cr
+
+    with _tpu_trace():
+        assert attention.latent_attention_plan(ql, lat, rope) == (128, None)
+        assert attention._latent_chunk(lat) == 1024
+        sds = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+               for shape, dtype in (
+                   ((s, h, 512), jnp.bfloat16), ((s, h, 128), jnp.bfloat16),
+                   (lat.shape, lat.dtype), (rope.shape, rope.dtype),
+                   ((s, 1, 512), jnp.bfloat16), ((s, 1, 128), jnp.bfloat16),
+                   ((s,), jnp.int32))]
+        text = jax.jit(step, donate_argnums=(2, 3)).lower(*sds).compile() \
+            .as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "f32[%d,%d,%d]" % (s, h, rows) not in text
+    assert "dynamic-update-slice" not in text
+    copies = [line for line in text.splitlines()
+              if " copy(" in line and ",%d," % rows in line.split("copy(")[0]]
+    assert not copies, copies[0][:300]
+
+
+CASES["latent_attention-128x128x512-over-8192-rows"] = _latent_attention_case
+
+
+def _latent_attention_lanes():
+    """Rotated rows of 64 cached as they are: the plan says ``lanes`` and
+    the call takes the masked einsum."""
+    ql = jax.ShapeDtypeStruct((128, 128, 512), jnp.bfloat16)
+    lat = jax.ShapeDtypeStruct((128, 1, 8192, 512), jnp.bfloat16)
+    rope = jax.ShapeDtypeStruct((128, 1, 8192, 64), jnp.bfloat16)
+    with _tpu_trace():
+        assert attention.latent_attention_plan(ql, lat, rope) \
+            == (8192, "lanes")
+
+
+CASES["latent_attention-refuses-rows-narrower-than-the-lanes"] = \
+    _latent_attention_lanes
+
+
+def _deepseek_v2_case(which):
+    """The engine's programs over ``models/deepseek_v2.py`` at
+    ``benchmark/configs/deepseek-v2.json``'s sizes (5 layers, 20 experts of
+    160 held, 12,800 rows of vocabulary, the configuration's slots x 8192,
+    bfloat16): each fits the chip beside what it is handed (under 15.0 GB
+    in all).  The step reads the latent rows through five calls of
+    ``latent_attention`` and writes ten arrays' rows with ``slot_write``;
+    no expanded K or V of held rows (``(slots, 128, 8192, ...)``), no
+    ``(slots, 128, 8192)`` scores, no update-slice, no copy of a cache-sized
+    array, under 64 MB of temporaries.  The largest prefill holds under
+    1.6 GB of temporaries: no ``(heads, P, P)`` scores and no ``(P, 20,
+    1536)`` product of every expert over every row (five calls of
+    ``flash_attention``, the experts a ragged product)."""
+    def run():
+        from benchmark import harness
+        from benchmark.tools import aot_compile_deepseek_v2 as tool
+
+        config = harness.load_json(os.path.join(
+            ROOT, "benchmark", "configs", "deepseek-v2.json"))
+        engine, params, state, keep, extra, sds = tool.engine_programs(
+            config, _one_chip())
+        s = config["engine"]["slots"]
+        assert sorted({a.shape for a in state[0] + state[1]}) == [
+            (s, 1, 8192, 128), (s, 1, 8192, 512)]
+        with _tpu_trace():
+            if which == "step":
+                compiled = engine._step_fn.lower(params, state, keep,
+                                                 extra).compile()
+            else:
+                compiled = engine._prefill_fns[which].lower(
+                    *tool.prefill_shapes(params, state, which,
+                                         sds)).compile()
+        ma = compiled.memory_analysis()
+        assert ma.argument_size_in_bytes + ma.output_size_in_bytes \
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes < 15.0e9
+        text = compiled.as_text()
+        if which == "step":
+            assert text.count("tpu_custom_call") == 5 + 2 * 5
+            assert text.count("latent_attention") >= 5
+            assert "dynamic-update-slice" not in text
+            for held in ("[%d,128,8192" % s, "[%d,8192,128," % s):
+                assert held not in text, held
+            assert ma.temp_size_in_bytes < 64e6, ma.temp_size_in_bytes
+        else:
+            assert text.count("flash_attention") >= 5
+            assert "ragged" in text
+            assert ma.temp_size_in_bytes < 1.6e9, ma.temp_size_in_bytes
+            assert "%d,%d]" % (which, which) not in text
+            assert "[%d,20,1536]" % which not in text
+        copies = tool.cache_copies(text, state)
+        assert not copies, "%d copies of a cache array, the first: %s" \
+            % (len(copies), copies[0][:200])
+    return run
+
+
+CASES["decode-step-deepseek-v2-no-cache-copy-no-expanded-heads"] = \
+    _deepseek_v2_case("step")
+CASES["decode-prefill-4096-deepseek-v2-under-1.6-GB-of-temporaries"] = \
+    _deepseek_v2_case(4096)
+
+
 def _decode_attention_lanes():
     """Heads of 64 cached on their own, ``(128, 20, 4096, 64)``: the plan
     says ``lanes`` and the call takes the plain path.  The compiler keeps
@@ -770,7 +886,8 @@ CASES["decode-prefill-1024-phi-4-mini-flash-128-slots"] = _sambay_case(1024)
 #: the engines' cases have a file a model family (``test_tpu_aot_<family>.py``:
 #: under ``--dist loadfile`` only a file can go to another worker); the
 #: kernels' own cases are this file's
-FAMILIES = ("gpt2-large", "k-exaone", "phi-4-mini-flash", "smallthinker")
+FAMILIES = ("gpt2-large", "k-exaone", "phi-4-mini-flash", "smallthinker",
+            "deepseek-v2")
 
 
 def cases_of(family=None):

@@ -6,14 +6,17 @@ is allocated and nothing runs.  A change to the engine or to the model
 protocol that is not meant to change a model's programs shows here as the
 same digests before and after.  With ``--tpu`` the programs are traced as
 they are for the chip (``ops.registry.trace_device``) and lowered for it, so
-the Pallas kernels are part of the text (with the paths and lines of their
-call stacks: compare two trees through one path, a symbolic link that is
-pointed at each in turn); without, as the CPU runs them.
+the Pallas kernels are part of the text: each kernel's body is read back
+and printed without the paths and lines of its call stack, so two trees, or
+one before and after a move of code, compare by what the kernels do;
+without, as the CPU runs them.
 
     JAX_PLATFORMS=cpu python tools/perf/program_fingerprints.py [--tpu] [config ...]
 """
 
+import base64
 import os
+import re
 import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -92,6 +95,24 @@ def _gpt2_shapes(engine, params):
     return params, state, sds((s,), jnp.bool_)
 
 
+_BODY_RE = re.compile(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22')
+
+
+def kernels_without_locations(text):
+    """``text`` with every Pallas kernel's serialized body replaced by its
+    operations as text, locations left out."""
+    from jax._src.interpreters import mlir
+    from jaxlib.mlir import ir
+
+    def plain(match):
+        with mlir.make_ir_context() as context:
+            context.allow_unregistered_dialects = True
+            return ir.Module.parse(base64.b64decode(match.group(1))) \
+                .operation.get_asm(enable_debug_info=False)
+
+    return _BODY_RE.sub(plain, text)
+
+
 def main():
     import jax
 
@@ -113,12 +134,14 @@ def main():
         if "engine" not in config:
             continue
         for what, fn, shapes in programs(config):
-            # a kernel's text carries the call stack of its FIRST trace in
-            # the process: each program is traced as if alone
             jax.clear_caches()
-            print("%s %s %s" % (name, what, perfdebug.fingerprint_text(
-                fn.trace(*shapes).lower(lowering_platforms=(platform,))
-                .as_text())), flush=True)
+            text = fn.trace(*shapes).lower(
+                lowering_platforms=(platform,)).as_text()
+            if platform == "tpu":
+                text = kernels_without_locations(text)
+            print("%s %s %s" % (name, what,
+                                perfdebug.fingerprint_text(text)),
+                  flush=True)
 
 
 if __name__ == "__main__":
