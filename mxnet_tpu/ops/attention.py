@@ -34,7 +34,8 @@ Design:
 * ``latent_attention`` — the absorbed step of latent (MLA) attention: all
   heads of a slot over the slot's latent rows, which serve as keys and as
   values; on a TPU trace ``_latent_pallas`` (the decode kernel's walk, one
-  copy of a chunk for both products), else ``_latent_xla``.
+  copy of a chunk for both products, multiplied in sub-blocks), else
+  ``_latent_xla``.
 
 * ``write_slot_rows`` — the decode step's one new K (or V) row a slot,
   put into a heads-major cache: on a TPU trace one Pallas kernel an array
@@ -47,6 +48,7 @@ Shapes follow (batch, heads, seq, head_dim) throughout.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -817,11 +819,19 @@ def decode_attention(q, cache_k, cache_v, lengths, scale):
 # ---------------------------------------------------------------------------
 
 #: bytes of latent rows one copy of the latent kernel moves (the rotated
-#: keys' copy beside it moves a quarter as much): tools/perf/mla_variants.py
-#: has the readings the size was chosen from, and the other layout's, one
-#: array of 640 values a row and one copy a chunk, which read 2% faster
-#: (1.866 against 1.910 ms) and was not taken (PERF.md section 6, PR 39)
+#: keys' copy beside it moves a quarter as much): 1024 rows at the
+#: DeepSeek-V2 shapes.  Read again at PR 40 with the products in sub-blocks
+#: (tools/perf/mla_variants.py, PERF.md section 6): 2048 rows a copy halve
+#: the turns and read the same (0.5% slower, 0.1% faster in two grids),
+#: because ``_walk_slot`` issues a possible copy for every piece of a
+#: chunk at every turn, twice as many; 512 rows read 9% slower.  The other
+#: layout, one array of 640 values a row and one copy a chunk, read 2%
+#: faster at PR 39 and was not taken
 _LATENT_CHUNK_BYTES = 1 << 20
+#: bytes of float32 scores one sub-block of the latent kernel makes: the
+#: vector registers of a v5e core, 64 of 4 KiB (512 rows for 128 heads;
+#: 256 rows read the same to 0.7% slower, the whole chunk 2-4% slower)
+_LATENT_SCORE_BYTES = 1 << 18
 
 
 def _latent_xla(q_lat, q_rope, cache_lat, cache_rope, lengths):
@@ -841,59 +851,144 @@ def _latent_xla(q_lat, q_rope, cache_lat, cache_rope, lengths):
 
 def _latent_kernel(len_ref, ql_ref, qr_ref, lat_hbm, rope_hbm, o_ref,
                    lat_buf, rope_buf, sems, turns, m_scr, l_scr, acc_scr, *,
-                   chunk, piece):
+                   chunk, piece, sub, walk=_walk_slot):
     """:func:`_walk_slot` over a cache whose row is one latent vector: ONE
     copy of a chunk of latent rows is the right-hand side of the scores and
     of the weighted sum, all ``heads`` queries of the slot against it at
     once; the rotated keys' narrower rows ride in a copy of their own and
-    add their product to the scores."""
+    add their product to the scores.
 
-    def accumulate(held, first, n):
-        """``lat (1, rows, c)``, ``rope (1, rows, r)`` join the running
-        softmax."""
-        lat, rope = held[0][0], held[1][0]
+    The copy's granule is the walk's (``chunk`` rows, at the edge ``piece``)
+    and the products' is ``sub`` rows, one step of the running softmax
+    each.  A whole chunk is multiplied in sub-blocks unrolled into one
+    basic block, the scores of the next written before the softmax of this
+    one: the chip's scheduler keeps program order, and so has products to
+    put under the exponentials.  The edge's pieces are waited for in the
+    walk and multiplied after it, from the buffer they lie in, in the same
+    sub-blocks, masked above the slot's length; a sub-block wholly above it
+    is skipped.  The running maximum ``(heads, lanes)`` is kept the same in
+    every lane and the running sum as one partial sum a lane, added up once
+    a slot, pair by pair: a step reduces across lanes once, for the
+    maximum.  (``walk``: tools/perf/mla_variants.py times the products
+    over a walk that copies nothing.)"""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes = m_scr.shape[1]
+
+    def scores(lat, rope):
         contract = (((1,), (1,)), ((), ()))
-        s = jax.lax.dot_general(ql_ref[0], lat, contract,
-                                preferred_element_type=jnp.float32) \
+        return jax.lax.dot_general(ql_ref[0], lat, contract,
+                                   preferred_element_type=jnp.float32) \
             + jax.lax.dot_general(qr_ref[0], rope, contract,
                                   preferred_element_type=jnp.float32)
-        if n is not None:                                   # (heads, rows)
-            rows = lat.shape[0]
-            at = first + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
-            s = jnp.where(at <= n, s, NEG_INF)
-            at = first + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-            lat = jnp.where(at <= n, lat, jnp.zeros_like(lat))
+
+    def across(x, n):
+        """``x (heads, lanes)``, the same in every lane, over ``n``."""
+        return x if n == lanes else jnp.concatenate([x] * (n // lanes), 1)
+
+    def join(s, lat):
+        """Scores ``s (heads, rows)`` and their rows ``lat (rows, c)`` as
+        one step of the running softmax."""
+        rows = s.shape[1]
         m_prev = m_scr[...]
         m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
+        p = jnp.exp(s - across(m_cur, rows))
+        l_cur = l_scr[...] * alpha
+        for at in range(0, rows, lanes):
+            l_cur = l_cur + p[:, at:at + lanes]
+        l_scr[...] = l_cur
         m_scr[...] = m_cur
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(lat.dtype), lat, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (heads, c)
+        acc_scr[...] = acc_scr[...] * across(alpha, lat.shape[1]) \
+            + jax.lax.dot_general(
+                p.astype(lat.dtype), lat, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # (heads, c)
 
-    _walk_slot(len_ref, ((lat_hbm, lat_buf), (rope_hbm, rope_buf)), sems,
-               turns, (m_scr, l_scr, acc_scr), chunk, piece, accumulate)
-    o_ref[0] = acc_scr[...] / l_scr[...]
+    def accumulate(held, first, n):
+        """A whole chunk ``lat (1, chunk, c)``, ``rope (1, chunk, r)`` joins
+        the running softmax.  A piece of the edge (``n`` given) is NOT
+        multiplied here, 128 rows a step of the softmax: the walk has
+        waited for it, and ``the_edge`` takes all of them at once."""
+        if n is not None:
+            return
+        lat, rope = held[0][0], held[1][0]
+        s = scores(lat[:sub], rope[:sub])
+        for at in range(0, chunk, sub):
+            ahead = at + sub
+            s_next = scores(lat[ahead:ahead + sub], rope[ahead:ahead + sub]) \
+                if ahead < chunk else None
+            join(s, lat[at:ahead])
+            s = s_next
+
+    def the_edge():
+        """The slot's last, partial chunk, from where the walk left it.
+        What is read here of the walk beyond ``accumulate``'s arguments,
+        and has to change with it
+        (``test_the_latent_kernel_reads_the_edge_where_the_walk_left_it``
+        fails on each):
+
+        - turn ``t`` of all slots uses buffer ``t % 2``, and ``turns[0]``
+          counts the turns made, this slot's last one included: the edge
+          is in buffer ``(turns[0] - 1) % 2``;
+        - piece ``p`` of the edge, the slot's rows from ``first + p *
+          piece``, lies at row ``p * piece`` of that buffer, and every
+          piece at or below the length has been waited for when the walk
+          returns;
+        - no copy into that buffer is started before the next grid step
+          (the last turn started the next slot's first into the other).
+
+        Rows above the length hold whatever an earlier turn left."""
+        n = len_ref[pl.program_id(0)]
+        first = n // chunk * chunk
+        buf = (turns[0] - 1) % 2
+        for at in range(0, chunk, sub):
+            @pl.when(first + at <= n)
+            def _sub_block():
+                lat = lat_buf[buf, 0, at:at + sub, :]
+                s = scores(lat, rope_buf[buf, 0, at:at + sub, :])
+                row = first + at + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, sub), 1)
+                s = jnp.where(row <= n, s, NEG_INF)
+                row = first + at + jax.lax.broadcasted_iota(
+                    jnp.int32, (sub, 1), 0)
+                join(s, jnp.where(row <= n, lat, jnp.zeros_like(lat)))
+
+    walk(len_ref, ((lat_hbm, lat_buf), (rope_hbm, rope_buf)), sems, turns,
+         (m_scr, l_scr, acc_scr), chunk, piece, accumulate)
+    the_edge()
+    # the lanes' partial sums pair by pair, lane i with lane i + w: the
+    # order of least rounding, the same here and in the interpreter, and
+    # the sum comes out in every lane
+    l = l_scr[...]
+    w = lanes
+    while w > 1:
+        w //= 2
+        l = l + pltpu.roll(l, w, 1)
+    o_ref[0] = acc_scr[...] / across(l, acc_scr.shape[1])
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "piece", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "piece", "sub",
+                                             "interpret"))
 def _latent_pallas(q_lat, q_rope, cache_lat, cache_rope, lengths, chunk,
-                   piece, interpret=False):
-    """Jitted on its own, as :func:`_decode_pallas` is."""
+                   piece, sub, interpret=False):
+    """Jitted on its own, as :func:`_decode_pallas` is.  ``sub``: the rows
+    multiplied at a time, a divisor of ``chunk``."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     s, h, c = q_lat.shape
     r = q_rope.shape[2]
+    # the softmax's state a lane of a vector register wide, where the
+    # shapes are whole registers (on the chip they are: the plan's rules)
+    lanes = math.gcd(128, sub, c)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
 
     def whole(i, lens):
         return (i, 0, 0)
 
     return pl.pallas_call(
-        functools.partial(_latent_kernel, chunk=chunk, piece=piece),
+        functools.partial(_latent_kernel, chunk=chunk, piece=piece, sub=sub),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(s,),
@@ -904,9 +999,9 @@ def _latent_pallas(q_lat, q_rope, cache_lat, cache_rope, lengths, chunk,
                             pltpu.VMEM((2, 1, chunk, r), cache_rope.dtype),
                             pltpu.SemaphoreType.DMA((2, 2)),
                             pltpu.SMEM((1,), jnp.int32),         # turns
-                            pltpu.VMEM((h, 1), jnp.float32),     # m
-                            pltpu.VMEM((h, 1), jnp.float32),     # l
-                            pltpu.VMEM((h, c), jnp.float32)]),   # acc
+                            pltpu.VMEM((h, lanes), jnp.float32),  # m
+                            pltpu.VMEM((h, lanes), jnp.float32),  # l
+                            pltpu.VMEM((h, c), jnp.float32)]),    # acc
         out_shape=jax.ShapeDtypeStruct((s, h, c), jnp.float32),
         # buffers, semaphores and the count of turns carry over from a slot
         # to the next: the slots run in order
@@ -927,6 +1022,16 @@ def _latent_chunk(cache_lat):
             and chunk * 2 <= rows:
         chunk *= 2
     return chunk
+
+
+def _latent_sub(heads, chunk):
+    """Rows multiplied at a time: as many as make :data:`_LATENT_SCORE_BYTES`
+    of scores for all heads, a power of two of pieces, no more than a
+    chunk."""
+    sub = _DECODE_PIECE
+    while heads * sub * 2 * 4 <= _LATENT_SCORE_BYTES and sub * 2 <= chunk:
+        sub *= 2
+    return sub
 
 
 def latent_attention_plan(q_lat, cache_lat, cache_rope):
@@ -968,8 +1073,10 @@ def latent_attention(q_lat, q_rope, cache_lat, cache_rope, lengths):
     the same latent rows.  Neither a key nor a value of any head is built.
 
     On a TPU trace the Pallas kernel walks, of each slot, only the rows at
-    or below its length, to a multiple of 128; one copy of a chunk serves
-    both products and the softmax stays in VMEM.  Elsewhere, and where the
+    or below its length, to a multiple of 128; one copy of a chunk
+    (:func:`_latent_chunk` rows) serves both products, which take it
+    :func:`_latent_sub` rows at a time, and the softmax stays in VMEM.
+    Both sizes are decided here from the shapes.  Elsewhere, and where the
     kernel refuses (:func:`latent_attention_plan`), every row is read and
     masked.  The choice is counted under ``ops.kernel_path``."""
     from .registry import count_kernel_path
@@ -977,8 +1084,10 @@ def latent_attention(q_lat, q_rope, cache_lat, cache_rope, lengths):
     _, reason = latent_attention_plan(q_lat, cache_lat, cache_rope)
     if reason is None:
         count_kernel_path("latent_attention", "pallas", "ok")
+        chunk = _latent_chunk(cache_lat)
         return _latent_pallas(q_lat, q_rope, cache_lat, cache_rope, lengths,
-                              _latent_chunk(cache_lat), _DECODE_PIECE)
+                              chunk, _DECODE_PIECE,
+                              _latent_sub(q_lat.shape[1], chunk))
     count_kernel_path("latent_attention", "xla", reason)
     return _latent_xla(q_lat, q_rope, cache_lat, cache_rope, lengths)
 

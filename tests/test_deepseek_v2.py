@@ -123,11 +123,12 @@ def test_prefill_then_decode_equal_the_reference_logits(prompt, bucket, new,
 
 def test_the_latent_kernel_in_the_step(monkeypatch):
     """The same session with every layer's rows through the Pallas kernel
-    (interpreter) in chunks of 16 rows and pieces of 8."""
+    (interpreter) in chunks of 16 rows, multiplied 8 at a time, and pieces
+    of 8."""
     monkeypatch.setattr(
         dm, "latent_attention",
         lambda ql, qr, cl, cr, lengths: attention._latent_pallas(
-            ql, qr, cl, cr, lengths, 16, 8, interpret=True))
+            ql, qr, cl, cr, lengths, 16, 8, 8, interpret=True))
     worst, _counted = _prefill_then_decode(_cfg(), 13, 16, 22,
                                            _programs(_cfg()))
     assert worst < 2e-4
@@ -284,26 +285,115 @@ def test_yarn_frequencies_and_scale_by_hand():
 
 
 # -- the kernels' arithmetic ---------------------------------------------------
+def _latent_operands(rows, dtype=jnp.float32, s=3):
+    h, c, r = 8, 256, 128
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    return ((jax.random.normal(keys[0], (s, h, c)) * 0.1).astype(dtype),
+            (jax.random.normal(keys[1], (s, h, r)) * 0.1).astype(dtype),
+            jax.random.normal(keys[2], (s, 1, rows, c)).astype(dtype),
+            jax.random.normal(keys[3], (s, 1, rows, r)).astype(dtype))
+
+
+def _latent_float64(ql, qr, lat, rope, n):
+    """The masked einsum of the same operands in numpy's float64."""
+    ql, qr, lat, rope = (np.asarray(x.astype(jnp.float32), np.float64)
+                         for x in (ql, qr, lat[:, 0], rope[:, 0]))
+    s = np.einsum("shc,smc->shm", ql, lat) \
+        + np.einsum("shr,smr->shm", qr, rope)
+    held = np.arange(lat.shape[1])[None, :] <= np.asarray(n)[:, None]
+    s = np.where(held[:, None, :], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum("shm,smc->shc", p / p.sum(-1, keepdims=True), lat)
+
+
 @pytest.mark.parametrize("lengths", [(0, 130, 511), (127, 128, 300),
                                      (255, 256, 257)])
-@pytest.mark.parametrize("chunk", [128, 256])
-def test_the_latent_kernel_is_the_masked_einsum(lengths, chunk):
-    s, h, c, r, rows = 3, 8, 256, 128, 512
-    keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    ql = jax.random.normal(keys[0], (s, h, c)) * 0.1
-    qr = jax.random.normal(keys[1], (s, h, r)) * 0.1
-    lat = jax.random.normal(keys[2], (s, 1, rows, c))
-    rope = jax.random.normal(keys[3], (s, 1, rows, r))
+@pytest.mark.parametrize("chunk,sub", [(128, 128), (256, 256), (256, 128)])
+def test_the_latent_kernel_is_the_masked_einsum(lengths, chunk, sub):
+    rows = 512
+    ql, qr, lat, rope = _latent_operands(rows)
     n = jnp.asarray(lengths, jnp.int32)
     want = attention._latent_xla(ql, qr, lat, rope, n)
-    got = attention._latent_pallas(ql, qr, lat, rope, n, chunk, 128,
+    got = attention._latent_pallas(ql, qr, lat, rope, n, chunk, 128, sub,
                                    interpret=True)
     np.testing.assert_allclose(got, want, atol=2e-6)
+    # and no further from the same einsum in float64 than the float32
+    # einsum is
+    exact = _latent_float64(ql, qr, lat, rope, n)
+    assert np.abs(got - exact).max() <= np.abs(want - exact).max()
     # off the TPU the public call is the masked einsum, and says so
     assert attention.latent_attention_plan(ql, lat, rope) \
         == (rows, "not_tpu")
     np.testing.assert_array_equal(
         attention.latent_attention(ql, qr, lat, rope, n), want)
+
+
+@pytest.mark.parametrize("above", [False, True])
+@pytest.mark.parametrize("chunk,sub", [(256, 128), (512, 128), (512, 256),
+                                       (512, 512)])
+def test_the_latent_kernel_in_sub_blocks(chunk, sub, above):
+    """A whole chunk joins the softmax ``sub`` rows at a time; lengths on
+    either side of a sub-block's and of a chunk's boundary."""
+    rows = 1152
+    ql, qr, lat, rope = _latent_operands(rows)
+    n = jnp.asarray((chunk, chunk + sub + 1, rows - 1) if above
+                    else (sub - 1, sub, chunk - 1), jnp.int32)
+    got = attention._latent_pallas(ql, qr, lat, rope, n, chunk, 128, sub,
+                                   interpret=True)
+    np.testing.assert_allclose(
+        got, attention._latent_xla(ql, qr, lat, rope, n), atol=2e-6)
+
+
+@pytest.mark.parametrize("lengths", [(255, 641, 1151), (127, 512, 700)])
+@pytest.mark.parametrize("sub", [128, 256])
+def test_the_latent_kernel_in_sub_blocks_in_bfloat16(sub, lengths):
+    """The float32 cases' operands in bfloat16, against the same einsum in
+    float64: the kernel within 2e-3 (``_latent_xla`` itself, which rounds
+    the weights after dividing them, stands at 2.5e-3 to 5.6e-3), no
+    further than ``_latent_xla``, and the sub-blocks no further than the
+    whole chunk at once is, by more than a twentieth of the limit."""
+    ql, qr, lat, rope = _latent_operands(1152, jnp.bfloat16)
+    n = jnp.asarray(lengths, jnp.int32)
+    exact = _latent_float64(ql, qr, lat, rope, n)
+
+    def off(got):
+        return np.abs(np.asarray(got) - exact).max()
+
+    got, whole = (off(attention._latent_pallas(
+        ql, qr, lat, rope, n, 512, 128, rows, interpret=True))
+        for rows in (sub, 512))
+    assert got < 2e-3
+    assert got <= off(attention._latent_xla(ql, qr, lat, rope, n))
+    assert got <= whole + 1e-4
+
+
+@pytest.mark.parametrize("sub", [128, 256])
+def test_the_latent_kernel_reads_the_edge_where_the_walk_left_it(sub):
+    """The kernel multiplies a slot's edge after ``_walk_slot`` returns,
+    from the walk's buffer (``_latent_kernel.the_edge``).  Slots whose
+    turns end in either buffer, the edge of one, two and no piece beyond
+    the first, each slot's rows of a size of their own and NaN above its
+    length: the other buffer holds another slot's rows or another chunk's,
+    the edge's buffer above its pieces what an earlier turn left, and the
+    cache above a length what no product may touch.  (Tried: the other
+    buffer, and the pieces read one place on: each fails both cases.)"""
+    chunk, rows = 256, 1152
+    lengths = (1100, 40, 300, 700, 5, 255, 256)   # turns 5, 1, 2, 3, 1, 1, 2
+    ql, qr, lat, rope = _latent_operands(rows, s=len(lengths))
+    size = (1.0 + np.arange(len(lengths), dtype=np.float32))[:, None, None,
+                                                              None]
+    held = (np.arange(rows)[None, :]
+            <= np.asarray(lengths)[:, None])[:, None, :, None]
+    lat, rope = (np.where(held, np.asarray(x) * size, 0.0)
+                 for x in (lat, rope))
+    n = jnp.asarray(lengths, jnp.int32)
+    want = attention._latent_xla(ql, qr, jnp.asarray(lat),
+                                 jnp.asarray(rope), n)
+    got = attention._latent_pallas(
+        ql, qr, jnp.asarray(np.where(held, lat, np.nan)),
+        jnp.asarray(np.where(held, rope, np.nan)), n, chunk, 128, sub,
+        interpret=True)
+    np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
 
 
 @pytest.mark.parametrize("path", ["scan", "blocks", "pallas"])

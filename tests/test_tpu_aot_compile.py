@@ -16,6 +16,7 @@ chip).
 """
 
 import contextlib
+import math
 import os
 import subprocess
 import sys
@@ -63,6 +64,17 @@ def _one_chip():
         print("cannot describe a v5e topology: %s" % e)
         sys.exit(_SKIP)
     return SingleDeviceSharding(topo.devices[0])
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it (a ``jit``
+    within the function: the kernels are jitted on their own)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in eqn.params.values():
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                yield from _equations(inner)
 
 
 def _unbuilt_engine(model, **opts):
@@ -632,8 +644,10 @@ def _latent_attention_case():
     """``ops.attention.latent_attention`` over a donated cache at the
     DeepSeek-V2 cell's shapes (128 slots, 128 heads, latent rows of 512 and
     rotated rows padded to 128 lanes, 8192 rows, bfloat16): the plan admits
-    it, chunks of 1024 rows (1 MiB of latent rows a copy), the new rows
-    written by ``slot_write``, and no ``(128, 128, 8192)`` scores."""
+    it, chunks of 1024 rows (1 MiB of latent rows a copy) multiplied 512
+    rows at a time (the scores of a sub-block one register file), the
+    kernel's buffers and softmax under the 16 MiB a kernel may use, the new
+    rows written by ``slot_write``, and no ``(128, 128, 8192)`` scores."""
     s, h, rows = 128, 128, 8192
     one_chip = _one_chip()
     lat = jax.ShapeDtypeStruct((s, 1, rows, 512), jnp.bfloat16)
@@ -647,13 +661,32 @@ def _latent_attention_case():
 
     with _tpu_trace():
         assert attention.latent_attention_plan(ql, lat, rope) == (128, None)
-        assert attention._latent_chunk(lat) == 1024
+        chunk = attention._latent_chunk(lat)
+        assert (chunk, attention._latent_sub(h, chunk)) == (1024, 512)
         sds = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
                for shape, dtype in (
                    ((s, h, 512), jnp.bfloat16), ((s, h, 128), jnp.bfloat16),
                    (lat.shape, lat.dtype), (rope.shape, rope.dtype),
                    ((s, 1, 512), jnp.bfloat16), ((s, 1, 128), jnp.bfloat16),
                    ((s,), jnp.int32))]
+        # the kernel as it is traced here: what it keeps in VMEM (two
+        # buffers of latent and of rotated rows, the accumulator, the
+        # running maximum and sum a lane wide) beside two blocks each of
+        # its queries and its output, and that it asks for no limit of its
+        # own, so that the compile below is held to the chip's 16 MiB
+        (kernel,) = [
+            eqn for eqn in _equations(jax.make_jaxpr(step)(*sds).jaxpr)
+            if eqn.primitive.name == "pallas_call"
+            and eqn.params["name"] == "latent_attention"]
+        scratch = kernel.params["jaxpr"].invars[
+            -kernel.params["grid_mapping"].num_scratch_operands:]
+        held = sum(math.prod(v.aval.shape) * v.aval.dtype.itemsize
+                   for v in scratch if str(v.aval.memory_space) == "vmem")
+        assert held == 2 * chunk * (512 + 128) * 2 \
+            + h * (512 + 2 * 128) * 4 == 3014656
+        assert held + 2 * h * ((512 + 128) * 2 + 512 * 4) < 16 << 20
+        assert kernel.params["compiler_params"]["mosaic_tpu"] \
+            .vmem_limit_bytes is None
         text = jax.jit(step, donate_argnums=(2, 3)).lower(*sds).compile() \
             .as_text()
     assert text.count("tpu_custom_call") == 3
