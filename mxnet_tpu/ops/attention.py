@@ -24,6 +24,8 @@ Design:
   counted under ``ops.kernel_path``) else the scan; backward recomputes blockwise
   from the saved (o, lse) residuals — the standard FA2 backward, written as
   plain JAX matmuls per K block so XLA schedules them on the MXU.
+  ``block=B`` with ``causal``: causal by blocks of ``B`` positions (a row
+  sees its whole block), on every path.
 
 * ``decode_attention`` — one query a slot over a dense K/V cache whose
   slots hold different numbers of rows (the decode step's full-attention
@@ -37,7 +39,8 @@ Design:
   copy of a chunk for both products, multiplied in sub-blocks), else
   ``_latent_xla``.
 
-* ``write_slot_rows`` — the decode step's one new K (or V) row a slot,
+* ``write_slot_rows`` — the decode step's one new K (or V) row a slot (a
+  run of ``B`` rows for a model that generates by blocks),
   put into a heads-major cache: on a TPU trace one Pallas kernel an array
   with every slot's tile of rows in flight at once (``_slot_write_pallas``),
   else one update-slice a slot (``_slot_write_xla``).
@@ -60,19 +63,30 @@ NEG_INF = -1e30
 
 
 def _attn_reference(q, k, v, causal=False, scale=None, kv_offset=0,
-                    window=None):
-    """Quadratic-memory reference attention (numerics oracle for tests)."""
+                    window=None, block=None):
+    """Quadratic-memory reference attention (numerics oracle for tests).
+    ``block`` with ``causal``: causal by blocks of ``block`` positions, a
+    row sees its whole block (:func:`flash_attention`)."""
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     qi = jnp.arange(q.shape[2])[:, None]
     ki = jnp.arange(k.shape[2])[None, :] + kv_offset
     if causal:
-        s = jnp.where(qi >= ki, s, NEG_INF)
+        s = jnp.where(_sees(qi, ki, block), s, NEG_INF)
     if window is not None:
         s = jnp.where(ki > qi - window, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
+
+
+def _sees(qi, ki, block):
+    """The causal mask: query ``qi`` reads key ``ki`` at or below it, or,
+    with ``block``, anywhere in a block of ``block`` positions at or below
+    its own (``ki // block <= qi // block``)."""
+    if block is None:
+        return qi >= ki
+    return qi // block >= ki // block
 
 
 # ---------------------------------------------------------------------------
@@ -148,7 +162,8 @@ def _k_block_range(qb, block_q, block_k, num_kb, causal, window):
     return first, last
 
 
-def _flash_blocks(q, k, v, causal, scale, block_q, block_k, window):
+def _flash_blocks(q, k, v, causal, scale, block_q, block_k, window,
+                  block=None):
     """Blockwise attention with the queries in blocks too: ``lax.map`` over
     Q blocks, and inside it a loop over the K/V blocks of
     :func:`_k_block_range` alone, so a block wholly outside the window (or
@@ -157,6 +172,8 @@ def _flash_blocks(q, k, v, causal, scale, block_q, block_k, window):
     head ``i // (H // kv_heads)``, by a grouped product and no copy.
     Products take operands in the inputs' dtype and accumulate in float32;
     the weights are rounded to ``v``'s dtype before their product.
+    ``block``: causal by blocks (:func:`_sees`); it divides ``block_q`` and
+    ``block_k``, so the K/V blocks visited are those of ``causal``.
     Returns (out, lse); forward only (the loop's trip count is traced)."""
     b, h, lq, d = q.shape
     n, lk, dv = k.shape[1], k.shape[2], v.shape[3]
@@ -186,7 +203,7 @@ def _flash_blocks(q, k, v, causal, scale, block_q, block_k, window):
             ki = kb * block_k + jnp.arange(block_k)[None, :]
             valid = jnp.ones((block_q, block_k), bool)
             if causal:
-                valid = valid & (qi >= ki)
+                valid = valid & _sees(qi, ki, block)
             if window is not None:
                 valid = valid & (ki > qi - window)
             s = jnp.where(valid, s, NEG_INF)
@@ -219,12 +236,14 @@ def _flash_blocks(q, k, v, causal, scale, block_q, block_k, window):
 # ---------------------------------------------------------------------------
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
-               scale, causal, window, block_q, block_k, num_kb):
+               scale, causal, window, block_q, block_k, num_kb, block=None):
     """Online-softmax flash attention body; grid = (BH, num_qb, num_kb),
     K innermost with scratch (m, l, acc) carried across K steps.  Both
     products take their operands as they come (bfloat16 stays bfloat16 on
     the MXU) and accumulate in float32; the weights are rounded to ``v``'s
-    dtype before theirs."""
+    dtype before theirs.  ``block`` (a power of two that divides both block
+    sizes): causal by blocks, which changes the mask of the diagonal block
+    alone; the last position of ``qi``'s block is ``qi | (block - 1)``."""
     from jax.experimental import pallas as pl
 
     qb = pl.program_id(1)
@@ -246,7 +265,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
                 jnp.int32, (block_q, block_k), 0)
             ki = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            valid = qi >= ki if causal else None
+            if causal:
+                valid = qi >= ki if block is None else qi | (block - 1) >= ki
             if window is not None:
                 near = ki > qi - window
                 valid = near if valid is None else valid & near
@@ -287,7 +307,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
 
 
 def _flash_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
-                  interpret=False, window=None):
+                  interpret=False, window=None, block=None):
     """``k``/``v`` may have fewer heads than ``q``: a query head's grid
     steps are handed its K/V head's blocks by the index map, and no copy of
     K or V is made.  ``v``'s rows may be of another width than ``q``'s and
@@ -310,7 +330,7 @@ def _flash_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
 
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, num_kb=num_kb)
+        block_q=block_q, block_k=block_k, num_kb=num_kb, block=block)
 
     def kv_block(b_, q_, k_):
         first, last = _k_block_range(q_, block_q, block_k, num_kb, causal,
@@ -351,7 +371,7 @@ def _flash_pallas(q, k, v, causal, scale, block_q=256, block_k=512,
     return out.reshape(b, h, lq, dv), lse.reshape(b, h, lq)
 
 
-def _kernel_refusal(q, k, block_q, block_k):
+def _kernel_refusal(q, k, block_q, block_k, block=None):
     """Why the Pallas kernel cannot take this call (``ops.kernel_path``
     reason), or None when it can.
 
@@ -373,6 +393,9 @@ def _kernel_refusal(q, k, block_q, block_k):
         return "tile"
     if (bq != lq and bq % 8) or (bk != lk and bk % 8):
         return "tile"
+    if block is not None and block & (block - 1):
+        # the kernel's mask of a block is a bit-or (:func:`_fa_kernel`)
+        return "block"
     return None
 
 
@@ -380,36 +403,46 @@ def _kernel_refusal(q, k, block_q, block_k):
 # custom-vjp flash attention (public functional API)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, block_q, block_k, window=None):
-    out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, scale, block_q, block_k, window=None,
+           block=None):
+    out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, window,
+                        block)
     return out
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None,
+               block=None):
     from .registry import count_kernel_path
 
     if q.shape[1] % k.shape[1]:
         raise ValueError("%d query heads over %d K/V heads"
                          % (q.shape[1], k.shape[1]))
     op = "FlashAttention" if window is None else "FlashAttention.window"
-    reason = _kernel_refusal(q, k, block_q, block_k)
+    if block is not None:
+        bq, bk = min(block_q, q.shape[2]), min(block_k, k.shape[2])
+        if not causal or bq % block or bk % block:
+            raise ValueError(
+                "block=%d needs causal=True and to divide the blocks "
+                "(%d, %d)" % (block, bq, bk))
+        op = "FlashAttention.block"
+    reason = _kernel_refusal(q, k, block_q, block_k, block)
     if reason is None:
         count_kernel_path(op, "pallas", "ok")
         out, lse = _flash_pallas(q, k, v, causal, scale, block_q, block_k,
-                                 window=window)
+                                 window=window, block=block)
     else:
         count_kernel_path(op, "xla", reason)
-        if window is None and q.shape[1] == k.shape[1]:
+        if window is None and block is None and q.shape[1] == k.shape[1]:
             out, lse = _flash_scan(q, k, v, causal, scale, block_k)
         else:
             out, lse = _flash_blocks(q, k, v, causal, scale, block_q,
-                                     block_k, window)
+                                     block_k, window, block)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_core(causal, scale, block_q, block_k, res, do, dlse=None,
-                    window=None):
+                    window=None, block=None):
     """FA2 backward: blockwise over K, plain-JAX matmuls (MXU via XLA).
 
     ``dlse`` (optional, (B,H,Lq) f32) is the cotangent of the logsumexp
@@ -448,7 +481,7 @@ def _flash_bwd_core(causal, scale, block_q, block_k, res, do, dlse=None,
         valid = jnp.broadcast_to((kpos < lk)[None, :], (lq, bk))
         qi = jnp.arange(lq)[:, None]
         if causal:
-            valid = valid & (qi >= kpos[None, :])
+            valid = valid & _sees(qi, kpos[None, :], block)
         if window is not None:
             valid = valid & (kpos[None, :] > qi - window)
         p = jnp.where(valid, jnp.exp(s - lse[..., None]), 0.0)
@@ -476,9 +509,9 @@ def _flash_bwd_core(causal, scale, block_q, block_k, res, do, dlse=None,
             of_kv_heads(dv_b).astype(v.dtype))
 
 
-def _flash_bwd(causal, scale, block_q, block_k, window, res, do):
+def _flash_bwd(causal, scale, block_q, block_k, window, block, res, do):
     return _flash_bwd_core(causal, scale, block_q, block_k, res, do,
-                           window=window)
+                           window=window, block=block)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -521,7 +554,7 @@ def flash_attention_with_lse(q, k, v, causal=False, softmax_scale=None,
 
 
 def flash_attention(q, k, v, causal=False, softmax_scale=None,
-                    block_q=256, block_k=512, window=None):
+                    block_q=256, block_k=512, window=None, block=None):
     """Memory-efficient attention.  ``q (batch, heads, seq, head_dim)``;
     ``k``/``v (batch, kv_heads, seq, head_dim)`` with ``kv_heads`` a
     divisor of ``heads`` (query head ``i`` reads K/V head ``i // (heads //
@@ -529,12 +562,19 @@ def flash_attention(q, k, v, causal=False, softmax_scale=None,
     a width of its own (latent attention's expanded heads score over 192
     values and carry 128), which is then the context's.  ``window``: a query at
     position ``p`` reads ``p - window + 1 .. p`` (with ``causal``), and the
-    K/V blocks wholly outside are skipped."""
+    K/V blocks wholly outside are skipped.  ``block`` (with ``causal``):
+    causal by blocks of ``block`` positions, row ``i`` reads column ``j``
+    iff ``j // block <= i // block`` — causal between blocks, both ways
+    inside one (generation by diffusion over blocks); it divides
+    ``block_q`` and ``block_k``, the K/V blocks visited are those of
+    ``causal`` and only the mask of the diagonal block changes.  The
+    backward pass honours it."""
     if softmax_scale is None:
         softmax_scale = float(1.0 / np.sqrt(q.shape[-1]))
     return _flash(q, k, v, bool(causal), float(softmax_scale),
                   int(block_q), int(block_k),
-                  None if window is None else int(window))
+                  None if window is None else int(window),
+                  None if block is None else int(block))
 
 
 # ---------------------------------------------------------------------------
@@ -1109,7 +1149,8 @@ def _slot_write_xla(cache, rows, at):
     """One update-slice a slot, in place in whatever layout the cache
     lives in (:func:`transformer_lm.write_rows` has why); a pass over all
     ``R`` rows would move the whole cache."""
-    pieces = jnp.split(rows[:, :, None], cache.shape[0])
+    pieces = jnp.split(rows if rows.ndim == 4 else rows[:, :, None],
+                       cache.shape[0])
     for i, piece in enumerate(pieces):
         cache = jax.lax.dynamic_update_slice(
             cache, piece, (i, 0, at[i], 0), allow_negative_indices=False)
@@ -1123,13 +1164,15 @@ def _slot_write_tile(dtype):
 
 
 def _slot_write_kernel(at_ref, rows_hbm, cache_hbm, out_hbm, tiles, rows,
-                       fetched, sems):
+                       fetched, sems, *, run=1):
     """One invocation an array.  ``cache_hbm`` and ``out_hbm`` are the
     same buffer and stay in HBM; what moves is, of each slot, the aligned
     ``(n, tile, d)`` tile that holds row ``at[i]`` (a bfloat16 row is half
     a packed sublane, so the tile and not the row is the least that can be
-    written).  A group's fetches are all started before one is waited for;
-    then a slot at a time the fetch is waited for, the row replaced in
+    written).  A slot's ``run`` rows ``at[i] .. at[i] + run - 1`` lie in
+    that one tile (``run`` divides the tile's rows and ``at[i]``), and
+    ``rows`` holds them as ``(S, n * run, d)``, head-major.  A group's
+    fetches are all started before one is waited for; then a slot at a time the fetch is waited for, the row replaced in
     VMEM and the write-back started, so nothing waits in turn but the
     scalar core that issues them.  A fetch has a semaphore of its own
     (``fetched``): copies of one size on one semaphore cannot be told
@@ -1168,11 +1211,14 @@ def _slot_write_kernel(at_ref, rows_hbm, cache_hbm, out_hbm, tiles, rows,
     def through_vmem(g, j):
         i = g * group + j
         fetch(g, j).wait()
-        hit = jax.lax.broadcasted_iota(jnp.int32, (tile, d), 0) \
-            == at_ref[i] % tile
+        sublane = jax.lax.broadcasted_iota(jnp.int32, (tile, d), 0)
+        first = at_ref[i] % tile
+        hits = [sublane == (first + r if r else first) for r in range(run)]
         for h in range(n):
-            row = jnp.broadcast_to(rows[i, pl.ds(h, 1), :], (tile, d))
-            tiles[g % 2, j, h] = jnp.where(hit, row, tiles[g % 2, j, h])
+            for r, hit in enumerate(hits):
+                row = jnp.broadcast_to(rows[i, pl.ds(h * run + r, 1), :],
+                                       (tile, d))
+                tiles[g % 2, j, h] = jnp.where(hit, row, tiles[g % 2, j, h])
         write_back(g, j).start()
 
     all_rows = pltpu.make_async_copy(rows_hbm, rows, rows_in)
@@ -1195,10 +1241,11 @@ def _slot_write_pallas(cache, rows, at, group, interpret=False):
     from jax.experimental.pallas import tpu as pltpu
 
     s, n, _, d = cache.shape
+    run = rows.shape[2] if rows.ndim == 4 else 1
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     buffers = 1 if group >= s else 2
     return pl.pallas_call(
-        _slot_write_kernel,
+        functools.partial(_slot_write_kernel, run=run),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(),
@@ -1207,7 +1254,7 @@ def _slot_write_pallas(cache, rows, at, group, interpret=False):
             scratch_shapes=[
                 pltpu.VMEM((buffers, group, n,
                             _slot_write_tile(cache.dtype), d), cache.dtype),
-                pltpu.VMEM((s, n, d), cache.dtype),
+                pltpu.VMEM((s, n * run, d), cache.dtype),
                 pltpu.SemaphoreType.DMA((buffers, group)),
                 pltpu.SemaphoreType.DMA((2, 2))]),
         out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
@@ -1216,7 +1263,7 @@ def _slot_write_pallas(cache, rows, at, group, interpret=False):
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=16 << 20),
         name="slot_write",
         interpret=interpret,
-    )(at, rows, cache)
+    )(at, rows.reshape(s, n * run, d), cache)
 
 
 def write_slot_rows_plan(cache, rows):
@@ -1229,13 +1276,15 @@ def write_slot_rows_plan(cache, rows):
     width (``lanes``: a narrower cache lives rows-minor on the chip, as
     :func:`decode_attention_plan` has it), ``R`` a multiple of the tile's
     rows (``tile``), and the rows and one tile a buffer inside the
-    kernel's VMEM (``vmem``)."""
+    kernel's VMEM (``vmem``); a run of rows a slot has to divide the
+    tile's rows, so that it lies in one tile (``run``)."""
     from .registry import on_tpu
 
     s, n, r, d = cache.shape
-    if rows.shape != (s, n, d):
-        raise ValueError("rows %s for a cache %s: one (n, d) a slot"
-                         % (rows.shape, cache.shape))
+    run = rows.shape[2] if rows.ndim == 4 else 1
+    if rows.shape not in ((s, n, d), (s, n, run, d)):
+        raise ValueError("rows %s for a cache %s: one (n, d) or one run "
+                         "(n, B, d) a slot" % (rows.shape, cache.shape))
     if not on_tpu():
         return 0, "not_tpu"
     if cache.dtype not in (jnp.bfloat16, jnp.float32):
@@ -1245,10 +1294,12 @@ def write_slot_rows_plan(cache, rows):
     tile = _slot_write_tile(cache.dtype)
     if r % tile:
         return 0, "tile"
+    if tile % run:
+        return 0, "run"
     itemsize = cache.dtype.itemsize
     tile_bytes = n * tile * d * itemsize
     # in VMEM a slot's ``(n, d)`` rows fill whole tiles of ``tile`` sublanes
-    rows_bytes = s * -(-n // tile) * tile * d * itemsize
+    rows_bytes = s * -(-n * run // tile) * tile * d * itemsize
     if rows_bytes > _SLOT_WRITE_ROWS_BYTES \
             or 2 * tile_bytes > _SLOT_WRITE_TILE_BYTES:
         return 0, "vmem"
@@ -1262,6 +1313,10 @@ def write_slot_rows(cache, rows, at):
     dtype, at ``[i, :, at[i]]``; ``at (S,)`` int32 within ``0 .. R - 1``
     (a ring's caller passes ``pos % R``).  Every other element is bit for
     bit what it was, and under donation the cache is written in place.
+    ``rows (S, n, B, d)`` is a run of ``B`` rows a slot, at ``[i, :, at[i]
+    .. at[i] + B - 1]`` with ``at[i]`` a multiple of ``B`` (a pass over a
+    block of a model that generates by blocks): a run that ``B`` divides
+    never crosses a tile.
 
     On a TPU trace one Pallas kernel an array moves each slot's tile of
     rows through VMEM, all slots' tiles in flight at once

@@ -11,7 +11,7 @@ with the NEXT step already dispatched, so the device is not left empty
 while the host waits, fans tokens out and walks its queue.
 
 :class:`DecodeEngine` owns a device-resident KV cache of fixed shape
-``(S slots, max_len)`` per layer and exactly TWO compiled programs:
+``(S slots, max_len)`` per layer and exactly TWO kinds of compiled program:
 
 * **prefill** (one per declared prompt-length bucket): run a
   bucket-padded prompt, scatter its K/V rows into a free slot, sample
@@ -21,6 +21,27 @@ while the host waits, fans tokens out and walks its queue.
   ``<= length`` horizon, sample (greedy or temperature), retire
   EOS/length-done slots — returning the packed ``(token, done,
   active)`` buffer whose single host read is the loop's only sync.
+
+Two programs, and one of two tails in them.  A step need not yield one
+token a slot: for a model that generates by **diffusion over blocks**
+(it declares ``cfg.block = B``; :mod:`~mxnet_tpu.models.sdar`) a step is
+one denoising PASS over ``(S, B)`` rows and delivers to a slot either
+nothing or a whole block.  A slot then carries, on the device, the
+tokens of its current block, which of them are fixed, the pass that
+fixed each and the pass the block is at (``finish_block``/``arm_block``
+beside ``finish_step``/``arm_slot`` in :meth:`DecodeEngine._build`); a
+pass fixes some of the open positions by the rule ``cfg.remasking`` names
+(the quota, the three rules and the threshold are the model object's:
+no engine option), and the pass that finds its block whole is the
+COMMIT: the slot's length moves by ``B``, the block's tokens beyond the
+prompt and below the session's end are delivered in order, and a fresh
+block starts.  The packed read grows to ``(B + 3, S)`` (``B`` tokens or
+-1, the passes that fixed them as one code, done, active), the fan-out
+hands a slot none or up to ``B`` tokens, and an admission's prefill
+yields NO token (the published sampler uses a prompt for K and V
+alone), so a session's first token, ``ttft()`` and
+``serving.decode.ttft_seconds`` are its first block's commit.
+:meth:`DecodeEngine.describe` says which tail an engine runs.
 
 **Sampling keys are position-derived, not sequential.**  Each session
 carries one host-side ``seed``; the token that will occupy absolute
@@ -35,7 +56,12 @@ checkpoint: re-prefilling ``prompt + generated-so-far`` on ANY replica
 resumes the exact stream an uninterrupted run would have produced —
 greedy and temperature — which is what
 :class:`~mxnet_tpu.serving.pool.ReplicaPool` failover relies on
-(docs/serving.md "Session failover & fault domains").
+(docs/serving.md "Session failover & fault domains").  A block model's
+key is of a position AND a pass, ``fold_in(fold_in(PRNGKey(seed), i),
+t)``, and its blocks are absolute (block ``k`` is positions ``kB .. kB +
+B - 1``): a transcript holds committed blocks, the block that was being
+denoised when a replica was lost is redone from its first pass, and the
+resumed stream is the uninterrupted one.
 
 Sequences are admitted into free slots BETWEEN steps (continuous
 batching: a late request joins the running batch instead of waiting for
@@ -89,10 +115,18 @@ is given a *model object* and asks it for four things:
   Entries may differ in every field;
 * ``prefill(params, tokens, length) -> (last_logits, firsts, seconds)``:
   for each entry the two values to put into ONE slot, written from row 0
-  (a K and a V may be shorter than the slot) or whole (a state);
+  (a K and a V may be shorter than the slot) or whole (a state).  A model
+  that declares a block length returns, in the logits' place, the
+  ``(B,)`` tokens of the block generation starts in (the prompt's
+  ``length % B`` last tokens, which start it as fixed positions, and
+  mask tokens), and its K and V cover the bucket, of which the slot's
+  length keeps the whole blocks below ``length // B * B``;
 * ``decode_step(params, firsts, seconds, last_tok, lengths, active,
   extra) -> (logits, firsts, seconds, extra)``: one token for all slots,
-  given and returning every entry's two arrays;
+  given and returning every entry's two arrays.  A model that declares a
+  block length is given ``(slots, B)`` tokens (each slot's current block)
+  and returns ``(slots, B, vocab)`` logits, row ``[i, j]`` of position
+  ``lengths[i] + j``'s own token;
 * ``extra_state()``: optional extra device state the step carries beside
   the cache (routing counters), or None.  It is an argument of its own,
   never donated, so :meth:`DecodeEngine.model_counters` may read the
@@ -100,17 +134,24 @@ is given a *model object* and asks it for four things:
 
 The engine's donated state is ``(firsts, seconds, last_tok, lengths,
 limits, active, temps, seeds)``: every entry's first array, every entry's
-second, six arrays of ``(slots,)``.
+second, six arrays of ``(slots,)``; a block model's has the blocks
+``(slots, B)`` in ``last_tok``'s place and, after ``seeds``, ``fixed``
+and ``fixed_at`` of ``(slots, B)`` and ``passes`` of ``(slots,)``
+(:meth:`DecodeEngine.state_shapes` gives it as shapes to a tool that
+lowers the programs without allocating them).  Its extra state holds
+the counters the tail counts (``commits``, ``tokens_committed``,
+``fixed_by_threshold``, ``fixed_by_quota``).
 
 A bare :class:`~mxnet_tpu.models.transformer_lm.LMConfig` stands for
 :class:`~mxnet_tpu.models.transformer_lm.DecodeModel`, the first
 implementer; :class:`~mxnet_tpu.models.exaone_moe.ExaoneMoE` is the second
-(:mod:`~mxnet_tpu.models.sambay`, :mod:`~mxnet_tpu.models.smallthinker` and
-:mod:`~mxnet_tpu.models.deepseek_v2` the others).
+(:mod:`~mxnet_tpu.models.sambay`, :mod:`~mxnet_tpu.models.smallthinker`,
+:mod:`~mxnet_tpu.models.deepseek_v2` and :mod:`~mxnet_tpu.models.sdar` the
+others).
 The model object is the only choice of a model path.  The paged layout
 asks for the two further methods ``prefill_paged``/``decode_step_paged``
-and a cache of full float32 layers alike; a model without them is refused
-with :class:`UnsupportedKVLayout`.
+and a cache of full float32 layers alike; a model without them, and any
+block model, is refused with :class:`UnsupportedKVLayout`.
 
 The engine is single-device; multi-replica throughput is
 :class:`~mxnet_tpu.serving.pool.ReplicaPool`'s job.  The hot loop is
@@ -204,7 +245,8 @@ class GenerateSession:
     """
 
     __slots__ = ("prompt", "max_new_tokens", "temperature", "deadline",
-                 "on_token", "on_event", "tokens", "future", "seed",
+                 "on_token", "on_event", "tokens", "fixed_at", "future",
+                 "seed",
                  "tenant", "migrations", "migrate_t0", "t_submit",
                  "t_admit", "t_first", "t_done", "slot", "admit_step",
                  "done_step",
@@ -221,6 +263,9 @@ class GenerateSession:
         self.on_token = on_token
         self.on_event = on_event
         self.tokens = []
+        #: of a model that generates by blocks: for each of ``tokens`` the
+        #: pass of its block at which the position was fixed (0 the first)
+        self.fixed_at = []
         self.future = Future()
         self.seed = int(seed) & 0xFFFFFFFF
         self.tenant = tenant
@@ -311,7 +356,8 @@ class GenerateSession:
 
     def ttft(self):
         """Time-to-first-token in seconds (None before the first
-        token)."""
+        token; of a model that generates by blocks, before its first
+        block's commit: its prefill yields no token)."""
         return None if self.t_first is None \
             else self.t_first - self.t_submit
 
@@ -434,9 +480,13 @@ class DecodeEngine:
         self._spec = tuple(self.model.cache_spec())
         #: whether an admission overwrites recurrent state (a "state" entry)
         self._resets_state = any(c.kind == "state" for c in self._spec)
-        if layout == "paged" and not (
+        #: positions a step covers for a slot: 0 for a model whose step
+        #: yields one token a slot (``finish_step``), ``cfg.block`` for one
+        #: that generates by diffusion over blocks (``finish_block``)
+        self._block = int(getattr(cfg, "block", 0) or 0)
+        if layout == "paged" and (self._block or not (
                 hasattr(self.model, "decode_step_paged")
-                and all(c.kind == "full" for c in self._spec)):
+                and all(c.kind == "full" for c in self._spec))):
             raise UnsupportedKVLayout(
                 "model %r (%s) has no paged decode path: the block pool "
                 "holds full layers of one shape; serve it with "
@@ -486,6 +536,7 @@ class DecodeEngine:
         cfg, model = self.cfg, self.model
         s, m = self.slots, cfg.max_len
         eos = np.int32(cfg.eos_id)
+        nblock = self._block
 
         def fold_key(seed, pos):
             # the ONE key derivation (failover invariant): the token
@@ -545,6 +596,128 @@ class DecodeEngine:
             out = jnp.stack([tok, first_done.astype(jnp.int32)])
             return (last_tok, lengths, limits, active, temps, seeds), out
 
+        def finish_block(state_rest, logits, keep, extra):
+            # the tail of a model that generates by diffusion over blocks
+            # (``cfg.block`` positions a slot): ``logits (S, B, vocab)``
+            # are one pass over each slot's block.  A pass that found its
+            # block whole at entry is the COMMIT (the length moves by B,
+            # the block's tokens beyond the prompt and below the limit are
+            # delivered, a fresh block starts); any other fixes some of
+            # the open positions by ``cfg.remasking``.  Block, flags and
+            # pass index stay on the device; the host learns of a commit
+            # from ``packed (B + 3, S)``: B tokens (-1: none), the passes
+            # at which they were fixed (one code a slot), done, active
+            (block, lengths, limits, active, temps, seeds, fixed, fixed_at,
+             passes) = state_rest
+            active = active & keep
+            pos = lengths[:, None] + jnp.arange(nblock)[None, :]
+            # the key of position p at pass t: a pure function of the
+            # transcript (blocks are absolute), so a re-prefill of prompt
+            # and committed blocks resumes the very stream
+            keys = jax.vmap(lambda seed, ps, t: jax.vmap(
+                lambda q: jax.random.fold_in(fold_key(seed, q), t))(ps))(
+                    seeds, pos, passes)
+            # read as rows: ``(S, B, vocab)`` with its 4 in the sublanes'
+            # place is another layout on the chip, and a copy of the logits
+            flat = logits.reshape(s * nblock, -1)
+
+            def first_of(z):
+                top = z.max(axis=-1)
+                return (jnp.argmax(z, axis=-1).astype(jnp.int32),
+                        jnp.exp(top - jax.nn.logsumexp(z, axis=-1)))
+
+            def drawn():
+                # a draw from softmax(logits / temperature) where a slot
+                # has one, and its probability there
+                hot = jnp.repeat(temps, nblock)
+                z = flat / jnp.where(hot > 0.0, jnp.maximum(hot, 1e-6),
+                                     1.0)[:, None]
+                tok = jnp.where(hot > 0.0, jax.vmap(jax.random.categorical)(
+                    keys.reshape(s * nblock, -1), z).astype(jnp.int32),
+                    first_of(z)[0])
+                return tok, jnp.exp(
+                    jnp.take_along_axis(z, tok[:, None], axis=-1)[:, 0]
+                    - jax.nn.logsumexp(z, axis=-1))
+
+            # greedy slots alone (the usual case) read the logits as they
+            # are: the best token and its probability under the softmax
+            x0, conf = (a.reshape(s, nblock) for a in jax.lax.cond(
+                (temps > 0.0).any(), drawn, lambda: first_of(flat)))
+            with jax.named_scope("diffusion.fix"):
+                opened = ~fixed & active[:, None]
+                # pass t's quota: B // T, one more in the first B % T
+                want = (nblock // cfg.denoise_steps + (
+                    passes < nblock % cfg.denoise_steps))[:, None]
+                # an open position's rank by confidence, ties to the first
+                rank = jnp.argsort(jnp.argsort(
+                    -jnp.where(opened, conf, -1.0), axis=-1, stable=True),
+                    axis=-1)
+                best = opened & (rank < want)
+                by_threshold = jnp.zeros_like(opened)
+                if cfg.remasking == "sequential":
+                    take = opened & (jnp.cumsum(opened, axis=-1) <= want)
+                elif cfg.remasking == "low_confidence_static":
+                    take = best
+                else:
+                    high = opened & (conf > cfg.threshold)
+                    by_threshold = high & (high.sum(-1, keepdims=True)
+                                           >= want)
+                    take = jnp.where(by_threshold.any(-1, keepdims=True),
+                                     high, best)
+            commit = active & fixed.all(-1)
+            mine = (fixed_at >= 0) & (pos < limits[:, None])
+            is_eos = mine & (block == eos)
+            deliver = commit[:, None] & mine \
+                & (jnp.cumsum(is_eos, axis=-1) - is_eos == 0)
+            new_len = lengths + commit * nblock
+            done = commit & ((deliver & is_eos).any(-1)
+                             | (new_len >= limits))
+            new_active = active & ~done
+            fresh = commit[:, None]
+            base = cfg.denoise_steps + 1
+            packed = jnp.concatenate([
+                jnp.where(deliver, block, -1).T,
+                ((fixed_at + 1) * base ** jnp.arange(nblock)).sum(-1)[None],
+                done.astype(jnp.int32)[None],
+                new_active.astype(jnp.int32)[None]])
+            if extra is not None:
+                counted = {
+                    "commits": commit.sum(),
+                    "tokens_committed": deliver.sum(),
+                    "fixed_by_threshold": (take & by_threshold).sum(),
+                    "fixed_by_quota": (take & ~by_threshold).sum()}
+                extra = dict(extra, **{
+                    k: extra[k] + v.astype(extra[k].dtype)
+                    for k, v in counted.items() if k in extra})
+            return (jnp.where(fresh, np.int32(cfg.mask_id),
+                              jnp.where(take, x0, block)),
+                    new_len, limits, new_active, temps, seeds,
+                    (fixed | take) & ~fresh,
+                    jnp.where(fresh, -1, jnp.where(
+                        take, passes[:, None], fixed_at)),
+                    jnp.where(commit, 0, passes + active)), packed, extra
+
+        def arm_block(state_rest, slot, first, length, limit, temp, seed,
+                      activate):
+            # the slot-arming tail of a block model's prefill, which
+            # yields NO token: ``first (B,)`` is the block that holds
+            # position ``length // B * B``, of which the prompt's
+            # ``length % B`` last tokens are fixed from the start (pass
+            # -1); ``limit`` is the end of the session, prompt included
+            (block, lengths, limits, active, temps, seeds, fixed, fixed_at,
+             passes) = state_rest
+            nothing = limit <= length
+            out = jnp.stack([jnp.int32(-1), nothing.astype(jnp.int32)])
+            return (block.at[slot].set(first),
+                    lengths.at[slot].set(length // nblock * nblock),
+                    limits.at[slot].set(limit),
+                    active.at[slot].set(activate & ~nothing),
+                    temps.at[slot].set(temp), seeds.at[slot].set(seed),
+                    fixed.at[slot].set(jnp.arange(nblock)
+                                       < length % nblock),
+                    fixed_at.at[slot].set(-1),
+                    passes.at[slot].set(0)), out
+
         if self._kv is not None:
             nb, bs = self._kv.num_blocks, self._kv.block_size
 
@@ -598,7 +771,11 @@ class DecodeEngine:
                 logits, cache_k, cache_v, extra = model.decode_step(
                     params, cache_k, cache_v, state[2], state[3], state[5],
                     extra)
-                rest, packed = finish_step(state[2:], logits, keep)
+                if nblock:
+                    rest, packed, extra = finish_block(state[2:], logits,
+                                                       keep, extra)
+                else:
+                    rest, packed = finish_step(state[2:], logits, keep)
                 return (cache_k, cache_v) + rest, packed, extra
 
             if extra0 is None:
@@ -625,8 +802,9 @@ class DecodeEngine:
 
                 cache = (into_slot(state[0], firsts),
                          into_slot(state[1], seconds))
-                rest, out = arm_slot(state[2:], slot, last_logits,
-                                     length, limit, temp, seed, activate)
+                rest, out = (arm_block if nblock else arm_slot)(
+                    state[2:], slot, last_logits, length, limit, temp,
+                    seed, activate)
                 return cache + rest, out
 
             self._step_fn = self._instrument(
@@ -696,24 +874,46 @@ class DecodeEngine:
         else:
             lead = None
         self._slot_len = [0] * s
+        state = self.state_shapes(jnp.zeros, lead)
+        if self._block:
+            # no position of a fresh block was fixed at any pass
+            state = state[:-2] + (state[-2] - 1,) + state[-1:]
+        return jax.device_put(state, self._device)
 
-        def zeros(i):
+    def state_shapes(self, make, lead=None):
+        """The donated slot state as ``make(shape, dtype)`` makes its
+        arrays (``jnp.zeros`` for the state itself, a
+        ``jax.ShapeDtypeStruct`` for a tool that lowers the programs and
+        allocates nothing): every entry's first array, every entry's
+        second, then ``last_tok``, ``lengths``, ``limits``, ``active``,
+        ``temps`` and the per-slot ``seeds``, each ``(slots,)``.  A block
+        model's has the tokens of each slot's current block ``(slots, B)``
+        in ``last_tok``'s place and after ``seeds`` which of them are
+        fixed, the pass at which each was, both ``(slots, B)``, and the
+        pass the block is at.  ``lead`` is the paged layout's ``(blocks,
+        block size)`` in the slots' place."""
+        import jax.numpy as jnp
+
+        s = self.slots
+
+        def entries(i):
             made = []
             for c in self._spec:
                 shape, dtype = _tlm.slot_arrays(c)[i]
-                made.append(jnp.zeros(
+                made.append(make(
                     lead + (c.kv_heads, c.head_dim) if lead
                     else (s,) + shape, dtype))
             return tuple(made)
 
-        state = (zeros(0), zeros(1),
-                 jnp.zeros((s,), jnp.int32),        # last_tok
-                 jnp.zeros((s,), jnp.int32),        # lengths
-                 jnp.zeros((s,), jnp.int32),        # limits
-                 jnp.zeros((s,), bool),             # active
-                 jnp.zeros((s,), jnp.float32),      # temps
-                 jnp.zeros((s,), jnp.uint32))       # per-slot seeds
-        return jax.device_put(state, self._device)
+        held = (s, self._block) if self._block else (s,)
+        state = (entries(0), entries(1), make(held, jnp.int32),
+                 make((s,), jnp.int32), make((s,), jnp.int32),
+                 make((s,), jnp.bool_), make((s,), jnp.float32),
+                 make((s,), jnp.uint32))
+        if self._block:
+            state += (make(held, jnp.bool_), make(held, jnp.int32),
+                      make((s,), jnp.int32))
+        return state
 
     def _warm(self, state):
         """Compile the decode step and every prefill bucket against the
@@ -977,6 +1177,10 @@ class DecodeEngine:
                   "hbm_bytes": sum(self._cache_bytes().values())}
         model = self.model_counters()
         return {"name": self.name, "kind": "generate",
+                # which tail the step runs: one token a slot, or a pass
+                # over blocks that delivers none or up to ``block`` tokens
+                "tail": "block" if self._block else "token",
+                **({"block": self._block} if self._block else {}),
                 **({"model_counters": model} if model else {}),
                 "version": getattr(self, "version", None),
                 "replica": self.replica, "device": str(self._device),
@@ -1238,7 +1442,7 @@ class DecodeEngine:
         turn ago, then the admissions' first tokens.  A dispatch or a
         read that raises ends the turn in :meth:`_fail_all`.  Returns the
         state the next turn donates."""
-        active, ahead = 0, 0
+        active, ahead, committed = 0, 0, 0
         try:
             firsts = []
             for sess in admits:
@@ -1255,12 +1459,13 @@ class DecodeEngine:
                     if active:
                         state = self._dispatch(state)
                     if landing:
-                        ahead = self._land_step(ssp)
+                        ahead, committed = self._land_step(ssp)
             for first in firsts:
                 self._land_first(*first)
         except _Poisoned as p:
             state = self._fail_all(p.cause)
-        isp.end("ok", admits=len(admits), active=active, ahead=ahead)
+        isp.end("ok", admits=len(admits), active=active, ahead=ahead,
+                **({"committed": committed} if self._block else {}))
         return state
 
     def _admit(self, sess, state):
@@ -1296,8 +1501,11 @@ class DecodeEngine:
             else:
                 full = sess.prompt
             n = int(full.size)
-            limit = np.int32(min(p0 + sess.max_new_tokens - 1,
-                                 cfg.max_len))
+            # the last position a step may sample for; of a block model
+            # the end of the session itself, prompt included (its prefill
+            # yields no token)
+            limit = np.int32(min(p0 + sess.max_new_tokens
+                                 - (0 if self._block else 1), cfg.max_len))
             if self._kv is not None:
                 try:
                     plan = self._kv.admit(sess.slot, full)
@@ -1379,21 +1587,18 @@ class DecodeEngine:
             raise _Poisoned(e) from e
         now = time.monotonic()
         tok = int(out[0])
-        sess.tokens.append(tok)
-        self._emit(sess, tok)
-        if sess.t_first is None:
-            # TTFT is first token EVER — a migrated session already
-            # paid (and recorded) its first-token latency
-            sess.t_first = now
-            _telemetry.observe("serving.decode.ttft_seconds",
-                               sess.t_first - sess.t_submit,
-                               buckets=TTFT_BUCKETS, model=self.name)
-        _telemetry.inc("serving.decode.tokens.count", model=self.name,
-                       replica=self.replica)
+        if tok >= 0:
+            sess.tokens.append(tok)
+            self._emit(sess, tok)
+            self._first_token(sess, now)
+            _telemetry.inc("serving.decode.tokens.count", model=self.name,
+                           replica=self.replica)
+        # else a block model's prefill, which yields no token: the first
+        # comes with the first block's commit (_land_step)
         with self._cond:
             sess.admit_step = self.steps
-            self.tokens_out += 1
-            self._rate_tokens += 1
+            self.tokens_out += tok >= 0
+            self._rate_tokens += tok >= 0
             if resumed:
                 self.resumed += 1
                 self.reprefilled_tokens += n
@@ -1411,6 +1616,15 @@ class DecodeEngine:
         if out[1]:  # EOS or max_new_tokens == 1: done at prefill
             self._retire(sess)
         self._occupancy_gauge()
+
+    def _first_token(self, sess, now):
+        """TTFT is first token EVER — a migrated session already paid
+        (and recorded) its first-token latency."""
+        if sess.t_first is None:
+            sess.t_first = now
+            _telemetry.observe("serving.decode.ttft_seconds",
+                               sess.t_first - sess.t_submit,
+                               buckets=TTFT_BUCKETS, model=self.name)
 
     def _dispatch(self, state):
         """ONE fixed-shape decode dispatch for all slots, queued behind
@@ -1511,7 +1725,8 @@ class DecodeEngine:
         self._read_t = t_read
         with self._cond:
             holders = list(self._slot_sessions)
-        emitted = 0
+        emitted, rode, committed = 0, 0, 0
+        nblock = self._block
         with _tracing.start_span("serving.decode.fanout") as fsp:
             for i, sess in enumerate(step.sessions):
                 # a session that an earlier fan-out retired (it finished
@@ -1531,6 +1746,29 @@ class DecodeEngine:
                     sess.trace.end("shed", reason=reason, where="active")
                     self._retire(sess, error=err)
                     continue
+                if nblock:
+                    # a pass: nothing, or a committed block's tokens in
+                    # order (at most ``nblock``; fewer at a session's two
+                    # ends), each with the pass that fixed it
+                    rode += 1
+                    code = int(packed[nblock, i])
+                    base = self.cfg.denoise_steps + 1
+                    gave = 0
+                    for j in range(nblock):
+                        tok = int(packed[j, i])
+                        if tok >= 0:
+                            gave += 1
+                            sess.tokens.append(tok)
+                            sess.fixed_at.append(
+                                code // base ** j % base - 1)
+                            self._emit(sess, tok)
+                    if gave:
+                        emitted += gave
+                        committed += 1
+                        self._first_token(sess, time.monotonic())
+                    if packed[nblock + 1, i]:
+                        self._retire(sess)
+                    continue
                 tok = int(packed[0, i])
                 if tok >= 0:
                     emitted += 1
@@ -1542,7 +1780,8 @@ class DecodeEngine:
                     self._slot_len[i] += 1
                 if packed[1, i]:
                     self._retire(sess)
-            fsp.annotate(emitted=emitted)
+            fsp.annotate(emitted=emitted,
+                         **({"committed": committed} if nblock else {}))
         ssp.annotate(live=emitted)
         with self._cond:
             self.steps += 1
@@ -1555,6 +1794,11 @@ class DecodeEngine:
                        replica=self.replica)
         if emitted:
             _telemetry.inc("serving.decode.tokens.count", emitted,
+                           model=self.name, replica=self.replica)
+        if nblock:
+            _telemetry.inc("serving.decode.passes.count", rode,
+                           model=self.name, replica=self.replica)
+            _telemetry.inc("serving.decode.commits.count", committed,
                            model=self.name, replica=self.replica)
         _telemetry.observe("serving.decode.token_latency_seconds", dt,
                            buckets=LATENCY_BUCKETS, model=self.name)
@@ -1571,7 +1815,7 @@ class DecodeEngine:
         self._occupancy_gauge()
         if self._on_step_ok is not None:
             self._on_step_ok()
-        return ahead
+        return ahead, committed
 
     def _fail_all(self, exc):
         """A failed dispatch or read poisons the donated chain, the

@@ -776,6 +776,121 @@ CASES["decode-prefill-4096-deepseek-v2-under-1.6-GB-of-temporaries"] = \
     _deepseek_v2_case(4096)
 
 
+def _sdar_case(which):
+    """The engine's programs over ``models/sdar.py`` at
+    ``benchmark/configs/sdar-30b-a3b-chat.json``'s sizes (6 layers of 128
+    experts, 151,936 rows of vocabulary, the configuration's slots x 4096,
+    bfloat16): each fits the chip beside what it is handed (under 15.5 GB
+    in all).  The step, a pass over ``(slots, 4)`` rows, reads every layer
+    through ``decode_attention`` at ``group`` 32 and writes twelve arrays'
+    runs of four rows with ``slot_write``; no ``(slots, 4, 32, 4096)``
+    scores, no update-slice, no copy of a cache-sized array.  A prefill
+    calls ``flash_attention`` under the blocked mask for every layer but
+    the last, whose output nothing reads, holds no ``(heads, P, P)``
+    scores, and its largest bucket takes the grouped product."""
+    def run():
+        from benchmark import harness
+        from benchmark.tools import aot_compile_sdar as tool
+
+        config = harness.load_json(os.path.join(
+            ROOT, "benchmark", "configs", "sdar-30b-a3b-chat.json"))
+        engine, params, state, keep, extra, sds = tool.engine_programs(
+            config, _one_chip())
+        s = config["engine"]["slots"]
+        assert {a.shape for a in state[0]} == {(s, 4, 4096, 128)}
+        assert state[2].shape == (s, 4) and len(state) == 11
+        with _tpu_trace():
+            if which == "step":
+                compiled = engine._step_fn.lower(params, state, keep,
+                                                 extra).compile()
+            else:
+                compiled = engine._prefill_fns[which].lower(
+                    *tool.prefill_shapes(params, state, which,
+                                         sds)).compile()
+        ma = compiled.memory_analysis()
+        assert ma.argument_size_in_bytes + ma.output_size_in_bytes \
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes < 15.5e9
+        text = compiled.as_text()
+        if which == "step":
+            assert text.count("tpu_custom_call") == 6 + 2 * 6
+            assert "dynamic-update-slice" not in text
+            assert "f32[%d,4,32,4096]" % s not in text
+            # the logits of 4 rows a slot and what the tail makes of them
+            assert ma.temp_size_in_bytes < 0.6e9, ma.temp_size_in_bytes
+        else:
+            assert text.count("flash_attention") >= 5
+            assert ("ragged" in text) == (which >= 1024)
+            assert ma.temp_size_in_bytes < 0.5e9, ma.temp_size_in_bytes
+            # (at 128 the queries themselves are ``[32, 128, 128]``)
+            assert which == 128 or "32,%d,%d]" % (which, which) not in text
+        copies = tool.cache_copies(text, state)
+        assert not copies, "%d copies of a cache array, the first: %s" \
+            % (len(copies), copies[0][:200])
+    return run
+
+
+CASES["decode-step-sdar-a-pass-no-cache-copy"] = _sdar_case("step")
+for _bucket in (128, 512, 1024):
+    CASES["decode-prefill-%d-sdar-blocked-mask" % _bucket] = \
+        _sdar_case(_bucket)
+
+
+def _block_kernels_case():
+    """The three kernels at the shapes a block model gives them, each
+    alone: ``write_slot_rows`` with a run of four rows a slot over a donated
+    cache (the compiler takes the kernel, nothing cache-sized is copied),
+    ``decode_attention`` at ``group`` 32, and ``flash_attention`` under the
+    mask that is causal by blocks of four (a block length that is no power
+    of two is refused by name and takes the plain path)."""
+    def run():
+        import re
+
+        shape = (96, 4, 4096, 128)
+        one_chip = _one_chip()
+
+        def sds(sh, dt):
+            return jax.ShapeDtypeStruct(sh, dt, sharding=one_chip)
+
+        cache = sds(shape, jnp.bfloat16)
+        rows = sds((96, 4, 4, 128), jnp.bfloat16)
+        at = sds((96,), jnp.int32)
+        with _tpu_trace():
+            assert attention.write_slot_rows_plan(cache, rows) == (96, None)
+            assert attention.write_slot_rows_plan(
+                sds(shape, jnp.float32),
+                sds((96, 4, 16, 128), jnp.float32)) == (0, "run")
+            written = jax.jit(attention.write_slot_rows,
+                              donate_argnums=(0,)).lower(
+                                  cache, rows, at).compile()
+            q = sds((96, 4, 32, 128), jnp.bfloat16)
+            assert attention.decode_attention_plan(q, cache) == (128, None)
+            attended = jax.jit(
+                lambda q, k, v, n: attention.decode_attention(
+                    q, k, v, n, 0.088)).lower(q, cache, cache, at).compile()
+            heads = sds((1, 32, 1024, 128), jnp.bfloat16)
+            kv = sds((1, 4, 1024, 128), jnp.bfloat16)
+            assert attention._kernel_refusal(heads, kv, 512, 512, 4) is None
+            assert attention._kernel_refusal(heads, kv, 512, 512, 6) \
+                == "block"
+            flashed = jax.jit(lambda q, k, v: attention.flash_attention(
+                q, k, v, causal=True, block_q=512, block_k=512,
+                block=4)).lower(heads, kv, kv).compile()
+        text = written.as_text()
+        assert text.count("tpu_custom_call") == 1 and "slot_write" in text
+        assert "dynamic-update-slice" not in text
+        assert not re.findall(
+            r"= bf16\[%d,%d,%d,%d\]\{[^}]*\} copy\(" % shape, text)
+        assert written.memory_analysis().temp_size_in_bytes < 1e6
+        assert "decode_attention" in attended.as_text()
+        assert "f32[96,4,32,4096]" not in attended.as_text()
+        assert "flash_attention" in flashed.as_text()
+    return run
+
+
+CASES["block-kernels-run-of-4-group-of-32-blocked-mask"] = \
+    _block_kernels_case()
+
+
 def _decode_attention_lanes():
     """Heads of 64 cached on their own, ``(128, 20, 4096, 64)``: the plan
     says ``lanes`` and the call takes the plain path.  The compiler keeps
@@ -920,7 +1035,7 @@ CASES["decode-prefill-1024-phi-4-mini-flash-128-slots"] = _sambay_case(1024)
 #: under ``--dist loadfile`` only a file can go to another worker); the
 #: kernels' own cases are this file's
 FAMILIES = ("gpt2-large", "k-exaone", "phi-4-mini-flash", "smallthinker",
-            "deepseek-v2")
+            "deepseek-v2", "sdar")
 
 
 def cases_of(family=None):

@@ -9,9 +9,13 @@ they are for the chip (``ops.registry.trace_device``) and lowered for it, so
 the Pallas kernels are part of the text: each kernel's body is read back
 and printed without the paths and lines of its call stack, so two trees, or
 one before and after a move of code, compare by what the kernels do;
-without, as the CPU runs them.
+without, as the CPU runs them.  With ``--tiny`` the configurations are
+those of ``tests/benchmark/tiny/`` (a few slots, buckets of tens: seconds
+where the benchmark's own take minutes), which is how a PR that adds a
+tail to the engine shows that the small engines of the models it does not
+touch lower to the parent's text: run it in both trees and ``diff``.
 
-    JAX_PLATFORMS=cpu python tools/perf/program_fingerprints.py [--tpu] [config ...]
+    JAX_PLATFORMS=cpu python tools/perf/program_fingerprints.py [--tpu] [--tiny] [config ...]
 """
 
 import base64
@@ -120,17 +124,17 @@ def main():
     from mxnet_tpu import perfdebug
     from mxnet_tpu.ops import registry
 
-    names = [a for a in sys.argv[1:] if a != "--tpu"]
+    names = [a for a in sys.argv[1:] if a not in ("--tpu", "--tiny")]
+    configs = os.path.join(ROOT, "tests", "benchmark", "tiny") \
+        if "--tiny" in sys.argv else os.path.join(ROOT, "benchmark",
+                                                  "configs")
     platform = "cpu"
     if "--tpu" in sys.argv:
         platform = "tpu"
         registry.trace_device.set(platform)
-    names = names or sorted(
-        f[:-5] for f in os.listdir(os.path.join(ROOT, "benchmark",
-                                                "configs")))
+    names = names or sorted(f[:-5] for f in os.listdir(configs))
     for name in names:
-        config = harness.load_json(os.path.join(
-            ROOT, "benchmark", "configs", name + ".json"))
+        config = harness.load_json(os.path.join(configs, name + ".json"))
         if "engine" not in config:
             continue
         for what, fn, shapes in programs(config):
