@@ -235,9 +235,13 @@ def _combine(cfg, chosen, w):
     return (hit * w[..., None]).sum(1)
 
 
-def expert_product(cfg, rows):
+def expert_product(cfg, rows, live=None):
     """"every" or "grouped": which product :func:`routed_experts` runs over
-    ``rows`` rows, from the shapes alone (``rows``, ``experts_held``,
+    ``rows`` rows, of which ``live`` are expected to take part (all of
+    them where none is given: every expert multiplies every row it is
+    handed, the grouped product's tiles the picks of the live ones, and its
+    layout has a place for every pick handed), from the shapes alone
+    (``rows``, ``experts_held``,
     ``num_experts``, ``top_k``, ``embed``, ``expert_ffn``): whichever
     :func:`~mxnet_tpu.ops.grouped_product.product_seconds` reckons cheaper
     on the chip (the readings it was fitted to, one layer alone at the four
@@ -251,7 +255,8 @@ def expert_product(cfg, rows):
     shape, 340 at K-EXAONE's."""
     every, grouped = product_seconds(
         cfg.embed, cfg.expert_ffn, cfg.experts_held, rows,
-        rows * cfg.top_k, rows * cfg.top_k / cfg.num_experts)
+        rows * cfg.top_k,
+        (rows if live is None else live) * cfg.top_k / cfg.num_experts)
     return "grouped" if grouped < every else "every"
 
 
@@ -270,18 +275,21 @@ def _every_expert(act, h, comb, moe):
                       preferred_element_type=jnp.float32)
 
 
-def _grouped_experts(cfg, act, h, chosen, w, moe):
+def _grouped_experts(cfg, act, h, chosen, w, moe, live=None):
     """The same sum over the picks grouped by expert: a pick of an expert
     held elsewhere adds exactly nothing; no group has a capacity.  By the
     kernel of :mod:`~mxnet_tpu.ops.grouped_product` where its plan takes
-    the shapes (counted under ``ops.kernel_path``), else
+    the shapes (counted under ``ops.kernel_path``; its row tile follows the
+    picks of the ``live`` rows expected to take part, all of them where
+    none is given), else
     :func:`_sorted_experts`.  The layout, the gather into it and the sum
     out of it are this product's and are timed with it."""
     from ..ops.registry import count_kernel_path
 
     held = cfg.experts_held
-    tiles, reason = grouped_product_plan(moe["gate"],
-                                         chosen.size / cfg.num_experts)
+    tiles, reason = grouped_product_plan(
+        moe["gate"], (chosen.size if live is None else live * cfg.top_k)
+        / cfg.num_experts)
     if reason is not None:
         count_kernel_path("grouped_product", "xla", reason)
         return _sorted_experts(cfg, act, h, chosen, w, moe)
@@ -290,8 +298,10 @@ def _grouped_experts(cfg, act, h, chosen, w, moe):
     key = jnp.where((local >= 0) & (local < held), local, held)
     xs, ws, owned, dest = rows_into_groups(
         h.astype(moe["gate"].dtype), w, key, held, tiles[0])
+    # a call that says which rows are live also has the kernel claim the
+    # fast memory its tiles need and no more (grouped_product has why)
     y = grouped_product(xs, ws, owned, moe["gate"], moe["up"], moe["down"],
-                        act, *tiles)
+                        act, *tiles, snug=live is not None)
     return sum_out_of_groups(y, dest, key, held)
 
 
@@ -324,32 +334,43 @@ def _sorted_experts(cfg, act, h, chosen, w, moe):
     return y[back].reshape(t, k, -1).sum(1)
 
 
-def routed_experts(cfg, h, chosen, w, moe):
+def routed_experts(cfg, h, chosen, w, moe, live=None):
     """The held experts' part of ``h (T, embed)``: ``sum over the chosen
     and held x of w[t, x] * E_x(h[t])``, by the product
     :func:`expert_product` chooses for ``T`` rows (counted under
-    ``ops.kernel_path``)."""
+    ``ops.kernel_path``).  ``live = (mask (T,) bool, rows)``: the rows that
+    take part, and how many of them the caller expects (a whole number, for
+    the choice of the product and of its row tile); a row outside the mask
+    takes no pick, of any expert, and comes back zero."""
     from ..ops.registry import count_kernel_path
 
     act = _ACTIVATIONS[cfg.activation]
-    path = expert_product(cfg, h.shape[0])
+    expected = ()
+    if live is not None:
+        mask, rows = live
+        # a pick of no expert anywhere: no place in a layout, weight 0
+        chosen = jnp.where(mask[:, None], chosen, -1)
+        expected = (rows,)
+    path = expert_product(cfg, h.shape[0], *expected)
     count_kernel_path("routed_experts", path, "rows")
     if path == "grouped":
         with jax.named_scope("moe.group"):
-            return _grouped_experts(cfg, act, h, chosen, w, moe)
+            return _grouped_experts(cfg, act, h, chosen, w, moe, *expected)
     return _every_expert(act, h, _combine(cfg, chosen, w), moe)
 
 
-def sparse_mlp(cfg, h, moe, shared=True, router_input=None):
+def sparse_mlp(cfg, h, moe, shared=True, router_input=None, live=None):
     """The sparse MLP of this share: ``(y (T, embed), chosen (T, top_k))``.
     The router reads ``router_input`` (``h`` where none is given).
     ``shared=False`` leaves the shared expert out (a share other than the
-    one that counts it, when shares are added up; a model that has none)."""
+    one that counts it, when shares are added up; a model that has none).
+    ``live``: the rows that take part in the routed experts and how many
+    are expected (:func:`routed_experts`); ``chosen`` is every row's."""
     with jax.named_scope("moe.route"):
         chosen, w = route(cfg, h if router_input is None else router_input,
                           moe)
     with jax.named_scope("moe.experts"):
-        y = routed_experts(cfg, h, chosen, w, moe)
+        y = routed_experts(cfg, h, chosen, w, moe, live)
     if shared:
         with jax.named_scope("moe.shared"):
             y = y + _swiglu(h, moe["shared"])

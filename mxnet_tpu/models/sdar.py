@@ -35,11 +35,19 @@ some: with ``denoise_steps = T`` the quota of pass ``t`` is ``B // T``, one
 more in the first ``B % T`` passes (:func:`quota`); ``low_confidence_static``
 fixes the quota's best ``c``; ``low_confidence_dynamic`` every position with
 ``c > threshold`` if those are at least the quota, else as static;
-``sequential`` the first of the unfixed.  A pass that finds every position
-fixed is the **commit**: the K and V of the final tokens are what the cache
-keeps, the length grows by ``B``, and the block's tokens beyond the prompt
-and below ``n + max_new`` are the session's next tokens.  The rules run in
-the engine's tail (:mod:`mxnet_tpu.serving.decode`), from this ``cfg``.
+``sequential`` the first of the unfixed.  A pass that leaves every position
+fixed **delivers** the block: its tokens beyond the prompt and below ``n +
+max_new`` are the session's next tokens, the length grows by ``B`` and the
+next block opens.  What the cache has to keep of the block are the K and V
+of its FINAL tokens, which no pass has computed yet (a pass writes the K
+and V of the block as it stood at entry): the published loop spends a pass
+of its own on them, the **commit**, which reads every weight and fixes
+nothing.  Here the finished block stays the slot's *pending* block and its
+``B`` rows ride the slot's NEXT pass, the one that opens the next block,
+for their K and V alone (:meth:`SDAR.decode_step`): a block costs its
+denoising passes and no other, and a session that ends with its block
+writes no commit at all.  The rules run in the engine's tail
+(:mod:`mxnet_tpu.serving.decode`), from this ``cfg``.
 
 Departures from ``generate.py``, each for a stated reason: which positions
 are fixed is kept as flags and not read off ``token == mask_id``, so a
@@ -56,15 +64,18 @@ the list; ``eps`` is this configuration's).
 
 **The cache** (:meth:`SDAR.cache_spec`): every layer a full layer
 ``(slots, kv_heads, max_len, head_dim)``, K stored normed and rotated.
-**Every pass writes** the block's K and V at rows ``length .. length + B -
-1`` (:func:`ops.attention.write_slot_rows`, a run of ``B`` rows; rows above
-a slot's length are hidden, so a pass that fixes nothing leaves nothing
-behind) and attends with :func:`ops.attention.decode_attention`: every row
-of a block sees the same rows (all earlier ones and its own block), so a
-pass is that kernel at ``group = (heads // kv_heads) x B`` and horizon
-``length + B - 1``, and the cache is read once for ``B`` positions.  A
-prompt's attention goes through :func:`ops.attention.flash_attention` with
-``block=B``.
+**Every pass writes** the open block's K and V at rows ``length .. length +
+B - 1`` and, before them, the pending block's at ``length - B .. length -
+1`` (:func:`ops.attention.write_slot_rows`, two runs of ``B`` rows; rows
+above a slot's length are hidden, so a pass that fixes nothing leaves
+nothing behind) and attends with :func:`ops.attention.decode_attention`:
+every row of a block sees the same rows (all earlier ones and its own
+block), so a pass is that kernel at ``group = (heads // kv_heads) x 2B``
+with a horizon a half, ``length - 1`` for the pending block's queries and
+``length + B - 1`` for the open one's, and the cache is read once for
+``2B`` positions.  The last layer leaves the pending rows at their K and V
+and the head runs over the open block's rows alone.  A prompt's attention
+goes through :func:`ops.attention.flash_attention` with ``block=B``.
 
 :func:`forward_logits` is the in-repo plain reference: float32, ``highest``
 precision, no cache, one sequence, under ``M``, each expert in a plain
@@ -108,6 +119,12 @@ SDARConfig = namedtuple("SDARConfig", [
 
 #: the prompt's attention: Q rows and K/V rows of a block
 _BLOCK_Q, _BLOCK_K = 512, 512
+
+#: the extra state's scalar counters (:meth:`SDAR.extra_state` has what each
+#: counts and who counts it)
+_COUNTERS = ("moe_picks_total", "rows", "commit_rows", "steps", "passes",
+             "rows_read", "commits", "tokens_committed",
+             "fixed_by_threshold", "fixed_by_quota")
 
 
 def quota(cfg, t):
@@ -195,8 +212,10 @@ def generate_plain(cfg, params, prompt, max_new, temperature=0.0, seed=0,
     """The published loop written out plainly, one sequence, no cache:
     ``(tokens, fixed_at, passes)`` — the ``max_new`` tokens after
     ``prompt`` (fewer where ``eos_id`` or ``max_len`` ends them), of each
-    the pass of its block at which it was fixed, and the passes run in all
-    (commits among them).  Every pass is one :func:`forward_logits` over
+    the pass of its block at which it was fixed, and the denoising passes
+    run in all (the published loop's commit, a pass that fixes nothing, is
+    not counted: the engine runs none).  Every pass is one
+    :func:`forward_logits` over
     the transcript so far and the block as it stands, of which the block's
     rows are read; ``forward(tokens) -> (T, vocab)`` stands in for it where
     given.  Keys are the engine's: ``fold_in(fold_in(PRNGKey(seed),
@@ -247,7 +266,6 @@ def generate_plain(cfg, params, prompt, max_new, temperature=0.0, seed=0,
                 block[i], fixed_at[i] = x0[i], t
             t += 1
             passes += 1
-        passes += 1                                      # the commit
         ended = False
         for i in range(b):
             if fixed_at[i] >= 0 and start + i < end and not ended:
@@ -261,25 +279,33 @@ def generate_plain(cfg, params, prompt, max_new, temperature=0.0, seed=0,
 
 
 # -- the block, shared by prefill and decode step ------------------------------
-def _block(cfg, l, p, x, pos, attend, counts=None):
+def _block(cfg, l, p, x, pos, attend, counts=None, live=None, lead=0):
     """One layer over rows ``x (T, embed)`` float32 at absolute positions
     ``pos (T,)``.  ``attend(l, q, k, v)`` is the caller's cache access: it
     is handed ``q (T, heads, d)``, ``k``/``v (T, kv_heads, d)`` (normed,
     rotated, in the weights' dtype) and returns the context ``(T, heads,
-    d)``.  ``counts(l, chosen)`` is told the layer's choices."""
+    d)``.  ``counts(l, chosen)`` is told the layer's choices.  ``live``:
+    the rows that take part in the experts and how many are expected
+    (:func:`exaone_moe.routed_experts`).  ``lead``: the first rows of ``x``
+    that are in this layer for their K and V alone (the pending blocks' in
+    a step's LAST layer, whose output nothing reads): ``attend`` is handed
+    their ``k`` and ``v`` with the others' and the queries of the rows
+    after them, and those ``T - lead`` rows are what comes back."""
     t = x.shape[0]
     dt = p["wq"].dtype
     with jax.named_scope("attn.block"):
         h = _rms(x, p["ln1"], cfg.eps)
-        q = _mm(h, p["wq"]).reshape(t, cfg.heads, cfg.head_dim)
         k = _mm(h, p["wk"]).reshape(t, cfg.kv_heads, cfg.head_dim)
         v = _mm(h, p["wv"]).reshape(t, cfg.kv_heads, cfg.head_dim)
-        q = _rope(cfg, _rms(q, p["q_norm"], cfg.eps), pos)
         k = _rope(cfg, _rms(k, p["k_norm"], cfg.eps), pos)
+        if lead:
+            x, h, pos, t = x[lead:], h[lead:], pos[lead:], t - lead
+        q = _mm(h, p["wq"]).reshape(t, cfg.heads, cfg.head_dim)
+        q = _rope(cfg, _rms(q, p["q_norm"], cfg.eps), pos)
         ctx = attend(l, q.astype(dt), k.astype(dt), v.astype(dt))
         x = x + _mm(ctx.reshape(t, -1), p["wo"])
     y, chosen = sparse_mlp(cfg, _rms(x, p["ln2"], cfg.eps), p["moe"],
-                           shared=False)
+                           shared=False, live=live)
     if counts is not None:
         counts(l, chosen)
     return x + y
@@ -329,35 +355,35 @@ class SDAR:
     def extra_state(self):
         """The device counters (uint32, wrapping).  Counted here, in passes
         over live slots: picks routed to each held expert of each layer
-        (every row of a live slot's block), picks made in all, rows
-        stepped, steps that stepped any, ``passes`` (live slot-passes) and
-        ``rows_read`` (the cache rows a layer's attention read for them).
-        Counted by the engine's tail, which knows what a pass decided:
-        ``commits``, ``tokens_committed`` (tokens the commits delivered),
-        ``fixed_by_threshold`` and ``fixed_by_quota`` (positions fixed, by
-        which branch of the rule)."""
-        zero = jnp.zeros((), jnp.uint32)
-        return {"moe_picks": jnp.zeros((self.cfg.layers,
-                                        self.cfg.experts_held), jnp.uint32),
-                "moe_picks_total": zero, "rows": zero, "steps": zero,
-                "passes": zero, "rows_read": zero, "commits": zero,
-                "tokens_committed": zero, "fixed_by_threshold": zero,
-                "fixed_by_quota": zero}
+        (every row of a live slot's open block, and of its pending one),
+        picks made in all, ``rows`` stepped (live ones: open and pending),
+        ``commit_rows`` (the pending ones among them), steps that stepped
+        any, ``passes`` (live slot-passes) and ``rows_read`` (the cache
+        rows a layer's attention read for them).  Counted by the engine's
+        tail, which knows what a pass decided: ``commits`` (blocks whose
+        final K and V a pass wrote), ``tokens_committed`` (tokens
+        delivered), ``fixed_by_threshold`` and ``fixed_by_quota``
+        (positions fixed, by which branch of the rule)."""
+        return dict({name: jnp.zeros((), jnp.uint32) for name in _COUNTERS},
+                    moe_picks=jnp.zeros((self.cfg.layers,
+                                         self.cfg.experts_held), jnp.uint32))
 
     def counters(self, extra):
         """The extra state read back (whole numbers), with the gauges the
         engine publishes under ``gauges``: picks a held expert sees a step,
-        the busiest held expert's picks over the mean's, and tokens
-        committed a live slot-pass (``1 / (T + 1) x B`` with one position a
-        pass)."""
+        the busiest held expert's picks over the mean's, tokens delivered
+        a live slot-pass (``B / T`` with one position a pass) and the share
+        of live slot-passes that carried a pending block's rows (``1 / T``
+        then)."""
         picks = np.asarray(extra["moe_picks"], np.int64)
-        out = {name: int(extra[name]) for name in extra
-               if name != "moe_picks"}
+        out = {name: int(extra[name]) for name in _COUNTERS}
         out["moe_picks"] = picks.tolist()
         gauges = routing_gauges(picks, out["steps"])
         if out["passes"]:
             gauges["serving.decode.tokens_per_pass"] = \
                 out["tokens_committed"] / out["passes"]
+            gauges["serving.decode.commit_rows_share"] = \
+                out["commit_rows"] / (self.cfg.block * out["passes"])
         if gauges:
             out["gauges"] = gauges
         return out
@@ -407,57 +433,102 @@ class SDAR:
     def decode_step(self, params, cache_k, cache_v, block, lengths, active,
                     extra):
         """One pass for all ``S`` slots: ``block (S, B)`` int32 are the
-        tokens of each slot's current block (the mask token where a
-        position is not fixed), at positions ``lengths .. lengths + B -
-        1``.  Their K/V go to those rows of each slot's cache and the ``B``
-        rows attend over everything up to ``lengths + B - 1``.  Returns
-        ``(logits (S, B, vocab), cache_k, cache_v, extra)``: row ``[i, j]``
-        the logits of position ``lengths[i] + j``'s own token."""
+        tokens of each slot's OPEN block (the mask token where a position
+        is not fixed), at positions ``lengths .. lengths + B - 1``.  Their
+        K/V go to those rows of each slot's cache and the ``B`` rows attend
+        over everything up to ``lengths + B - 1``.  Returns ``(logits (S,
+        B, vocab), cache_k, cache_v, extra)``: row ``[i, j]`` the logits of
+        position ``lengths[i] + j``'s own token.
+
+        The pass runs ``(S, 2B)`` rows.  ``extra["pending"] (S,)`` bool and
+        ``extra["pending_block"] (S, B)`` int32 are the engine's: a slot's
+        block that its last pass left whole and delivered, final tokens
+        whose K and V the cache does not hold yet.  Its ``B`` rows ride
+        this pass at positions ``lengths - B .. lengths - 1`` for their K
+        and V alone, under the horizon ``lengths - 1`` (what a pass of
+        their own would have seen); no logits are made of them.  The
+        pending rows of a slot with nothing pending are DEAD: they are
+        written where the open block's rows then overwrite them, take no
+        pick in the expert layer and count in no counter.  In the last
+        layer the pending rows stop at their K and V (the experts' picks
+        counted there are the open rows')."""
         cfg = self.cfg
         s, b = block.shape
         group = cfg.heads // cfg.kv_heads
         at = jnp.clip(lengths, 0, cfg.max_len - b)
-        pos = (at[:, None] + jnp.arange(b)[None, :]).reshape(-1)
+        pending = extra["pending"] & active
+        below = jnp.where(pending, at - b, at)
+        # the rows in the order (pending | open, slot, position)
+        pos = (jnp.stack([below, at])[:, :, None]
+               + jnp.arange(b)[None, None, :]).reshape(-1)
+        horizon = jnp.stack([below, at], axis=1) + (b - 1)
         scale = 1.0 / math.sqrt(cfg.head_dim)
         new_k, new_v = list(cache_k), list(cache_v)
         live = active.astype(jnp.uint32)
-        live_rows = jnp.repeat(live, b)
+        live_rows = jnp.repeat(jnp.concatenate([pending, active]), b)
+        # a quarter of the slots carry a pending block (one pass in four
+        # of a block of four): what the expert layer sizes its tiles by
+        expected = s * b + s * b // cfg.denoise_steps
+        last = cfg.layers - 1
         picks = []
 
         def by_slot(rows):
-            """``(S B, n, d) -> (S, n, B, d)``: a slot's run of rows."""
-            return jnp.swapaxes(rows.reshape(s, b, *rows.shape[1:]), 1, 2)
+            """``(2 S B, n, d) -> two of (S, n, B, d)``: a slot's run of
+            pending rows, and of open ones."""
+            return jnp.swapaxes(rows.reshape(2, s, b, *rows.shape[1:]), 2, 3)
 
         def attend(l, q, k, v):
-            new_k[l] = write_slot_rows(cache_k[l], by_slot(k), at)
-            new_v[l] = write_slot_rows(cache_v[l], by_slot(v), at)
-            # every row of a block sees the same rows: the block's queries
-            # ride as one group of ``group x B`` over each K/V head
-            q = q.reshape(s, b, cfg.kv_heads, group, cfg.head_dim)
+            # two runs of B rows a slot: a run of 2B from a row that only B
+            # divides could cross a tile.  The open block's goes last, over
+            # a dead run
+            for new, cache, rows in ((new_k, cache_k[l], by_slot(k)),
+                                     (new_v, cache_v[l], by_slot(v))):
+                new[l] = write_slot_rows(
+                    write_slot_rows(cache, rows[0], below), rows[1], at)
+            # every row of a block sees the same rows: a slot's queries
+            # ride as one group of ``2B x group`` over each K/V head, its
+            # first half (the pending block's) under the lower horizon, and
+            # the cache is read once for both.  (The last layer's are the
+            # open block's alone.)
+            n = q.shape[0] // (s * b)
+            q = q.reshape(n, s, b, cfg.kv_heads, group, cfg.head_dim)
             ctx = decode_attention(
-                jnp.swapaxes(q, 1, 2).reshape(s, cfg.kv_heads, b * group,
-                                              cfg.head_dim),
-                new_k[l], new_v[l], at + (b - 1), scale)
-            ctx = ctx.reshape(s, cfg.kv_heads, b, group, cfg.head_dim)
-            return jnp.swapaxes(ctx, 1, 2).reshape(s * b, cfg.heads,
-                                                   cfg.head_dim)
+                jnp.transpose(q, (1, 3, 0, 2, 4, 5)).reshape(
+                    s, cfg.kv_heads, n * b * group, cfg.head_dim),
+                new_k[l], new_v[l], horizon if n == 2 else horizon[:, 1],
+                scale)
+            ctx = ctx.reshape(s, cfg.kv_heads, n, b, group, cfg.head_dim)
+            return jnp.transpose(ctx, (2, 0, 3, 1, 4, 5)).reshape(
+                n * s * b, cfg.heads, cfg.head_dim)
 
         def counts(l, chosen):
-            picks.append(count_picks(cfg, chosen, live_rows))
+            picks.append(count_picks(
+                cfg, chosen, (live_rows[s * b:] if l == last
+                              else live_rows).astype(jnp.uint32)))
 
-        x = params["embed"][block.reshape(-1)].astype(jnp.float32)
+        x = params["embed"][jnp.concatenate(
+            [extra["pending_block"], block]).reshape(-1)].astype(jnp.float32)
         for l, p in enumerate(params["layers"]):
-            x = _block(cfg, l, p, x, pos, attend, counts)
+            if l < last:
+                x = _block(cfg, l, p, x, pos, attend, counts,
+                           (live_rows, expected))
+            else:
+                # nothing reads the last layer's output of a pending row:
+                # past its K and V the layer runs the open rows alone
+                x = _block(cfg, l, p, x, pos, attend, counts,
+                           (live_rows[s * b:], s * b), lead=s * b)
         with jax.named_scope("head"):
             logits = _mm(_rms(x, params["ln_f"], cfg.eps), params["head"])
         slots = live.sum()
-        rows = slots * np.uint32(b)
+        commit_rows = pending.sum().astype(jnp.uint32) * np.uint32(b)
+        rows = slots * np.uint32(b) + commit_rows
         extra = dict(
             extra,
             moe_picks=extra["moe_picks"] + jnp.stack(picks),
-            moe_picks_total=extra["moe_picks_total"]
-            + rows * np.uint32(cfg.top_k * cfg.layers),
+            moe_picks_total=extra["moe_picks_total"] + np.uint32(cfg.top_k)
+            * (rows * np.uint32(cfg.layers) - commit_rows),
             rows=extra["rows"] + rows,
+            commit_rows=extra["commit_rows"] + commit_rows,
             steps=extra["steps"] + (slots > 0).astype(jnp.uint32),
             passes=extra["passes"] + slots,
             rows_read=extra["rows_read"]

@@ -596,15 +596,21 @@ def _decode_xla(q, cache_k, cache_v, lengths, scale):
     weighted sum accumulated in float32."""
     scores = jnp.einsum("skgd,skmd->skgm", q, cache_k,
                         preferred_element_type=jnp.float32) * scale
-    mask = jnp.arange(cache_k.shape[2])[None, :] <= lengths[:, None]
-    att = jax.nn.softmax(
-        jnp.where(mask[:, None, None, :], scores, NEG_INF), axis=-1)
+    if lengths.ndim == 2:
+        # a horizon a half of the group
+        mask = jnp.arange(cache_k.shape[2])[None, None, :] \
+            <= jnp.repeat(lengths, q.shape[2] // 2, axis=1)[:, :, None]
+        mask = mask[:, None]
+    else:
+        mask = jnp.arange(cache_k.shape[2])[None, :] <= lengths[:, None]
+        mask = mask[:, None, None, :]
+    att = jax.nn.softmax(jnp.where(mask, scores, NEG_INF), axis=-1)
     return jnp.einsum("skgm,skmd->skgd", att.astype(cache_v.dtype), cache_v,
                       preferred_element_type=jnp.float32)
 
 
 def _walk_slot(len_ref, pairs, sems, turns, softmax, chunk, piece,
-               accumulate):
+               accumulate, low_ref=None):
     """The walk a decode kernel makes of its slot's rows: one grid step a
     slot, the caches in HBM.  A slot of length ``n`` is walked in ``n //
     chunk + 1`` turns of a loop: ``n // chunk`` whole chunks of ``chunk``
@@ -622,7 +628,10 @@ def _walk_slot(len_ref, pairs, sems, turns, softmax, chunk, piece,
     n)`` is handed each array's rows ``(n, rows, d)`` from row ``first`` of
     the slot, and in the edge the slot's length ``n`` above which rows take
     no part (they hold whatever an earlier session, or an earlier turn,
-    left); None in a whole chunk."""
+    left); None in a whole chunk.  ``low_ref``: a second, lower horizon a
+    slot that some of the caller's queries have (``low_ref[i] <=
+    len_ref[i]``): a whole chunk that reaches above it is handed the slot's
+    length too, for the caller to mask by the horizon each query has."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -686,7 +695,19 @@ def _walk_slot(len_ref, pairs, sems, turns, softmax, chunk, piece,
         def _whole():
             for copy in whole(i, c, buf):
                 copy.wait()
-            accumulate([vmem[buf] for _, vmem in pairs], c * chunk, None)
+            if low_ref is None:
+                accumulate([vmem[buf] for _, vmem in pairs], c * chunk, None)
+                return
+            below = (c + 1) * chunk <= low_ref[i] + 1
+
+            @pl.when(below)
+            def _below():
+                accumulate([vmem[buf] for _, vmem in pairs], c * chunk, None)
+
+            @pl.when(~below)
+            def _across():
+                accumulate([vmem[buf] for _, vmem in pairs], c * chunk,
+                           length)
 
         @pl.when(c == last)
         def _edge():
@@ -705,10 +726,16 @@ def _walk_slot(len_ref, pairs, sems, turns, softmax, chunk, piece,
 
 
 def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
-                   turns, m_scr, l_scr, acc_scr, *, scale, chunk, piece):
+                   turns, m_scr, l_scr, acc_scr, *, scale, chunk, piece,
+                   low_ref=None):
     """:func:`_walk_slot` over K and V: the running maximum, sum and
     accumulator of the slot's ``(kv_heads, group)`` queries stay in VMEM
-    across its turns."""
+    across its turns.  With ``low_ref`` the first half of the group sees
+    the rows up to ``low_ref[i]`` and the second those up to
+    ``len_ref[i]``, which the walk reaches."""
+    from jax.experimental import pallas as pl
+
+    low = None if low_ref is None else low_ref[pl.program_id(0)]
 
     def accumulate(held, first, n):
         """``k``, ``v (kv, rows, d)`` join the running softmax."""
@@ -719,7 +746,13 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
         if n is not None:
             rows = k.shape[1]
             at = first + jax.lax.broadcasted_iota(jnp.int32, (1, 1, rows), 2)
-            s = jnp.where(at <= n, s, NEG_INF)
+            if low_ref is None:
+                s = jnp.where(at <= n, s, NEG_INF)
+            else:
+                g = s.shape[1]
+                half = jax.lax.broadcasted_iota(jnp.int32, (1, g, 1), 1)
+                s = jnp.where(at <= jnp.where(half < g // 2, low, n), s,
+                              NEG_INF)
             at = first + jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1)
             v = jnp.where(at <= n, v, jnp.zeros_like(v))
         m_prev = m_scr[...]
@@ -733,8 +766,14 @@ def _decode_kernel(len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
             preferred_element_type=jnp.float32)            # (kv, g, d)
 
     _walk_slot(len_ref, ((k_hbm, k_buf), (v_hbm, v_buf)), sems, turns,
-               (m_scr, l_scr, acc_scr), chunk, piece, accumulate)
+               (m_scr, l_scr, acc_scr), chunk, piece, accumulate, low_ref)
     o_ref[0] = acc_scr[...] / l_scr[...]
+
+
+def _decode_kernel_halves(len_ref, low_ref, *refs, **sizes):
+    """:func:`_decode_kernel` with a horizon a half of the group: the lower
+    one is a second scalar-prefetch operand."""
+    _decode_kernel(len_ref, *refs, low_ref=low_ref, **sizes)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "chunk", "piece",
@@ -749,15 +788,19 @@ def _decode_pallas(q, cache_k, cache_v, lengths, scale, chunk, piece,
 
     s, kv, g, d = q.shape
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # a horizon a half of the group: the walk's is the higher one
+    halves = lengths.ndim == 2
+    horizons = (lengths[:, 1], lengths[:, 0]) if halves else (lengths,)
 
-    def whole(i, lens):
+    def whole(i, *lens):
         return (i, 0, 0, 0)
 
     return pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, chunk=chunk,
+        functools.partial(_decode_kernel_halves if halves
+                          else _decode_kernel, scale=scale, chunk=chunk,
                           piece=piece),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(horizons),
             grid=(s,),
             in_specs=[pl.BlockSpec((1, kv, g, d), whole), hbm, hbm],
             out_specs=pl.BlockSpec((1, kv, g, d), whole),
@@ -775,7 +818,7 @@ def _decode_pallas(q, cache_k, cache_v, lengths, scale, chunk, piece,
             dimension_semantics=("arbitrary",)),
         name="decode_attention",
         interpret=interpret,
-    )(lengths, q, cache_k, cache_v)
+    )(*horizons, q, cache_k, cache_v)
 
 
 def _decode_chunk(cache_k):
@@ -832,7 +875,13 @@ def decode_attention(q, cache_k, cache_v, lengths, scale):
     read; ``cache_k``/``cache_v (S, kv_heads, rows, d)``; ``lengths (S,)``
     int32 within ``0 .. rows - 1``, the inclusive horizon: slot ``i``
     attends rows ``0 .. lengths[i]``, so every slot reads one row at the
-    least.  Returns the context ``(S, kv_heads, group, d)`` float32.
+    least.  ``lengths (S, 2)`` is a horizon a HALF of the group, ``[i, 0]
+    <= [i, 1]``: the first ``group // 2`` queries of every K/V head see the
+    rows ``0 .. lengths[i, 0]`` and the others ``0 .. lengths[i, 1]`` (a
+    pass of a model that generates by blocks over two blocks of a slot at
+    once, :mod:`~mxnet_tpu.models.sdar`): one walk of the slot's rows as
+    far as the higher horizon, the lower one a mask on the rows above it.
+    Returns the context ``(S, kv_heads, group, d)`` float32.
     Scores accumulate in float32 from operands in the cache's dtype, the
     weights are rounded to the cache's dtype before the product with V,
     which accumulates in float32.
@@ -845,6 +894,10 @@ def decode_attention(q, cache_k, cache_v, lengths, scale):
     The choice is counted under ``ops.kernel_path``."""
     from .registry import count_kernel_path
 
+    if lengths.ndim == 2 and (lengths.shape[1] != 2 or q.shape[2] % 2):
+        raise ValueError("horizons %s for a group of %d: one a slot, or "
+                         "two and a group of two halves"
+                         % (lengths.shape, q.shape[2]))
     _, reason = decode_attention_plan(q, cache_k)
     if reason is None:
         count_kernel_path("decode_attention", "pallas", "ok")

@@ -154,9 +154,9 @@ def _grouped_kernel(group_ref, live_ref, x_ref, w_ref, gate_ref, up_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("act", "tile", "block",
-                                             "interpret"))
+                                             "interpret", "vmem_limit"))
 def _grouped_pallas(xs, ws, tiles, gate, up, down, act, tile, block,
-                    interpret=False):
+                    interpret=False, vmem_limit=_VMEM_BYTES):
     """Jitted on its own, as the decode kernels are: a step that calls it
     for six layers traces and lowers the kernel once a shape."""
     from jax.experimental import pallas as pl
@@ -199,7 +199,7 @@ def _grouped_pallas(xs, ws, tiles, gate, up, down, act, tile, block,
         # steps lean on what the last live one left: the steps run in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=_VMEM_BYTES),
+            vmem_limit_bytes=vmem_limit),
         name="grouped_product",
         interpret=interpret,
     )(group, live.astype(jnp.int32), xs, ws[:, None], gate, up, down)
@@ -347,7 +347,8 @@ def product_seconds(k, f, groups, rows, handed, expect, itemsize=2):
     return every, grouped
 
 
-def grouped_product(xs, ws, tiles, gate, up, down, act, tile, block):
+def grouped_product(xs, ws, tiles, gate, up, down, act, tile, block,
+                    snug=False):
     """``xs (M, k)`` in the weights' dtype and ``ws (M,)`` float32 laid out
     by :func:`group_rows` in tiles of ``tile`` rows, ``tiles (groups,)``
     the tiles each group owns -> ``(M, k)`` float32: each group's rows
@@ -357,5 +358,24 @@ def grouped_product(xs, ws, tiles, gate, up, down, act, tile, block):
     tiles come back unwritten, not zero** (the kernel visits none of them):
     a caller must mask what it reads there, as :func:`sum_out_of_groups`
     does for the picks of no group here, whose ``dest`` points past the
-    layout."""
-    return _grouped_pallas(xs, ws, tiles, gate, up, down, act, tile, block)
+    layout.
+
+    ``snug``: the kernel claims the VMEM its tiles need (:func:`_vmem_bytes`
+    and the quarter the plan leaves the compiler, to a whole power of two)
+    where it otherwise claims :data:`_VMEM_BYTES` whatever it holds.  What
+    a kernel claims the compiler keeps free of the arrays it would itself
+    hold in VMEM between kernels: beside a claim of 64 MiB the output of
+    SDAR's 768-row pass, ``(10240, 2048)`` float32, 84 MB, stayed in HBM
+    and the gather out of it read 1.0 ms a pass where the 384-row pass's
+    58 MB, held in VMEM, had read 0.13 (PERF.md section 6, PR 44); beside
+    32 MiB it is held there.  An argument, and not the rule, because the
+    claim is part of a program's text and the other users' programs were
+    to keep theirs (ROADMAP S12)."""
+    limit = _VMEM_BYTES
+    if snug:
+        need = _vmem_bytes(tile, block, gate.shape[1], gate.dtype.itemsize)
+        limit = 1 << 20
+        while 0.75 * limit < need:
+            limit *= 2
+    return _grouped_pallas(xs, ws, tiles, gate, up, down, act, tile, block,
+                           vmem_limit=limit)

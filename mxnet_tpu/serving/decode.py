@@ -32,15 +32,22 @@ fixed each and the pass the block is at (``finish_block``/``arm_block``
 beside ``finish_step``/``arm_slot`` in :meth:`DecodeEngine._build`); a
 pass fixes some of the open positions by the rule ``cfg.remasking`` names
 (the quota, the three rules and the threshold are the model object's:
-no engine option), and the pass that finds its block whole is the
-COMMIT: the slot's length moves by ``B``, the block's tokens beyond the
-prompt and below the session's end are delivered in order, and a fresh
-block starts.  The packed read grows to ``(B + 3, S)`` (``B`` tokens or
--1, the passes that fixed them as one code, done, active), the fan-out
-hands a slot none or up to ``B`` tokens, and an admission's prefill
-yields NO token (the published sampler uses a prompt for K and V
-alone), so a session's first token, ``ttft()`` and
-``serving.decode.ttft_seconds`` are its first block's commit.
+no engine option), and the pass that LEAVES its block whole delivers it:
+the block's tokens beyond the prompt and below the session's end go out
+in order, the slot's length moves by ``B`` and a fresh block opens.  The
+K and V of the block's final tokens (the COMMIT, a pass of its own in
+the published sampler, which reads every weight and fixes nothing) are
+written by the slot's NEXT pass: the finished block stays the slot's
+*pending* block, and its ``B`` rows ride beside the open block's for
+their K and V alone (the model's step runs ``(S, 2B)`` rows; the pending
+rows of a slot with nothing pending are dead).  A session that ends
+with its block writes no commit; an admission, a cancel and a deadline
+drop a slot's pending block.  The packed read grows to ``(B + 3, S)``
+(``B`` tokens or -1, the passes that fixed them as one code, done,
+active), the fan-out hands a slot none or up to ``B`` tokens, and an
+admission's prefill yields NO token (the published sampler uses a prompt
+for K and V alone), so a session's first token, ``ttft()`` and
+``serving.decode.ttft_seconds`` are its first block's delivery.
 :meth:`DecodeEngine.describe` says which tail an engine runs.
 
 **Sampling keys are position-derived, not sequential.**  Each session
@@ -59,9 +66,11 @@ greedy and temperature — which is what
 (docs/serving.md "Session failover & fault domains").  A block model's
 key is of a position AND a pass, ``fold_in(fold_in(PRNGKey(seed), i),
 t)``, and its blocks are absolute (block ``k`` is positions ``kB .. kB +
-B - 1``): a transcript holds committed blocks, the block that was being
-denoised when a replica was lost is redone from its first pass, and the
-resumed stream is the uninterrupted one.
+B - 1``): a transcript holds delivered blocks (the last of them perhaps
+pending, its K and V never written: the re-prefill writes them as it
+writes the prompt's), the block that was being denoised when a replica
+was lost is redone from its first pass, and the resumed stream is the
+uninterrupted one.
 
 Sequences are admitted into free slots BETWEEN steps (continuous
 batching: a late request joins the running batch instead of waiting for
@@ -124,9 +133,13 @@ is given a *model object* and asks it for four things:
 * ``decode_step(params, firsts, seconds, last_tok, lengths, active,
   extra) -> (logits, firsts, seconds, extra)``: one token for all slots,
   given and returning every entry's two arrays.  A model that declares a
-  block length is given ``(slots, B)`` tokens (each slot's current block)
+  block length is given ``(slots, B)`` tokens (each slot's open block)
   and returns ``(slots, B, vocab)`` logits, row ``[i, j]`` of position
-  ``lengths[i] + j``'s own token;
+  ``lengths[i] + j``'s own token; beside its own extra state it finds in
+  ``extra`` the engine's ``pending (slots,)`` bool and ``pending_block
+  (slots, B)``: the final tokens of the block at ``lengths - B``, whose K
+  and V this pass is to write (they are the engine's, and what the step
+  returns of them is dropped);
 * ``extra_state()``: optional extra device state the step carries beside
   the cache (routing counters), or None.  It is an argument of its own,
   never donated, so :meth:`DecodeEngine.model_counters` may read the
@@ -136,7 +149,8 @@ The engine's donated state is ``(firsts, seconds, last_tok, lengths,
 limits, active, temps, seeds)``: every entry's first array, every entry's
 second, six arrays of ``(slots,)``; a block model's has the blocks
 ``(slots, B)`` in ``last_tok``'s place and, after ``seeds``, ``fixed``
-and ``fixed_at`` of ``(slots, B)`` and ``passes`` of ``(slots,)``
+and ``fixed_at`` of ``(slots, B)``, ``passes`` of ``(slots,)``, and
+``pending`` ``(slots,)`` with ``pending_block`` ``(slots, B)``
 (:meth:`DecodeEngine.state_shapes` gives it as shapes to a tool that
 lowers the programs without allocating them).  Its extra state holds
 the counters the tail counts (``commits``, ``tokens_committed``,
@@ -357,7 +371,7 @@ class GenerateSession:
     def ttft(self):
         """Time-to-first-token in seconds (None before the first
         token; of a model that generates by blocks, before its first
-        block's commit: its prefill yields no token)."""
+        block's delivery: its prefill yields no token)."""
         return None if self.t_first is None \
             else self.t_first - self.t_submit
 
@@ -599,21 +613,28 @@ class DecodeEngine:
         def finish_block(state_rest, logits, keep, extra):
             # the tail of a model that generates by diffusion over blocks
             # (``cfg.block`` positions a slot): ``logits (S, B, vocab)``
-            # are one pass over each slot's block.  A pass that found its
-            # block whole at entry is the COMMIT (the length moves by B,
-            # the block's tokens beyond the prompt and below the limit are
-            # delivered, a fresh block starts); any other fixes some of
-            # the open positions by ``cfg.remasking``.  Block, flags and
-            # pass index stay on the device; the host learns of a commit
-            # from ``packed (B + 3, S)``: B tokens (-1: none), the passes
-            # at which they were fixed (one code a slot), done, active
+            # are one pass over each slot's OPEN block, which fixes some of
+            # its open positions by ``cfg.remasking``.  A pass that leaves
+            # its block whole DELIVERS it at once (the tokens beyond the
+            # prompt and below the limit), moves the length by B, opens a
+            # fresh block at the next B positions and keeps the finished
+            # one as the slot's PENDING block: its final tokens' K and V
+            # are not in the cache yet (what the pass wrote are the K and V
+            # of the block as it stood at entry), and the slot's next pass
+            # writes them beside its own rows.  A session that ends with
+            # the block leaves nothing pending.  Blocks, flags and pass
+            # index stay on the device; the host learns of a delivery from
+            # ``packed (B + 3, S)``: B tokens (-1: none), the passes at
+            # which they were fixed (one code a slot), done, active
             (block, lengths, limits, active, temps, seeds, fixed, fixed_at,
-             passes) = state_rest
+             passes, pending, pending_block) = state_rest
+            # the blocks whose final K and V the step has just written
+            wrote = pending & active
             active = active & keep
             pos = lengths[:, None] + jnp.arange(nblock)[None, :]
             # the key of position p at pass t: a pure function of the
             # transcript (blocks are absolute), so a re-prefill of prompt
-            # and committed blocks resumes the very stream
+            # and delivered blocks resumes the very stream
             keys = jax.vmap(lambda seed, ps, t: jax.vmap(
                 lambda q: jax.random.fold_in(fold_key(seed, q), t))(ps))(
                     seeds, pos, passes)
@@ -664,16 +685,20 @@ class DecodeEngine:
                                            >= want)
                     take = jnp.where(by_threshold.any(-1, keepdims=True),
                                      high, best)
-            commit = active & fixed.all(-1)
+            # the block as this pass leaves it
+            block = jnp.where(take, x0, block)
+            fixed = fixed | take
+            fixed_at = jnp.where(take, passes[:, None], fixed_at)
+            whole = active & fixed.all(-1)
             mine = (fixed_at >= 0) & (pos < limits[:, None])
             is_eos = mine & (block == eos)
-            deliver = commit[:, None] & mine \
+            deliver = whole[:, None] & mine \
                 & (jnp.cumsum(is_eos, axis=-1) - is_eos == 0)
-            new_len = lengths + commit * nblock
-            done = commit & ((deliver & is_eos).any(-1)
-                             | (new_len >= limits))
+            new_len = lengths + whole * nblock
+            done = whole & ((deliver & is_eos).any(-1)
+                            | (new_len >= limits))
             new_active = active & ~done
-            fresh = commit[:, None]
+            fresh = whole[:, None]
             base = cfg.denoise_steps + 1
             packed = jnp.concatenate([
                 jnp.where(deliver, block, -1).T,
@@ -682,20 +707,19 @@ class DecodeEngine:
                 new_active.astype(jnp.int32)[None]])
             if extra is not None:
                 counted = {
-                    "commits": commit.sum(),
+                    "commits": wrote.sum(),
                     "tokens_committed": deliver.sum(),
                     "fixed_by_threshold": (take & by_threshold).sum(),
                     "fixed_by_quota": (take & ~by_threshold).sum()}
                 extra = dict(extra, **{
                     k: extra[k] + v.astype(extra[k].dtype)
                     for k, v in counted.items() if k in extra})
-            return (jnp.where(fresh, np.int32(cfg.mask_id),
-                              jnp.where(take, x0, block)),
+            return (jnp.where(fresh, np.int32(cfg.mask_id), block),
                     new_len, limits, new_active, temps, seeds,
-                    (fixed | take) & ~fresh,
-                    jnp.where(fresh, -1, jnp.where(
-                        take, passes[:, None], fixed_at)),
-                    jnp.where(commit, 0, passes + active)), packed, extra
+                    fixed & ~fresh, jnp.where(fresh, -1, fixed_at),
+                    jnp.where(whole, 0, passes + active),
+                    whole & ~done,
+                    jnp.where(fresh, block, pending_block)), packed, extra
 
         def arm_block(state_rest, slot, first, length, limit, temp, seed,
                       activate):
@@ -703,9 +727,11 @@ class DecodeEngine:
             # yields NO token: ``first (B,)`` is the block that holds
             # position ``length // B * B``, of which the prompt's
             # ``length % B`` last tokens are fixed from the start (pass
-            # -1); ``limit`` is the end of the session, prompt included
+            # -1); ``limit`` is the end of the session, prompt included.
+            # Whatever block the slot's last session left pending is
+            # nobody's now
             (block, lengths, limits, active, temps, seeds, fixed, fixed_at,
-             passes) = state_rest
+             passes, pending, pending_block) = state_rest
             nothing = limit <= length
             out = jnp.stack([jnp.int32(-1), nothing.astype(jnp.int32)])
             return (block.at[slot].set(first),
@@ -716,7 +742,8 @@ class DecodeEngine:
                     fixed.at[slot].set(jnp.arange(nblock)
                                        < length % nblock),
                     fixed_at.at[slot].set(-1),
-                    passes.at[slot].set(0)), out
+                    passes.at[slot].set(0),
+                    pending.at[slot].set(False), pending_block), out
 
         if self._kv is not None:
             nb, bs = self._kv.num_blocks, self._kv.block_size
@@ -768,10 +795,17 @@ class DecodeEngine:
 
             def advance(params, state, keep, extra):
                 cache_k, cache_v = state[0], state[1]
+                if nblock:
+                    # the slots' pending blocks are the engine's state, and
+                    # reach the model beside its own
+                    riding = dict(pending=state[-2], pending_block=state[-1])
+                    extra = dict(extra or {}, **riding)
                 logits, cache_k, cache_v, extra = model.decode_step(
                     params, cache_k, cache_v, state[2], state[3], state[5],
                     extra)
                 if nblock:
+                    extra = {k: v for k, v in extra.items()
+                             if k not in riding} or None
                     rest, packed, extra = finish_block(state[2:], logits,
                                                        keep, extra)
                 else:
@@ -877,7 +911,7 @@ class DecodeEngine:
         state = self.state_shapes(jnp.zeros, lead)
         if self._block:
             # no position of a fresh block was fixed at any pass
-            state = state[:-2] + (state[-2] - 1,) + state[-1:]
+            state = state[:9] + (state[9] - 1,) + state[10:]
         return jax.device_put(state, self._device)
 
     def state_shapes(self, make, lead=None):
@@ -889,9 +923,11 @@ class DecodeEngine:
         ``temps`` and the per-slot ``seeds``, each ``(slots,)``.  A block
         model's has the tokens of each slot's current block ``(slots, B)``
         in ``last_tok``'s place and after ``seeds`` which of them are
-        fixed, the pass at which each was, both ``(slots, B)``, and the
-        pass the block is at.  ``lead`` is the paged layout's ``(blocks,
-        block size)`` in the slots' place."""
+        fixed, the pass at which each was, both ``(slots, B)``, the pass
+        the block is at, which slots hold a pending block (delivered, its
+        final K and V not yet written) and its tokens ``(slots, B)``.
+        ``lead`` is the paged layout's ``(blocks, block size)`` in the
+        slots' place."""
         import jax.numpy as jnp
 
         s = self.slots
@@ -912,7 +948,8 @@ class DecodeEngine:
                  make((s,), jnp.uint32))
         if self._block:
             state += (make(held, jnp.bool_), make(held, jnp.int32),
-                      make((s,), jnp.int32))
+                      make((s,), jnp.int32), make((s,), jnp.bool_),
+                      make(held, jnp.int32))
         return state
 
     def _warm(self, state):
@@ -1594,7 +1631,7 @@ class DecodeEngine:
             _telemetry.inc("serving.decode.tokens.count", model=self.name,
                            replica=self.replica)
         # else a block model's prefill, which yields no token: the first
-        # comes with the first block's commit (_land_step)
+        # comes with the first block's delivery (_land_step)
         with self._cond:
             sess.admit_step = self.steps
             self.tokens_out += tok >= 0
@@ -1747,7 +1784,7 @@ class DecodeEngine:
                     self._retire(sess, error=err)
                     continue
                 if nblock:
-                    # a pass: nothing, or a committed block's tokens in
+                    # a pass: nothing, or a delivered block's tokens in
                     # order (at most ``nblock``; fewer at a session's two
                     # ends), each with the pass that fixed it
                     rode += 1

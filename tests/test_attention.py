@@ -306,6 +306,87 @@ def test_decode_kernel_walks_whole_chunks_then_the_edge_in_pieces(
                          tol, rows, chunk, piece)
 
 
+def _halves_einsum(q, k, v, horizons, scale):
+    """Float32 einsums, a query at a time of its own horizon: the first
+    half of a slot's group sees ``0 .. horizons[i, 0]``, the second ``0 ..
+    horizons[i, 1]``."""
+    q, k, v = (np.asarray(a, np.float32) for a in (q, k, v))
+    out = np.zeros(q.shape, np.float32)
+    g = q.shape[2]
+    for i, pair in enumerate(np.asarray(horizons)):
+        for j in range(g):
+            n = int(pair[1 if j >= g // 2 else 0]) + 1
+            s = np.einsum("kd,kmd->km", q[i, :, j], k[i, :, :n]) * scale
+            p = np.exp(s - s.max(-1, keepdims=True))
+            out[i, :, j] = np.einsum("km,kmd->kd",
+                                     p / p.sum(-1, keepdims=True),
+                                     v[i, :, :n])
+    return out
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("case,group,rows,chunk,piece,horizons", [
+    # SDAR's shape: two blocks of four positions, eight heads a K/V head;
+    # the lower horizon one block below the upper, about a piece's and a
+    # chunk's edges (the lower one the last row of the chunk before), and
+    # a slot with nothing pending (both the same)
+    ("sdar", 64, 512, 256, 128,
+     [(3, 7), (123, 127), (127, 131), (251, 255), (255, 259), (259, 263),
+      (507, 511), (7, 7), (300, 300)]),
+    # any two horizons: a whole chunk that reaches above the lower one is
+    # masked for the first half (and one far below the walk's edge)
+    ("far", 8, 512, 128, 128,
+     [(0, 511), (5, 300), (127, 128), (128, 400), (200, 255), (383, 384)]),
+    # a ring's horizons ``min(pos, rows - 1)``
+    ("ring", 16, 64, 32, 16, [(0, 3), (59, 63), (63, 63), (30, 34)]),
+], ids=lambda c: c if isinstance(c, str) else None)
+def test_decode_attention_with_a_horizon_a_half_of_the_group(
+        case, group, rows, chunk, piece, horizons, dtype, tol):
+    """The kernel (interpreter) and the plain path against float32 einsums
+    of each query's own rows; the kernel's cache holds NaN in every row
+    above the slot's UPPER horizon, and the rows between the two horizons
+    hold values a first-half query must not see."""
+    horizons = jnp.asarray(horizons, jnp.int32)
+    q, k, v, k_nan, v_nan, _ = _decode_case(
+        np.asarray(horizons[:, 1]), group, 128, dtype, seed=3, rows=rows)
+    want = _halves_einsum(q, k, v, horizons, 0.25)
+    plain = _decode_xla(q, k, v, horizons, 0.25)
+    np.testing.assert_allclose(np.asarray(plain), want, rtol=tol, atol=tol)
+    got = _decode_pallas(q, k_nan, v_nan, horizons, 0.25, chunk, piece,
+                         interpret=True)
+    assert got.dtype == jnp.float32 and got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got), want, rtol=tol, atol=tol)
+    # what the lower horizon hides matters: the one-horizon form differs
+    if bool((horizons[:, 0] < horizons[:, 1]).any()):
+        assert float(jnp.abs(plain - _decode_xla(
+            q, k, v, horizons[:, 1], 0.25)).max()) > 1e-2
+
+
+def test_one_horizon_a_slot_gives_what_it_gave():
+    """``lengths (S,)`` and the same horizon in both columns are the same
+    bytes, on the plain path and in the kernel; the public entry refuses
+    horizons it cannot read."""
+    q, k, v, k_nan, v_nan, lengths = _decode_case(
+        [0, 17, 63, 32], 8, 128, jnp.float32, seed=5)
+    both = jnp.stack([lengths, lengths], axis=1)
+    np.testing.assert_array_equal(
+        np.asarray(_decode_xla(q, k, v, lengths, 0.25)),
+        np.asarray(_decode_xla(q, k, v, both, 0.25)))
+    np.testing.assert_array_equal(
+        np.asarray(_decode_pallas(q, k_nan, v_nan, lengths, 0.25, _CHUNK,
+                                  _PIECE, interpret=True)),
+        np.asarray(_decode_pallas(q, k_nan, v_nan, both, 0.25, _CHUNK,
+                                  _PIECE, interpret=True)))
+    np.testing.assert_array_equal(
+        np.asarray(decode_attention(q, k, v, both, 0.25)),
+        np.asarray(decode_attention(q, k, v, lengths, 0.25)))
+    with pytest.raises(ValueError, match="two halves"):
+        decode_attention(q, k, v, jnp.stack([lengths] * 3, axis=1), 0.25)
+    with pytest.raises(ValueError, match="two halves"):
+        decode_attention(q[:, :, :7], k, v, both, 0.25)
+
+
 def test_decode_xla_is_the_softmax_over_the_rows_a_slot_holds():
     q, k, v, _, _, lengths = _decode_case([0, 9, 63], 4, 16, jnp.float32)
     got = np.asarray(_decode_xla(q, k, v, lengths, 0.25))

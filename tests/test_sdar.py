@@ -77,8 +77,11 @@ def programs():
 def test_prefill_then_passes_agree_with_the_plain_reference(
         prompt, bucket, blocks, programs):
     """Every pass's logits, of every state a block goes through (one more
-    position fixed a pass, then the commit), are the plain forward's over
-    the transcript and the block as it stands."""
+    position fixed a pass), are the plain forward's over the transcript and
+    the block as it stands; a block's final tokens ride the next block's
+    first pass as the slot's pending block, and the K and V the cache then
+    holds of them are what a commit pass of their own (the block whole,
+    nothing pending) writes."""
     model, prefill, step = programs
     cfg = model.cfg
     params = _params(cfg, seed=prompt)
@@ -98,32 +101,75 @@ def test_prefill_then_passes_agree_with_the_plain_reference(
         for l, r in enumerate(rows):
             side[l] = jax.lax.dynamic_update_slice(side[l], r[None],
                                                    (slot, 0, 0, 0))
-    extra = model.extra_state()
     active = jnp.arange(slots) == slot
-    worst, passes = 0.0, 0
-    for k in range(blocks):
+
+    def one_pass(cache, extra, at, state, pending=None):
+        """A pass of ``slot`` alone over ``state`` at row ``at``; the other
+        slots hold other tokens, lengths and a pending block nobody owns."""
+        block = np.full((slots, B), 7, np.int32)
+        block[slot] = state
+        lengths = np.full((slots,), 5, np.int32)
+        lengths[slot] = at
+        riding = np.full((slots, B), 9, np.int32)
+        has = np.ones((slots,), bool)
+        has[slot] = pending is not None
+        if pending is not None:
+            riding[slot] = pending
+        logits, ck, cv, extra = step(
+            params, tuple(cache[0]), tuple(cache[1]), jnp.asarray(block),
+            jnp.asarray(lengths), active,
+            dict(extra, pending=jnp.asarray(has),
+                 pending_block=jnp.asarray(riding)))
+        return np.asarray(logits)[slot], [list(ck), list(cv)], extra
+
+    def masked(at, fixed):
+        state = final[at:at + B].copy()
+        state[fixed:] = cfg.mask_id
+        return state
+
+    extra = plain_extra = model.extra_state()
+    plain = cache       # the published order: a commit pass a block
+    worst, passes, commits = 0.0, 0, 0
+    for k in range(blocks + 1):
         at = start + k * B
-        for fixed in range(tail if k == 0 else 0, B + 1):
-            state = final[at:at + B].copy()
-            state[fixed:] = cfg.mask_id
-            block = np.full((slots, B), 7, np.int32)
-            block[slot] = state
-            lengths = np.full((slots,), 5, np.int32)
-            lengths[slot] = at
-            logits, ck, cv, extra = step(
-                params, tuple(cache[0]), tuple(cache[1]), jnp.asarray(block),
-                jnp.asarray(lengths), active, extra)
-            cache = [list(ck), list(cv)]
+        # the last turn is the pass that opens the block after the last
+        for fixed in range(tail if k == 0 else 0, B if k < blocks else 1):
+            riding = final[at - B:at] if k and not fixed else None
+            state = masked(at, fixed) if k < blocks \
+                else np.full((B,), cfg.mask_id, np.int32)
+            logits, cache, extra = one_pass(cache, extra, at, state, riding)
+            passes += 1
+            commits += riding is not None
+            if k == blocks:
+                break
             want = np.asarray(_forward(cfg, params, jnp.asarray(
                 np.concatenate([final[:at], state]))))[at:at + B]
-            worst = max(worst, float(
-                np.abs(np.asarray(logits)[slot] - want).max()))
-            passes += 1
+            worst = max(worst, float(np.abs(logits - want).max()))
+            got, plain, plain_extra = one_pass(plain, plain_extra, at, state)
+            worst = max(worst, float(np.abs(got - want).max()))
+        if k < blocks:
+            _, plain, plain_extra = one_pass(plain, plain_extra, at,
+                                             final[at:at + B])
     assert worst < 2e-3, worst
+    for mine, theirs in zip(cache[0] + cache[1], plain[0] + plain[1]):
+        np.testing.assert_allclose(
+            np.asarray(mine)[slot, :, :start + blocks * B],
+            np.asarray(theirs)[slot, :, :start + blocks * B], atol=1e-5)
+        # a slot that is not live holds no pending block whatever the
+        # flag says: the rows below its length are as they were
+        assert not np.asarray(mine)[[0, 2], :, :5].any()
     got = model.counters(jax.tree_util.tree_map(np.asarray, extra))
-    assert got["passes"] == passes and got["rows"] == passes * B
-    assert got["moe_picks_total"] == passes * B * cfg.top_k * cfg.layers
+    assert commits == blocks
+    assert got["passes"] == passes
+    assert got["commit_rows"] == commits * B
+    assert got["rows"] == (passes + commits) * B
+    # a pending row picks in every layer but the last, which it leaves
+    # after its K and V
+    assert got["moe_picks_total"] == cfg.top_k * (
+        got["rows"] * cfg.layers - got["commit_rows"])
     assert np.sum(got["moe_picks"]) == got["moe_picks_total"]
+    assert got["gauges"]["serving.decode.commit_rows_share"] \
+        == pytest.approx(commits / passes)
 
 
 # -- the engine's generation against the published loop ------------------------
@@ -290,6 +336,263 @@ def test_sequential_resumes_from_inside_a_block_too():
         eng.close(drain=False)
 
 
+# -- a block's commit rides the next block's first pass ------------------------
+class _ByHand:
+    """The engine's own programs, dispatched by hand as its loop dispatches
+    them (a prefill into a slot, a step for all slots) with no thread
+    between: a test reads the slot state after any step."""
+
+    def __init__(self, cfg, params, slots=2):
+        self.eng = _engine(cfg, params, slots=slots, autostart=False)
+        self.cfg = cfg
+        self.state = self.eng._fresh_state()
+        self.tokens, self.fixed_at, self.deliveries = {}, {}, {}
+        self.done = set()
+
+    def close(self):
+        self.eng.close(drain=False)
+
+    def admit(self, slot, prompt, new, temperature=0.0, seed=0):
+        n = len(prompt)
+        bucket = next(b for b in self.eng.prefill_buckets if n <= b)
+        padded = np.zeros((bucket,), np.int32)
+        padded[:n] = prompt
+        self.state, out = self.eng._prefill_fns[bucket](
+            self.eng._params, self.state, padded, np.int32(n),
+            np.int32(slot), np.int32(min(n + new, self.cfg.max_len)),
+            np.float32(temperature), np.uint32(seed), np.bool_(True))
+        assert np.asarray(out).tolist() == [-1, 0]
+        self.tokens[slot], self.fixed_at[slot] = [], []
+        self.deliveries[slot] = []
+        self.done.discard(slot)
+
+    def step(self, keep=None):
+        """One pass; ``deliveries[slot]`` notes the steps that delivered."""
+        keep = np.ones((self.eng.slots,), bool) if keep is None else keep
+        self.state, packed = self.eng._dispatch_step(self.state, keep)
+        packed = np.asarray(packed)
+        base = self.cfg.denoise_steps + 1
+        for slot in self.tokens:
+            got = [j for j in range(B) if packed[j, slot] >= 0]
+            for j in got:
+                self.tokens[slot].append(int(packed[j, slot]))
+                self.fixed_at[slot].append(
+                    int(packed[B, slot]) // base ** j % base - 1)
+            self.deliveries[slot].append(bool(got))
+            if packed[B + 1, slot]:
+                self.done.add(slot)
+        return packed
+
+    def run(self, slot, most=200):
+        for _ in range(most):
+            if slot in self.done:
+                return self.tokens[slot]
+            self.step()
+        raise AssertionError("slot %d did not finish" % slot)
+
+    def pending(self, slot):
+        return bool(np.asarray(self.state[11])[slot])
+
+    def rows(self, slot, upto):
+        """K then V of every layer, rows ``0 .. upto - 1`` of ``slot``."""
+        return [np.asarray(a)[slot, :, :upto]
+                for side in self.state[:2] for a in side]
+
+    def counters(self):
+        return self.eng.model_counters()
+
+
+def _published_order(cfg, params, prompt, new, **kw):
+    """The parent's order written out plainly, one slot through the model's
+    cache: a block is denoised by passes over its ``B`` rows alone, nothing
+    pending, and then COMMITTED by a pass of its own over the block whole,
+    which fixes nothing (five passes a block of four at four steps).  The
+    sampling is ``generate_plain``'s; only where its logits come from is
+    this function's.  ``(tokens, fixed_at, K and V rows, passes run, commits
+    among them)``; the session's last block is left uncommitted, since
+    nothing would read its rows."""
+    model = sdar.SDAR(cfg, jnp.float32)
+    prefill, step = jax.jit(model.prefill), jax.jit(model.decode_step)
+    n = len(prompt)
+    bucket = next(b for b in (8, 16, 32) if n <= b)
+    padded = np.zeros((bucket,), np.int32)
+    padded[:n] = prompt
+    _first, ks, vs = prefill(params, jnp.asarray(padded), jnp.int32(n))
+    held = {"cache": [[jax.lax.dynamic_update_slice(
+        jnp.zeros((1,) + tlm.slot_shape(c), c.dtype), r[None], (0, 0, 0, 0))
+        for c, r in zip(model.cache_spec(), rows)] for rows in (ks, vs)],
+        "extra": dict(model.extra_state(),
+                      pending=jnp.zeros((1,), bool),
+                      pending_block=jnp.zeros((1, B), jnp.int32)),
+        "at": n // B * B, "passes": 0, "commits": 0}
+
+    def one_pass(block, at):
+        logits, ck, cv, held["extra"] = step(
+            params, tuple(held["cache"][0]), tuple(held["cache"][1]),
+            jnp.asarray(block, jnp.int32)[None], jnp.full((1,), at, jnp.int32),
+            jnp.ones((1,), bool), held["extra"])
+        held["cache"] = [list(ck), list(cv)]
+        held["passes"] += 1
+        return np.asarray(logits)[0]
+
+    def commit(tokens, at):
+        one_pass(tokens[at:at + B], at)
+        held["commits"] += 1
+
+    def forward(tokens):
+        tokens = np.asarray(tokens)
+        at = len(tokens) - B
+        if at > held["at"]:
+            commit(tokens, held["at"])
+            held["at"] = at
+        out = np.zeros((len(tokens), cfg.vocab), np.float32)
+        out[at:] = one_pass(tokens[at:], at)
+        return out
+
+    tokens, fixed_at, _ = sdar.generate_plain(cfg, params, prompt, new,
+                                              forward=forward, **kw)
+    return (tokens, fixed_at, [np.asarray(a)[0] for side in held["cache"]
+                               for a in side], held["passes"],
+            held["commits"])
+
+
+@pytest.mark.parametrize("prompt", [6, 8], ids=["inside", "edge"])
+@pytest.mark.parametrize("rule", ["static", "dynamic"])
+@pytest.mark.parametrize("temperature", [0.0, 0.7], ids=["greedy", "hot"])
+def test_the_merged_pass_is_the_published_order(temperature, rule, prompt):
+    """Tokens, the passes that fixed them and the K and V rows of every
+    committed block are those of the published order, which spends a pass
+    of its own on every commit; the merged engine runs none of them."""
+    cfg = _cfg(**(RULES["static-4"] if rule == "static" else dict(
+        remasking="low_confidence_dynamic", threshold=0.5)))
+    params = _params(cfg)
+    new, seed = 22, 77
+    want, want_at, want_rows, ran, commits = _published_order(
+        cfg, params, _prompt(prompt), new, temperature=temperature,
+        seed=seed)
+    assert len(want) == new
+    hand = _ByHand(cfg, params)
+    try:
+        # a neighbour in the other slot, admitted a pass later
+        hand.admit(1, _prompt(prompt), new, temperature, seed)
+        hand.step()
+        hand.admit(0, _prompt(5), 9)
+        assert hand.run(1) == want
+        assert hand.fixed_at[1] == want_at
+        # the session's last block is delivered and never committed
+        end = prompt + new
+        kept = (end - 1) // B * B
+        blocks = kept // B - prompt // B
+        for mine, theirs in zip(hand.rows(1, kept), want_rows):
+            np.testing.assert_allclose(mine, theirs[:, :kept], atol=1e-5)
+        per_block = np.diff([0] + [i + 1 for i, gave in enumerate(
+            hand.deliveries[1]) if gave]).tolist()
+        assert len(per_block) == blocks + 1
+        if rule == "static":
+            assert per_block[1:] == [cfg.denoise_steps] * blocks
+        else:
+            # the threshold fixes several positions a pass: blocks of one
+            # to four passes, not of ``T`` alone
+            assert min(per_block) < cfg.denoise_steps and \
+                len(set(per_block)) > 1, per_block
+        assert not hand.pending(1)
+        hand.run(0)
+        got = hand.counters()
+        # the neighbour's three blocks: two commits
+        assert got["commits"] == blocks + 2
+        assert got["commit_rows"] == got["commits"] * B
+        # the passes the published order ran, less its commits
+        assert sum(hand.deliveries[1]) == commits + 1 == blocks + 1
+        assert sum(per_block) == ran - commits
+    finally:
+        hand.close()
+
+
+def _until_pending(hand, slot, most=50):
+    """Steps until ``slot`` holds a pending block: delivered, its final K
+    and V not written."""
+    for _ in range(most):
+        hand.step()
+        if hand.pending(slot):
+            return
+    raise AssertionError("slot %d never held a pending block" % slot)
+
+
+@pytest.mark.parametrize("case", ["ends-with-its-block", "cancel", "resume",
+                                  "admission"])
+def test_what_clears_a_pending_block(case):
+    """A session that ends with its block runs no commit; a cancel drops
+    the pending block with the slot; ``resume()`` re-prefills a transcript
+    whose last block's K and V were never written; an admission into a
+    slot whose flag still stands (no loop leaves one so: the belt) starts
+    with nothing pending, and its prompt's rows are not written over."""
+    cfg = _cfg(**RULES["static-4"])
+    params = _params(cfg)
+    hand = _ByHand(cfg, params)
+    other = None
+    try:
+        if case == "ends-with-its-block":
+            # one block and no more: T passes, nothing pending after them
+            hand.admit(0, _prompt(8), 4)
+            assert hand.run(0) == _plain(cfg, params, _prompt(8), 4)[0]
+            assert len(hand.deliveries[0]) == cfg.denoise_steps
+            got = hand.counters()
+            assert got["commits"] == 0 and got["commit_rows"] == 0
+            assert got["passes"] == cfg.denoise_steps
+            assert got["tokens_committed"] == 4
+            assert not hand.pending(0)
+            # and two blocks: one commit, in the second block's first pass
+            hand.admit(0, _prompt(8), 8)
+            hand.run(0)
+            got = hand.counters()
+            assert got["commits"] == 1 and got["commit_rows"] == B
+            assert got["passes"] == 3 * cfg.denoise_steps
+            return
+        want = _plain(cfg, params, _prompt(6), 30)[0]
+        hand.admit(0, _prompt(6), 30)
+        _until_pending(hand, 0)
+        held = list(hand.tokens[0])
+        assert held == want[:len(held)] and (6 + len(held)) % B == 0
+        before = hand.counters()["commits"]
+        if case == "cancel":
+            keep = np.ones((2,), bool)
+            keep[0] = False
+            packed = hand.step(keep)
+            assert packed[:B, 0].tolist() == [-1] * B and not packed[B + 2, 0]
+            assert not hand.pending(0)
+            # the pass wrote the block's rows before it learnt of the
+            # cancel; the next one writes none
+            assert hand.counters()["commits"] == before + 1
+            hand.step()
+            assert hand.counters()["commits"] == before + 1
+        elif case == "resume":
+            cut = GenerateSession(_prompt(6), 30, 0.0, None, None, seed=0)
+            cut.tokens = held
+            cut.fixed_at = list(hand.fixed_at[0])
+            other = _engine(cfg, params)
+            other.resume(cut)
+            assert cut.result(120) == want
+            return
+        # the slot serves the next session as a fresh one would
+        prompt = _prompt(12)
+        hand.admit(0, prompt, 9)
+        if case == "admission":
+            assert not hand.pending(0)
+        assert hand.run(0) == _plain(cfg, params, prompt, 9)[0]
+        fresh = _ByHand(cfg, params)
+        try:
+            fresh.admit(0, prompt, 9)
+            fresh.run(0)
+            for mine, theirs in zip(hand.rows(0, 16), fresh.rows(0, 16)):
+                np.testing.assert_array_equal(mine, theirs)
+        finally:
+            fresh.close()
+    finally:
+        hand.close()
+        if other is not None:
+            other.close(drain=False)
+
+
 @pytest.mark.parametrize("model", ["block", "token"])
 def test_the_engine_says_its_states_shapes(model):
     """``DecodeEngine.state_shapes`` is what ``_fresh_state`` is made from:
@@ -312,11 +615,14 @@ def test_the_engine_says_its_states_shapes(model):
         assert [(a.shape, a.dtype) for a in jax.tree_util.tree_leaves(
             state)] == [(a.shape, a.dtype)
                         for a in jax.tree_util.tree_leaves(shapes)]
-        assert len(state) == (11 if model == "block" else 8)
+        assert len(state) == (13 if model == "block" else 8)
         if model == "block":
             assert state[2].shape == (3, cfg.block)
-            assert (np.asarray(state[-2]) == -1).all()
-            assert not np.asarray(state[-3]).any()
+            assert (np.asarray(state[9]) == -1).all()
+            assert not np.asarray(state[8]).any()
+            # and no slot holds a pending block
+            assert state[11].shape == (3,) and not np.asarray(state[11]).any()
+            assert state[12].shape == (3, cfg.block)
     finally:
         eng.close(drain=False)
 
@@ -481,6 +787,52 @@ def test_two_halves_of_the_experts_add_up_to_the_whole_layer():
     np.testing.assert_allclose(total, want, atol=1e-5)
 
 
+@pytest.mark.parametrize("product", ["every", "ragged_dot", "kernel"])
+def test_a_row_outside_the_mask_takes_no_pick(monkeypatch, product):
+    """``sparse_mlp(live=(mask, expected))``: the rows inside the mask come
+    out as they do with no mask; a row outside it takes no pick of any
+    expert (zero out of every product, no place in the kernel's layout),
+    whatever it holds, NaN included; the choices returned are every
+    row's.  The product and its row tile are chosen for the rows expected
+    live, not for the rows handed."""
+    from mxnet_tpu.ops import grouped_product as gp
+
+    cfg = _cfg()
+    moe = _params(cfg)["layers"][0]["moe"]
+    rs = np.random.RandomState(6)
+    h = jnp.asarray(rs.normal(size=(24, 32)), jnp.float32)
+    mask = jnp.asarray(rs.rand(24) < 0.6)
+    asked = []
+    monkeypatch.setattr(
+        xm, "expert_product", lambda cfg, rows, live=None: asked.append(
+            (rows, live)) or ("every" if product == "every" else "grouped"))
+    if product == "kernel":
+        monkeypatch.setattr(
+            xm, "grouped_product_plan", lambda gate, expect: asked.append(
+                expect) or ((8, 16), None))
+        monkeypatch.setattr(
+            xm, "grouped_product", lambda *a, snug=False: asked.append(
+                "snug" if snug else "roomy") or gp._grouped_pallas(
+                    *a, interpret=True))
+    want, chosen = xm.sparse_mlp(cfg, h, moe, shared=False)
+    dirty = jnp.where(mask[:, None], h, jnp.nan)
+    got, again = xm.sparse_mlp(cfg, dirty, moe, shared=False,
+                               live=(mask, 15))
+    assert (np.asarray(again)[np.asarray(mask)]
+            == np.asarray(chosen)[np.asarray(mask)]).all()
+    np.testing.assert_allclose(np.asarray(got)[np.asarray(mask)],
+                               np.asarray(want)[np.asarray(mask)], atol=2e-6)
+    if product != "every":
+        # (every expert over a NaN row is NaN times a weight of zero)
+        assert not np.asarray(got)[~np.asarray(mask)].any()
+    assert asked[0] == (24, None) and (24, 15) in asked
+    if product == "kernel":
+        # and the kernel of a call that names its live rows claims the
+        # fast memory it needs, the others' what they claimed
+        assert asked[1:3] == [24 * cfg.top_k / cfg.num_experts, "roomy"]
+        assert asked[-2:] == [15 * cfg.top_k / cfg.num_experts, "snug"]
+
+
 #: embed, expert_ffn, num_experts, top_k, experts_held of the four cells
 #: that route
 _SHARES = {"k-exaone": (6144, 2048, 128, 8, 16),
@@ -531,3 +883,27 @@ def _share(name):
 ])
 def test_expert_product_decides_for_each_share_as_it_did(name, rows, want):
     assert xm.expert_product(_share(name), rows) == want
+
+
+def test_the_merged_pass_s_experts_are_sized_by_the_rows_expected_live(
+        monkeypatch):
+    """768 rows a pass of which 480 are expected live (96 slots x 4, and a
+    quarter more for the pending blocks): the grouped product, at the row
+    tile 30 rows an expert ask (32), where the 768 handed would ask 64;
+    and its kernel claims 32 MiB of VMEM for the 20 MB it holds, where a
+    call that names no live rows claims the 64 MiB it claimed."""
+    from mxnet_tpu.ops import grouped_product as gp
+
+    cfg = _share("sdar")
+    assert xm.expert_product(cfg, 768, 480) == "grouped"
+    e, f = cfg.embed, cfg.expert_ffn
+    assert gp.group_tiles(e, f, 2, 480 * 8 / 128)[0] == 32
+    assert gp.group_tiles(e, f, 2, 768 * 8 / 128)[0] == 64
+    claimed = []
+    monkeypatch.setattr(gp, "_grouped_pallas",
+                        lambda *a, vmem_limit: claimed.append(vmem_limit))
+    gate = jax.ShapeDtypeStruct((128, e, f), jnp.bfloat16)
+    for snug in (True, False):
+        gp.grouped_product(None, None, None, gate, None, None, None, 32, f,
+                           snug=snug)
+    assert claimed == [32 << 20, 64 << 20]

@@ -179,7 +179,8 @@ def _through_the_kernel(monkeypatch, tile=8, block=16):
                         lambda gate, expect: ((tile, block), None))
     monkeypatch.setattr(
         xm, "grouped_product",
-        functools.partial(grouped_product._grouped_pallas, interpret=True))
+        lambda *a, snug=False: grouped_product._grouped_pallas(
+            *a, interpret=True))
 
 
 def _picks(imbalance, rs, top_k):

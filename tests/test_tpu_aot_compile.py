@@ -18,6 +18,7 @@ chip).
 import contextlib
 import math
 import os
+import re
 import subprocess
 import sys
 
@@ -785,12 +786,16 @@ def _sdar_case(which):
     ``benchmark/configs/sdar-30b-a3b-chat.json``'s sizes (6 layers of 128
     experts, 151,936 rows of vocabulary, the configuration's slots x 4096,
     bfloat16): each fits the chip beside what it is handed (under 15.5 GB
-    in all).  The step, a pass over ``(slots, 4)`` rows, reads every layer
-    through ``decode_attention`` at ``group`` 32 and writes twelve arrays'
-    runs of four rows with ``slot_write``; no ``(slots, 4, 32, 4096)``
-    scores, no update-slice, no copy of a cache-sized array; its 384
-    rows go through the kernel ``grouped_product`` in every layer, so it
-    holds no ``(384, 128, 768)`` product of every expert over every row.
+    in all, and under the chip's 16 GB with the temporaries of the second
+    step the loop keeps dispatched).  The step, a pass over ``(slots, 8)``
+    rows (a slot's pending block and its open one), reads every layer
+    through ONE ``decode_attention`` at ``group`` 64, a horizon a half, and
+    writes twelve arrays' runs of four rows with ``slot_write`` twice each;
+    no ``(slots, 4, 64, 4096)`` scores, no update-slice, no copy of a
+    cache-sized array; its 768 rows go through the kernel
+    ``grouped_product`` in every layer, so it holds no ``(768, 128, 768)``
+    product of every expert over every row, and the head's logits are the
+    open blocks' ``(384, vocab)`` alone.
     A prefill calls ``flash_attention`` under the blocked mask for every
     layer but the last, whose output nothing reads, holds no ``(heads, P,
     P)`` scores, and from 512 rows takes the grouped product by the
@@ -805,7 +810,7 @@ def _sdar_case(which):
             config, _one_chip())
         s = config["engine"]["slots"]
         assert {a.shape for a in state[0]} == {(s, 4, 4096, 128)}
-        assert state[2].shape == (s, 4) and len(state) == 11
+        assert state[2].shape == (s, 4) and len(state) == 13
         with _tpu_trace():
             if which == "step":
                 compiled = engine._step_fn.lower(params, state, keep,
@@ -820,13 +825,26 @@ def _sdar_case(which):
         text = compiled.as_text()
         assert "ragged" not in text
         if which == "step":
-            assert text.count("tpu_custom_call") == 6 + 2 * 6 + 6
+            # a layer: one decode_attention, K and V each written twice,
+            # one grouped product
+            assert text.count("tpu_custom_call") == 6 + 4 * 6 + 6
+            for kernel, calls in (("decode_attention", 6),
+                                  ("slot_write", 24)):
+                assert len(re.findall(r"(?m)^\s*%%%s\S* = .* custom-call\("
+                                      % kernel, text)) == calls, kernel
             assert text.count("grouped_product") >= 6
-            assert "[%d,128,768]" % (4 * s) not in text
+            for rows in (4 * s, 8 * s):
+                assert "[%d,128,768]" % rows not in text
             assert "dynamic-update-slice" not in text
-            assert "f32[%d,4,32,4096]" % s not in text
+            assert "f32[%d,4,64,4096]" % s not in text
             # the logits of 4 rows a slot and what the tail makes of them
             assert ma.temp_size_in_bytes < 0.6e9, ma.temp_size_in_bytes
+            assert "[%d,151936]" % (4 * s) in text
+            assert "[%d,151936]" % (8 * s) not in text
+            # the loop keeps two steps dispatched: the second one's
+            # temporaries beside the first one's whole
+            assert ma.argument_size_in_bytes + ma.output_size_in_bytes \
+                - ma.alias_size_in_bytes + 2 * ma.temp_size_in_bytes < 16e9
         else:
             assert text.count("flash_attention") >= 5
             # five layers' experts (the sixth layer's run in no prefill)
