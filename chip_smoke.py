@@ -8,8 +8,9 @@ the entry points a user calls, on one TPU chip:
   --benchmark 1`` runs: ``common/fit.py:fit`` -> ``Module.fit`` on
   ``mx.tpu(0)``, ResNet-50 at 224x224, batch 128 float32, SGD+momentum,
   device metrics, the synthetic iterator's 100 steps;
-* **bulk** — ``bench.setup()`` and two ``Module.run_bulk`` bulks at b128
-  bf16 (the ``train_sgd_scan`` executor kind ``bench.py`` times);
+* **bulk** — two ``Module.run_bulk`` bulks of ten full training steps at
+  b128 bf16, each one XLA computation (the ``train_sgd_scan`` executor
+  kind, and the only bfloat16 training path there is);
 * **serve** — ``serving.save_model`` -> ``ModelRegistry`` ->
   ``ServingHTTPServer`` with ResNet-50 at its declared buckets, ``/predict``
   over HTTP against ``Module.predict`` on the same rows, ``/healthz`` and
@@ -289,24 +290,55 @@ def phase_fit(ctx, num_layers=50, image_shape=(3, 224, 224),
 def phase_bulk(ctx, num_layers=50, image_shape=(3, 224, 224),
                num_classes=1000, batch=128, bulk=10, dtype="bfloat16",
                seed=0):
-    """``bench.setup()``, one bulk of the program ``bench.py`` times and
-    one with the per-step outputs kept, for the losses."""
+    """Full training steps (forward, backward, SGD with momentum) through
+    ``Module.run_bulk``: one bulk of ``bulk`` steps scanned inside one XLA
+    computation, and one with the per-step outputs kept, for the losses."""
+    # fwd+bwd+update as ONE XLA dispatch with donated param buffers
+    os.environ.setdefault("MXNET_FUSE_TRAIN_STEP", "1")
     import numpy as np
 
-    import bench
     import mxnet_tpu as mx
     from mxnet_tpu import io as mxio
+    from mxnet_tpu.models import resnet
 
     mx.random.seed(seed)
+    data_shape = (batch,) + tuple(image_shape)
+
+    def batch_of(data, label):
+        return mxio.DataBatch(
+            data=[mx.nd.array(data.astype(np.float32), ctx=ctx, dtype=dtype)],
+            label=[mx.nd.array(label.astype(np.float32), ctx=ctx)])
+
     with Accounting("bulk", ctx.jax_device()) as acct:
-        mod, run, sync = bench.setup(
-            num_layers=num_layers, image_shape=image_shape,
-            num_classes=num_classes, batch=batch, bulk=bulk, dtype=dtype,
-            ctx=ctx)
+        net = resnet.get_symbol(num_classes=num_classes,
+                                num_layers=num_layers,
+                                image_shape=tuple(image_shape))
+        rs = np.random.RandomState(seed)
+        warm = [batch_of(rs.rand(*data_shape),
+                         rs.randint(0, num_classes, batch))
+                for _ in range(bulk)]
+        mod = mx.mod.Module(net, context=ctx)
+        mod.bind(data_shapes=[("data", data_shape)],
+                 label_shapes=[("softmax_label", (batch,))])
+        mod.init_params(mx.init.Xavier(rnd_type="gaussian",
+                                       factor_type="in", magnitude=2))
         ex = mod._exec
+        # bf16 params/activations; BatchNorm stats stay f32 inside the op.
+        # Module has no dtype argument: this in-place cast after init_params
+        # is the only bf16 training path there is
+        if dtype != "float32":
+            for n, a in ex.arg_dict.items():
+                if n != "softmax_label":
+                    a._jx = a._jx.astype(dtype)
+        mod.init_optimizer(kvstore=None, optimizer="sgd",
+                           optimizer_params={"learning_rate": 0.05,
+                                             "momentum": 0.9, "wd": 1e-4})
         first = np.asarray(ex.arg_dict["fc1_weight"]._jx, np.float32)
-        run(bulk)
-        sync()
+        mod.run_bulk(warm)
+        # a 1-element host read of a just-updated param is the cheap TRUE
+        # device barrier (reading the whole buffer would copy MBs to the
+        # host); the last step's update depends on every step before it
+        np.asarray(ex.arg_dict["conv0_weight"]._jx.reshape(-1)[:1])
         kinds = {k[0][0] for k in ex._fns if isinstance(k[0], tuple)}
         _check("train_sgd_scan" in kinds,
                "run_bulk did not take the train_sgd_scan kind: %r" % kinds)
@@ -314,12 +346,9 @@ def phase_bulk(ctx, num_layers=50, image_shape=(3, 224, 224),
         # (K, batch, classes) softmax rows against the labels kept here
         rs = np.random.RandomState(seed + 1)
         labels = [rs.randint(0, num_classes, batch) for _ in range(bulk)]
-        batches = [mxio.DataBatch(
-            data=[mx.nd.array(rs.rand(batch, *image_shape)
-                              .astype(np.float32), ctx=ctx, dtype=dtype)],
-            label=[mx.nd.array(lab.astype(np.float32), ctx=ctx)])
-            for lab in labels]
-        (probs,) = mod.run_bulk(batches, return_outputs=True)
+        (probs,) = mod.run_bulk(
+            [batch_of(rs.rand(*data_shape), lab) for lab in labels],
+            return_outputs=True)
         probs = np.asarray(probs, np.float32)
         losses = [float(-np.log(p[np.arange(batch), lab] + 1e-8).mean())
                   for p, lab in zip(probs, labels)]

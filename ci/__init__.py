@@ -1,3 +1,3 @@
 """CI tooling package — makes ``python -m ci.graftlint`` runnable from
 the repo root and the ``ci/check_*.py`` scripts importable as modules
-(``ci.check_bench_gate`` etc.) for graftlint's orchestrated passes."""
+(``ci.check_compile_cache``) for graftlint's orchestrated pass."""

@@ -5,8 +5,7 @@ The baseline ledger (``ci/graftlint/baseline.json``) exists so a NEW
 pass can land before its pre-existing findings are triaged — but nothing
 stopped entries from quietly living there forever: ``--update-baseline``
 is one command, and a baselined finding never fails the build again.
-This guard (mirroring the bench-gate waiver workflow in
-``ci/check_bench_gate.py`` / docs/observability.md) closes that hole:
+This guard closes that hole:
 at HEAD the ledger must be EMPTY, unless every entry carries a
 ``waiver`` field saying who accepted the debt and why::
 

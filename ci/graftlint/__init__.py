@@ -10,9 +10,8 @@ The five historical ``ci/check_*.py`` lint scripts were removed after
 their deprecation cycle (graftlint v2): run the migrated passes with
 ``--pass bare-except`` / ``print`` / ``env-docs`` / ``host-sync`` /
 ``signal-restore`` instead.  Legacy suppression comments (``# noqa``,
-``# host-sync: ok``) are still honored forever.  ``check_bench_gate`` /
-``check_compile_cache`` stay full scripts but are also exposed as
-orchestrated passes.
+``# host-sync: ok``) are still honored forever.  ``check_compile_cache``
+stays a full script but is also exposed as an orchestrated pass.
 """
 
 from __future__ import annotations
@@ -71,8 +70,8 @@ def main(argv=None):
     parser.add_argument("--pass", dest="passes", action="append",
                         metavar="ID",
                         help="run only this pass (repeatable); "
-                             "orchestrated passes (bench-gate, "
-                             "compile-cache) only run when named here")
+                             "the orchestrated pass (compile-cache) "
+                             "only runs when named here")
     parser.add_argument("--changed", nargs="?", const="HEAD",
                         metavar="REV",
                         help="diff-scoped fast lane: only report on "

@@ -163,7 +163,7 @@ class Pass:
     excluded_files = frozenset()
     #: legacy suppression comments (exact substrings) still honored
     legacy_tags = ()
-    #: orchestrated passes run an external workload (subprocess bench /
+    #: orchestrated passes run an external workload (subprocess
     #: cache probes) instead of analyzing sources — opt-in only
     orchestrated = False
     #: interprocedural passes analyze the whole collected tree at once

@@ -1,8 +1,8 @@
 """Pass registry — every pass, in the order the runner executes them.
 
 The five migrated syntactic passes first (cheapest), then the four
-dataflow passes, then the opt-in orchestrated runners (excluded from
-the default set; see their module docstring)."""
+dataflow passes, then the opt-in orchestrated runner (excluded from
+the default set; see its module docstring)."""
 
 from __future__ import annotations
 
@@ -13,7 +13,7 @@ from .env_docs import EnvDocsPass
 from .event_docs import EventDocsPass
 from .host_sync import HostSyncPass
 from .lock_discipline import LockDisciplinePass
-from .orchestrated import BenchGatePass, CompileCachePass
+from .orchestrated import CompileCachePass
 from .print_call import PrintPass
 from .recompile_hazard import RecompileHazardPass
 from .replica_divergence import ReplicaDivergencePass
@@ -37,12 +37,11 @@ ALL_PASSES = (
     ReplicaDivergencePass,
     SpecShapePass,
     StateProtocolPass,
-    BenchGatePass,
     CompileCachePass,
 )
 
 #: the default ``python -m ci.graftlint`` set: every source-analysis
-#: pass; orchestrated runners are opt-in by name
+#: pass; the orchestrated runner is opt-in by name
 DEFAULT_PASSES = tuple(p for p in ALL_PASSES if not p.orchestrated)
 
 

@@ -1,57 +1,31 @@
-"""Orchestrated passes — CI runners re-exposed through graftlint.
+"""Orchestrated pass — a CI runner re-exposed through graftlint.
 
-``check_bench_gate`` and ``check_compile_cache`` are not source
-analyzers: one gates checked-in bench rows, the other runs a fit+predict
-workload twice in subprocesses.  They keep their scripts (and their
-run_tests.sh slots/gating) but are ALSO addressable as graftlint passes
-(``--pass bench-gate`` / ``--pass compile-cache``) so one entry point
-can drive the whole lint surface and one JSON artifact can report it.
-They are excluded from the default pass set: the compile-cache probe
-alone costs two subprocess jax sessions, far past the <30 s lint
-budget."""
+``check_compile_cache`` is not a source analyzer: it runs a fit+predict
+workload twice in subprocesses.  It keeps its script (and its
+run_tests.sh slot) but is ALSO addressable as a graftlint pass
+(``--pass compile-cache``) so one entry point can drive the whole lint
+surface and one JSON artifact can report it.  It is excluded from the
+default pass set: the probe costs two subprocess jax sessions, far past
+the <30 s lint budget."""
 
 from __future__ import annotations
 
 from ..core import Finding, Pass
 
 
-class _ScriptPass(Pass):
-    orchestrated = True
-    script_module = None  # "ci.check_bench_gate"
-    script_argv = ()
-
-    def run(self, sources, ctx):
-        import importlib
-
-        mod = importlib.import_module(self.script_module)
-        rc = mod.main(list(self.script_argv)) \
-            if self._takes_argv(mod) else mod.main()
-        if rc:
-            rel = self.script_module.replace(".", "/") + ".py"
-            return [Finding(
-                self.id, rel, 0, "orchestrated-failure",
-                "%s failed with exit status %r (its own output above "
-                "has the details)" % (self.script_module, rc))]
-        return []
-
-    @staticmethod
-    def _takes_argv(mod):
-        import inspect
-
-        try:
-            return len(inspect.signature(mod.main).parameters) > 0
-        except (TypeError, ValueError):  # builtins/C — be permissive
-            return False
-
-
-class BenchGatePass(_ScriptPass):
-    id = "bench-gate"
-    title = "no unwaived bench regressions vs best"
-    script_module = "ci.check_bench_gate"
-    script_argv = ()
-
-
-class CompileCachePass(_ScriptPass):
+class CompileCachePass(Pass):
     id = "compile-cache"
     title = "second run against a warm cache compiles nothing"
-    script_module = "ci.check_compile_cache"
+    orchestrated = True
+
+    def run(self, sources, ctx):
+        from ... import check_compile_cache
+
+        rc = check_compile_cache.main()
+        if rc:
+            return [Finding(
+                self.id, "ci/check_compile_cache.py", 0,
+                "orchestrated-failure",
+                "ci.check_compile_cache failed with exit status %r (its "
+                "own output above has the details)" % (rc,))]
+        return []

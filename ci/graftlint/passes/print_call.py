@@ -2,8 +2,8 @@
 
 Migrated from ``ci/check_print.py`` (shim removed after its deprecation cycle).  Framework
 output flows through logging or telemetry; a stray print pollutes
-stdout, which bench.py's one-JSON-line contract and launcher scrapers
-treat as machine-readable.  ``visualization.py`` is exempt wholesale
+stdout, which ``benchmark/run.py``'s and ``chip_smoke.py``'s JSON-line
+contracts and launcher scrapers treat as machine-readable.  ``visualization.py`` is exempt wholesale
 (its prints are the feature); legacy ``# noqa`` honored."""
 
 from __future__ import annotations

@@ -1,6 +1,8 @@
 #!/bin/sh
 # Local CI entry point (the reference's tests/travis/run_test.sh analog):
-# lint-lite -> native build -> unit suite -> multichip dryrun.
+# byte-compile -> graftlint + baseline guard -> native build -> unit suite
+# with its pinned skips -> multichip dryrun -> mesh smoke -> trace smoke ->
+# compile-cache check -> (CHAOS=1) kill/resume chaos matrix.
 #
 #   sh ci/run_tests.sh precommit   # fast lane: diff-scoped lint only
 #
@@ -28,11 +30,11 @@ python -m compileall -q mxnet_tpu tools example
 # '# lint: ok[pass-id] <reason>' suppression grammar and the per-pass
 # baselines.  The JSON findings report lands at /tmp/graftlint.json as
 # a CI artifact, and per-pass finding counts export through telemetry
-# (lint.findings gauges) so PROGRESS/bench tooling can track lint debt.
+# (lint.findings gauges) so lint debt can be tracked.
 python -m ci.graftlint --json /tmp/graftlint.json --emit-telemetry
 # baseline-debt guard: the ledger must be empty at HEAD unless every
-# entry carries a documented waiver (mirrors the bench-gate waiver
-# workflow) — baseline debt cannot silently accrete.
+# entry carries a documented waiver: baseline debt cannot silently
+# accrete.
 python ci/check_lint_baseline.py
 if command -v g++ > /dev/null; then
   g++ -O2 -shared -fPIC -std=c++17 -o libmxnet_tpu_native.so \
@@ -94,14 +96,6 @@ python ci/check_trace_smoke.py
 # identities re-introduce cold warm-up costs in serving/CI/resume.
 # (also runnable as the orchestrated graftlint pass 'compile-cache')
 python ci/check_compile_cache.py
-# bench regression gate: fail on BENCH_extra.json rows regressed >5%
-# vs best without a recorded waiver — opt-in (BENCH_GATE=1) because the
-# snapshot is only refreshed on bench hosts; see docs/observability.md
-# "Bench regression gate" for the waiver workflow.
-# (also runnable as the orchestrated graftlint pass 'bench-gate')
-if [ "${BENCH_GATE:-0}" = "1" ]; then
-  python ci/check_bench_gate.py
-fi
 # kill/resume chaos matrix (5x rotating seeds) — opt-in, it multiplies
 # suite time: CHAOS=1 sh ci/run_tests.sh
 if [ "${CHAOS:-0}" = "1" ]; then

@@ -7,8 +7,8 @@ prefixes, ``--kv-store device`` default, ``--test-io`` IO-throughput mode,
 ``--benchmark`` synthetic-data mode.  TPU notes: ``--kv-store device``
 maps to an in-XLA allreduce over the chip mesh.  Training is float32:
 ``Module`` has no dtype argument, so ``--dtype bfloat16`` is refused
-with the path that is missing (``bench.py`` casts the bound arrays in
-place after ``init_params``; nothing public does).
+with the path that is missing (``chip_smoke.py``'s bulk phase casts the
+bound arrays in place after ``init_params``; nothing public does).
 """
 
 import argparse
@@ -102,7 +102,8 @@ def fit(args, network, data_loader, **kwargs):
         raise NotImplementedError(
             "--dtype %s: Module.bind/init_params create float32 "
             "parameters whatever the iterator's dtype, and there is no "
-            "public cast (bench.py rewrites Executor.arg_dict in place)"
+            "public cast (chip_smoke.py's bulk phase rewrites "
+            "Executor.arg_dict in place)"
             % args.dtype)
     kv = mx.kvstore.create(args.kv_store)
     logging.basicConfig(level=logging.INFO,

@@ -8,7 +8,7 @@
  * builds sit on.  Signatures mirror the reference's (float I/O, uint32
  * shape indptr encoding).
  *
- * Implementation note (the explicit ABI stance, VERDICT r1 missing #5):
+ * Implementation note (the explicit ABI stance):
  * the compute path of this framework is XLA driven through the Python
  * package, so libmxnet_tpu_predict embeds the CPython interpreter — the
  * same one-runtime/N-frontends shape as the reference where every binding

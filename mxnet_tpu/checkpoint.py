@@ -655,8 +655,8 @@ class AsyncSnapshotWriter:
         self.prefix = prefix
         self.keep_last = keep_last
         self.logger = logger
-        #: sync=True serializes inline in submit() — the benchmark
-        #: baseline (bench_extra.py ckpt_score) and a debugging aid
+        #: sync=True serializes inline in submit(): what the async
+        #: path is compared with, and a debugging aid
         self.sync = sync
         self._cv = threading.Condition()
         self._slot = None

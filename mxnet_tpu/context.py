@@ -141,8 +141,8 @@ def num_tpus():
 
 
 def measurement_context():
-    """Context for entry points whose output is a measurement (``bench.py``,
-    ``bench_extra.py``, ``benchmark_score.py``): the chip, and a loud
+    """Context for entry points whose output is a measurement (the benchmark's
+    ``module_fit`` family, ``benchmark_score.py``): the chip, and a loud
     failure when there is none.  The CPU is used only when the caller
     names it with ``JAX_PLATFORMS=cpu`` (as the tests do) — never as a
     silent fallback whose numbers would pass for the chip's."""

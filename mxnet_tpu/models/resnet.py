@@ -4,8 +4,8 @@ model).
 
 TPU notes: the symbol carries no dtype — ``get_symbol`` has no ``dtype``
 argument and ``Module`` binds float32.  A bf16 step exists only where the
-caller casts the bound arrays itself (``bench.py``); BN stats then stay
-f32 inside the op.
+caller casts the bound arrays itself (``chip_smoke.py``'s bulk phase);
+BN stats then stay f32 inside the op.
 """
 
 from .. import symbol as sym

@@ -620,8 +620,8 @@ class Module(BaseModule):
         Opt-in via ``MXNET_FUSE_TRAIN_STEP=1``: one dispatch per step
         instead of two (not measured on the present machine).  The library
         default stays two-phase because the fused path restricts what get_outputs/
-        get_input_grads can observe mid-step; bench.py and throughput-
-        sensitive training loops should set the flag.  Numerics are
+        get_input_grads can observe mid-step; throughput-sensitive loops
+        (``chip_smoke.py``'s bulk phase) set the flag.  Numerics are
         identical either way (see
         tests/test_module.py::test_fused_full_step_matches_two_phase).
         """

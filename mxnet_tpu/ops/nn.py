@@ -339,9 +339,9 @@ def _batch_norm(attrs, inputs, aux, is_train, rng, act_type=None):
         # elementwise read pass.  For bf16 activations the read stays
         # bf16: materializing x.astype(f32) made XLA emit a second
         # full-size f32 copy of every conv output as a fusion epilogue
-        # (+wider reduce reads) — measured
-        # ~4 ms/step of pure bandwidth on ResNet-50 b128 (per-HLO
-        # profile, tools/perf/step_profile.py).  The probe-shift below
+        # (+wider reduce reads): pure bandwidth, paid at every
+        # BatchNorm of a ResNet step.
+        # The probe-shift below
         # bounds the bf16 rounding of d to ~2^-8 relative of the
         # *deviation*, and round-to-nearest is unbiased, so the
         # batch-mean/var error vanishes as 1/sqrt(N) — validated by the

@@ -1,8 +1,8 @@
 """Performance attribution + crash flight recorder.
 
-BENCH regressions used to be unexplainable: the framework could time
+A regression used to be unexplainable: the framework could time
 *phases* (telemetry ``fit.phase_seconds``) but not attribute cost — a
-"resnet-50 inference is 38% slower than best" row said nothing about
+"resnet-50 inference is slower than it was" reading said nothing about
 WHICH compiled executable got slower or bigger.  TVM's premise (Chen et
 al., 2018) is that op-level cost profiles are the prerequisite for any
 fusion/layout tuning; this module is that layer for the XLA executor:
@@ -21,8 +21,7 @@ fusion/layout tuning; this module is that layer for the XLA executor:
   event, ``perf.fingerprint_changes`` counter).  "Regression vs best"
   becomes "these 2 of 7 executables changed".
   :func:`save_fingerprints` / :func:`diff_fingerprints` compare across
-  runs/commits; ``bench.py`` / ``bench_extra.py`` persist per-model
-  fingerprints in their JSON rows for the same purpose.
+  runs/commits.
 * **Live MFU / HBM gauges** — :func:`note_throughput` (called by
   ``Speedometer`` at its log cadence, no extra syncs) combines the
   latest train-step executable's measured flops with the chip's rated
@@ -65,7 +64,7 @@ from . import tracing as _tracing
 from .base import atomic_write
 
 __all__ = [
-    "enabled", "enable", "disable", "capture", "analyze_signature",
+    "enabled", "enable", "disable", "capture",
     "instrument", "report", "report_text", "fingerprints", "changes",
     "save_fingerprints", "diff_fingerprints", "reset",
     "device_peak_tflops", "step_flops", "note_throughput",
@@ -174,30 +173,24 @@ _MEM_FIELDS = (
 )
 
 
-def _analyze_compiled(lowered):
+def _analyze_lowered(lowered):
     """(fingerprint, flops, bytes_accessed, hbm_breakdown) of one
-    lowered program, compiled for the cost/memory numbers.  Raises what
-    the compiler raises."""
+    lowered program, compiled for the cost/memory numbers.  The live
+    capture hook must never raise into the step: a program that will not
+    compile or cost out keeps its fingerprint and loses the numbers."""
     fp = fingerprint_text(lowered.as_text())
-    compiled = lowered.compile()
-    cost = compiled.cost_analysis() or {}
-    m = compiled.memory_analysis()
-    mem = {name: int(getattr(m, attr)) for name, attr in _MEM_FIELDS}
+    try:
+        compiled = lowered.compile()
+        cost = compiled.cost_analysis() or {}
+        m = compiled.memory_analysis()
+        mem = {name: int(getattr(m, attr)) for name, attr in _MEM_FIELDS}
+    except Exception as e:  # noqa: broad-except — attribution only
+        _log.debug("perfdebug: cost/memory analysis failed: %s", e)
+        return fp, None, None, {}
     flops = float(cost["flops"]) if cost.get("flops") else None
     bytes_accessed = float(cost["bytes accessed"]) \
         if cost.get("bytes accessed") else None
     return fp, flops, bytes_accessed, mem
-
-
-def _analyze_lowered(lowered):
-    """:func:`_analyze_compiled` for the live capture hook, which must
-    never raise into the step: a program that will not compile or cost
-    out keeps its fingerprint and loses the numbers."""
-    try:
-        return _analyze_compiled(lowered)
-    except Exception as e:  # noqa: broad-except — attribution only
-        _log.debug("perfdebug: cost/memory analysis failed: %s", e)
-        return fingerprint_text(lowered.as_text()), None, None, {}
 
 
 def _hbm_total(mem):
@@ -315,25 +308,6 @@ def _capture(name, kind, lower_fn, args, kwargs):
     _refresh_hbm_gauge()
     _telemetry.observe("perf.attrib_seconds", time.perf_counter() - t0)
     return entry
-
-
-def analyze_signature(sig):
-    """One-shot attribution of an abstract call signature ``(fn,
-    abstract_args)`` — the shape ``Module._last_bulk_sig`` stores.  Used
-    by the bench harnesses to stamp ``hlo_fingerprint`` /
-    ``cost_gflops`` / ``hbm_peak_bytes`` onto their JSON rows; one
-    lower+compile covers fingerprint AND cost.  A measurement rests on
-    these numbers, so a program that will not lower, compile or yield a
-    flop count raises instead of returning a partial dict."""
-    fn, args = sig
-    fp, flops, bytes_accessed, mem = _analyze_compiled(fn.lower(*args))
-    if not flops:
-        raise RuntimeError(
-            "XLA cost analysis reports no flops for the compiled program "
-            "(fingerprint %s)" % fp)
-    return {"fingerprint": fp, "flops": flops,
-            "bytes_accessed": bytes_accessed, "hbm": mem,
-            "hbm_peak_bytes": _device_peak_bytes() or _hbm_total(mem)}
 
 
 class _FirstCallHook:

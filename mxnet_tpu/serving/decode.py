@@ -89,7 +89,7 @@ the device a step late, a finished session's slot is re-admitted a step
 late, and a device fault surfaces a dispatch late — when every unread
 step is dropped whole, so a transcript never differs from what
 ``on_token`` saw.  The streams are those of a plain serial loop over the
-same programs (``tools/perf/serial_loop.py``).
+same programs (``tests/serial_loop.py``).
 
 **The model protocol.**  The engine learns nothing of an architecture; it
 is given a *model object* and asks it for four things:

@@ -287,7 +287,7 @@ class phase:
 def phase_totals(family="fit"):
     """``{phase: (sum_seconds, count)}`` for one family's phase
     histograms — the per-phase step-time breakdown consumers
-    (``TelemetryReport``, ``bench.py``) read."""
+    (``TelemetryReport``, the flight recorder) read."""
     name = family + ".phase_seconds"
     out = {}
     with _lock:

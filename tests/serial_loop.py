@@ -12,7 +12,7 @@ Run as a script it makes the same comparison on the chip at a benchmark
 configuration of the K-EXAONE family (the weights are the benchmark
 reference's, drawn from ``--seed``):
 
-    chiprun -- python tools/perf/serial_loop.py --sessions 64
+    chiprun -- python tests/serial_loop.py --sessions 64
 
 Phase 1 queues ``--sessions`` requests on a stopped engine and starts it, so
 the engine and the serial loop launch the same programs in the same order

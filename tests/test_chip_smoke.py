@@ -3,6 +3,7 @@
 four virtual devices, and the script's own verdict without a chip.  The
 sizes the chip runs, and the chip, are the builder's and the driver's."""
 
+import glob
 import json
 import os
 import subprocess
@@ -55,8 +56,8 @@ def test_phase_fit():
 
 
 def test_phase_bulk(monkeypatch):
-    # bench.setup() turns the fused step on for its process; keep that
-    # from leaking into whatever this worker runs next
+    # the phase turns the fused step on for its process; keep that from
+    # leaking into whatever this worker runs next
     monkeypatch.setenv("MXNET_FUSE_TRAIN_STEP", "1")
     out = chip_smoke.phase_bulk(mx.cpu(), batch=4, bulk=2, **TINY_RESNET)
     assert out["executor_kinds"] == ["train_sgd_scan"]
@@ -142,7 +143,7 @@ _NO_BACKEND = r"""
 import sys
 sys.argv = ["probe"]
 import mxnet_tpu, mxnet_tpu.io, mxnet_tpu.models, mxnet_tpu.serving
-import bench, bench_extra, chip_smoke
+import chip_smoke
 from mxnet_tpu.sentinel import Supervisor
 
 rc = Supervisor([sys.executable, "-c", "print('child ran')"]).run()
@@ -158,10 +159,10 @@ except RuntimeError as e:
 def test_parents_of_chip_children_initialise_no_backend():
     """One process per chip: whatever starts a child that may need the
     chip must not have taken it.  Under a platform name JAX does not
-    know, ANY backend initialisation raises — so importing the package,
-    the bench scripts and the smoke, and supervising a child
-    (``sentinel.Supervisor``, what ``tools/supervise.py`` runs), passing
-    here proves none of them touches a backend."""
+    know, ANY backend initialisation raises — so importing the package and
+    the smoke, and supervising a child (``sentinel.Supervisor``, what
+    ``tools/supervise.py`` runs), passing here proves none of them touches
+    a backend."""
     proc = subprocess.run(
         [sys.executable, "-c", _NO_BACKEND],
         env=dict(os.environ, JAX_PLATFORMS="no_such_platform",
@@ -170,3 +171,29 @@ def test_parents_of_chip_children_initialise_no_backend():
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "supervised child rc 0" in proc.stdout
     assert "a backend init raises" in proc.stdout
+
+
+_IMPORT_TOOL = r"""
+import importlib.util, sys
+path = sys.argv[1]
+sys.argv = [path]
+spec = importlib.util.spec_from_file_location("tool", path)
+spec.loader.exec_module(importlib.util.module_from_spec(spec))
+"""
+
+
+@pytest.mark.parametrize("tool", sorted(
+    os.path.basename(p)
+    for p in glob.glob(os.path.join(ROOT, "tools", "perf", "*.py"))))
+def test_tools_perf_import_without_a_backend(tool):
+    """``tools/perf/README.md``'s rule: a hand tool stays while it runs on
+    this tree.  Each imports, from the root as a chip call runs it, against
+    the code that is there, and touches no backend while it does (a tool
+    that starts children for the chip must not hold it)."""
+    proc = subprocess.run(
+        [sys.executable, "-c", _IMPORT_TOOL,
+         os.path.join("tools", "perf", tool)],
+        env=dict(os.environ, JAX_PLATFORMS="no_such_platform",
+                 PYTHONPATH=ROOT), cwd=ROOT, capture_output=True, text=True,
+        timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]

@@ -27,7 +27,7 @@ from mxnet_tpu.serving import (DeadlineExceeded, DecodeEngine,
                                InvalidRequest, ModelRegistry, Overloaded,
                                QuotaExceeded, ReplicaPool,
                                ServingHTTPServer, lm_pool)
-from tools.perf.serial_loop import serial_loop
+from serial_loop import serial_loop
 
 # tiny LM: every compile stays sub-second on the CPU CI host
 VOCAB, EMBED, HEADS, LAYERS, FFN, MAX_LEN = 32, 16, 2, 2, 32, 32
@@ -558,7 +558,7 @@ def _as_built(eng):
 
 
 def _serial_reference(eng, temperature):
-    """``REQUESTS`` through ``tools/perf/serial_loop.py``'s plain serial
+    """``REQUESTS`` through ``tests/serial_loop.py``'s plain serial
     loop over a stopped engine's own ``jit_prefill`` and ``jit_step``:
     what the engine's loop has to equal, session by session."""
     key = (eng.kv_layout, temperature)
@@ -921,7 +921,7 @@ def test_acceptance_64_concurrent_generate_compile_arithmetic():
     srv = ServingHTTPServer(reg, port=0).start()
     rs = np.random.RandomState(0)
     # prompts pre-drawn before the threads start: RandomState is not
-    # thread-safe (same rule bench_extra.py documents)
+    # thread-safe
     prompts = [[int(t) for t in
                 rs.randint(0, VOCAB, size=1 + int(rs.randint(0, 8)))]
                for _ in range(64)]

@@ -192,7 +192,7 @@ def test_group2ctx_predict_and_aux():
 
 # -- Module.fit on an explicit mesh with TP shard_rules ---------------------
 def test_module_fit_on_mesh_with_tp_rules():
-    """VERDICT round-1 #6: `Module.fit` — not a second trainer class —
+    """`Module.fit` — not a second trainer class —
     runs dp×tp: params sharded by shard_rules train to the same weights
     as a plain single-device module."""
     _need_devices(8)

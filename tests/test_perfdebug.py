@@ -4,8 +4,7 @@ every executor kind ``Module.fit`` and ``Predictor`` use, HLO
 fingerprint stability across identical runs (and change detection
 across different ones), flight-recorder dumps on NaN trip / preemption
 / crash / serving drain, the live MFU gauge, the checkpoint queue-wait
-histogram, the serving trace spans, and the bench regression gate
-(``ci/check_bench_gate.py`` pass/fail/waiver)."""
+histogram, and the serving trace spans."""
 
 import glob
 import json
@@ -407,67 +406,6 @@ def test_serving_dispatch_and_http_spans(tmp_path):
     assert "serving:http:spanny" in names
 
 
-# -- bench regression gate --------------------------------------------------
-
-GATE = os.path.join(ROOT, "ci", "check_bench_gate.py")
-
-
-def _run_gate(*args):
-    return subprocess.run([sys.executable, GATE, *args],
-                          capture_output=True, text=True, timeout=300)
-
-
-def _bench_file(tmp_path, rows):
-    path = tmp_path / "bench.json"
-    path.write_text(json.dumps({"rows": rows}))
-    return str(path)
-
-
-def test_gate_passes_clean_file(tmp_path):
-    path = _bench_file(tmp_path, [
-        {"metric": "a", "value": 100.0, "unit": "images/sec"},
-        {"metric": "b", "value": 2.0, "unit": "sec/step",
-         "regression_vs_best_pct": 4.9}])  # under threshold
-    r = _run_gate(path)
-    assert r.returncode == 0, r.stdout + r.stderr
-
-
-def test_gate_fails_unwaived_regression(tmp_path):
-    path = _bench_file(tmp_path, [
-        {"metric": "slow", "value": 100.0, "latest_value": 60.0,
-         "unit": "images/sec", "regression_vs_best_pct": 40.0}])
-    r = _run_gate(path)
-    assert r.returncode == 1
-    assert "REGRESSED slow" in r.stdout
-    assert "waiver" in r.stdout  # the fix-or-waive hint
-
-
-def test_gate_passes_waived_regression(tmp_path):
-    path = _bench_file(tmp_path, [
-        {"metric": "slow", "value": 100.0, "latest_value": 60.0,
-         "unit": "images/sec", "regression_vs_best_pct": 40.0,
-         "waiver": "2026-08: known, ROADMAP item 2"}])
-    r = _run_gate(path)
-    assert r.returncode == 0
-    assert "waived" in r.stdout
-
-
-def test_gate_covers_stamp_dead_zone(tmp_path):
-    """bench_extra only stamps regression_vs_best_pct past 10%; the
-    gate computes the pct itself from value/latest_value so the 5..10%
-    band is enforced too."""
-    path = _bench_file(tmp_path, [
-        {"metric": "m", "value": 100.0, "latest_value": 92.0,
-         "unit": "images/sec"}])  # 8% down, NO stamped field
-    assert _run_gate(path).returncode == 1
-    assert _run_gate(path, "--threshold", "10").returncode == 0
-    # lower-is-better units invert the ratio
-    path2 = _bench_file(tmp_path, [
-        {"metric": "s", "value": 1.0, "latest_value": 1.08,
-         "unit": "sec/step"}])
-    assert _run_gate(path2).returncode == 1
-
-
 def test_flight_recorder_env_implies_telemetry(tmp_path):
     """An armed flight recorder over disabled telemetry would dump
     hollow files; arming via env at process start must enable the
@@ -482,48 +420,3 @@ def test_flight_recorder_env_implies_telemetry(tmp_path):
          "assert perfdebug.flight_enabled()"],
         capture_output=True, text=True, env=env, cwd=ROOT, timeout=300)
     assert r.returncode == 0, r.stderr
-
-
-def test_gate_threshold_flag(tmp_path):
-    path = _bench_file(tmp_path, [
-        {"metric": "m", "value": 100.0, "unit": "images/sec",
-         "regression_vs_best_pct": 12.0}])
-    assert _run_gate(path, "--threshold", "15").returncode == 0
-    assert _run_gate(path, "--threshold", "10").returncode == 1
-
-
-def test_gate_missing_file_is_noop(tmp_path):
-    r = _run_gate(str(tmp_path / "nope.json"))
-    assert r.returncode == 0
-
-
-def test_persist_waiver_survives_gate_band_and_sheds_on_recovery(
-        tmp_path, monkeypatch):
-    """A waiver on a 5..10% regression must NOT flap: bench_extra only
-    sheds it once the metric recovers inside the GATE's 5% tolerance,
-    not at its own 10% stamp threshold."""
-    monkeypatch.chdir(tmp_path)
-    if ROOT not in sys.path:
-        sys.path.insert(0, ROOT)
-    import bench_extra
-
-    def rows():
-        with open("BENCH_extra.json") as f:
-            return {r["metric"]: r for r in json.load(f)["rows"]}
-
-    with open("BENCH_extra.json", "w") as f:
-        json.dump({"rows": [{"metric": "m", "value": 100.0,
-                             "unit": "images/sec", "waiver": "known",
-                             "latest_hlo_fingerprint": "stalefp"}]}, f)
-    # 7% down: inside the gate band, under the 10% stamp threshold
-    bench_extra._persist({"metric": "m", "value": 93.0,
-                          "unit": "images/sec", "commit": "x", "ts": 1})
-    r = rows()["m"]
-    assert r["latest_value"] == 93.0
-    assert "regression_vs_best_pct" not in r
-    assert r["waiver"] == "known"          # still regressed: waiver kept
-    assert "latest_hlo_fingerprint" not in r  # no fingerprint this run
-    # recovered within the gate tolerance: waiver sheds
-    bench_extra._persist({"metric": "m", "value": 99.0,
-                          "unit": "images/sec", "commit": "x", "ts": 2})
-    assert "waiver" not in rows()["m"]

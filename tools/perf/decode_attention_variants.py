@@ -7,17 +7,16 @@ bfloat16), and the whole K-EXAONE decode step with it.  PERF.md section 6
 
     chiprun -- python tools/perf/decode_attention_variants.py [kernel] [step]
 
-``kernel``: the grid the kernel had up to PR 34 (a grid step a block of
-``max_len``, kept below for this comparison alone) against the walk it is
-now (one grid step a slot, chunks of ``chunk`` rows, the edge in pieces of
-``piece``) at several chunk sizes, over ragged lengths as the cell holds
-them, every slot full and every slot empty: the last two separate what a
-chunk that is read costs from what a slot costs whatever it holds.
+``kernel``: the walk the kernel is (one grid step a slot, chunks of
+``chunk`` rows, the edge in pieces of ``piece``) at the plan's own chunk
+and several others, against the same sum in plain XLA over every row, over
+ragged lengths as the cell holds them, every slot full and every slot
+empty: the last two separate what a chunk that is read costs from what a
+slot costs whatever it holds.
 ``step``: the step as the program has it against the step with every row
 read, timed as ``benchmark/tools/moe_step_variants.py`` times them.
 """
 
-import functools
 import json
 import os
 import sys
@@ -74,101 +73,6 @@ def _in_a_row(fn):
     return jax.jit(run)
 
 
-def _grid_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                 acc_scr, *, scale, block):
-    """The kernel up to PR 34: grid ``(slots, blocks of rows)``; a step
-    wholly above the slot's length does nothing."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    from mxnet_tpu.ops.attention import NEG_INF
-
-    j = pl.program_id(1)
-    length = len_ref[pl.program_id(0)]
-    last = length // block
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    def accumulate(edge):
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale
-        if edge:
-            at = j * block + jax.lax.broadcasted_iota(
-                jnp.int32, (1, 1, block), 2)
-            s = jnp.where(at <= length, s, NEG_INF)
-            rows = j * block + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block, 1), 1)
-            v = jnp.where(rows <= length, v, jnp.zeros_like(v))
-        m_prev = m_scr[...]
-        m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur)
-        l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
-        m_scr[...] = m_cur
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-
-    pl.when(j < last)(lambda: accumulate(False))
-    pl.when(j == last)(lambda: accumulate(True))
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finish():
-        o_ref[0] = acc_scr[...] / l_scr[...]
-
-
-def _grid(q, cache_k, cache_v, lengths, scale, block):
-    """The call up to PR 34: the steps above a slot's last block ask for
-    the next slot's first block, which is fetched once, ahead of its
-    turn."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    s, kv, g, d = q.shape
-
-    def rows_of(i, j, lens):
-        ahead = j > lens[i] // block
-        return (jnp.minimum(i + ahead, s - 1), 0, jnp.where(ahead, 0, j), 0)
-
-    def whole(i, j, lens):
-        return (i, 0, 0, 0)
-
-    return pl.pallas_call(
-        functools.partial(_grid_kernel, scale=scale, block=block),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(s, cache_k.shape[2] // block),
-            in_specs=[pl.BlockSpec((1, kv, g, d), whole),
-                      pl.BlockSpec((1, kv, block, d), rows_of),
-                      pl.BlockSpec((1, kv, block, d), rows_of)],
-            out_specs=pl.BlockSpec((1, kv, g, d), whole),
-            scratch_shapes=[pltpu.VMEM((kv, g, 1), jnp.float32),
-                            pltpu.VMEM((kv, g, 1), jnp.float32),
-                            pltpu.VMEM((kv, g, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((s, kv, g, d), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        name="decode_attention_grid",
-    )(lengths, q, cache_k, cache_v)
-
-
-def _grid_block(kv, d, rows):
-    """The block the plan gave up to PR 34: 1 MiB of bfloat16 K."""
-    block = 128
-    while block * 2 * kv * d * 2 <= 1 << 20 and rows % (block * 2) == 0:
-        block *= 2
-    return block
-
-
 def kernel_alone(cells):
     import jax
     import jax.numpy as jnp
@@ -189,11 +93,9 @@ def kernel_alone(cells):
                  np.minimum(rs.randint(lo, hi, (s,)), rows - 1),
                  "full": np.full((s,), rows - 1), "empty": np.zeros((s,))}
         row_bytes = 2 * kv * d * ck.dtype.itemsize
-        block = _grid_block(kv, d, rows)
         planned = attention._decode_chunk(ck), attention._DECODE_PIECE
-        _say(what="plan", cell=cell, chunk=planned[0], piece=planned[1],
-             parent_block=block)
-        variants = [("xla", None), ("grid", block)] \
+        _say(what="plan", cell=cell, chunk=planned[0], piece=planned[1])
+        variants = [("xla", None)] \
             + [("walk", w) for w in dict.fromkeys([planned] + WALKS)
                if w[0] <= rows
                and 4 * w[0] * kv * d * ck.dtype.itemsize <= 12 << 20]
@@ -205,9 +107,6 @@ def kernel_alone(cells):
                 if name == "xla":
                     fn = _in_a_row(lambda *a: attention._decode_xla(*a, scale))
                     read = s * rows
-                elif name == "grid":
-                    fn = _in_a_row(lambda *a: _grid(*a, scale, size))
-                    read = int((np.asarray(lengths) // size + 1).sum()) * size
                 else:
                     fn = _in_a_row(lambda *a: attention._decode_pallas(
                         *a, scale, *size))
