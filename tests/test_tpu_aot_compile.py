@@ -1059,12 +1059,99 @@ CASES["decode-step-phi-4-mini-flash-128-slots"] = _sambay_case("step")
 CASES["decode-prefill-1024-phi-4-mini-flash-128-slots"] = _sambay_case(1024)
 
 
+def _ssd_scan_case(positions):
+    """``ops.ssm.ssd_scan`` alone at the granite-4.0-h-micro prefill's
+    shapes (64 heads of 64, a state of 128 values a channel, float32; 1024
+    positions in chunks of 256, and the first bucket's one chunk of 128):
+    what the plan admits (eight heads' state a grid step) the compiler
+    takes: slices of one lane out of a block of eight, the ``(Q, Q)``
+    decays of a head, the float32 product with the carried state."""
+    def run():
+        from mxnet_tpu.ops import ssm
+
+        f32 = jnp.float32
+        t = positions
+        shapes = [((t, 4096), f32), ((t, 64), f32), ((64,), f32),
+                  ((t, 128), f32), ((t, 128), f32), ((64,), f32),
+                  ((128, 4096), f32)]
+        with _tpu_trace():
+            assert ssm.ssd_scan_plan(*(jax.ShapeDtypeStruct(*shapes[i])
+                                       for i in (0, 1, 3))) \
+                == ((8, min(t, 256)), None)
+            _compile(ssm.ssd_scan, *shapes)
+    return run
+
+
+for _positions in (128, 1024):
+    CASES["ssd_scan-%d-positions-64x64x128" % _positions] = \
+        _ssd_scan_case(_positions)
+
+
+def _granite_case(which):
+    """The engine's programs over ``models/granite_hybrid.py`` at
+    ``benchmark/configs/granite-4.0-h-micro.json``'s sizes (all 40 layers,
+    100,352 rows of vocabulary, 64 slots x 4096, bfloat16 weights and K/V,
+    float32 states): each fits the chip beside the 13.4 GB it is handed.
+    The step's four attention layers are a ``decode_attention`` and two
+    ``slot_write`` each over heads cached in pairs (no ``lanes`` refusal),
+    its 36 state updates plain fusions that copy no state; a prefill's
+    recurrences are 36 calls of ``ssd_scan``."""
+    def run():
+        from benchmark import harness
+        from benchmark.tools import aot_compile_granite_hybrid as tool
+
+        config = harness.load_json(os.path.join(
+            ROOT, "benchmark", "configs", tool.CONFIG + ".json"))
+        engine, params, state, keep, extra, sds = tool.engine_programs(
+            config, _one_chip())
+        assert config["engine"]["slots"] == 64
+        big = [a for side in state[:2] for a in side
+               if a.size * a.dtype.itemsize > 30e6]
+        assert sorted({a.shape for a in big}) == [
+            (64, 4, 4096, 128), (64, 128, 4096)]
+        assert len(big) == 36 + 2 * 4
+        with _tpu_trace():
+            if which == "step":
+                compiled = engine._step_fn.lower(params, state, keep,
+                                                 extra).compile()
+            else:
+                compiled = engine._prefill_fns[which].lower(
+                    *tool.prefill_shapes(params, state, which,
+                                         sds)).compile()
+        ma = compiled.memory_analysis()
+        assert 13.3e9 < ma.argument_size_in_bytes < 13.5e9
+        assert ma.argument_size_in_bytes + ma.output_size_in_bytes \
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes < 15.5e9
+        text = compiled.as_text()
+        if which == "step":
+            assert text.count("tpu_custom_call") == 3 * 4
+            for kernel, calls in (("decode_attention", 4),
+                                  ("slot_write", 8)):
+                assert len(re.findall(r"(?m)^\s*%%%s\S* = .* custom-call\("
+                                      % kernel, text)) == calls, kernel
+            assert "dynamic-update-slice" not in text
+            assert ma.temp_size_in_bytes < 0.2e9, ma.temp_size_in_bytes
+        else:
+            assert text.count("tpu_custom_call") == 36
+            assert text.count("ssd_scan") >= 36
+        copies = tool.cache_copies(text, (big, ()))
+        assert not copies, "%d copies of slot state, the first: %s" \
+            % (len(copies), copies[0][:200])
+    return run
+
+
+CASES["decode-step-granite-4.0-h-micro-64-slots"] = _granite_case("step")
+CASES["decode-prefill-128-granite-4.0-h-micro-64-slots"] = _granite_case(128)
+CASES["decode-prefill-1024-granite-4.0-h-micro-64-slots"] = \
+    _granite_case(1024)
+
+
 # -- the tests -----------------------------------------------------------------
 #: the engines' cases have a file a model family (``test_tpu_aot_<family>.py``:
 #: under ``--dist loadfile`` only a file can go to another worker); the
 #: kernels' own cases are this file's
 FAMILIES = ("gpt2-large", "k-exaone", "phi-4-mini-flash", "smallthinker",
-            "deepseek-v2", "sdar")
+            "deepseek-v2", "sdar", "granite-4.0-h-micro")
 
 
 def cases_of(family=None):
