@@ -37,6 +37,17 @@ owns the operational half the raw JAX knob lacks:
   "cold" below always means an actual ``backend.compile`` ran
   (= a persistent-cache miss, or the cache is off).
 
+**The executable store** (:func:`stored_program`), inside tier 1's
+directory and alive exactly when it is: the persistent cache's key is made
+from a program's lowered text, so a warm start still traced and lowered
+every program to find an executable that was already on disk.  For the
+programs a caller hands it (the decode engine's step and prefill buckets)
+the store keeps, under a key made WITHOUT tracing (the sources' digest, the
+versions, the device, the flags, the build's kind, the call's signature and
+the statics the program closes over), what is needed to load the cached
+executable and call it; a start that finds the entry loads it, recorded as
+a ``load`` in :func:`phases`, and neither traces nor lowers.
+
 **Tier 2 — AOT warm-up manifests.**  While recording
 (:func:`recording`, implied by tier 1), every executor jit build is
 noted with its full identity: executor name, kind, abstract call
@@ -64,10 +75,14 @@ See docs/how_to/perf.md "Compile once".
 
 from __future__ import annotations
 
+import functools
 import hashlib
+import inspect
 import json
 import logging
 import os
+import pickle
+import sys
 import threading
 import time
 from collections import deque
@@ -78,11 +93,11 @@ from . import faults as _faults
 from . import perfdebug as _perfdebug
 from . import telemetry as _telemetry
 from . import tracing as _tracing
-from .base import MXNetError, atomic_write
+from .base import MXNetError, atomic_write, atomic_write_bytes
 
 __all__ = [
     "DEFAULT_DIR", "enabled", "recording", "enable", "disable",
-    "cache_dir", "stats", "phases", "programs",
+    "cache_dir", "stats", "phases", "programs", "stored_program",
     "cache_entries", "cache_size_bytes", "gc", "verify", "note_build",
     "instrument", "records", "recording_scope", "reset_records",
     "manifest_path", "save_manifest", "save_manifest_if_changed",
@@ -107,6 +122,9 @@ DEFAULT_DIR = os.path.join(
 #: on every read — the recency signal :func:`gc` evicts by
 _CACHE_SUFFIX = "-cache"
 _ATIME_SUFFIX = "-atime"
+#: an entry of the executable store (:func:`stored_program`), beside them:
+#: ``mxstore-<key>-exec``, with the same ``-atime`` sidecar
+_STORE_SUFFIX = "-exec"
 
 _lock = threading.Lock()
 _dir = None            # active cache directory (None = tier 1 off)
@@ -137,6 +155,11 @@ _lowerings = 0
 _phases = deque(maxlen=4096)
 _evictions = 0
 _corrupt_dropped = 0
+_store_hits = 0        # first calls the executable store served
+_store_misses = 0      # ... and those it had no whole entry for
+_store_writes = 0
+_store_refused = {}    # program -> why the store would not keep it
+_config_names = None   # jax's options when the cache first came on
 
 _COUNTERS = (
     "xla.compile.persistent_cache_hits",
@@ -193,7 +216,7 @@ def enable(directory=None, max_bytes=None):
     decode verification with ``MXNET_COMPILE_CACHE_VERIFY=1``) and
     enforces the size bound.  Idempotent; safe to call after compiles
     already happened (JAX's cached "cache unused" verdict is reset)."""
-    global _dir, _max_bytes
+    global _dir, _max_bytes, _config_names
     import jax
     from jax._src import compilation_cache as _jcc
 
@@ -227,6 +250,13 @@ def enable(directory=None, max_bytes=None):
     # compiles that ran before enable() memoized "cache unused" — drop
     # that verdict (and any stale cache object) so this process caches
     _jcc.reset_cache()
+    if _config_names is None:
+        # the options jax has now, as a rule at the package's import: a
+        # module imported later defines more (a kernel's first trace
+        # imports Pallas and its three), and they must not move the
+        # executable store's key between a start that traces a program
+        # and one that loads it
+        _config_names = frozenset(jax.config.values)
     with _lock:
         _dir = directory
         _max_bytes = max(0, int(max_bytes or 0))
@@ -426,6 +456,9 @@ def _install_read_fault_shim():
     def _guarded(cache_key, compile_options, backend, executable_devices):
         if _dir is not None and _faults.should_fire("compile_cache.read"):
             _truncate_entry(cache_key)
+        # the executable store keeps this key beside what it pickles of
+        # the program (_StoredProgram.first)
+        _tls.cache_key = cache_key
         try:
             return _orig_get(cache_key, compile_options, backend,
                              executable_devices)
@@ -446,7 +479,7 @@ def _install_read_fault_shim():
 # -- size accounting / GC / verification ------------------------------------
 def _entry_list():
     """[(key, cache_path, bytes, atime_seconds)] for every on-disk
-    entry, oldest-read first."""
+    entry, the executable store's among them, oldest-read first."""
     if _dir is None:
         return []
     out = []
@@ -455,9 +488,9 @@ def _entry_list():
     except OSError:
         return []
     for name in names:
-        if not name.endswith(_CACHE_SUFFIX):
+        if not name.endswith((_CACHE_SUFFIX, _STORE_SUFFIX)):
             continue
-        key = name[:-len(_CACHE_SUFFIX)]
+        key = name[:name.rindex("-")]
         path = os.path.join(_dir, name)
         try:
             size = os.path.getsize(path)
@@ -552,8 +585,11 @@ def verify(deep=False):
 
                 with open(path, "rb") as f:
                     blob = f.read()
-                _jcc.extract_executable_and_time(
-                    _jcc.decompress_executable(blob))
+                if path.endswith(_STORE_SUFFIX):
+                    bad = _store_decode(blob) is None
+                else:
+                    _jcc.extract_executable_and_time(
+                        _jcc.decompress_executable(blob))
             except Exception:  # noqa: broad-except — any decode error
                 # means the entry can never load; drop it
                 bad = True
@@ -567,33 +603,40 @@ def verify(deep=False):
     return dropped
 
 
-_size_memo = (None, 0, 0)  # (mutation stamp, entries, bytes)
+_size_memo = (None, 0, 0, 0)  # (mutation stamp, entries, bytes, the store's)
 
 
 def _sized():
-    """(entries, bytes) of the on-disk cache, rescanned only when a
-    mutation counter moved since the last scan — new entries appear
-    exactly on misses, disappear on evictions/corrupt drops — so the
-    polled consumers (``/healthz``, per-warmup stats deltas) don't pay
+    """(entries, bytes, the executable store's bytes among them) of the
+    on-disk cache, rescanned only when a mutation counter moved since the
+    last scan — new entries appear exactly on misses and on the store's
+    writes, disappear on evictions/corrupt drops — so the polled
+    consumers (``/healthz``, per-warmup stats deltas) don't pay
     O(entries) stat calls per read."""
     global _size_memo
     with _lock:
-        stamp = (_dir, _misses, _evictions, _corrupt_dropped)
+        stamp = (_dir, _misses, _store_writes, _evictions, _corrupt_dropped)
         if stamp == _size_memo[0]:
-            return _size_memo[1], _size_memo[2]
+            return _size_memo[1:]
     entries = _entry_list()
-    n, b = len(entries), sum(e[2] for e in entries)
+    sized = (len(entries), sum(e[2] for e in entries),
+             sum(e[2] for e in entries if e[1].endswith(_STORE_SUFFIX)))
     with _lock:
-        _size_memo = (stamp, n, b)
-    return n, b
+        _size_memo = (stamp,) + sized
+    return sized
 
 
 def stats():
     """Operational snapshot: enabled/dir/entries/bytes plus the
     process-local persistent hit/miss/saved/eviction counters (tracked
     independently of telemetry enablement, so the CI effectiveness check
-    and ``/healthz`` always see them)."""
-    n_entries, n_bytes = _sized()
+    and ``/healthz`` always see them).  ``entries`` and ``bytes`` are the
+    directory's, the executable store's entries among them;
+    ``store_hits`` and ``store_misses`` count the first calls
+    :func:`stored_program` served and those it had no whole entry for,
+    ``store_bytes`` is what its entries hold on disk, and
+    ``store_refused`` names each program it would not keep, with why."""
+    n_entries, n_bytes, store_bytes = _sized()
     with _lock:
         return {
             "enabled": _dir is not None,
@@ -611,6 +654,10 @@ def stats():
             "evictions": _evictions,
             "corrupt_dropped": _corrupt_dropped,
             "recorded_builds": len(_records),
+            "store_hits": _store_hits,
+            "store_misses": _store_misses,
+            "store_bytes": store_bytes,
+            "store_refused": dict(_store_refused),
         }
 
 
@@ -636,6 +683,373 @@ def programs():
     return [(t1, t1 - t0, phase == "load")
             for phase, _name, t0, t1, _tid in phases()
             if phase in ("load", "compile")]
+
+
+# -- the executable store -----------------------------------------------------
+#: what an entry starts with: then the SHA-256 of the rest, then the rest
+_STORE_MAGIC = b"MXEXEC01"
+_package_memo = None   # digest of the package's sources, read once a process
+
+
+class _Undigestible(Exception):
+    """The store cannot name everything a program depends on."""
+
+
+def _package_digest():
+    """SHA-256 over every ``.py`` under ``mxnet_tpu/``, names and bytes."""
+    global _package_memo
+    if _package_memo is None:
+        root = os.path.dirname(os.path.abspath(__file__))
+        h = hashlib.sha256()
+        for where, dirs, files in os.walk(root):
+            dirs.sort()
+            for name in sorted(f for f in files if f.endswith(".py")):
+                path = os.path.join(where, name)
+                with open(path, "rb") as f:
+                    h.update(b"%s\0%s\0" % (
+                        os.path.relpath(path, root).encode(), f.read()))
+        _package_memo = h.hexdigest()
+    return _package_memo
+
+
+def _named(obj, files):
+    """``module.qualname`` of a class or function; the file that defines it
+    joins ``files``, its bytes a part of the key."""
+    module = sys.modules.get(obj.__module__)
+    path = getattr(module, "__file__", None)
+    if path:
+        files.add(path)
+    return "%s.%s" % (obj.__module__, obj.__qualname__)
+
+
+def _plain(x, files):
+    """A static a program closes over (a model with its ``cfg``, a bucket, a
+    dtype) as JSON writes it: values, containers, named tuples, dtypes,
+    module-level functions and ``functools.partial`` of them by name, and
+    an object as its class and ``vars()``.  What it cannot see into (a
+    closure, a bound method, an array) raises :class:`_Undigestible`: a
+    program whose statics cannot be told apart is not kept."""
+    if x is None or isinstance(x, (bool, int, float, str)):
+        return x
+    if isinstance(x, np.generic):
+        return [type(x).__name__, x.item()]
+    if isinstance(x, np.dtype):
+        return ["dtype", x.name]
+    if isinstance(x, type):
+        # a scalar type stands for its dtype (np.float32, jnp.bfloat16)
+        if issubclass(x, np.generic) \
+                or isinstance(getattr(x, "dtype", None), np.dtype):
+            return ["dtype", np.dtype(x).name]
+        return _named(x, files)
+    if isinstance(x, (tuple, list, set, frozenset)):
+        items = [_plain(i, files) for i in x]
+        if isinstance(x, (set, frozenset)):
+            items.sort(key=repr)
+        return [_named(type(x), files)] + items
+    if isinstance(x, dict):
+        return {"dict": sorted(([_plain(k, files), _plain(v, files)]
+                                for k, v in x.items()), key=repr)}
+    if isinstance(x, functools.partial):
+        return ["partial", _plain(x.func, files), _plain(x.args, files),
+                _plain(x.keywords, files)]
+    if inspect.isfunction(x):
+        if x.__closure__ or "<" in x.__qualname__:
+            raise _Undigestible("the closure %s" % x.__qualname__)
+        return _named(x, files)
+    if hasattr(x, "__dict__") and not callable(x) \
+            and not hasattr(x, "shape"):
+        return [_named(type(x), files), _plain(vars(x), files)]
+    raise _Undigestible("a %s" % type(x).__name__)
+
+
+def _leaf_signature(x, device):
+    """What of one argument decides the program: shape, dtype, whether the
+    type is weak, and where it lies."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    if isinstance(x, jax.Array):
+        if x.committed and not (
+                isinstance(x.sharding, SingleDeviceSharding)
+                and x.sharding.device_set == {device}):
+            raise _Undigestible("an argument laid out as %r" % (x.sharding,))
+        return [list(x.shape), x.dtype.name, bool(x.aval.weak_type),
+                x.sharding.memory_kind if x.committed else None]
+    if isinstance(x, (np.ndarray, np.generic)):
+        return [list(x.shape), x.dtype.name, False, None]
+    if isinstance(x, (bool, int, float, complex)):
+        return [type(x).__name__]
+    raise _Undigestible("an argument of type %s" % type(x).__name__)
+
+
+def _store_key(name, kind, statics, device, args, kwargs):
+    """The key of one program's entry: everything its lowered text would
+    have held, from what can be read without tracing it.  The sources (this
+    package's, and the files of the classes and functions among the
+    statics), the versions of ``jax``, ``jaxlib``, the backend and Python,
+    the device, ``jax.config`` (the options it had when the cache came on)
+    and the environment's ``MXNET_*``, ``JAX_*``, ``XLA_FLAGS`` and
+    ``LIBTPU_INIT_ARGS``, whether telemetry counts (an
+    entry keeps what a trace counted), the build's name and kind, the
+    statics, and the call: the arguments' tree, and each leaf's shape,
+    dtype and place.  Weights are arguments: their values are no part."""
+    import jax
+    import jaxlib
+
+    files = set()
+    leaves, tree = jax.tree_util.tree_flatten((args, kwargs))
+    parts = {
+        "name": name, "kind": _plain(kind, files),
+        "statics": _plain(statics, files),
+        "tree": str(tree),
+        "leaves": [_leaf_signature(x, device) for x in leaves],
+        "versions": [jax.__version__, jaxlib.__version__,
+                     device.client.platform_version, sys.version],
+        "device": [device.platform, device.device_kind, device.id],
+        "config": sorted((k, v) for k, v in jax.config.values.items()
+                         if k in _config_names),
+        "env": sorted((k, v) for k, v in os.environ.items()
+                      if k.startswith(("MXNET_", "JAX_"))
+                      or k in ("XLA_FLAGS", "LIBTPU_INIT_ARGS")),
+        "counts": _telemetry.enabled(),
+        "package": _package_digest(),
+    }
+    package = os.path.dirname(os.path.abspath(__file__)) + os.sep
+    sources = {}
+    for path in sorted(files):
+        if not os.path.abspath(path).startswith(package):
+            with open(path, "rb") as f:
+                sources[path] = hashlib.sha256(f.read()).hexdigest()
+    parts["files"] = sources
+    return hashlib.sha256(json.dumps(
+        parts, sort_keys=True, default=repr).encode()).hexdigest()
+
+
+def _store_encode(entry):
+    body = pickle.dumps(entry, protocol=pickle.HIGHEST_PROTOCOL)
+    return _STORE_MAGIC + hashlib.sha256(body).digest() + body
+
+
+def _store_decode(blob):
+    """The entry ``blob`` holds, or None where it is torn, truncated or
+    not one: read only when the digest agrees (what is unpickled is what
+    :func:`_store_encode` wrote)."""
+    head = len(_STORE_MAGIC) + 32
+    if len(blob) <= head or not blob.startswith(_STORE_MAGIC) or \
+            hashlib.sha256(blob[head:]).digest() \
+            != blob[len(_STORE_MAGIC):head]:
+        return None
+    return pickle.loads(blob[head:])
+
+
+def _store_pickle(compiled, cache_key):
+    """``compiled`` (a ``jax.stages.Compiled``) as bytes, its trees apart,
+    with the executable itself left out: where it stood, the persistent
+    cache's key for it.  What ``jax.experimental.serialize_executable``
+    pickles, but for that: an executable that was itself loaded from the
+    cache does not survive being serialized again (XLA:CPU loses the
+    functions it calls), and a second copy of its bytes would double the
+    directory."""
+    import io
+
+    import jax
+    from jax._src.lib import xla_client as xc
+    from jax.experimental import serialize_executable as _se
+
+    class Pickler(_se._JaxPjrtPickler):
+        def persistent_id(self, obj):
+            if isinstance(obj, (xc.LoadedExecutable, xc._xla.Executable)):
+                return ("cached", cache_key)
+            return super().persistent_id(obj)
+
+    unloaded = getattr(compiled._executable, "_unloaded_executable", None)
+    if unloaded is None or compiled._params.const_args or (
+            getattr(unloaded, "mut", None) and unloaded.mut.in_mut):
+        raise ValueError("the compilation does not support serialization")
+    args_info, in_tree = jax.tree_util.tree_flatten(compiled.args_info)
+    with io.BytesIO() as file:
+        Pickler(file).dump((unloaded, args_info, compiled._no_kwargs))
+        return file.getvalue(), in_tree, compiled.out_tree
+
+
+def _store_unpickle(entry, device):
+    """The ``jax.stages.Compiled`` an entry holds, its executable read from
+    the persistent cache as a warm compile reads it (through the same
+    guard: a torn entry there is dropped); ``KeyError`` where the cache
+    has it no more."""
+    import io
+
+    import jax
+    from jax._src import compilation_cache as _jcc
+    from jax.experimental import serialize_executable as _se
+
+    class Unpickler(_se._JaxPjrtUnpickler):
+        def persistent_load(self, pid):
+            if pid[0] != "cached":
+                return super().persistent_load(pid)
+            loaded, _seconds = _jcc.get_executable_and_time(
+                pid[1], None, self.backend, self.execution_devices)
+            if loaded is None:
+                raise KeyError(pid[1])
+            return loaded
+
+    unloaded, args_info, no_kwargs = Unpickler(
+        io.BytesIO(entry["program"]), device.client, [device]).load()
+    return jax.stages.Compiled(
+        unloaded.load(), [], entry["in_tree"].unflatten(args_info),
+        entry["out_tree"], no_kwargs=no_kwargs)
+
+
+class _StoredProgram:
+    """One program's place in the executable store: the first call of its
+    ``jit`` (:meth:`first`), and the write after a first call that found
+    nothing (:meth:`save`)."""
+
+    def __init__(self, name, kind, statics, device):
+        self.name, self.kind, self.statics = name, kind, statics
+        self.device = device
+        #: whether :meth:`first` served the call from the store
+        self.hit = False
+        self._path = None       # the entry's; None: the program is refused
+        self._counted = {}      # what the miss's trace counted
+        self._seq = 0           # builds recorded before the first call
+        self._cache_key = None  # the persistent cache's, of the executable
+
+    def _refuse(self, why):
+        self._path = None
+        with _lock:
+            _store_refused["%s/%s" % (self.name, self.kind)] = str(why)
+        _log.warning("compile_cache: the executable store does not keep "
+                     "%s/%s: %s", self.name, self.kind, why)
+
+    def _load(self, fn, args, kwargs):
+        """The stored executable for this call, loaded, or None."""
+        global _store_hits, _store_misses, _program_seconds
+
+        if _dir is None:
+            return None
+        try:
+            key = _store_key(self.name, self.kind, self.statics, self.device,
+                             args, kwargs)
+        except (_Undigestible, OSError) as e:  # OSError: a source unread
+            self._refuse(e)
+            return None
+        stem = os.path.join(_dir, "mxstore-" + key)
+        self._path = stem + _STORE_SUFFIX
+        t0 = time.monotonic()
+        loaded = entry = None
+        try:
+            with open(self._path, "rb") as f:
+                blob = f.read()
+        except OSError:
+            blob = None
+        if blob is not None:
+            try:
+                entry = _store_decode(blob)
+                loaded = _store_unpickle(entry, self.device)
+            except Exception as e:  # noqa: broad-except — a torn entry is
+                # a miss, never an error: the start goes on as without it
+                _log.warning("compile_cache: dropped the store's entry %s "
+                             "(%s: %s); it is written again", self._path,
+                             type(e).__name__, e)
+                _drop_entry("mxstore-" + key, self._path, "corrupt")
+        t1 = time.monotonic()
+        with _lock:
+            if loaded is None:
+                _store_misses += 1
+                self._seq = _record_seq
+                return None
+            _store_hits += 1
+            _program_seconds += t1 - t0
+            _phases.append(("load", "jit(%s)" % getattr(
+                fn, "__name__", _kind_name(self.kind)), t0, t1,
+                threading.get_native_id()))
+        with open(stem + _ATIME_SUFFIX, "w"):
+            pass  # read now: what gc() evicts by
+        _telemetry.replay(entry["counted"])
+        for build in entry["builds"]:
+            _record(dict(build))
+        self.hit = True
+        return loaded
+
+    def first(self, fn, args, kwargs):
+        """The program's first call: ``(what it returns, what every later
+        call goes through)``.  Where the store holds the program for this
+        call its executable is loaded (a ``load`` in :func:`phases`), what
+        its trace had counted is counted again (``telemetry.replay``) and
+        the builds it had recorded are recorded again, and ``fn`` is
+        neither traced nor lowered; handed arguments of another shape
+        later, the executable raises ``TypeError``.  Where it holds none,
+        ``fn`` is called as it would have been."""
+        loaded = self._load(fn, args, kwargs)
+        if loaded is not None:
+            return loaded(*args, **kwargs), loaded
+        _tls.cache_key = None
+        with _telemetry.tap() as self._counted:
+            out = fn(*args, **kwargs)
+        # the call's last look into the persistent cache was for its own
+        # program: found or compiled and written, it lies under this key
+        self._cache_key = _tls.cache_key
+        return out, fn
+
+    def save(self, fn, args, kwargs):
+        """After a first call that found no entry (and after the manifest's
+        :func:`note_build`): write the program ``fn`` has just been given,
+        with what its trace counted and the builds recorded under the
+        program's name since.  ``fn.lower`` of the same call finds the
+        trace, the lowering and the executable the call made.  Never
+        raises: a program that cannot be written is refused by name."""
+        global _store_writes
+
+        if self.hit or self._path is None or _dir is None:
+            return
+        try:
+            if self._cache_key is None:
+                raise ValueError("its first call asked the persistent "
+                                 "cache for no executable")
+            program, in_tree, out_tree = _store_pickle(
+                fn.lower(*_abstractify(args),
+                         **_abstractify(kwargs)).compile(), self._cache_key)
+            with _lock:
+                builds = [_public(e) for e in _records
+                          if e["_seq"] > self._seq and e["exec"] == self.name
+                          and e["kind"] == kind_to_json(self.kind)]
+            atomic_write_bytes(self._path, _store_encode({
+                "program": program, "in_tree": in_tree, "out_tree": out_tree,
+                "counted": self._counted, "builds": builds}), durable=False)
+        except Exception as e:  # noqa: broad-except — the start has its
+            # executable either way; the next one compiles as this one did
+            self._refuse("%s: %s" % (type(e).__name__, e))
+            return
+        with _lock:
+            _store_writes += 1
+
+
+def stored_program(name, kind, statics, device):
+    """The executable store's handle for one jitted program, for
+    ``perfdebug.first_call_hook(store=)``, or None while the cache is off.
+
+    Beside the persistent cache's entries, in the same directory and under
+    the same :func:`gc` bound, the store keeps what
+    ``jax.experimental.serialize_executable`` pickles of a program's
+    COMPILED executable (its shardings, layouts, donation and trees), and
+    for the executable itself the persistent cache's key, under a key of
+    its own made without tracing the function (:func:`_store_key`).  A
+    start that finds the entry, and the cache's under the key it names,
+    loads the executable as a warm compile would and neither traces nor
+    lowers the program; the persistent cache needs the lowered text to
+    make its key, and a warm start spent most of its time making that
+    text.  A start that finds none does what it did, and writes one
+    (:meth:`save`, after the first call).  ``name`` and ``kind`` are the build's, as
+    :func:`note_build` takes them; ``statics`` is everything the function
+    closes over that decides its program, in any shape :func:`_plain` can
+    write (what it cannot, refuses the program: ``stats()``
+    ``store_refused``); ``device`` is the one device the program runs on.
+    An edit to any source under ``mxnet_tpu/`` moves every key: stale
+    entries age out under the size bound like any other."""
+    if _dir is None:
+        return None
+    return _StoredProgram(name, kind, statics, device)
 
 
 # -- tier 2: build recording ------------------------------------------------
@@ -825,6 +1239,12 @@ def _note_build_impl(exec_name, kind, lower_fn, args, kwargs, seconds):
         "compile_seconds": round(seconds, 4) if seconds else None,
         "sig": signature_to_json(sds_args, sds_kwargs),
     }
+    return _record(entry)
+
+
+def _record(entry):
+    """Put one build's entry into the registry (a fresh build's, or one the
+    executable store kept with the program it loaded)."""
     global _record_seq
     with _lock:
         # one entry per identity; a rebuild refreshes the entry and its
@@ -839,7 +1259,7 @@ def _note_build_impl(exec_name, kind, lower_fn, args, kwargs, seconds):
                 _records.pop(i)
                 break
         _records.append(entry)
-    _telemetry.inc("compile_cache.builds_recorded", kind=kind_name)
+    _telemetry.inc("compile_cache.builds_recorded", kind=entry["kind_name"])
     return entry
 
 
@@ -867,11 +1287,14 @@ def records():
 def reset_records():
     """Clear the tier-2 registry, save memos and the process-local
     persistent-cache counters (tests)."""
-    global _hits, _misses, _saved_seconds, _evictions, _corrupt_dropped
+    global _hits, _misses, _saved_seconds, _evictions, _corrupt_dropped, \
+        _store_hits, _store_misses
     with _lock:
         _records.clear()
         _saved_manifests.clear()
         _hits = _misses = _evictions = _corrupt_dropped = 0
+        _store_hits = _store_misses = 0
+        _store_refused.clear()
         _saved_seconds = 0.0
 
 

@@ -311,26 +311,26 @@ def _capture(name, kind, lower_fn, args, kwargs):
 
 
 class _FirstCallHook:
-    """First-call wrapper for jitted functions built outside the executor's
-    ``_get_fn`` path (e.g. ``Module``'s fused update): ``hook(fn, args,
-    kwargs, seconds)`` runs once after the first call, inside the span
-    ``span()`` opens; then one boolean check a dispatch."""
+    """First-call wrapper of a jitted function: ``hook(fn, args, kwargs,
+    seconds)`` runs once after the first call, inside the span ``span()``
+    opens; a ``store`` makes that call (``compile_cache.stored_program``)."""
 
-    __slots__ = ("_fn", "_hook", "_pending", "_span")
+    __slots__ = ("_fn", "_call", "_hook", "_pending", "_span", "_store")
 
-    def __init__(self, fn, hook, span=None):
-        self._fn = fn
-        self._hook = hook
+    def __init__(self, fn, hook, span=None, store=None):
+        self._fn = self._call = fn
+        self._hook, self._span, self._store = hook, span, store
         self._pending = True
-        self._span = span
 
     def __call__(self, *args, **kwargs):
         if not self._pending:
-            return self._fn(*args, **kwargs)
-        self._pending = False
-        t0 = time.perf_counter()
+            return self._call(*args, **kwargs)
+        self._pending, t0 = False, time.perf_counter()
         with self._span() if self._span else _tracing.NULL_SPAN:
-            out = self._fn(*args, **kwargs)
+            if self._store is None:
+                out = self._fn(*args, **kwargs)
+            else:
+                out, self._call = self._store.first(self._fn, args, kwargs)
             self._hook(self._fn, args, kwargs, time.perf_counter() - t0)
         return out
 
@@ -341,16 +341,22 @@ class _FirstCallHook:
         return self._fn.trace(*args, **kwargs)
 
 
-def first_call_hook(fn, hook, span=None):
+def first_call_hook(fn, hook, span=None, store=None):
     """Wrap jitted ``fn`` so ``hook(fn, args, kwargs, seconds)`` fires
     once after its first call.  Shared by perfdebug attribution and
     compile_cache manifest recording.  ``span``, a function of no
     arguments that opens a span (``tracing.setup_span``), makes that call
-    and the hook one span: whose trace, lowering and load it was.  (The
-    wrapper's ``lower`` stands on the line it stood on: a kernel's lowered
-    text carries the line numbers of its call stack, and
-    ``tools/perf/program_fingerprints.py --tpu`` lowers through it.)"""
-    return _FirstCallHook(fn, hook, span)
+    and the hook one span: whose trace, lowering and load it was.
+    ``store`` (``compile_cache.stored_program``; None: the ``jit`` as it
+    is) makes the first call, ``store.first(fn, args, kwargs)``, and gives
+    back what every later call goes through: the executable it loaded
+    where it holds one for this call, and then ``fn`` is never traced;
+    ``fn`` itself where it holds none.  ``lower`` and ``trace`` stay the
+    ``jit``'s either way.  (The wrapper's ``lower`` stands on the line it
+    stood on: a kernel's lowered text carries the line numbers of its call
+    stack, and ``tools/perf/program_fingerprints.py --tpu`` lowers through
+    it.)"""
+    return _FirstCallHook(fn, hook, span, store)
 
 
 def instrument(fn, name, kind):

@@ -867,8 +867,8 @@ class DecodeEngine:
                 replica=self.replica)
             _telemetry.set_gauge(
                 "serving.decode.warmup.cache_loads",
-                cc1["hits"] - cc0["hits"], model=self.name,
-                replica=self.replica)
+                cc1["hits"] + cc1["store_hits"] - cc0["hits"]
+                - cc0["store_hits"], model=self.name, replica=self.replica)
         if self._kv is None:
             for kind, held in self._cache_bytes().items():
                 _telemetry.set_gauge("serving.cache.bytes", held,
@@ -881,16 +881,34 @@ class DecodeEngine:
 
     def _instrument(self, fn, kind, build_kind):
         """First-call hook (a ``serving.setup.program`` span): count the
-        compile and record the build into the warm-up manifest registry."""
+        compile and record the build into the warm-up manifest registry.
+        The first call is the executable store's
+        (``compile_cache.stored_program``): a start that finds the program
+        there loads it, and ``fn`` is neither traced nor lowered."""
+        # everything the two programs close over: the model with its cfg
+        # and cache dtype, and what _build read of the engine
+        store = _compile_cache.stored_program(
+            "serving:%s" % self.name, build_kind,
+            {"model": self.model, "spec": self._spec, "slots": self.slots,
+             "buckets": self.prefill_buckets, "layout": self.kv_layout,
+             "block": self._block, "donate": (1,),
+             "paged": self._kv and (self._kv.num_blocks,
+                                    self._kv.block_size,
+                                    self._kv.max_blocks)}, self._device)
+
         def hook(f, args, kwargs, dt):
             _telemetry.inc("xla.compile.count", kind=kind)
             _telemetry.inc("xla.compile.seconds", dt, kind=kind)
+            if store is not None and store.hit:
+                return  # the store recorded the build its entry held
             if _compile_cache.recording():
                 _compile_cache.note_build(
                     "serving:%s" % self.name, build_kind, f.lower, args,
                     kwargs, dt)
+            if store is not None:
+                store.save(f, args, kwargs)
         return _perfdebug.first_call_hook(
-            fn, hook, span=self._program_span(kind, build_kind))
+            fn, hook, span=self._program_span(kind, build_kind), store=store)
 
     def _fresh_state(self):
         """Zeroed device-resident slot state, committed to the replica

@@ -133,7 +133,9 @@ class ServingHandle:
             payload["compile_cache"] = {
                 k: cc[k] for k in ("entries", "bytes", "hits", "misses",
                                    "evictions", "trace_seconds",
-                                   "lower_seconds", "lowerings")}
+                                   "lower_seconds", "lowerings",
+                                   "store_hits", "store_misses",
+                                   "store_bytes")}
         # per-model KV-storage occupancy (paged decode tiers): the
         # capacity number an operator reads before anything else —
         # blocks_free hitting 0 is the "admissions will shed typed"

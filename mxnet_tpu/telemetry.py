@@ -36,6 +36,7 @@ server=0)``).
 from __future__ import annotations
 
 import bisect
+import contextlib
 import json
 import os
 import re
@@ -46,7 +47,8 @@ from collections import deque
 from . import profiler as _profiler
 from . import tracing as _tracing
 
-__all__ = ["enabled", "enable", "disable", "inc", "declare", "set_gauge",
+__all__ = ["enabled", "enable", "disable", "inc", "tap", "replay", "declare",
+           "set_gauge",
            "observe", "event", "phase", "snapshot", "dump", "dump_events",
            "prometheus_text", "write_prometheus", "reset", "sample_memory",
            "phase_totals", "counter_total", "gauge_value", "hist_quantile",
@@ -127,10 +129,37 @@ def inc(name, value=1, **labels):
     if not _enabled:
         return
     k = _key(name, labels)
+    heard = getattr(_tap, "counts", None)
+    if heard is not None and value:
+        heard[k] = heard.get(k, 0) + value
     with _lock:
         if value == 0:
             _declared.add(k)
         _counters[k] = _counters.get(k, 0) + value
+
+
+_tap = threading.local()  # .counts: what tap() hears on this thread
+
+
+@contextlib.contextmanager
+def tap():
+    """Hear every :func:`inc` this thread makes inside the block: yields
+    the dict ``{(name, labels): sum}`` they add up in, which
+    :func:`replay` counts again.  How what a trace counts
+    (``ops.kernel_path``) is kept beside the executable it made
+    (``compile_cache``'s store), for the start that loads the executable
+    and traces nothing.  Empty while the registry is off."""
+    outer, _tap.counts = getattr(_tap, "counts", None), {}
+    try:
+        yield _tap.counts
+    finally:
+        _tap.counts = outer
+
+
+def replay(counts):
+    """Count again what a :func:`tap` heard."""
+    for (name, labels), value in counts.items():
+        inc(name, value, **dict(labels))
 
 
 def declare(*names):
