@@ -145,14 +145,63 @@ _program_seconds = 0.0
 _trace_seconds = 0.0
 _lower_seconds = 0.0
 _lowerings = 0
-#: ``(phase, fun_name, t0, t1, tid)`` of the newest things JAX said it
+#: records :class:`_Phases` keeps of a process's start, and of its newest
+_PHASES_ROOM = 4096
+
+
+class _Phases:
+    """The process's first :data:`_PHASES_ROOM` records and its newest
+    :data:`_PHASES_ROOM`, and how many it ``dropped`` between them.  What a
+    process does first is its set-up, and what it compiles later, however
+    much, pushes none of that out (the benchmark's reference check compiles
+    a program for every transcript length a window finished, some 17
+    records a request: of one ring of 4096 the 243 requests of a
+    ``gpt2-large`` window took all of set-up's records, PR 48)."""
+
+    def __init__(self):
+        self.first, self.newest = [], deque(maxlen=_PHASES_ROOM)
+        self.dropped = 0
+
+    def append(self, record):
+        # ``newest`` holds something only while ``first`` is full
+        if len(self.first) < _PHASES_ROOM:
+            self.first.append(record)
+            return
+        if len(self.newest) == _PHASES_ROOM:
+            if not self.dropped:
+                _log.warning(
+                    "compile_cache: phases() keeps the first %d records "
+                    "and the newest %d; those between are dropped from "
+                    "here on (stats()['phases_dropped'])",
+                    _PHASES_ROOM, _PHASES_ROOM)
+            self.dropped += 1
+        self.newest.append(record)
+
+    def last(self):
+        held = self.newest or self.first
+        return held[-1] if held else None
+
+    def pop(self):
+        (self.newest or self.first).pop()
+
+    def all(self):
+        return self.first + list(self.newest)
+
+    def restore(self, records):
+        """Hold ``records`` (an earlier :meth:`all`) and nothing else."""
+        self.__init__()
+        for record in records:
+            self.append(record)
+
+
+#: ``(phase, fun_name, t0, t1, tid)`` of the things JAX said it
 #: did on the way to a program, ``time.monotonic()`` seconds and the
 #: thread's native id: a ``trace`` (to a jaxpr; the outermost only, see
 #: :func:`_on_duration`), a ``lower`` (jaxpr to MLIR module), and a pass
 #: through ``compile_or_get_cached``, a ``load`` where the persistent
 #: cache had the program and a ``compile`` where it had not.  A reader
 #: cuts at a moment of its own (the benchmark: the opening of its window)
-_phases = deque(maxlen=4096)
+_phases = _Phases()
 _evictions = 0
 _corrupt_dropped = 0
 _store_hits = 0        # first calls the executable store served
@@ -348,9 +397,10 @@ def _absorb_traces(t0, tid):
     arrived first, so they are the newest; the walk stops at the first
     record that is not one of them.  Lock held by the caller."""
     global _trace_seconds
-    while _phases:
-        last = _phases[-1]
-        if last[0] != "trace" or last[4] != tid or last[2] < t0:
+    while True:
+        last = _phases.last()
+        if last is None or last[0] != "trace" or last[4] != tid \
+                or last[2] < t0:
             return
         _trace_seconds -= last[3] - last[2]
         _phases.pop()
@@ -634,8 +684,9 @@ def stats():
     directory's, the executable store's entries among them;
     ``store_hits`` and ``store_misses`` count the first calls
     :func:`stored_program` served and those it had no whole entry for,
-    ``store_bytes`` is what its entries hold on disk, and
-    ``store_refused`` names each program it would not keep, with why."""
+    ``store_bytes`` is what its entries hold on disk,
+    ``store_refused`` names each program it would not keep, with why, and
+    ``phases_dropped`` counts the records :func:`phases` no longer holds."""
     n_entries, n_bytes, store_bytes = _sized()
     with _lock:
         return {
@@ -651,6 +702,7 @@ def stats():
             "trace_seconds": round(_trace_seconds, 6),
             "lower_seconds": round(_lower_seconds, 6),
             "lowerings": _lowerings,
+            "phases_dropped": _phases.dropped,
             "evictions": _evictions,
             "corrupt_dropped": _corrupt_dropped,
             "recorded_builds": len(_records),
@@ -663,8 +715,9 @@ def stats():
 
 def phases():
     """``(phase, fun_name, t0, t1, tid)`` of the newest traces, lowerings
-    and passes through XLA's ``compile_or_get_cached`` in this process (at
-    most 4096, oldest first; :data:`_phases` says what each is).  Times
+    and passes through XLA's ``compile_or_get_cached`` in this process
+    (the first 4096 and the newest 4096, oldest first; :data:`_phases` says
+    what each is, ``stats()["phases_dropped"]`` how many lay between).  Times
     are ``time.monotonic()`` seconds, ``tid`` the thread's native id, as a
     span's.  Of nested traces the outermost is kept, but where two threads
     trace at once some inner ones may stay: a reader takes the union of a
@@ -672,7 +725,7 @@ def phases():
     lowerings that ran; ``trace`` records are not a count of anything (a
     hit in the trace cache leaves one)."""
     with _lock:
-        return list(_phases)
+        return _phases.all()
 
 
 def programs():

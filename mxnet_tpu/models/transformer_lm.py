@@ -26,7 +26,8 @@ __all__ = ["LMConfig", "CacheLayer", "StateLayer", "LatentLayer",
            "slot_shape",
            "slot_arrays", "DecodeModel",
            "init_params", "forward_logits", "prefill_kv",
-           "write_rows", "decode_step_math", "prefill_kv_paged",
+           "write_rows", "ladder", "attended_rows", "decode_step_math",
+           "prefill_kv_paged",
            "decode_step_paged", "params_to_blob", "params_from_blob"]
 
 #: model hyperparameters; ``max_len`` bounds the KV cache (so prompt +
@@ -155,15 +156,81 @@ def _attend_rows(q, k, v, mask):
     return jnp.einsum("...hqk,...khd->...qhd", att, v)
 
 
-def _attend_slots(q, ck, cv, kpos, pos):
-    """One query a slot, ``q (S, heads, d)``, over that slot's rows of
-    ``ck``/``cv (S, M, heads, d)`` at positions ``kpos (M,) <= pos (S,)``."""
-    scores = jnp.einsum("shd,smhd->shm", q, ck) \
-        * (1.0 / np.sqrt(q.shape[-1]))
-    mask = kpos[None, None, :] <= pos[:, None, None]
-    att = jax.nn.softmax(
-        jnp.where(mask, scores, jnp.float32(-1e30)), axis=-1)
-    return jnp.einsum("shm,smhd->shd", att, cv)
+#: positions in one lane tile of the dense cache as the chip keeps it
+#: (positions-minor): a prefix of whole tiles is read without the rest
+_TILE = 128
+#: the most rungs a ladder has: one branch each, in every layer's attention
+_RUNGS = 8
+
+
+def ladder(max_len):
+    """``(width, rungs)``: the prefixes of a slot's ``max_len`` positions
+    the decode step's attention may read instead of all of them.  A rung
+    every ``width`` positions, the last ``max_len`` itself; ``width`` is
+    the fewest whole lane tiles that give at most ``_RUNGS`` rungs.  A
+    function of ``max_len`` alone: 1024 gives 128, 256, ..., 1024; 128 or
+    less gives one rung, and a step with one rung has no branch."""
+    width = -(-max_len // (_TILE * _RUNGS)) * _TILE
+    return width, tuple(range(width, max_len, width)) + (max_len,)
+
+
+def attended_rows(max_len, longest):
+    """The rung a step reads whose longest active slot holds ``longest``
+    rows: the host's reading of what :func:`_rung` chooses on the device."""
+    width, rungs = ladder(max_len)
+    return rungs[min(max(longest, 0), max_len - 1) // width]
+
+
+def _rung(max_len, pos, active):
+    """Which rung of :func:`ladder` holds every active slot's inclusive
+    horizon ``pos (S,)``, or None where the ladder has one rung.  A slot
+    that is not active (``active`` None: all are) holds nobody's session:
+    its stale length does not hold the ladder up, and its row of the
+    step's output is the host's to drop."""
+    from ..ops.registry import count_kernel_path
+
+    width, rungs = ladder(max_len)
+    if len(rungs) == 1:
+        count_kernel_path("attend_slots", "whole", "one_rung")
+        return None
+    count_kernel_path("attend_slots", "ladder", "ok")
+    live = pos if active is None else jnp.where(active, pos, 0)
+    return live.max() // width
+
+
+def _attend_slots(q, kt, vt, pos, rung=None):
+    """One query a slot, ``q (S, heads, d)``, over that slot's rows
+    ``kt``/``vt (S, heads, d, M)``, positions last, at positions ``<= pos
+    (S,)``.  ``rung`` (:func:`_rung`) says how many of the ``M`` rows any
+    slot's horizon reaches: the branch taken reads that prefix alone (None:
+    ``M`` is one rung, and there is no branch).  A row above a slot's
+    horizon has weight exactly 0, so every rung that holds the horizons
+    gives the context all ``M`` rows give, up to the order of a sum of
+    zeros.
+
+    Positions last is the order the chip keeps the dense cache in: there
+    the step's transpose moves nothing, a prefix of whole lane tiles is a
+    slice the reading fusion takes in, and no branch holds a copy (handed
+    ``(S, M, heads, d)`` every branch copied both arrays whole: the v5e
+    compiler's layouts inside a conditional).  Both products are a float32
+    multiply and sum, what the compiler makes of an einsum of one query a
+    slot outside a branch: inside one it made the scores a matrix product
+    over K and q rounded to bfloat16."""
+    scale = 1.0 / np.sqrt(q.shape[-1])
+
+    def over(rows):
+        def attend(q, kt, vt):
+            scores = (q[..., None] * kt[..., :rows]).sum(2) * scale
+            mask = jnp.arange(rows) <= pos[:, None, None]
+            att = jax.nn.softmax(
+                jnp.where(mask, scores, jnp.float32(-1e30)), axis=-1)
+            return (att[:, :, None] * vt[..., :rows]).sum(3)
+        return attend
+
+    if rung is None:
+        return over(kt.shape[-1])(q, kt, vt)
+    return jax.lax.switch(
+        rung, [over(rows) for rows in ladder(kt.shape[-1])[1]], q, kt, vt)
 
 
 def forward_logits(cfg, params, tokens):
@@ -233,7 +300,8 @@ def write_rows(cache, rows, pos):
     return cache
 
 
-def decode_step_math(cfg, params, cache_k, cache_v, last_tok, lengths):
+def decode_step_math(cfg, params, cache_k, cache_v, last_tok, lengths,
+                     active=None):
     """One decode token for all ``S`` slots.  ``cache_k``/``cache_v``:
     per-layer tuples of ``(S, max_len, heads, head_dim)``; ``last_tok (S,)
     int32`` is each slot's most recent token; ``lengths (S,) int32`` its
@@ -241,13 +309,17 @@ def decode_step_math(cfg, params, cache_k, cache_v, last_tok, lengths):
     of a cache-sized array) and the inclusive attention horizon.  Returns
     ``(logits (S, vocab), new_cache_k, new_cache_v)``.  Inactive slots ride
     along: their row lands where the mask hides it until a real write
-    replaces it, and the host drops their logits."""
-    kpos = jnp.arange(cache_k[0].shape[1])
+    replaces it, and the host drops their logits.  Attention reads the
+    rows up to the longest horizon among the slots ``active (S,) bool``
+    names (None: all), rounded up to a rung of :func:`ladder`, chosen on
+    the device once a step."""
     pos = jnp.clip(lengths, 0, cfg.max_len - 1)
+    rung = _rung(cache_k[0].shape[1], pos, active)
     return _through_cache(
         cfg, params, _embed(params, last_tok, pos), cache_k, cache_v,
         lambda cache, rows: write_rows(cache, rows, pos),
-        lambda q, ck, cv: _attend_slots(q, ck, cv, kpos, pos))
+        lambda q, ck, cv: _attend_slots(q, ck.transpose(0, 2, 3, 1),
+                                        cv.transpose(0, 2, 3, 1), pos, rung))
 
 
 def _held(pool, table, m):
@@ -296,33 +368,42 @@ def decode_step_paged(cfg, params, pool_k, pool_v, tables, last_tok,
     max_blocks) int32`` names each slot's pool rows; the incoming K/V
     scatters into the block covering position ``lengths`` (the engine
     allocates it before dispatch), the slot's table is gathered and sliced
-    to ``(S, max_len)``, and the attention is the dense step's, float for
-    float.  Inactive slots hold all-zero tables: their scatter lands in the
-    scratch block, their lanes are mask-dead."""
+    to ``(S, max_len)``, and the attention is the dense step's over every
+    row (the lines of its last rung, with no branch): the gather has moved all
+    ``max_len`` rows of every slot before attention reads one, and a
+    gathered array is not kept positions last, so a branch a rung would
+    copy it to read less of it.  Inactive slots hold all-zero tables:
+    their scatter lands in the scratch block, their lanes are mask-dead."""
+    from ..ops.registry import count_kernel_path
+
     bs = pool_k[0].shape[1]
     m = cfg.max_len
     rows = jnp.arange(tables.shape[0])
-    kpos = jnp.arange(m)
     pos = jnp.clip(lengths, 0, m - 1)
     wblk = tables[rows, pos // bs]
     woff = pos % bs
+    count_kernel_path("attend_slots", "whole", "gathered")
     return _through_cache(
         cfg, params, _embed(params, last_tok, pos), pool_k, pool_v,
         lambda pool, rows: pool.at[wblk, woff].set(rows),
-        lambda q, pk, pv: _attend_slots(q, _held(pk, tables, m),
-                                        _held(pv, tables, m), kpos, pos))
+        lambda q, pk, pv: _attend_slots(
+            q, _held(pk, tables, m).transpose(0, 2, 3, 1),
+            _held(pv, tables, m).transpose(0, 2, 3, 1), pos))
 
 
 class DecodeModel:
     """This LM as the decode engine's model protocol (:mod:`mxnet_tpu.
     serving.decode`): a float32 cache of ``layers`` full layers, the
-    functions above under the protocol's names, no extra device state."""
+    functions above under the protocol's names, no extra device state.
+    ``attended_rows(longest)`` is the protocol's optional reading of how
+    many of a slot's rows a step's attention reads."""
 
     def __init__(self, cfg):
         self.cfg = cfg
         self.prefill = functools.partial(prefill_kv, cfg)
         self.prefill_paged = functools.partial(prefill_kv_paged, cfg)
         self.decode_step_paged = functools.partial(decode_step_paged, cfg)
+        self.attended_rows = functools.partial(attended_rows, cfg.max_len)
 
     def cache_spec(self):
         cfg = self.cfg
@@ -335,9 +416,8 @@ class DecodeModel:
 
     def decode_step(self, params, cache_k, cache_v, last_tok, lengths,
                     active, extra):
-        del active
         return decode_step_math(self.cfg, params, cache_k, cache_v,
-                                last_tok, lengths) + (extra,)
+                                last_tok, lengths, active) + (extra,)
 
 
 def params_to_blob(cfg, params):

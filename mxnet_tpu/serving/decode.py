@@ -143,7 +143,12 @@ is given a *model object* and asks it for four things:
 * ``extra_state()``: optional extra device state the step carries beside
   the cache (routing counters), or None.  It is an argument of its own,
   never donated, so :meth:`DecodeEngine.model_counters` may read the
-  latest from any thread; ``model.counters(extra)`` names what it holds.
+  latest from any thread; ``model.counters(extra)`` names what it holds;
+* ``attended_rows(longest)``: optional, on the host: how many of a slot's
+  ``max_len`` rows a step's attention reads when its longest active slot
+  holds ``longest`` rows.  The engine asks at every dispatch, by its own
+  mirror of the lengths, and :meth:`DecodeEngine.model_counters`
+  publishes the mean as ``serving.attn.rows_read_share``.
 
 The engine's donated state is ``(firsts, seconds, last_tok, lengths,
 limits, active, temps, seeds)``: every entry's first array, every entry's
@@ -518,8 +523,15 @@ class DecodeEngine:
             if layout == "paged" else None
         #: host mirror of each slot's device ``lengths`` — the paged
         #: loop derives the next write position (and block-boundary
-        #: appends) from it without a device read
+        #: appends) from it without a device read, and either loop the
+        #: rows a step's attention reads
         self._slot_len = [0] * self.slots
+        #: of a model that offers ``attended_rows`` (its dense step's): the
+        #: rows a slot its steps' attention read and those steps, totals
+        #: the loop writes, and the pair model_counters() last read
+        self._attended_rows = getattr(self.model, "attended_rows", None) \
+            if layout == "dense" else None
+        self._attended = self._attended_seen = (0, 0)
 
         self._step_fn, self._prefill_fns = None, {}    # built in _build()
         with self._setup_span():
@@ -1265,25 +1277,37 @@ class DecodeEngine:
         donated, so the latest one a step returned stays readable.  The
         device counts in wrapping uint32; the differences between reads
         are summed here, so a count is exact while reads are less than
-        2**32 picks apart."""
+        2**32 picks apart.  Of a model that offers ``attended_rows`` also
+        the gauge ``serving.attn.rows_read_share``: the rows a slot its
+        attention read over ``max_len``, the mean of the steps dispatched
+        since the last read, by the host's mirror of the lengths (no
+        device read; a step behind the device at most)."""
+        out = {}
         extra = self._extra
-        if extra is None:
-            return {}
-        import jax
+        if extra is not None:
+            import jax
 
-        with _tracing.host_read("decode.model_counters"):
-            now = jax.tree_util.tree_map(
-                lambda a: np.asarray(a).astype(np.int64), extra)  # lint: ok[host-sync] counters read on the caller's thread (describe/telemetry), never in the loop
+            with _tracing.host_read("decode.model_counters"):
+                now = jax.tree_util.tree_map(
+                    lambda a: np.asarray(a).astype(np.int64), extra)  # lint: ok[host-sync] counters read on the caller's thread (describe/telemetry), never in the loop
+            with self._cond:
+                if self._extra_seen is None:
+                    self._extra_total = now
+                else:
+                    self._extra_total = jax.tree_util.tree_map(
+                        lambda t, a, b: t + (a - b) % (1 << 32),
+                        self._extra_total, now, self._extra_seen)
+                self._extra_seen = now
+                total = self._extra_total
+            out = self.model.counters(total)
+        attended = self._attended
         with self._cond:
-            if self._extra_seen is None:
-                self._extra_total = now
-            else:
-                self._extra_total = jax.tree_util.tree_map(
-                    lambda t, a, b: t + (a - b) % (1 << 32),
-                    self._extra_total, now, self._extra_seen)
-            self._extra_seen = now
-            total = self._extra_total
-        out = self.model.counters(total)
+            rows, steps = (now - seen for now, seen
+                           in zip(attended, self._attended_seen))
+            self._attended_seen = attended
+        if steps:
+            out.setdefault("gauges", {})["serving.attn.rows_read_share"] = \
+                rows / float(steps * self.cfg.max_len)
         labels = {"model": self.name, "replica": self.replica}
         for name, value in out.get("gauges", {}).items():
             _telemetry.set_gauge(name, value, **labels)
@@ -1623,8 +1647,8 @@ class DecodeEngine:
                 # over whatever the slot's last session left
                 _telemetry.inc("serving.ssm.state_resets", model=self.name,
                                replica=self.replica)
+            self._slot_len[sess.slot] = n
             if plan is not None:
-                self._slot_len[sess.slot] = n
                 # index the (now dispatched) prompt prefix for future
                 # admissions — insertion AFTER a successful dispatch only
                 self._kv.offer(sess.slot, sess.prompt)
@@ -1688,6 +1712,12 @@ class DecodeEngine:
         keep = np.ones((self.slots,), bool)
         with self._cond:
             sessions = list(self._slot_sessions)
+        if self._attended_rows is not None:
+            # by the loop's own mirror of the lengths: a step behind at most
+            rows, steps = self._attended
+            self._attended = (rows + self._attended_rows(max(  # lint: ok[lock-discipline] explicit hand-off: one writer (the loop's thread), an atomic swap of an immutable pair of totals; model_counters() takes whichever is latest, whole
+                (n for n, sess in zip(self._slot_len, sessions)
+                 if sess is not None), default=0)), steps + 1)
         now = time.monotonic()
         for i, sess in enumerate(sessions):
             if sess is None:

@@ -83,9 +83,13 @@ def test_phase_decode():
     judged = out["greedy_vs_forward_logits"]
     # on the CPU both programs multiply in float32: bit for bit
     assert judged["argmax_matches"] == judged["positions"] == 18
-    # off the chip the flash kernel is refused, and the refusal is counted
+    # off the chip the flash kernel is refused, and the refusal is counted;
+    # a cache of 96 positions is one rung, and the paged step attends over
+    # all it gathered whatever the rungs
     assert out["kernel_paths"] == {
-        "op=FlashAttention,path=xla,reason=not_tpu": 2}
+        "op=FlashAttention,path=xla,reason=not_tpu": 2,
+        "op=attend_slots,path=whole,reason=one_rung": 1,
+        "op=attend_slots,path=whole,reason=gathered": 1}
     assert not any(k["tpu_custom_call"] for k in out["pallas"].values())
 
 

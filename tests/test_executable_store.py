@@ -34,8 +34,7 @@ def _clean():
     phases = compile_cache.phases()
     yield
     with compile_cache._lock:
-        compile_cache._phases.clear()
-        compile_cache._phases.extend(phases)
+        compile_cache._phases.restore(phases)
     if compile_cache.enabled():
         compile_cache.disable()
     compile_cache.reset_records()

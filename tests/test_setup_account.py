@@ -54,8 +54,36 @@ def _engine(params, **kw):
 
 def _since(t):
     """The records that ended at ``t`` (``time.monotonic()``) or later: the
-    store is a bounded deque, full in a process that ran many tests."""
+    store is bounded, and full in a process that ran many tests."""
     return [r for r in compile_cache.phases() if r[3] >= t]
+
+
+def test_what_a_process_does_later_pushes_out_none_of_its_first_records(
+        caplog):
+    """The store keeps the first records and the newest, counts those it
+    dropped between them and says so once, aloud: set-up's records outlive
+    a reference check that compiles thousands of programs after it."""
+    room = compile_cache._PHASES_ROOM
+    held = compile_cache._Phases()
+    assert held.last() is None and held.all() == []
+    with caplog.at_level("WARNING", logger="mxnet_tpu.compile_cache"):
+        for i in range(3 * room + 7):
+            held.append(("lower", "f", i, i, 0))
+    kept = held.all()
+    assert [r[2] for r in kept[:room]] == list(range(room))
+    assert [r[2] for r in kept[room:]] == list(
+        range(2 * room + 7, 3 * room + 7))
+    assert held.dropped == room + 7
+    assert len([r for r in caplog.records if "dropped" in r.message]) == 1
+    held.pop()
+    assert held.last()[2] == 3 * room + 5
+    held.restore(kept[:5])
+    assert (held.all(), held.dropped) == (kept[:5], 0)
+    held.pop()
+    held.append(kept[9])
+    assert held.all() == kept[:4] + [kept[9]]
+    assert compile_cache.stats()["phases_dropped"] == \
+        compile_cache._phases.dropped
 
 
 def _called_once():

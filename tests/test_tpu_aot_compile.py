@@ -151,6 +151,40 @@ CASES["flash_attention-refuses-what-the-compiler-refuses"] = _flash_refusal
 _GPT2_LARGE, _SLOTS, _BUCKET = (50304, 1280, 20, 2, 5120, 1024), 12, 768
 
 
+def _held_in_a_branch(text, cfg, slots):
+    """Instructions of a compiled dense step that MAKE an array the size of
+    a rung of ``transformer_lm.ladder`` or of the whole cache, rows second
+    or rows last, outside every fused computation: a copy, a transpose or
+    a conversion of a cache array, or a slice of one that no reading fusion
+    took in.  (Parameters, tuple elements, bitcasts, the row writes and the
+    compiler's own prefetches of a whole array are not that.)"""
+    from mxnet_tpu.models import transformer_lm as tlm
+
+    heads, hd = cfg.heads, cfg.embed // cfg.heads
+    made, fused = [], False
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            fused = line.startswith("%fused_computation")
+            continue
+        m = re.match(r"\s+(?:ROOT )?%\S+ = (?:f32|bf16)\[([0-9,]+)\]"
+                     r"\{[^}]*\} ([a-z-]+)\(", line)
+        if fused or not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        for rows in tlm.ladder(cfg.max_len)[1]:
+            if dims in ([slots, rows, heads, hd], [slots, heads, hd, rows]) \
+                    and (rows < cfg.max_len or m.group(2) in (
+                        "copy", "transpose", "convert", "fusion", "slice")):
+                made.append(line.strip()[:200])
+    return made
+
+
+#: temporaries of the dense step at ``_GPT2_LARGE``'s two layers before the
+#: ladder (PR 47's tree, this compiler), and the room the ladder's eight
+#: branches a layer are given above it (they take 3,692,032)
+_STEP_TEMPORARIES, _LADDER_ROOM = 2096640, 2 << 20
+
+
 def _dense_engine_case(program):
     """The engine's own ``jit_step`` / ``jit_prefill``, state donated, at the
     ``gpt2-large`` cell's widths: the program may hold no temporaries the
@@ -210,11 +244,18 @@ def _dense_engine_case(program):
         assert temp - own < cache_bytes, \
             "%d bytes of temporaries (%d of them the program's own), " \
             "one cache array is %d" % (temp, own, cache_bytes)
+        text = compiled.as_text()
         copies = re.findall(
-            r"= f32\[%d,%d,%d,%d\]\{[^}]*\} copy\(.*" % kv.shape,
-            compiled.as_text())
+            r"= f32\[%d,%d,%d,%d\]\{[^}]*\} copy\(.*" % kv.shape, text)
         assert not copies, "%d copies of a cache array, the first: %s" \
             % (len(copies), copies[0][:200])
+        if program == "step":
+            # the ladder (PR 48): a conditional a layer, whose branches
+            # read a prefix of the cache where it lies
+            assert len(re.findall(r" conditional\(", text)) == cfg.layers
+            made = _held_in_a_branch(text, cfg, _SLOTS)
+            assert not made, "%d, the first: %s" % (len(made), made[0])
+            assert temp < _STEP_TEMPORARIES + _LADDER_ROOM, temp
     return run
 
 
@@ -324,7 +365,9 @@ def _gpt2_step_as_the_benchmark_lowers_it():
     compiled = engine._step_fn.lower(params, state,
                                      sds((s,), jnp.bool_)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes \
-        < kv.size * kv.dtype.itemsize
+        < _STEP_TEMPORARIES + _LADDER_ROOM
+    made = _held_in_a_branch(compiled.as_text(), cfg, s)
+    assert not made, "%d, the first: %s" % (len(made), made[0])
 
 
 CASES["decode-dense-step-gpt2-large-as-the-benchmark-lowers-it"] = \

@@ -19,7 +19,9 @@ from test_tpu_aot_compile import ROOT, cases_of, compile_in_a_child
 #: programs of every other decode model at ``tests/benchmark/tiny/``'s
 #: sizes, traced as for the chip.  A PR that means to change one of these
 #: models' programs replaces its lines with what the tool prints then (and
-#: says so); one that does not and fails here has changed them by accident
+#: says so); one that does not and fails here has changed them by accident.
+#: PR 48 meant to: ``lm_tiny``'s step attends through the ladder's one body
+#: (one rung at 64 positions, no branch) where it ran two einsums
 THE_OTHER_MODELS = """\
 deepseek_v2_tiny jit_step a74d72ea96e43ff5
 deepseek_v2_tiny jit_prefill 8 56c3ba6695767f68
@@ -28,7 +30,7 @@ deepseek_v2_tiny jit_prefill 32 9326ff51f83c220c
 exaone_tiny jit_step 929f47eb0097a063
 exaone_tiny jit_prefill 8 bef8ce0fd1a76f16
 exaone_tiny jit_prefill 32 97273e3848493a7b
-lm_tiny jit_step a02103c5d56a3b9d
+lm_tiny jit_step 78299685fcc0f486
 lm_tiny jit_prefill 8 9e15d6c4c644b793
 lm_tiny jit_prefill 32 b43409b6a90dd3e3
 sambay_tiny jit_step 4de5912459323169

@@ -43,8 +43,8 @@ for plan in "$@"; do
       --workload "$cell" --seed "$seed" --seconds 30 > "$out/_run.log" 2>&1 )
   rc=$?
   mv "$root/.bench_side" "$root/$dir"
-  grep "^compared\|^reference check\|^device memory" "$out/_run.log" | cut -c1-240
+  grep "^compared\|^reference check\|^device memory\|^itl_ms" "$out/_run.log" | cut -c1-240
   setup=$(grep "^set-up" "$out/_run.log" | head -n 1)
-  echo "{\"side\": \"$side\", \"seed\": $seed, \"trace\": \"$trace\", \"rc\": $rc, \"wall_s\": $(( $(date +%s) - t0 )), \"setup_line\": \"$setup\", \"result\": $(tail -n 1 "$out/_run.log")}" \
+  echo "{\"side\": \"$side\", \"seed\": $seed, \"trace\": \"$trace\", \"rc\": $rc, \"wall_s\": $(( $(date +%s) - t0 )), \"setup_line\": \"$setup\", \"result\": $(grep '^{"correct"' "$out/_run.log" | tail -n 1)}" \
     | tee -a "$log" | cut -c1-3000
 done

@@ -105,8 +105,9 @@ def write_account(run, out, reader):
     out.write("%s: setup_s %.3f\n" % (run["cell"]["name"], run["setup_s"]))
     for key in PARTS:
         out.write("  %-18s %10.3f\n" % (key, parts[key]))
-    out.write("records kept: %d of at most 4096 (%d before the ramp)\n"
-              % (len(compile_cache.phases()), len(heard)))
+    out.write("records kept: %d, %d dropped (%d before the ramp)\n"
+              % (len(compile_cache.phases()),
+                 compile_cache.stats().get("phases_dropped", 0), len(heard)))
     out.write("\nset-up spans (start after the process's, seconds, then "
               "the trace | lower | load | compile inside, same thread):\n")
     for r in spans:
@@ -141,7 +142,7 @@ def write_account(run, out, reader):
     for p in sorted(heard, key=lambda p: p[2] - p[3])[:16]:
         out.write("  %8.3f %8.3f  %-7s %s\n"
                   % (p[2] - start, p[3] - p[2], p[0], p[1]))
-    n = 20000
+    n = 2000    # fewer than phases() has room for
     t = time.perf_counter()
     for _ in range(n):
         compile_cache._on_duration(compile_cache._EVENT_TRACE, 1e-6,
